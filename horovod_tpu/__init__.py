@@ -17,10 +17,6 @@ Bindings:
 """
 
 from horovod_tpu.version import __version__
-from horovod_tpu.common import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from horovod_tpu.jax import *  # noqa: F401,F403 — flagship binding at top level
 from horovod_tpu.jax import __all__ as _jax_all
 

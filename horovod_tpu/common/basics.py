@@ -58,18 +58,7 @@ def init(comm: Optional[Sequence[int]] = None, devices=None) -> None:
             # the TPU pod runtime); a connect failure must propagate —
             # swallowing it would leave this rank world-size 1 while its
             # peers block on the barrier, with zero diagnostics.
-            # jax.distributed.is_initialized() is a recent addition; the
-            # 0.4.x era exposes the same fact as the singleton state's
-            # live client (the exact check is_initialized wraps).
-            if hasattr(jax.distributed, "is_initialized"):
-                already_up = jax.distributed.is_initialized()
-            else:
-                from jax._src import distributed as _dist
-
-                already_up = (
-                    getattr(_dist.global_state, "client", None)
-                    is not None)
-            if not already_up:
+            if not jax.distributed.is_initialized():
                 jax.distributed.initialize(
                     coordinator_address=jax_coord,
                     num_processes=int(os.environ["HOROVOD_SIZE"]),

@@ -112,8 +112,6 @@ def _run_over_process_mesh(body, cache_key, x, out_rows_per_proc: bool):
     from jax.experimental import multihost_utils
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.parallel.spmd import _SHARD_MAP_CHECK_KW, _shard_map
-
     mesh = _process_mesh()
     g = multihost_utils.host_local_array_to_global_array(x[None], mesh,
                                                          P("proc"))
@@ -125,9 +123,9 @@ def _run_over_process_mesh(body, cache_key, x, out_rows_per_proc: bool):
             return body(t[0], "proc")[None] if out_rows_per_proc else body(
                 t[0], "proc")
 
-        compiled = jax.jit(_shard_map(
+        compiled = jax.jit(jax.shard_map(
             per_rank, mesh=mesh, in_specs=P("proc"), out_specs=out_spec,
-            **{_SHARD_MAP_CHECK_KW: False}))
+            check_vma=False))
     _EXCHANGE_CACHE[key] = compiled
     while len(_EXCHANGE_CACHE) > _EXCHANGE_CACHE_MAX:
         _EXCHANGE_CACHE.pop(next(iter(_EXCHANGE_CACHE)))

@@ -8,8 +8,8 @@ long-context support is a first-class extension of this rebuild (SURVEY
 §5 "Long-context / sequence parallelism: absent").
 
 ``flash_attention`` is a Pallas TPU kernel (online-softmax tiling so the
-L x L score matrix never materializes in HBM); off-TPU it runs in
-interpreter mode so tests cover the same code path. On the causal square
+L x L score matrix never materializes in HBM); on the CPU test platform
+it runs in interpreter mode so tests cover the same code path. On the causal square
 path all three streamed kernels (forward, dQ, dK/dV) execute a PACKED
 at-or-below-diagonal grid — the strictly-masked half of the (q-block,
 k-block) plane never occupies a grid step, so neither its K/V DMA bytes
@@ -27,9 +27,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from horovod_tpu.utils.device import pallas_interpret
+
 NEG_INF = -1e30  # finite stand-in for -inf: exp() of it is exactly 0
 
-# Measured dense/flash crossover on the LM lane (PERF.md round-5 honest
+# Measured dense/flash crossover on the LM lane (PERF.md pre-round
 # adjudication #2): dense still wins at seq 2048 (-6%), flash wins 1.31x
 # at seq 4096 and is the only structurally-compiling path beyond it.
 # ``bench.py --attention auto`` selects by this threshold so nobody
@@ -309,7 +311,7 @@ def _pick_block(cap: int, seq_len: int) -> int:
 
 
 def _default_blocks(seq_q: int, seq_k: int):
-    """Measured tiling policy (TPU v5e block sweep, PERF.md round 5):
+    """Measured tiling policy (TPU v5e block sweep, PERF.md pre-round):
     256x512 won at seq 2048 (1.29x vs the old 128x128 default) and
     256x256 at seq 4096 (1.35x) — larger k-blocks amortize the online
     softmax rescale until the streamed K/V footprint presses VMEM, so
@@ -346,8 +348,8 @@ def flash_attention(q, k, v, causal: bool = False,
     Sequence lengths must be multiples of the block sizes (pad upstream).
     Block sizes default to the measured-on-TPU policy in
     :func:`_default_blocks`; pass explicit values to override.
-    ``interpret`` defaults to True off-TPU so the same kernel is testable
-    on the CPU mesh.
+    ``interpret`` defaults to the platform's: compiled on a TPU,
+    interpreted on the CPU test platform.
 
     ``q_offset``/``k_offset`` (static) are the global positions of the
     first query/key token, matching :func:`dot_product_attention` — so
@@ -374,7 +376,7 @@ def flash_attention(q, k, v, causal: bool = False,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     dq, dk = _default_blocks(q.shape[1], k.shape[1])
     if block_q is None:
         block_q = dq
@@ -414,9 +416,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    q_offset=0, k_offset=0, truncate=None):
     """Returns (out [B, Lq, H, D], lse [B, H, Lq])."""
     from jax.experimental import pallas as pl
-
-    from horovod_tpu.common.jax_compat import pallas_tpu
-    pltpu = pallas_tpu()
+    from jax.experimental.pallas import tpu as pltpu
 
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
@@ -746,9 +746,7 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     grids stay full and blocks entirely on the masked side of the
     diagonal skip their compute only."""
     from jax.experimental import pallas as pl
-
-    from horovod_tpu.common.jax_compat import pallas_tpu
-    pltpu = pallas_tpu()
+    from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
     B, Lq, H, D = q.shape
@@ -871,7 +869,7 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
 
 
 # Key length at/above which the kernel backward takes over from the
-# scan backward by default (measured crossover, PERF.md round 5).
+# scan backward by default (measured crossover, PERF.md pre-round).
 _FLASH_BWD_PALLAS_MIN_LK = 8192
 
 
@@ -895,7 +893,7 @@ def resolve_bwd_impl(bwd_impl: Optional[str], seq_k: int) -> str:
 
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
                    q_offset, k_offset, truncate, res, do):
-    """Backward dispatch, measured not assumed (PERF.md round 5): the
+    """Backward dispatch, measured not assumed (PERF.md pre-round): the
     scan backward's batched einsums win at short key lengths; the
     O(block)-VMEM kernel split is required at long ones (the scan's
     per-block [B, H, Lq, block_k] slabs scale with Lq). ``bwd_impl``

@@ -48,6 +48,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.utils.device import pallas_interpret
+
 # Keep the whole [K, N] weight + one [block_m, K] input tile + the f32
 # accumulator resident in VMEM; fall back to the unfused path when the
 # estimate exceeds this budget (v4/v5 VMEM is 16 MB; leave headroom for
@@ -188,15 +190,7 @@ def _forward(x, w, a, b, interpret: bool):
     operands, vma = _vma_align(*operands)
 
     def out_struct(shape, dtype):
-        # Legacy jax (check_rep era) has no vma kwarg on ShapeDtypeStruct
-        # — and no vma typing at all, so _vma_align always returns the
-        # empty set there and plain structs are exactly right. Passing
-        # the kwarg only when a nonempty set needs expressing keeps one
-        # code path valid on both runtimes (same compat discipline as
-        # common/jax_compat.py).
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
     kernel = _make_kernel(prologue, m if (prologue and pad) else None, bm)
     y, s1, s2 = pl.pallas_call(
@@ -300,7 +294,7 @@ matmul_prologue_bn_stats.defvjp(_matmul_prologue_fwd, _matmul_prologue_bwd)
 
 def _nhwc_wrap(op, x, w, strides, interpret, *affine):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if w.ndim == 4:
         assert w.shape[:2] == (1, 1), w.shape
         w = w[0, 0]

@@ -10,15 +10,15 @@ a request at position ``t`` pays HBM traffic proportional to the
 configured ``Lmax``, not to ``t``.
 
 :func:`paged_attention_decode` kills that gather: a Pallas kernel whose
-grid walks ``(slot, head, page-step)`` with the page tables and
-per-slot lengths SCALAR-PREFETCHED (the ``PrefetchScalarGridSpec``
-step-table technique of the packed causal flash grid), so each step's
-K/V ``BlockSpec`` index maps straight to the slot's next PHYSICAL page
-— Mosaic streams ``[page_size, D]`` K/V tiles through double-buffered
-VMEM DMA while an online-softmax state (m/l/acc scratch) accumulates
-across the page walk. The dense intermediate never exists, and the
-pages a slot streams are exactly its ``ceil((t+1)/page_size)`` LIVE
-pages:
+grid walks ``(slot, page-step)`` with the page tables and per-slot
+lengths SCALAR-PREFETCHED (the ``PrefetchScalarGridSpec`` step-table
+technique of the packed causal flash grid), so each step's K/V
+``BlockSpec`` index maps straight to the slot's next PHYSICAL page —
+Mosaic streams one whole ``[page_size, H, D]`` page (every head) per
+step through double-buffered VMEM DMA while an online-softmax state
+(m/l/acc scratch, one row per head) accumulates across the page walk.
+The dense intermediate never exists, and the pages a slot streams are
+exactly its ``ceil((t+1)/page_size)`` LIVE pages:
 
 * the page axis is the grid's innermost ("arbitrary") dimension, and
   steps past a slot's last live page clamp their index map to that
@@ -33,11 +33,20 @@ pages:
   :data:`~horovod_tpu.ops.attention.NEG_INF` before the running max,
   exactly the reference cache mask.
 
-Off-TPU the kernel runs in interpreter mode (the flash discipline), so
-the whole path — ragged lengths, page-boundary edges, the null page —
-is CI-pinned on CPU; :func:`paged_grid_info` is the static accounting
-twin (the ``flash_grid_info`` pattern) that serve_bench stamps into
-records and tests assert against.
+Block shapes: Mosaic wants a block's last two dimensions divisible by
+the (8, 128) tile or equal to the array's. With ``H`` and ``D`` in the
+last two positions of both the query ``[S, H, D]`` and the pages
+``[P, ps, H, D]``, the only block that is legal for every head count
+(12 unsharded, 3 per shard at tp=4) takes them whole. One query row per
+head is no matmul: the body is a broadcast multiply and a lane
+reduction over ``[ps, H, D]``, with no in-kernel transpose or
+relayout.
+
+On the CPU test platform the kernel runs in interpreter mode (the flash
+discipline), so the whole path — ragged lengths, page-boundary edges,
+the null page — is CI-pinned; :func:`paged_grid_info` is the static
+accounting twin (the ``flash_grid_info`` pattern) that serve_bench
+stamps into records and tests assert against.
 """
 
 from __future__ import annotations
@@ -50,26 +59,27 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.ops.attention import NEG_INF
+from horovod_tpu.utils.device import pallas_interpret
 
 
 def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
                          o_ref, m_scr, l_scr, acc_scr, *,
                          page_size: int, scale: float):
-    """One (slot, head, page-step) grid step.
+    """One (slot, page-step) grid step over every head.
 
-    ``q_ref`` is the slot's single query row for this head
-    ``[1, D]``; ``k_ref``/``v_ref`` are one physical page's slice for
-    the head ``[page_size, 1, D]`` (the index maps resolved the page
-    table BEFORE the body runs — scalar prefetch); the online-softmax
-    state persists in VMEM scratch across the page walk (grid axis 2 is
-    sequential). Shapes stay 2-D everywhere (the [1, D] query row is
-    the MQA/GQA group-of-one layout the reference TPU paged-attention
-    kernel uses; the statistics are [1, 1] columns — the Mosaic
-    discipline of ops/attention.py)."""
+    ``q_ref`` is the slot's query ``[H, D]``; ``k_ref``/``v_ref`` are
+    one physical page ``[page_size, H, D]`` (the index maps resolved
+    the page table BEFORE the body runs — scalar prefetch); the
+    online-softmax state persists in VMEM scratch across the page walk
+    (grid axis 1 is sequential): ``[H, 1]`` columns for m and l,
+    ``[H, D]`` for the accumulator. ``H`` and ``D`` stay in the tiled
+    (sublane, lane) positions throughout: scores are a lane reduction
+    kept as ``[ps, H, 1]``, and the page axis — the leading, untiled
+    one — is what the softmax statistics reduce over."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     live = lens_ref[s]                          # keys 0..t  (t+1 of them)
     live_pages = (live + page_size - 1) // page_size   # 0 for idle lanes
 
@@ -81,30 +91,25 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j < live_pages)
     def _compute():
-        # Input-dtype matmuls with f32 accumulation (the flash-kernel
-        # discipline); all softmax statistics stay f32.
-        q = q_ref[...]                          # [1, D]
-        k_blk = k_ref[...][:, 0, :]             # [ps, D]
-        v_blk = v_ref[...][:, 0, :]
-        sc = jnp.dot(q, k_blk.T,
-                     preferred_element_type=jnp.float32) * scale  # [1, ps]
+        # All arithmetic in f32 on the VPU; the page upcasts on load.
+        q = q_ref[...].astype(jnp.float32)              # [H, D]
+        k = k_ref[...].astype(jnp.float32)              # [ps, H, D]
+        v = v_ref[...].astype(jnp.float32)
+        sc = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
         # The cache mask: key positions past t (unwritten rows of the
         # last live page) contribute exactly zero — same NEG_INF
         # spelling as the reference kernel, applied BEFORE the running
         # max so garbage rows can never leak into the statistics.
         k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        sc = jnp.where(k_pos < live, sc, NEG_INF)
+            jnp.int32, sc.shape, 0)
+        sc = jnp.where(k_pos < live, sc, NEG_INF)       # [ps, H, 1]
         m = m_scr[...]
-        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m, jnp.max(sc, axis=0))     # [H, 1]
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(sc - m_new)
+        p = jnp.exp(sc - m_new[None])                   # [ps, H, 1]
         m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=0)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.sum(p * v, axis=0)
 
     # Idle lanes (live_pages == 0) finalize at j == 0 with the zeroed
     # scratch: a deterministic all-zero output row (discarded upstream).
@@ -143,13 +148,12 @@ def paged_attention_decode(q, k_pages, v_pages, tables, lengths,
     ``ceil((t+1)/ps)`` is a MAPPED page (never 0) — the scheduler's
     ``ensure_pages``/reserve-admission invariant.
 
-    ``interpret`` defaults to True off-TPU so the same kernel is
-    CI-testable on the CPU mesh (the flash-kernel discipline).
+    ``interpret`` defaults to the platform's (compiled on a TPU,
+    interpreted on the CPU test platform —
+    :func:`horovod_tpu.utils.device.pallas_interpret`).
     """
     from jax.experimental import pallas as pl
-
-    from horovod_tpu.common.jax_compat import pallas_tpu
-    pltpu = pallas_tpu()
+    from jax.experimental.pallas import tpu as pltpu
 
     S, H, D = q.shape
     P, ps, Hk, Dk = k_pages.shape
@@ -165,7 +169,7 @@ def paged_attention_decode(q, k_pages, v_pages, tables, lengths,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     def _page(s, j, tables, lengths):
         # The slot's next LIVE page; steps past the last live page
@@ -177,28 +181,22 @@ def paged_attention_decode(q, k_pages, v_pages, tables, lengths,
 
     kernel = functools.partial(_paged_decode_kernel, page_size=ps,
                                scale=float(scale))
+    row = pl.BlockSpec((None, H, D), lambda s, j, t, ln: (s, 0, 0))
+    page = pl.BlockSpec((None, ps, H, D),
+                        lambda s, j, t, ln: (_page(s, j, t, ln), 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # Page steps ride the INNERMOST axis (sequential, "arbitrary")
         # so the scratch-carried softmax state is legal while Mosaic
-        # double-buffers the per-page K/V tile DMAs; slots and heads
-        # are independent ("parallel").
-        grid=(S, H, pps),
-        in_specs=[
-            pl.BlockSpec((None, 1, D), lambda s, h, j, t, ln: (s, h, 0)),
-            pl.BlockSpec((None, ps, 1, D),
-                         lambda s, h, j, t, ln: (_page(s, j, t, ln),
-                                                 0, h, 0)),
-            pl.BlockSpec((None, ps, 1, D),
-                         lambda s, h, j, t, ln: (_page(s, j, t, ln),
-                                                 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, 1, D),
-                               lambda s, h, j, t, ln: (s, h, 0)),
+        # double-buffers the per-page K/V DMAs; slots are independent
+        # ("parallel").
+        grid=(S, pps),
+        in_specs=[row, page, page],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),    # running max m
-            pltpu.VMEM((1, 1), jnp.float32),    # running sum l
-            pltpu.VMEM((1, D), jnp.float32),    # output accumulator
+            pltpu.VMEM((H, 1), jnp.float32),    # running max m
+            pltpu.VMEM((H, 1), jnp.float32),    # running sum l
+            pltpu.VMEM((H, D), jnp.float32),    # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -206,7 +204,7 @@ def paged_attention_decode(q, k_pages, v_pages, tables, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q, k_pages, v_pages)

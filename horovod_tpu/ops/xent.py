@@ -164,21 +164,11 @@ _fce.defvjp(_fce_fwd, _fce_bwd)
 # Vocab-parallel (tensor-parallel head) variant.
 
 
-def _vp_chunk_stats(hc, w_local, tc, axis, v_local, descale_grads=False):
+def _vp_chunk_stats(hc, w_local, tc, axis, v_local):
     """One chunk's per-token (global lse, global target logit) when the
-    vocab axis is sharded over mesh axis ``axis``. ``descale_grads``
-    is the plain-autodiff path's psum-transpose correction
-    (:func:`_descale_grad`); the custom VJP never differentiates
-    through here and leaves it off."""
+    vocab axis is sharded over mesh axis ``axis``."""
     logits = jnp.dot(hc, w_local, preferred_element_type=jnp.float32)
-    if descale_grads:
-        logits = _descale_grad(logits, axis)
-    # stop_gradient on the stabilizer is EXACT (the log-sum-exp max
-    # shift's gradient contributions cancel identically) and lets the
-    # legacy plain-autodiff path (_vp_plain) differentiate through this
-    # function — pmax has no differentiation rule on 0.4.x runtimes.
-    gmax = lax.stop_gradient(
-        lax.pmax(jnp.max(lax.stop_gradient(logits), axis=-1), axis))
+    gmax = lax.pmax(jnp.max(logits, axis=-1), axis)
     lse = gmax + jnp.log(lax.psum(
         jnp.sum(jnp.exp(logits - gmax[:, None]), axis=-1), axis))
     offset = lax.axis_index(axis) * v_local
@@ -209,91 +199,10 @@ def tp_vocab_cross_entropy(h, w_local, targets, axis: str,
     non-differentiable bookkeeping and are ``stop_gradient``-ed at
     entry — a learnable weighting must be applied outside this op.
     """
-    from horovod_tpu.parallel._vma import vma_typing_available
-
     weights, denom = _fill_defaults(h, weights, denom)
     weights = lax.stop_gradient(weights)
     denom = lax.stop_gradient(denom)
-    if not vma_typing_available():
-        # Legacy (check_rep-era) runtimes cannot run the custom-VJP
-        # spelling: the old scan checker rejects the psum-collapsed
-        # carry type ("mismatched replication types" — lax.pcast
-        # polyfills to identity, so the carry can never be typed), and
-        # the shard_map TRANSPOSE machinery dies on the VJP's rank-0
-        # residuals (_SpecError on float32[]; rank-0 values have no dim
-        # to carry the stacking axis names). Fall back to the SAME
-        # chunk math, unrolled, under plain autodiff — numerically
-        # identical loss/grads (pinned vs dense in tests/test_xent.py)
-        # at the cost of autodiff saving per-chunk logits, i.e. the
-        # op's HBM win is traded for correctness on runtimes that
-        # cannot express it. The 3-test tier-1 class this closes was
-        # carried since PR 1.
-        return _vp_plain(h, w_local, targets, weights, denom, axis,
-                         t_chunk)
     return _vp(h, w_local, targets, weights, denom, axis, t_chunk)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _descale_grad(x, axis):
-    """Identity whose backward divides by the ``axis`` size.
-
-    Every path from the local logits to :func:`_vp_plain`'s loss crosses
-    exactly one raw ``lax.psum`` (the lse normalizer or the masked
-    target pick), and a raw psum's transpose is psum — the classic
-    gotcha (see parallel/tp.py:tp_region_output) that scales the
-    cotangent by the axis size while keeping only this rank's shard
-    term. Dividing here restores the exact per-rank dl the custom VJP
-    computes, so dw_local comes out as the dense dw's vocab slice."""
-    return x
-
-
-def _descale_fwd(x, axis):
-    return x, None
-
-
-def _descale_bwd(axis, _, g):
-    return (g / lax.axis_size(axis),)
-
-
-_descale_grad.defvjp(_descale_fwd, _descale_bwd)
-
-
-def _vp_plain(h, w_local, targets, weights, denom, axis, t_chunk):
-    """The vocab-parallel CE as a plain (non-custom-VJP) unrolled chunk
-    loop — the legacy-runtime fallback of :func:`tp_vocab_cross_entropy`.
-
-    Two conjugates make IN-REGION autodiff (a ``jax.grad`` taken inside
-    the shard_map body — the training path, models/parallel_lm.py)
-    reproduce the custom VJP's gradient conventions exactly:
-    :func:`_descale_grad` on the local logits undoes the psum-transposed
-    cotangent's axis-size scaling (leaving dw rank-local, the dense
-    slice), and ``tp_region_input`` on ``h`` assembles dh across the
-    vocab shards (each rank's backward only carries its own slice's
-    term; the true dh is their sum). Rank-1 accumulator on purpose: a
-    rank-0 axis-varying value is exactly what the old rewrite machinery
-    cannot name.
-
-    Known legacy limitation: differentiating THROUGH the shard_map
-    boundary (``jax.grad`` outside the region) double-corrects —
-    the boundary transpose is already exact there, and without vma
-    typing the op cannot mark its assembled cotangents as invariant,
-    so ``dw`` comes out axis-size-times small at a legacy boundary.
-    Modern runtimes reconcile both conventions through vma typing
-    (``_vp``'s typed residuals); legacy cannot express it, so the
-    through-boundary grad pins are version-gated xfails in
-    tests/test_xent.py while the in-region pins (the convention every
-    in-repo caller uses) hold on every runtime."""
-    from horovod_tpu.parallel.tp import tp_region_input
-
-    h = tp_region_input(h, axis)
-    hcs, tcs, wcs = _chunked(h, targets, weights, t_chunk)
-    v_local = w_local.shape[1]
-    total = jnp.zeros((1,), jnp.float32)
-    for i in range(hcs.shape[0]):
-        lse, tgt = _vp_chunk_stats(hcs[i], w_local, tcs[i], axis, v_local,
-                                   descale_grads=True)
-        total = total + jnp.sum((lse - tgt) * wcs[i]).reshape(1)
-    return (total / denom)[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))

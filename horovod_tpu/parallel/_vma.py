@@ -26,24 +26,6 @@ def vma_of(*arrays) -> frozenset:
     return union
 
 
-try:
-    from jax._src import config as _jax_config
-
-    _CHECK_VMA_FLAG = _jax_config._check_vma
-except (ImportError, AttributeError):  # pragma: no cover - jax internals
-    _CHECK_VMA_FLAG = None
-
-
-def vma_typing_available() -> bool:
-    """Whether this jax types shard_map values with varying-manual-axes
-    (the check_vma regime). Legacy runtimes (check_rep era) return
-    False. Used to gate optimizations whose transpose rules only
-    type-check under vma — e.g. ring attention's causal dead-block skip
-    is a rank-divergent ``lax.cond`` whose GRADIENT the old check_rep
-    machinery cannot unify (its own error suggests check_rep=False)."""
-    return _CHECK_VMA_FLAG is not None
-
-
 def vma_checking() -> bool:
     """Whether the enclosing shard_map traces with check_vma=True.
 
@@ -54,20 +36,16 @@ def vma_checking() -> bool:
     gradients by the axis size — if a jax upgrade moves the internal,
     fail loudly here instead. Pinned by
     tests/test_parallel.py::test_vma_checking_tracks_region."""
-    if _CHECK_VMA_FLAG is None:
-        if not hasattr(jax, "typeof"):
-            # Legacy runtime (no jax.typeof): vma TYPING does not exist
-            # at all, so the enclosing shard_map can only be the
-            # untyped regime — a fact, not a guess. The untyped-branch
-            # reductions are pinned against dense gold on exactly these
-            # runtimes (tests/test_parallel_lm.py dense-parity suite).
-            return False
+    from jax._src import config as jax_config
+
+    flag = getattr(jax_config, "_check_vma", None)
+    if flag is None:
         raise RuntimeError(
             "jax no longer exposes jax._src.config._check_vma; "
             "horovod_tpu.parallel._vma.vma_checking must be updated for "
             "this jax version (guessing would silently mis-scale "
             "gradients)")
-    return bool(_CHECK_VMA_FLAG.value)
+    return bool(flag.value)
 
 
 def reduce_cotangent(g, axis: str, mean: bool, invariant_loss: bool = False):

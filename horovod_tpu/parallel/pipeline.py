@@ -84,11 +84,17 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x,
 
     from horovod_tpu.parallel._vma import match_vma
 
-    # Zero-init carries typed varying like the stage weights/input so the
-    # fori_loop carry types match under check_vma=True.
-    vma_refs = (x, *jax.tree_util.tree_leaves(params))
-    current0 = match_vma(jnp.zeros(micro_shape, x.dtype), *vma_refs)
-    outputs0 = match_vma(jnp.zeros((M,) + micro_shape, x.dtype), *vma_refs)
+    # Zero-init carries typed exactly as the loop body types them under
+    # check_vma=True: varying over the stage axis (the rank-selected
+    # inject), over whatever the input varies over, and over whatever
+    # the STAGE'S OUTPUT varies over — not over every axis a weight is
+    # sharded on. A tp-sharded stage ends in a psum over tp, so its
+    # output (and this function's result) is tp-invariant, and typing
+    # the carry from the weights would make the caller's out_specs lie.
+    current0 = match_vma(jnp.zeros(micro_shape, x.dtype), x, rank)
+    current0 = match_vma(current0,
+                         jax.eval_shape(stage_fn, params, current0))
+    outputs0 = match_vma(jnp.zeros((M,) + micro_shape, x.dtype), current0)
     _, outputs = lax.fori_loop(0, n_ticks, tick, (current0, outputs0))
 
     # Only the last stage holds real outputs; replicate them to all chips

@@ -31,7 +31,7 @@ from horovod_tpu.parallel.logical import module_axis
 
 def ring_attention(q, k, v, axis: Optional[str] = None, causal: bool = False,
                    scale: Optional[float] = None,
-                   skip_dead_blocks: Optional[bool] = None):
+                   skip_dead_blocks: bool = True):
     """Exact multi-head attention over a sequence-sharded mesh axis.
 
     Shapes (per chip): q, k, v [B, L_local, H, D] -> [B, L_local, H, D].
@@ -40,11 +40,9 @@ def ring_attention(q, k, v, axis: Optional[str] = None, causal: bool = False,
     the gathered sequence exactly.
 
     ``skip_dead_blocks`` (causal only) conditionally skips the einsums
-    for visiting blocks entirely above this shard's diagonal. The
-    default (None) enables it exactly when the runtime's vma typing can
-    transpose the rank-divergent cond (see the in-loop note); the
-    explicit values exist for A/B and for CI on legacy runtimes, where
-    the cond path is only legal under ``check_vma=False`` regions.
+    for visiting blocks entirely above this shard's diagonal; ``False``
+    runs the numerically identical unconditional masked update (the
+    A/B side).
     """
     axis = module_axis("seq", axis)
     if scale is None:
@@ -84,13 +82,7 @@ def ring_attention(q, k, v, axis: Optional[str] = None, causal: bool = False,
             # flash kernels' truncated grid; ~half the ring steps on a
             # causal square). Only the local compute is conditional:
             # the ppermute rotation below stays unconditional, since
-            # every rank must feed the collective on every step. Off by
-            # default on legacy (no-vma-typing) runtimes: the check_rep
-            # machinery cannot unify this rank-divergent cond's
-            # TRANSPOSE (dead-branch symbolic-zero cotangents type
-            # replicated), so there the unconditional — numerically
-            # identical — masked update runs instead; CI still pins the
-            # cond path through check_vma=False regions.
+            # every rank must feed the collective on every step.
             has_live = rank * Lq + Lq - 1 >= src * Lk
             m, l, acc = lax.cond(has_live, _update,
                                  lambda operand: operand[2:],
@@ -103,10 +95,7 @@ def ring_attention(q, k, v, axis: Optional[str] = None, causal: bool = False,
         v_next = lax.ppermute(v_blk, axis, perm)
         return k_next, v_next, m, l, acc
 
-    from horovod_tpu.parallel._vma import match_vma, vma_typing_available
-
-    if skip_dead_blocks is None:
-        skip_dead_blocks = vma_typing_available()
+    from horovod_tpu.parallel._vma import match_vma
 
     # Type the zero-init carries as varying like q/k/v so the loop body's
     # carry-out matches under check_vma=True (values unchanged).

@@ -30,27 +30,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from horovod_tpu.common import state as _state
 from horovod_tpu.parallel.logical import DATA_AXIS
 
-# jax.shard_map is the public top-level API on current jax (with the
-# varying-manual-axes checker spelled ``check_vma``); older jax ships
-# the same transform as jax.experimental.shard_map.shard_map with the
-# checker's predecessor spelled ``check_rep``. Resolve once at import so
-# the whole hvd.* dispatch harness (and everything built on it: bench,
-# the window loop, the gate lanes) runs on both. The checker kwarg is
-# read off the resolved function's OWN signature — promotion and rename
-# did not land in the same jax release, so inferring one from the other
-# would TypeError on the in-between versions.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep")
-
-
 def _default_mesh() -> Mesh:
     st = _state.global_state()
     st.require_init()
@@ -142,12 +121,12 @@ def spmd_fn(
                 st.dispatch_host_local = saved_hl
                 _state.reset_spmd_axis(token)
 
-        return _shard_map(
+        return jax.shard_map(
             wrapped,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            **{_SHARD_MAP_CHECK_KW: check_vma},
+            check_vma=check_vma,
         )
 
     shmapped = _build_shmapped()

@@ -114,6 +114,38 @@ def _worker_env(base: Dict[str, str], rank: int, size: int, local_rank: int,
     return env
 
 
+def _local_tpu_chips() -> List[str]:
+    """Device nodes of this host's TPU chips (what libtpu opens), found
+    without importing jax — the launcher must never hold a chip."""
+    import glob
+
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_shared_chips(placements: List[tuple],
+                         env: Dict[str, str]) -> None:
+    """The SPMD lane is one process per host (docs/tpus.md): every local
+    rank gets the same environment, so each would open every chip of
+    the host, and a chip belongs to one process at a time. Nothing here
+    assigns chips per local rank — refuse at launch instead of letting
+    rank 1 fail or hang inside libtpu."""
+    local = [p for p in placements
+             if p[0] is None or p[0] in ("localhost", "127.0.0.1")]
+    if len(local) < 2 or env.get("JAX_PLATFORMS") == "cpu":
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise LaunchError(
+            f"{len(local)} local ranks on a host with {len(chips)} TPU "
+            "chip(s): the SPMD lane is one process per host — one "
+            "process drives every chip (hvd.init() meshes "
+            "jax.devices()), and a chip belongs to one process at a "
+            "time. Launch one rank per host (-np 1, or -H host:1,...), "
+            "or set JAX_PLATFORMS=cpu for ranks that do not use the "
+            "chips.")
+
+
 def _parse_hosts(hosts: str) -> List[tuple]:
     """Parse ``host1:4,host2:4`` into [(host, slots), ...]
     (reference horovodrun -H syntax)."""
@@ -364,6 +396,7 @@ def launch_job(cmd: Sequence[str], np: int,
                 placements.append((host, lr, slots))
     else:
         placements = [(None, r, np) for r in range(np)]
+    _refuse_shared_chips(placements, base_env)
 
     first_host = placements[0][0]
     if first_host is None or first_host in ("localhost", "127.0.0.1"):
@@ -459,9 +492,10 @@ def run(fn, args: tuple = (), kwargs: Optional[dict] = None, np: int = 1,
     """Run ``fn(*args, **kwargs)`` on ``np`` local ranks; returns the list
     of per-rank return values, rank-ordered (reference horovod.spark.run
     semantics, spark/__init__.py:80-196)."""
+    base_env = dict(env if env is not None else os.environ)
+    _refuse_shared_chips([(None, r, np) for r in range(np)], base_env)
     key = make_secret_key()
     driver = Driver(np, key, fn=fn, args=args, kwargs=kwargs)
-    base_env = dict(env if env is not None else os.environ)
     secret_hex = key.hex()
     controller = f"127.0.0.1:{_free_port()}"
     # Publish EVERY candidate endpoint (loopback + per-NIC addresses);

@@ -595,13 +595,17 @@ class ServeEngine:
     request lifecycle; :meth:`submit` queues work, :meth:`step` runs
     one compiled step (returns False when fully idle), :meth:`run`
     drains to idle. ``clock`` is injectable for deterministic tests.
+    ``device`` pins an unsharded (tp=1) engine — parameters, pages and
+    therefore its compiled step — to one device, so several replicas in
+    one process each own a chip; ``None`` leaves JAX's default device.
     """
 
     def __init__(self, params: Dict, config: ServeConfig, *,
-                 chips: int = 1, clock=time.perf_counter):
+                 chips: int = 1, clock=time.perf_counter, device=None):
         self.config = config
         self.chips = chips
         self.clock = clock
+        self.device = device
         #: Bound LogicalMesh + tensor axis + degree (mesh=None -> tp=1).
         #: Fail-fast happens HERE (device budget, divisibility), never
         #: at first compile.
@@ -610,24 +614,24 @@ class ServeEngine:
         kv_sharding = None
         self._param_specs = None
         if self.tp > 1:
-            import jax
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
 
             from horovod_tpu.models.parallel_lm import lm_param_specs
 
-            mesh = self.logical_mesh.mesh
             # Megatron param placement + head-sharded pages: the DATA
             # plane. Specs double as the shard_map in/out_specs below.
             self._param_specs = lm_param_specs(
                 len(params["layers"]), self._tp_axis,
                 vocab_parallel=True)
-            params = jax.tree_util.tree_map(
-                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-                params, self._param_specs)
             self._kv_spec = P(None, None, self._tp_axis, None)
-            kv_sharding = NamedSharding(mesh, self._kv_spec)
-        self.params = params
+            kv_sharding = NamedSharding(self.logical_mesh.mesh,
+                                        self._kv_spec)
+        elif device is not None:
+            from jax.sharding import SingleDeviceSharding
+
+            kv_sharding = SingleDeviceSharding(device)
+        self.params = params = self._place_params(params)
         #: Speculative decoding plane (``config.speculate_k`` > 0):
         #: static k compiled into the step, layer-skip draft depth
         #: resolved against THIS model (0 = auto: half the depth, at
@@ -723,36 +727,47 @@ class ServeEngine:
         if self.tp > 1:
             from jax.sharding import PartitionSpec as P
 
-            from horovod_tpu.parallel.spmd import (
-                _SHARD_MAP_CHECK_KW,
-                _shard_map,
-            )
-
             mesh = self.logical_mesh.mesh
             kv = self._kv_spec
             # dec/pre arrive replicated (P() prefix over the host
             # dicts), pages head-sharded in AND out, logits replicated
             # full-vocab (the step's all-gather makes them so).
-            untyped = {_SHARD_MAP_CHECK_KW: False}
             # The spec step returns (pages, ver_logits, draft_toks,
             # draft_logits, pre_logits) — two extra replicated outputs
             # over the base step's (pages, dec_logits, pre_logits).
             n_rep = 4 if self.spec_k else 2
-            self._step_mixed = jax.jit(_shard_map(
+            self._step_mixed = jax.jit(jax.shard_map(
                 lambda p, pages, dec, pre: step(p, pages, dec, pre),
                 mesh=mesh,
                 in_specs=(self._param_specs, kv, P(), P()),
-                out_specs=(kv,) + (P(),) * n_rep, **untyped))
-            self._step_decode = jax.jit(_shard_map(
+                out_specs=(kv,) + (P(),) * n_rep, check_vma=False))
+            self._step_decode = jax.jit(jax.shard_map(
                 lambda p, pages, dec: step(p, pages, dec, None),
                 mesh=mesh,
                 in_specs=(self._param_specs, kv, P()),
-                out_specs=(kv,) + (P(),) * n_rep, **untyped))
+                out_specs=(kv,) + (P(),) * n_rep, check_vma=False))
         else:
             self._step_mixed = jax.jit(step)
             self._step_decode = jax.jit(
                 lambda params, pages, dec: step(params, pages, dec,
                                                 None))
+
+    def _place_params(self, params: Dict) -> Dict:
+        """Where the compiled step expects the weights: head / feature
+        / vocab shards over the tp mesh, or whole on this engine's
+        pinned device."""
+        import jax
+
+        if self.tp > 1:
+            from jax.sharding import NamedSharding
+
+            mesh = self.logical_mesh.mesh
+            return jax.tree_util.tree_map(
+                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+                params, self._param_specs)
+        if self.device is not None:
+            return jax.device_put(params, self.device)
+        return params
 
     # ------------------------------------------------------ submission
 
@@ -1275,17 +1290,7 @@ class ServeEngine:
                 f"{tuple(new)} vs the engine's {tuple(old)} — a "
                 "geometry change needs a fresh engine, not a weight "
                 "swap")
-        if self.tp > 1:
-            # Same placement as construction: the compiled sharded
-            # step expects head/feature/vocab shards, not replicas.
-            import jax
-            from jax.sharding import NamedSharding
-
-            mesh = self.logical_mesh.mesh
-            params = jax.tree_util.tree_map(
-                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-                params, self._param_specs)
-        self.params = params
+        self.params = self._place_params(params)
         if self.prefix is not None:
             # K/V rows are a function of the weights: stale-version
             # pages must never serve a new-version request.

@@ -590,6 +590,7 @@ class ServeFleet:
         self.chips = chips_per_replica * self.fleet.replicas
         self.clock = clock
         self._sleep = sleep
+        self._refuse_local_chip_workers(worker_env or {})
 
         # Static admission geometry (survives every replica dying):
         # exactly PagedKVCache.fits, computed off params + config —
@@ -736,6 +737,38 @@ class ServeFleet:
 
             self.disagg = DisaggCoordinator(self)
 
+    def _refuse_local_chip_workers(self, worker_env: Dict[str, str]
+                                   ) -> None:
+        """One process holds a TPU chip at a time. This process has
+        touched JAX to make ``params``; if its platform is ``tpu`` it
+        holds every chip of the host, and a local worker process that
+        also wants the TPU would fail or hang until ``spawn_timeout``.
+        Nothing in the tree assigns chips per worker, so raise now
+        with the way out instead."""
+        if self.fleet.transport == "inproc":
+            return
+        if self.fleet.transport == "tcp" and self.fleet.hosts:
+            from horovod_tpu.serve.config import (LOCAL_HOSTS,
+                                                  parse_host_entry)
+
+            if not any(parse_host_entry(h)[0] in LOCAL_HOSTS
+                       for h in self.fleet.hosts):
+                return
+        if {**os.environ, **worker_env}.get("JAX_PLATFORMS") == "cpu":
+            return
+        import jax
+
+        if jax.devices()[0].platform != "tpu":
+            return
+        raise RuntimeError(
+            f"ServeFleet(transport={self.fleet.transport!r}): this "
+            "process holds the host's TPU chips (a chip belongs to one "
+            "process) and local worker processes would need them. Use "
+            "transport='inproc' — one process drives every chip, "
+            "replica i on device i — or place the workers on other "
+            "hosts (FleetConfig.hosts), or run them on the CPU "
+            "(worker_env={'JAX_PLATFORMS': 'cpu'}).")
+
     def close(self) -> None:
         """Tear the fleet down and release its host-side footprint.
         Idempotent; a closed fleet can no longer step.
@@ -798,9 +831,16 @@ class ServeFleet:
             pass
         if self.fleet.transport == "process":
             return self._spawn_process(rid, hb)
+        import jax
+
+        # Replica i on device i: parameters, pages and compiled step.
+        # A tp mesh spans devices itself and stays where it binds.
+        devices = jax.devices()
+        device = (devices[rid % len(devices)]
+                  if self.config.tp_degree == 1 else None)
         engine = ServeEngine(self.params, self.config,
                              chips=self.chips_per_replica,
-                             clock=self.clock)
+                             clock=self.clock, device=device)
         rep = Replica(rid, engine, hb)
         # In-process engines share the fleet's params object directly —
         # no wire, so the version stamp lands at spawn.

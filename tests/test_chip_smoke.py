@@ -1,0 +1,144 @@
+"""The chip gate, checked from the CPU: what must NOT run here, and the
+one decision (where the compile cache lives) that is a pure function.
+
+``chip_smoke.py`` itself passes only on a TPU (the driver runs it there
+after every PR); its ``--rehearsal`` — the same phases at toy sizes with
+interpreted kernels — is the slow-lane proof that the script's own code
+still runs.
+"""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+
+
+def _run(script, *args, timeout=120, **env_over):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("HVD_TPU_FORCE_CPU", "XLA_FLAGS")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env.update(env_over)
+    return subprocess.run([sys.executable, str(REPO / script), *args],
+                          env=env, cwd=str(REPO), capture_output=True,
+                          text=True, timeout=timeout)
+
+
+@pytest.mark.parametrize("script,args", [
+    ("chip_smoke.py", ()),
+    ("bench.py", ("--probe-only",)),
+])
+def test_measuring_entry_points_refuse_the_cpu(script, args):
+    """No TPU, no explicit CPU switch: non-zero exit naming the platform,
+    before any compile, and no result line on stdout. (For bench.py this
+    is also the replacement of the supervisor's tests: a run that did not
+    measure is a non-zero exit code, never a record.)"""
+    proc = _run(script, *args)
+    assert proc.returncode != 0
+    assert "platform" in proc.stderr and "'cpu'" in proc.stderr
+    assert not [line for line in proc.stdout.splitlines()
+                if line.startswith("{")]
+
+
+def test_compile_cache_dir_is_a_function_of_the_environment():
+    from horovod_tpu.utils.compile_cache import cache_dir
+
+    assert cache_dir({"JAX_COMPILATION_CACHE_DIR": "/x"}) is None
+    assert cache_dir({}) == str(REPO / ".jax_cache")
+    assert cache_dir({"JAX_COMPILATION_CACHE_DIR": ""}) == \
+        str(REPO / ".jax_cache")
+
+
+def test_launcher_refuses_local_ranks_that_would_share_chips(monkeypatch):
+    """A chip belongs to one process: N > 1 local ranks on a chip host are
+    refused at launch with the reason, never left to hang in libtpu."""
+    from horovod_tpu import run
+
+    monkeypatch.setattr(run, "_local_tpu_chips", lambda: ["/dev/vfio/0"])
+    two_local = [(None, 0, 2), (None, 1, 2)]
+    with pytest.raises(run.LaunchError, match="one process per host"):
+        run._refuse_shared_chips(two_local, {})
+    run._refuse_shared_chips(two_local, {"JAX_PLATFORMS": "cpu"})
+    run._refuse_shared_chips([(None, 0, 1), ("otherhost", 0, 1)], {})
+    monkeypatch.setattr(run, "_local_tpu_chips", lambda: [])
+    run._refuse_shared_chips(two_local, {})
+
+
+def test_fleet_refuses_local_chip_workers(monkeypatch):
+    """A router that holds the host's chips cannot give them to local
+    worker processes: ServeFleet raises at construction, before any
+    spawn, instead of waiting out spawn_timeout."""
+    import types
+
+    import jax
+
+    from horovod_tpu.serve import FleetConfig, ServeConfig, ServeFleet
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [types.SimpleNamespace(platform="tpu")])
+    cfg = ServeConfig(page_size=8, num_pages=8, decode_slots=1,
+                      prefill_chunk=4)
+    for transport in ("process", "tcp"):
+        with pytest.raises(RuntimeError, match="one process"):
+            ServeFleet({}, cfg, FleetConfig(replicas=1, transport=transport))
+
+
+def test_kernels_compile_for_a_tpu_from_here():
+    """libtpu compiles for a v5e topology without a chip: the paged
+    decode kernel (whole-head blocks, 12 heads and the 3 a tp=4 shard
+    holds) and the packed-grid flash forward/dQ/dK-dV kernels go through
+    Mosaic itself, not the interpreter."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.attention import flash_attention
+    from horovod_tpu.ops.paged_attention import paged_attention_decode
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu in this environment
+        pytest.skip(f"no TPU compiler here: {exc}")
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def compiled_text(fn, *args):
+        lowered = jax.jit(fn).lower(*args)
+        lowered.compile()
+        return lowered.as_text()
+
+    for heads in (12, 3):
+        text = compiled_text(
+            lambda q, k, v, t, n: paged_attention_decode(
+                q, k, v, t, n, interpret=False),
+            spec((8, heads, 64)), spec((32, 16, heads, 64)),
+            spec((32, 16, heads, 64)), spec((8, 4), jnp.int32),
+            spec((8,), jnp.int32))
+        assert "tpu_custom_call" in text
+
+    def flash_loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               bwd_impl="pallas").astype(jnp.float32).sum()
+
+    qkv = spec((1, 512, 2, 64), jnp.bfloat16)
+    text = compiled_text(jax.grad(flash_loss, argnums=(0, 1, 2)),
+                         qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_rehearsal_passes():
+    proc = _run("chip_smoke.py", "--rehearsal", timeout=1500)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "rehearsal" in proc.stdout and ": pass" not in proc.stdout
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last == {"ok": False, "device": {"platform": "cpu",
+                                            "kind": "cpu", "count": 4}}

@@ -649,13 +649,8 @@ def test_vma_checking_tracks_region(hvd):
     detector must read True/False inside matching shard_map regions —
     the typed/untyped gradient reductions branch on it, so a jax upgrade
     that moves the internal must fail THIS test loudly, not mis-scale
-    gradients silently. On legacy runtimes with NO vma typing at all
-    (jax.typeof absent; check_vma maps onto check_rep), the detector
-    must report False in BOTH regions: the old rewrite machinery does
-    not do the typed-regime cotangent reduction, so the untyped-branch
-    reductions are the correct ones — pinned end-to-end by the
-    dense-parity suites (tests/test_parallel_lm.py)."""
-    from horovod_tpu.parallel._vma import vma_checking, vma_typing_available
+    gradients silently."""
+    from horovod_tpu.parallel._vma import vma_checking
 
     seen = {}
 
@@ -670,10 +665,7 @@ def test_vma_checking_tracks_region(hvd):
                           out_specs=P()))(jnp.ones((4,)))
     jax.jit(jax.shard_map(probe("untyped"), mesh=m, in_specs=P(),
                           out_specs=P(), check_vma=False))(jnp.ones((4,)))
-    if vma_typing_available():
-        assert seen == {"typed": True, "untyped": False}
-    else:
-        assert seen == {"typed": False, "untyped": False}
+    assert seen == {"typed": True, "untyped": False}
 
 
 class TestMoE:

@@ -1,7 +1,7 @@
 """Chunked fused cross-entropy (ops/xent.py) vs the dense composition.
 
 The dense reference materializes [T, V] logits and log-softmaxes them —
-exactly what the LM bench's unfused loss does (bench.py bench_lm); the
+exactly what the LM bench's unfused loss does (bench.py build_lm_lane); the
 fused op must match its loss and gradients while never building the
 full logits tensor.
 """
@@ -139,20 +139,6 @@ class TestVocabParallel:
         lv, (vdh, vdw) = jax.value_and_grad(loss_vp, argnums=(0, 1))(h, w)
 
         np.testing.assert_allclose(float(lv), float(ld), rtol=1e-6)
-        from horovod_tpu.parallel._vma import vma_typing_available
-        if not vma_typing_available():
-            # Legacy (check_rep-era) runtimes: the loss is exact (above)
-            # but differentiating THROUGH the shard_map boundary cannot
-            # coexist with the op's in-region gradient convention — the
-            # legacy fallback (_vp_plain) corrects for in-region
-            # transposes (what every in-repo caller does; pinned below
-            # in test_loss_and_grads_match_dense_in_region), and without
-            # vma typing the boundary transpose double-corrects dw.
-            # Tracking: ops/xent.py _vp_plain docstring.
-            pytest.xfail("legacy check_rep boundary transpose cannot "
-                         "express the op's in-region gradient "
-                         "convention (dw scales by tp size); in-region "
-                         "grads are pinned exact on this runtime")
         np.testing.assert_allclose(np.asarray(vdh), np.asarray(gdh),
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(vdw), np.asarray(gdw),

@@ -3,7 +3,7 @@
 The native coordinator's runtime mismatch checks (op/dtype/root/shape/
 ragged, ``csrc/coordinator.cc``), made STATIC: any entry program is
 traced via ``jax.make_jaxpr`` on CPU (no devices, no compilation), the
-closed jaxpr is walked recursively through ``pjit``/``scan``/``cond``/
+closed jaxpr is walked recursively through ``jit``/``scan``/``cond``/
 ``while``/``shard_map``/``custom_vjp`` sub-jaxprs, and the extracted
 collective schedule — op kind, axis names, shapes, dtypes, issue order,
 wire bytes — is checked against the HVV rule catalogue

@@ -15,9 +15,8 @@ import sys
 from typing import Sequence
 
 # The sweep traces under an 8-device virtual CPU mesh (no chips, no
-# compilation). Must land before jax initializes a backend; the repo's
-# sitecustomize may import jax at startup, so jax.config is the
-# reliable platform override (same pattern as tests/conftest.py).
+# compilation), set before jax loads.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=8").strip()
@@ -70,10 +69,6 @@ def main(argv: Sequence[str] = None) -> int:
     names = [n.strip() for n in args.program.split(",") if n.strip()]
     if not (args.sweep or groups or names):
         parser.error("nothing to do: pass --sweep, --group or --program")
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from tools.hvdverify.core import verify_programs
 

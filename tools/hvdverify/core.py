@@ -113,7 +113,7 @@ def verify(
     try:
         with warnings.catch_warnings():
             # Nested-donation warnings are expected: tracing a dispatch
-            # handle under make_jaxpr nests its pjit, and HVV104 judges
+            # handle under make_jaxpr nests its jit, and HVV104 judges
             # the donation flags itself.
             warnings.simplefilter("ignore")
             closed = jax.make_jaxpr(fn)(*args)

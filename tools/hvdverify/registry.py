@@ -147,7 +147,7 @@ def _abstract_train_state(model, optimizer, sample):
 def _image_lane(model_name, *, image=64, per_chip=2, overlap=None,
                 zero=False, window=1, num_classes=100):
     """A driver-gate image lane: models.build -> make_train_step ->
-    spmd_fn with the state donated — bench.py's bench_image composition
+    spmd_fn with the state donated — bench.py's build_image_lane composition
     (window>1 adds the stage_synthetic_window scan, the --steps-per-
     dispatch lane)."""
 
@@ -209,7 +209,7 @@ def _image_lane(model_name, *, image=64, per_chip=2, overlap=None,
 
 def _lm_lane(*, fused_ce=False, seq=256, per_chip=1, layers=4, dim=256,
              heads=4, vocab=1024):
-    """The transformer_lm gate lane: bench.py's bench_lm step (dense
+    """The transformer_lm gate lane: bench.py's build_lm_lane step (dense
     attention; the fused_ce variant routes the loss through
     ops/xent.fused_cross_entropy exactly as --fused-ce does)."""
 
@@ -421,15 +421,14 @@ def _submesh(axes: Dict[str, int]):
 
 
 def _shmapped(fn, mesh, in_specs, out_specs):
-    """Raw shard_map in the repo's version-compat spelling (the legacy
-    checker cannot type these rank-programs; the wire bytes and the
+    """Raw shard_map with the varying-axes checker off (these
+    rank-programs are deliberately rank-varying; the wire bytes and the
     schedule are what hvdverify pins — same opt-out class as
     tests/test_wire_bytes.py)."""
-    from horovod_tpu.parallel.spmd import _SHARD_MAP_CHECK_KW, _shard_map
+    import jax
 
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs,
-                      **{_SHARD_MAP_CHECK_KW: False})
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _build_parallel_spmd():

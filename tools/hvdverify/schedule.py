@@ -6,7 +6,7 @@ fire mid-negotiation, and a rank-divergent collective simply deadlocks
 the job. Under XLA the whole rank program is one traced artifact, so the
 same questions are decidable at TRACE time: this module walks a closed
 jaxpr recursively through every higher-order primitive
-(``pjit``/``scan``/``while``/``cond``/``shard_map``/``custom_vjp``/
+(``jit``/``scan``/``while``/``cond``/``shard_map``/``custom_vjp``/
 ``remat``) and extracts the **collective schedule** — op kind, axis
 names, shapes, dtypes, issue order, and payload bytes per collective —
 plus the walk-local facts the HVV rules need:
@@ -56,7 +56,7 @@ class CollectiveOp:
     dtype: str                # operand dtype name
     payload_bytes: int        # sum of array-operand bytes (one execution)
     index: int                # issue order within the traced program
-    path: str                 # higher-order context, e.g. "pjit:step/scan"
+    path: str                 # higher-order context, e.g. "jit:step/scan"
     times: Optional[int]      # static execution count (None: unknown —
                               # nested under a while loop)
     name_stack: str           # jax named_scope stack (fusion tags buckets
@@ -259,16 +259,13 @@ class ScheduleWalker:
                     eqn.params["jaxpr"], eqn, f"{path}/shard_map",
                     frozenset(bound_axes) | set(names), tainted, mult)
 
-            elif prim in ("custom_vjp_call_jaxpr", "custom_jvp_call",
-                          "custom_vjp_call"):
-                body = eqn.params.get("fun_jaxpr",
-                                      eqn.params.get("call_jaxpr"))
+            elif prim in ("custom_jvp_call", "custom_vjp_call"):
+                body = eqn.params.get("call_jaxpr")
                 if body is not None:
                     self._descend(body, eqn, f"{path}/{prim}", bound_axes,
                                   tainted, mult)
 
-            elif prim in ("pjit", "closed_call", "core_call", "xla_call",
-                          "remat2", "remat", "checkpoint", "named_call"):
+            elif prim in ("jit", "closed_call", "call", "remat2"):
                 body = eqn.params.get("jaxpr",
                                       eqn.params.get("call_jaxpr"))
                 if body is not None:
@@ -299,7 +296,7 @@ class ScheduleWalker:
         self.walk(body, path=path, bound_axes=bound_axes,
                   tainted=inner_taint, mult=mult)
         # Taint born INSIDE the sub-jaxpr (axis_index under a nested
-        # pjit/remat/scan) must surface, or a cond on the call's result
+        # jit/remat/scan) must surface, or a cond on the call's result
         # is misclassified as uniform: align inner outvars to the call's
         # outvars from the end and lift.
         for outer, inner in zip(reversed(list(eqn.outvars)),

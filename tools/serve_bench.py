@@ -223,6 +223,13 @@ def run_continuous(params, cfg, workload, warm=True):
     eng = ServeEngine(params, cfg, chips=cfg.tp_degree)
     if warm:
         _warm(eng, workload)
+    return drive_continuous(eng, workload)
+
+
+def drive_continuous(eng, workload):
+    """The open-loop clock over a built (and warmed) engine: requests
+    enter when the wall clock passes their arrival; returns the engine
+    drained."""
     pending = sorted(workload, key=lambda w: w[0])
     t0 = eng.clock()
     eng._t_start = t0
@@ -447,11 +454,12 @@ def pin_exact(params, eng):
                 f"{req.output} lm_decode={ref}")
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__)
-    from tools.lm_common import (add_model_args, build_params,
-                                 validate_model_args)
+def build_parser() -> argparse.ArgumentParser:
+    """The serve_bench CLI; its defaults are the geometry and traffic
+    ``chip_smoke.py`` drives too."""
+    from tools.lm_common import add_model_args
 
+    ap = argparse.ArgumentParser(description=__doc__)
     add_model_args(ap)
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--rate", type=float, default=2.0,
@@ -611,6 +619,39 @@ def main() -> int:
                          "for every finished request")
     ap.add_argument("--require-finished", action="store_true",
                     help="exit nonzero unless every request finished")
+    return ap
+
+
+def build_config(args, system_prompt_len: int = 0):
+    """``(ServeConfig, lmax)`` for the argparse'd geometry: Lmax covers
+    the worst request (incl. the shared system prompt), rounded up to
+    whole pages; ``--num-pages 0`` sizes the pool to every decode slot
+    plus one prefill at Lmax. Raises ``ValueError`` on a bad --mesh."""
+    from horovod_tpu.serve import ServeConfig
+
+    ps = args.page_size
+    lmax = -(-(system_prompt_len + args.prompt_max + args.new_max)
+             // ps) * ps
+    num_pages = args.num_pages
+    if num_pages <= 0:
+        num_pages = (args.decode_slots + 1) * (lmax // ps) + 1
+    cfg = ServeConfig(
+        page_size=ps, num_pages=num_pages,
+        decode_slots=args.decode_slots,
+        prefill_chunk=args.prefill_chunk, policy=args.policy,
+        slo=args.slo, admission=args.admission,
+        attention=args.attention,
+        prefix_caching=args.prefix,
+        mesh=args.mesh or None,
+        speculate_k=args.speculate,
+        draft_layers=args.draft_layers)
+    return cfg, lmax
+
+
+def main() -> int:
+    from tools.lm_common import build_params, validate_model_args
+
+    ap = build_parser()
     args = ap.parse_args()
     validate_model_args(ap, args)
     if args.requests < 1 or args.rate <= 0:
@@ -758,36 +799,28 @@ def main() -> int:
                          "the params-push wire — use --fleet-transport "
                          "process or tcp")
 
-    from horovod_tpu.serve import ServeConfig
-
-    # Lmax covers the worst request (incl. the shared system prompt),
-    # rounded up to whole pages.
     ps = args.page_size
     spl = args.system_prompt_len
     if spl < 0:
         spl = 4 * ps if args.ab_prefix else 0
-    lmax = -(-(spl + args.prompt_max + args.new_max) // ps) * ps
-    pages_per_seq = lmax // ps
-    num_pages = args.num_pages
-    if num_pages <= 0:
-        num_pages = (args.decode_slots + 1) * pages_per_seq + 1
     try:
-        cfg = ServeConfig(
-            page_size=ps, num_pages=num_pages,
-            decode_slots=args.decode_slots,
-            prefill_chunk=args.prefill_chunk, policy=args.policy,
-            slo=args.slo, admission=args.admission,
-            attention=args.attention,
-            prefix_caching=args.prefix,
-            mesh=args.mesh or None,
-            speculate_k=args.speculate,
-            draft_layers=args.draft_layers)
+        cfg, lmax = build_config(args, spl)
     except ValueError as e:          # bad --mesh string: fail at argparse
         ap.error(str(e))
+    num_pages = cfg.num_pages
     if args.ab_tp and cfg.tp_degree < 2:
         ap.error(f"--ab-tp needs a sharded side: --mesh {args.mesh!r} "
                  f"resolves to tp={cfg.tp_degree}")
 
+    from horovod_tpu.utils import compile_cache
+    from horovod_tpu.utils.device import require_tpu
+
+    compile_cache.enable()
+    device = require_tpu(
+        cpu_requested=os.environ.get("JAX_PLATFORMS") == "cpu")
+    print(f"[serve_bench] device: {device['platform']} / "
+          f"{device['device_kind']} x {device['count']}",
+          file=sys.stderr, flush=True)
     params = build_params(args, lmax)
     workload = make_workload(args, system_prompt_len=spl)
 
@@ -1292,6 +1325,7 @@ def main() -> int:
         "value": headline["tokens_per_sec_per_chip"],
         "unit": "tokens/sec/chip",
         "vs_baseline": None,
+        "device": device,
         "serve": serve,
         "config": {
             "page_size": ps, "num_pages": num_pages,
