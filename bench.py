@@ -452,6 +452,7 @@ def build_lm_lane(args, log) -> Lane:
                 return jnp.mean(nll)
 
         loss, grads = jax.value_and_grad(loss_fn)(state["params"])
+        state, loss = models.read_before_update(state, loss)
         return models.apply_gradients(optimizer, state, grads), loss
 
     batch = {"tokens": jax.random.randint(

@@ -48,14 +48,10 @@ class Sizes:
     dp_tol_resnet: float   # |four-chip - one-chip| first-step loss, relative
 
 
-#: An LM lane's first-step loss against lm_reference_loss, relative.
-#: The flash + fused-CE lane reports the reference's value to four
-#: decimals on the chip (10.8904). The dense lane's step program reports
-#: 0.97% less (10.7850) than the reference finds on the same parameters,
-#: at step one and after every update, while its parameters move exactly
-#: as the other lane's do: PERF.md section 7 has it as an open question,
-#: and this bound is that deviation doubled, not bf16's.
-REF_TOL, DENSE_REF_TOL = 0.002, 0.02
+#: An LM lane's first-step loss against lm_reference_loss, relative: every
+#: lane reports the reference's value to four decimals on the chip
+#: (10.8904 dense and flash at seq 2048; PERF.md "Bring-up").
+REF_TOL = 0.002
 
 
 # bench.py's and serve_bench's own defaults are the measured width; only
@@ -228,7 +224,7 @@ def train_one_chip(sz, kernels_compiled):
     lm = (*sz.lm, "--batch-size", str(sz.lm_batch))
     train_phase(f"train lm dense seq {sz.seq_dense}",
                 (*lm, "--seq-len", str(sz.seq_dense)), sz.steps,
-                ref_tol=DENSE_REF_TOL)
+                ref_tol=REF_TOL)
     # The backward pinned to the Pallas kernels: `auto` would pick the
     # scan backward below 8192 keys and leave dQ and dK/dV uncompiled.
     flash = ("--attention", "flash", "--flash-bwd", "pallas", "--remat",
@@ -261,68 +257,72 @@ def train_all_chips(sz, one_chip_resnet, n):
         f"train lm dense seq {sz.seq_dense} dp={n}",
         (*sz.lm, "--batch-size", str(sz.lm_batch // n), "--seq-len",
          str(sz.seq_dense)), sz.steps, want_collective=True,
-        ref_tol=DENSE_REF_TOL)
+        ref_tol=REF_TOL)
 
 
 # ------------------------------------------------------------------ serve
 
 
-#: How far below the best reference logit a chosen token may sit and
-#: still count as a near-tie. On the chip the f32 matmuls take bf16
-#: passes, the kernel reduces in f32 in another order, and a random
-#: 32,000-way head has a runner-up within ~0.1 of the winner almost
-#: everywhere: greedy streams from two correct implementations part at
-#: such ties (PERF.md "Bring-up"). A wrong implementation picks tokens
-#: whole logit-widths (~1) down.
-TIE_TOL = 0.25
+#: How far below the best reference logit a greedy token may sit. On the
+#: chip the f32 matmuls take bf16 passes and the paged kernel reduces in
+#: f32 in another order, while along these streams a random 32,000-way
+#: head has its runner-up within 0.05 of the winner at one position in
+#: eight: two correct implementations part at such near-ties and their
+#: streams differ from there on (PERF.md "Bring-up"). The widest gap seen
+#: on the chip over every token of every engine is 0.010 and the bound is
+#: five of those; a random wrong token sits 2.6 or more down.
+TIE_TOL = 0.05
 
 
 @functools.cache
-def reference_forward():
-    """One jitted padded forward for every near-tie check: the weights are
-    an argument, so the four comparisons share one compile."""
+def reference_gaps():
+    """One jitted padded forward for every stream check: ``gap[i]`` is how
+    far ``tokens[i + 1]`` sits below the best logit at position ``i``. The
+    weights are an argument, so every engine's check shares one compile."""
     import jax
+    import jax.numpy as jnp
 
     from horovod_tpu.models import parallel_lm as plm
 
-    return jax.jit(lambda params, tokens: plm.lm_apply(params,
-                                                       tokens[None])[0])
+    def gaps(params, tokens):
+        logits = plm.lm_apply(params, tokens[None])[0, :-1]
+        chosen = jnp.take_along_axis(logits, tokens[1:, None], -1)[:, 0]
+        return logits.max(-1) - chosen
+
+    return jax.jit(gaps)
 
 
-def same_up_to_ties(name, params, workload, a, b):
-    """Two sets of greedy streams over the same requests. Where a pair
-    parts, the contexts differ from there on and later tokens say
-    nothing; what must hold is that AT the first differing position both
-    tokens are near-ties of the best under reference logits (f32,
-    highest matmul precision, one padded forward per parted pair).
-    Returns a sentence for the log."""
+def near_greedy(name, params, workload, streams):
+    """Every token of every stream is the reference's greedy choice GIVEN
+    THE STREAM'S OWN CONTEXT, up to a near-tie: one teacher-forced padded
+    forward per stream (f32, highest matmul precision). Unlike comparing
+    two streams token for token, this says something about every token
+    after a parting too."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     lmax = int(params["pos"].shape[0])
-    identical, worst = 0, 0.0
-    for (_, prompt, _), x, y in zip(workload, a, b):
-        at = next((i for i, (p, q) in enumerate(zip(x, y)) if p != q), None)
-        if at is None:
-            identical += 1
-            continue
-        context = np.concatenate([prompt, np.asarray(x[:at], np.int32)])
-        tokens = np.zeros((lmax,), np.int32)
-        tokens[:len(context)] = context       # causal: the pad is unseen
+    worst = 0.0
+    for rid, ((_, prompt, _), out) in enumerate(zip(workload, streams)):
+        n, m = len(prompt), len(out)
+        tokens = np.zeros((lmax,), np.int32)       # causal: the pad is unseen
+        tokens[:n + m] = np.concatenate([prompt, out])
         with jax.default_matmul_precision("highest"):
-            logits = np.asarray(reference_forward()(
-                params, jnp.asarray(tokens)), np.float32)[len(context) - 1]
-        gap = float(logits.max() - min(logits[x[at]], logits[y[at]]))
-        worst = max(worst, gap)
-        assert gap <= TIE_TOL, (
-            f"{name}: streams part at token {at} on {x[at]} vs {y[at]}, "
-            f"{gap:.3f} below the best reference logit (a near-tie is "
+            gap = np.asarray(reference_gaps()(
+                params, jnp.asarray(tokens)))[n - 1:n + m - 1]
+        at = int(gap.argmax())
+        assert gap[at] <= TIE_TOL, (
+            f"{name}: request {rid} token {at} ({out[at]}) sits "
+            f"{gap[at]:.3f} below the best reference logit (a near-tie is "
             f"within {TIE_TOL})")
-    return (f"{name}: {identical} of {len(a)} identical"
-            + (f", the rest part at near-ties (at most {worst:.3f} below "
-               f"the best reference logit, bound {TIE_TOL})"
-               if identical < len(a) else ""))
+        worst = max(worst, float(gap[at]))
+    say("serve streams", f"{name}: every token of {len(streams)} streams "
+        f"within {worst:.3f} of the best reference logit (bound {TIE_TOL})")
+
+
+def identical(a, b):
+    return f"{sum(x == y for x, y in zip(a, b))} of {len(a)}"
 
 
 def check_paged_kernel(sargs, cfg):
@@ -426,18 +426,22 @@ def serve_one_chip(sz, kernels_compiled, tag):
     cfg = dataclasses.replace(cfg, attention="paged")
     _, paged = serve_phase("serve paged", params, cfg, workload,
                            want_mosaic=kernels_compiled)
-    # lm_decode compiles once per prompt length: two requests bound it.
+    t0 = time.perf_counter()
     decode = jax.jit(plm.lm_decode, static_argnames=("steps",))
     ref = [list(np.asarray(decode(
         params, jnp.asarray(prompt, jnp.int32)[None], steps=n))[0])
-        for _, prompt, n in workload[:2]]
-    say("serve streams", same_up_to_ties(
-        "gather vs lm_decode", params, workload[:2], gather[:2], ref))
-    say("serve streams", same_up_to_ties(
-        "paged vs gather", params, workload, paged, gather))
+        for _, prompt, n in workload]
+    say("serve lm_decode", f"set-up: {len(ref)} streams incl. one compile "
+        f"a prompt length {time.perf_counter() - t0:.1f}s")
+    for name, streams in (("lm_decode", ref), ("gather", gather),
+                          ("paged", paged)):
+        near_greedy(name, params, workload, streams)
+    say("serve streams", f"identical: gather = lm_decode on "
+        f"{identical(gather, ref)}, paged = gather on "
+        f"{identical(paged, gather)}")
     if not kernels_compiled:
         # The CPU pins of the test suite, restated: bit-identical.
-        assert gather[:2] == ref and paged == gather, "CPU streams differ"
+        assert gather == ref and paged == gather, "CPU streams differ"
     say("serve", tag)
     return sargs.heads, cfg, params, workload, paged
 
@@ -457,9 +461,7 @@ def serve_all_chips(one_chip, n, tag):
     assert shard[2] == heads // n, (
         f"tp={n}: a shard holds {shard[2]} of {heads} heads")
     del eng
-    say("serve streams", same_up_to_ties(
-        f"tp={n} ({shard[2]} heads a shard) vs one chip", params, workload,
-        tp, one_chip_streams))
+    near_greedy(f"tp={n} ({shard[2]} heads a shard)", params, workload, tp)
 
     t0 = time.perf_counter()
     fleet, reqs = serve_bench.run_fleet(
@@ -480,9 +482,11 @@ def serve_all_chips(one_chip, n, tag):
             f"{time.perf_counter() - t0:.1f}s")
     finally:
         fleet.close()
-    say("serve streams", same_up_to_ties(
-        f"fleet (pages on {n} distinct devices, {busy} replicas stepped) "
-        "vs one chip", params, workload, fl, one_chip_streams))
+    near_greedy(f"fleet (pages on {n} distinct devices, {busy} replicas "
+                "stepped)", params, workload, fl)
+    say("serve streams", f"identical to the one-chip paged engine: tp={n} "
+        f"on {identical(tp, one_chip_streams)}, fleet on "
+        f"{identical(fl, one_chip_streams)}")
     say("serve all chips", tag)
 
 

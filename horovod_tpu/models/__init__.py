@@ -24,6 +24,7 @@ from horovod_tpu.models.train import (
     make_eval_step,
     make_train_step,
     make_windowed_train_step,
+    read_before_update,
     state_partition_specs,
 )
 from horovod_tpu.models import parallel_lm
@@ -84,5 +85,6 @@ __all__ = [
     "make_eval_step",
     "make_train_step",
     "make_windowed_train_step",
+    "read_before_update",
     "state_partition_specs",
 ]

@@ -147,6 +147,22 @@ def apply_gradients(
     )
 
 
+def read_before_update(state: TrainState, reads):
+    """Order a step's read-outs (loss, metrics) before its update.
+
+    The step's state is donated, so the update writes the new parameters
+    into the old ones' buffers, and only data dependence orders that write
+    after a read. XLA:TPU's rematerialisation does not keep to it: near the
+    HBM limit it recomputed the logits for the reported loss at the end of
+    the program, from the ``lm_head`` buffer the update had already
+    rewritten (the dense seq-2048 bs-8 LM step on a v5e reported 10.7850
+    for a loss of 10.8904; PERF.md "Bring-up"). The barrier hands the state
+    to the update only once ``reads`` exist, so nothing that produces them
+    can be scheduled behind an in-place write. An identity otherwise."""
+    reads, state = jax.lax.optimization_barrier((reads, state))
+    return state, reads
+
+
 def make_train_step(model, optimizer: optax.GradientTransformation, average_loss: bool = True):
     """Build the per-rank SPMD training step.
 
@@ -185,9 +201,11 @@ def make_train_step(model, optimizer: optax.GradientTransformation, average_loss
         if average_loss:
             loss = mpi_ops.allreduce(loss, average=True, name="train.loss")
             accuracy = mpi_ops.allreduce(accuracy, average=True, name="train.accuracy")
+        state, metrics = read_before_update(
+            state, {"loss": loss, "accuracy": accuracy})
         new_state = apply_gradients(optimizer, state, grads,
                                     batch_stats=new_stats)
-        return new_state, {"loss": loss, "accuracy": accuracy}
+        return new_state, metrics
 
     return train_step
 

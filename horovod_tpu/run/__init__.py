@@ -116,11 +116,31 @@ def _worker_env(base: Dict[str, str], rank: int, size: int, local_rank: int,
 
 def _local_tpu_chips() -> List[str]:
     """Device nodes of this host's TPU chips (what libtpu opens), found
-    without importing jax — the launcher must never hold a chip."""
+    without importing jax — the launcher must never hold a chip. On a
+    v5e host a chip is a PCI function of Google's vendor id (0x1ae0;
+    device 0x0063, class 0xff0000) whose IOMMU group has a
+    ``/dev/vfio/<group>`` node; earlier generations show as
+    ``/dev/accel<n>``. A VFIO group alone says nothing — GPU and NIC
+    passthrough make them too — and the vendor alone is also the cloud's
+    virtual NIC (class 0x02) and disk (class 0x01)."""
     import glob
 
-    return sorted(glob.glob("/dev/accel[0-9]*")
-                  + glob.glob("/dev/vfio/[0-9]*"))
+    chips = glob.glob("/dev/accel[0-9]*")
+    for dev in glob.glob("/sys/bus/pci/devices/*"):
+        try:
+            with open(os.path.join(dev, "vendor")) as f:
+                vendor = f.read().strip()
+            with open(os.path.join(dev, "class")) as f:
+                pci_class = f.read().strip()
+            group = os.path.basename(os.readlink(
+                os.path.join(dev, "iommu_group")))
+        except OSError:
+            continue
+        node = os.path.join("/dev/vfio", group)
+        if (vendor == "0x1ae0" and pci_class[:4] not in ("0x01", "0x02")
+                and os.path.exists(node)):
+            chips.append(node)
+    return sorted(chips)
 
 
 def _refuse_shared_chips(placements: List[tuple],
@@ -129,21 +149,25 @@ def _refuse_shared_chips(placements: List[tuple],
     rank gets the same environment, so each would open every chip of
     the host, and a chip belongs to one process at a time. Nothing here
     assigns chips per local rank — refuse at launch instead of letting
-    rank 1 fail or hang inside libtpu."""
+    rank 1 fail or hang inside libtpu. Ranks that never open the chips
+    (the torch and TensorFlow bindings over the native core, CPU JAX)
+    say so with ``JAX_PLATFORMS=cpu``."""
     local = [p for p in placements
              if p[0] is None or p[0] in ("localhost", "127.0.0.1")]
-    if len(local) < 2 or env.get("JAX_PLATFORMS") == "cpu":
+    platforms = env.get("JAX_PLATFORMS", "")
+    if len(local) < 2 or (platforms and "tpu" not in platforms.split(",")):
         return
     chips = _local_tpu_chips()
     if chips:
         raise LaunchError(
             f"{len(local)} local ranks on a host with {len(chips)} TPU "
-            "chip(s): the SPMD lane is one process per host — one "
-            "process drives every chip (hvd.init() meshes "
-            "jax.devices()), and a chip belongs to one process at a "
-            "time. Launch one rank per host (-np 1, or -H host:1,...), "
-            "or set JAX_PLATFORMS=cpu for ranks that do not use the "
-            "chips.")
+            f"chip(s) ({', '.join(chips)}): the SPMD lane is one process "
+            "per host — one process drives every chip (hvd.init() "
+            "meshes jax.devices()), and a chip belongs to one process "
+            "at a time. Launch one rank per host (-np 1, or -H "
+            "host:1,...). Ranks that do not open the chips (torch or "
+            "TensorFlow over the native core, JAX on the CPU) opt out "
+            "by launching with JAX_PLATFORMS=cpu in the environment.")
 
 
 def _parse_hosts(hosts: str) -> List[tuple]:

@@ -53,19 +53,62 @@ def test_compile_cache_dir_is_a_function_of_the_environment():
         str(REPO / ".jax_cache")
 
 
+def test_an_installed_package_writes_no_cache_beside_itself(monkeypatch,
+                                                            tmp_path):
+    """No ``bench.py`` beside the package: not a checkout, so nothing is
+    set in code (site-packages must not grow a ``.jax_cache``)."""
+    from horovod_tpu.utils import compile_cache
+
+    monkeypatch.setattr(compile_cache, "_CHECKOUT", str(tmp_path))
+    assert compile_cache.cache_dir({}) is None
+    assert compile_cache.cache_dir({"JAX_COMPILATION_CACHE_DIR": "/x"}) \
+        is None
+
+
 def test_launcher_refuses_local_ranks_that_would_share_chips(monkeypatch):
     """A chip belongs to one process: N > 1 local ranks on a chip host are
     refused at launch with the reason, never left to hang in libtpu."""
     from horovod_tpu import run
 
-    monkeypatch.setattr(run, "_local_tpu_chips", lambda: ["/dev/vfio/0"])
+    monkeypatch.setattr(run, "_local_tpu_chips", lambda: ["/dev/vfio/3"])
     two_local = [(None, 0, 2), (None, 1, 2)]
-    with pytest.raises(run.LaunchError, match="one process per host"):
+    with pytest.raises(run.LaunchError, match="one process per host") as err:
         run._refuse_shared_chips(two_local, {})
+    assert "JAX_PLATFORMS=cpu" in str(err.value)     # how to opt out
+    with pytest.raises(run.LaunchError):
+        run._refuse_shared_chips(two_local, {"JAX_PLATFORMS": "tpu,cpu"})
     run._refuse_shared_chips(two_local, {"JAX_PLATFORMS": "cpu"})
     run._refuse_shared_chips([(None, 0, 1), ("otherhost", 0, 1)], {})
     monkeypatch.setattr(run, "_local_tpu_chips", lambda: [])
     run._refuse_shared_chips(two_local, {})
+
+
+def test_a_tpu_is_told_from_other_passed_through_devices(monkeypatch,
+                                                        tmp_path):
+    """What the v5e host shows (vendor 0x1ae0, class 0xff0000, a VFIO
+    node for its IOMMU group) — not the cloud's NIC (same vendor), not a
+    GPU behind VFIO, not a chip whose group this host was not given."""
+    import glob
+
+    from horovod_tpu import run
+
+    for addr, vendor, pci_class, group in (
+            ("0000:00:04.0", "0x1ae0", "0x020000", 4),    # NIC under VFIO
+            ("0000:00:0a.0", "0x1ae0", "0xff0000", 0),    # chip, no node
+            ("0000:00:0b.0", "0x1ae0", "0xff0000", 3),    # chip
+            ("0000:00:06.0", "0x10de", "0x030200", 6)):   # GPU under VFIO
+        (tmp_path / addr).mkdir()
+        (tmp_path / addr / "vendor").write_text(vendor + "\n")
+        (tmp_path / addr / "class").write_text(pci_class + "\n")
+        (tmp_path / addr / "iommu_group").symlink_to(
+            f"../../kernel/iommu_groups/{group}")
+    nodes = {"/dev/vfio/3", "/dev/vfio/4", "/dev/vfio/6"}
+    exists = os.path.exists
+    monkeypatch.setattr(glob, "glob", lambda pattern: (
+        [str(p) for p in tmp_path.iterdir()] if "pci" in pattern else []))
+    monkeypatch.setattr(os.path, "exists",
+                        lambda path: path in nodes or exists(path))
+    assert run._local_tpu_chips() == ["/dev/vfio/3"]
 
 
 def test_fleet_refuses_local_chip_workers(monkeypatch):

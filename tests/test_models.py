@@ -63,6 +63,11 @@ def test_train_step_single_process(hvd, rng):
     assert int(state["step"]) == 10
     # Learns the fixed batch (dropout keeps it noisy; compare min to start).
     assert min(losses[3:]) < losses[0]
+    # The read-outs are ordered before the in-place update of the donated
+    # state (models.read_before_update). The hazard is XLA:TPU's; here one
+    # can only check that the barrier is in the program.
+    assert "optimization_barrier" in str(jax.make_jaxpr(
+        models.make_train_step(model, opt))(state, batch))
 
 
 def test_train_step_spmd_matches_large_batch(hvd, rng):

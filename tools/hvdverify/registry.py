@@ -255,6 +255,7 @@ def _lm_lane(*, fused_ce=False, seq=256, per_chip=1, layers=4, dim=256,
                     return jnp.mean(nll)
 
             loss, grads = jax.value_and_grad(loss_fn)(state["params"])
+            state, loss = models.read_before_update(state, loss)
             return models.apply_gradients(optimizer, state, grads), loss
 
         from horovod_tpu.parallel.logical import DATA_AXIS

@@ -483,11 +483,18 @@ def main() -> int:
     # `python tools/x.py` puts tools/ on sys.path, not the repo root —
     # every lane must import horovod_tpu regardless of entry location.
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    # Where the lanes keep their persistent compile cache (decided by
-    # horovod_tpu/utils/compile_cache.py in each child; read here only
-    # for the per-lane cache column).
-    cache_dir = (env.get("JAX_COMPILATION_CACHE_DIR")
-                 or os.path.join(REPO, ".jax_cache"))
+    # Where the lanes keep their persistent compile cache: each child's
+    # horovod_tpu/utils/compile_cache.py decides, and the per-lane cache
+    # column asks the same function. Loaded by path — importing the
+    # package imports jax, and this parent stays off it.
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "compile_cache",
+        os.path.join(REPO, "horovod_tpu", "utils", "compile_cache.py"))
+    compile_cache = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(compile_cache)
+    cache_dir = (compile_cache.cache_dir(env)
+                 or env["JAX_COMPILATION_CACHE_DIR"])
 
     results = {}
     for lane, cmd, *tags in LANES:

@@ -75,6 +75,7 @@ def build_step(args):
         def step_fn(state, tokens):
             loss, grads = jax.value_and_grad(
                 lambda p: loss_fn(p, tokens))(state["params"])
+            state, loss = models.read_before_update(state, loss)
             return models.apply_gradients(optimizer, state, grads), loss
     else:
         kwargs = {"fused_bn": True} if args.fused_bn else {}
