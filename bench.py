@@ -262,6 +262,7 @@ def build_image_lane(args, log) -> Lane:
 
     import horovod_tpu.jax as hvd
     from horovod_tpu import models
+    from horovod_tpu.utils.timeline import span
 
     n = hvd.size()
     batch_size = args.batch_size if args.batch_size is not None else 64
@@ -305,12 +306,13 @@ def build_image_lane(args, log) -> Lane:
     state_spec = models.state_partition_specs(state)
 
     global_batch = batch_size * n
-    batch = {
-        "image": jax.random.normal(
-            rng, (global_batch, args.image_size, args.image_size, 3),
-            jnp.float32),
-        "label": jax.random.randint(rng, (global_batch,), 0, 1000),
-    }
+    with span("hvd.lane.place"):
+        batch = {
+            "image": jax.random.normal(
+                rng, (global_batch, args.image_size, args.image_size, 3),
+                jnp.float32),
+            "label": jax.random.randint(rng, (global_batch,), 0, 1000),
+        }
 
     # One prebuilt compiled handle — no per-step cache lookup/hashing — with
     # the train state donated so XLA updates weights/momenta in place
@@ -325,14 +327,13 @@ def build_image_lane(args, log) -> Lane:
         out_specs=(state_spec, P()),
         donate_argnums=(0,),
     )
-    state, batch = place(state, state_spec), place(batch, batch_spec)
+    with span("hvd.lane.place"):
+        state, batch = place(state, state_spec), place(batch, batch_spec)
     log(f"Model: {args.model}, batch size {batch_size}/chip, {n} chips "
         f"({jax.devices()[0].platform})"
         + (f", {k}-step dispatch windows" if k > 1 else ""),
         file=sys.stderr)
-    stamp = overlap_stamp(args, state, log)
-    stamp.update(wire_stamp(args, state, log))
-    stamp.update(collectives_stamp(run_step, state, batch, log))
+    stamp = audit_stamps(args, run_step, state, batch, log)
     return Lane(model, run_step, state, batch, batch_size, "img/sec", stamp)
 
 
@@ -346,6 +347,7 @@ def build_lm_lane(args, log) -> Lane:
 
     import horovod_tpu.jax as hvd
     from horovod_tpu import models
+    from horovod_tpu.utils.timeline import FORWARD, LOSS, span
 
     if args.fused_bn:
         raise ValueError(
@@ -435,28 +437,34 @@ def build_lm_lane(args, log) -> Lane:
             from horovod_tpu.ops.xent import fused_cross_entropy
 
             def loss_fn(params):
-                hidden = model.apply({"params": params}, tokens,
-                                     train=False, return_hidden=True)
-                e = hidden.shape[-1]
-                h = hidden[:, :-1].reshape(-1, e).astype(jnp.float32)
-                wv = params["lm_head"]["kernel"].astype(jnp.float32)
-                return fused_cross_entropy(h, wv, tokens[:, 1:].reshape(-1))
+                with jax.named_scope(FORWARD):
+                    hidden = model.apply({"params": params}, tokens,
+                                         train=False, return_hidden=True)
+                with jax.named_scope(LOSS):
+                    e = hidden.shape[-1]
+                    h = hidden[:, :-1].reshape(-1, e).astype(jnp.float32)
+                    wv = params["lm_head"]["kernel"].astype(jnp.float32)
+                    return fused_cross_entropy(h, wv,
+                                               tokens[:, 1:].reshape(-1))
         else:
             def loss_fn(params):
-                logits = model.apply({"params": params}, tokens,
-                                     train=False)
-                logp = jax.nn.log_softmax(
-                    logits[:, :-1].astype(jnp.float32))
-                tgt = tokens[:, 1:]
-                nll = -jnp.take_along_axis(logp, tgt[..., None], -1)
-                return jnp.mean(nll)
+                with jax.named_scope(FORWARD):
+                    logits = model.apply({"params": params}, tokens,
+                                         train=False)
+                with jax.named_scope(LOSS):
+                    logp = jax.nn.log_softmax(
+                        logits[:, :-1].astype(jnp.float32))
+                    tgt = tokens[:, 1:]
+                    nll = -jnp.take_along_axis(logp, tgt[..., None], -1)
+                    return jnp.mean(nll)
 
         loss, grads = jax.value_and_grad(loss_fn)(state["params"])
         state, loss = models.read_before_update(state, loss)
         return models.apply_gradients(optimizer, state, grads), loss
 
-    batch = {"tokens": jax.random.randint(
-        rng, (batch_size * n, L), 0, args.vocab)}
+    with span("hvd.lane.place"):
+        batch = {"tokens": jax.random.randint(
+            rng, (batch_size * n, L), 0, args.vocab)}
     k = args.steps_per_dispatch
     step_fn, batch, batch_spec = apply_window(step_fn, batch, k)
     run_step = hvd.spmd_fn(
@@ -465,7 +473,8 @@ def build_lm_lane(args, log) -> Lane:
         out_specs=(state_spec, P()),
         donate_argnums=(0,),
     )
-    state, batch = place(state, state_spec), place(batch, batch_spec)
+    with span("hvd.lane.place"):
+        state, batch = place(state, state_spec), place(batch, batch_spec)
     grid_note = ""
     if flash_grid is not None:
         grid_note = (f", grid {flash_grid['steps']}/"
@@ -477,18 +486,33 @@ def build_lm_lane(args, log) -> Lane:
         f"({jax.devices()[0].platform}), {attention} attention{grid_note}"
         + (f", {k}-step dispatch windows" if k > 1 else ""),
         file=sys.stderr)
-    stamp = overlap_stamp(args, state, log)
-    stamp.update(wire_stamp(args, state, log))
-    stamp.update(collectives_stamp(run_step, state, batch, log))
+    stamp = audit_stamps(args, run_step, state, batch, log)
     return Lane(model, run_step, state, batch, batch_size * L,
                 "tokens/sec", {"attention": attention,
                                "flash_grid": flash_grid, **stamp})
 
 
+def audit_stamps(args, run_step, state, batch, log) -> dict:
+    """The lane's evidence fields (span ``hvd.lane.audit``)."""
+    from horovod_tpu.utils.timeline import span
+
+    with span("hvd.lane.audit"):
+        stamp = overlap_stamp(args, state, log)
+        stamp.update(wire_stamp(args, state, log))
+        stamp.update(collectives_stamp(run_step, state, batch, log))
+    return stamp
+
+
 def build_lane(args, log) -> Lane:
-    if args.model == "transformer_lm":
-        return build_lm_lane(args, log)
-    return build_image_lane(args, log)
+    """Span ``hvd.lane.build``; its children are ``hvd.lane.model_init`` and
+    ``hvd.lane.train_state`` (``models.create_train_state``),
+    ``hvd.lane.place`` and ``hvd.lane.audit``."""
+    from horovod_tpu.utils.timeline import span
+
+    with span("hvd.lane.build", model=args.model):
+        if args.model == "transformer_lm":
+            return build_lm_lane(args, log)
+        return build_image_lane(args, log)
 
 
 def measure_lane(lane: Lane, args, log):

@@ -43,108 +43,116 @@ def init(comm: Optional[Sequence[int]] = None, devices=None) -> None:
     with state.lock:
         if state.initialized:
             return
-        import jax
+        from horovod_tpu.utils import timeline
 
-        # Multi-host: when the launcher provides a jax coordinator
-        # (HOROVOD_JAX_COORDINATOR, set by `hvdrun --jax`), join the jax
-        # distributed runtime BEFORE the first backend query so every
-        # process sees the global device set — the analogue of the
-        # reference joining MPI_COMM_WORLD at init (operations.cc:1724).
-        # TPU pods that pre-initialize via the runtime env need nothing
-        # here, and single-process usage stays zero-config.
-        jax_coord = os.environ.get("HOROVOD_JAX_COORDINATOR", "")
-        if jax_coord and os.environ.get("HOROVOD_SIZE"):
-            # Skip only when the distributed runtime is ALREADY up (e.g.
-            # the TPU pod runtime); a connect failure must propagate —
-            # swallowing it would leave this rank world-size 1 while its
-            # peers block on the barrier, with zero diagnostics.
-            if not jax.distributed.is_initialized():
-                jax.distributed.initialize(
-                    coordinator_address=jax_coord,
-                    num_processes=int(os.environ["HOROVOD_SIZE"]),
-                    process_id=int(os.environ.get("HOROVOD_RANK", "0")),
-                )
-        state.config = Config.from_env()
-        state.devices = list(devices) if devices is not None else list(jax.devices())
-        if comm is not None:
-            # Ranks are chips on the SPMD lane, so the reference's
-            # rank-subset semantics (horovod_init(ranks, nranks),
-            # operations.cc:1728-1746) map to subsetting the mesh device
-            # list: hvd.init(comm=[0, 2]) builds a 2-chip job from chips
-            # 0 and 2 of the global order.
-            bad = [r for r in comm if not 0 <= r < len(state.devices)]
-            if bad:
-                raise InvalidArgumentError(
-                    f"comm ranks {bad} out of range for "
-                    f"{len(state.devices)} devices"
-                )
-            state.devices = [state.devices[r] for r in comm]
-            if jax.process_count() > 1 and not any(
-                getattr(d, "process_index", 0) == jax.process_index()
-                for d in state.devices
-            ):
-                # A process owning NO chip of the subset has no rank; two
-                # such processes would otherwise both report rank 0 and
-                # double-run every rank-0-gated action (checkpoint writes,
-                # logs). Exclude the process at launch instead.
-                raise InvalidArgumentError(
-                    "hvd.init(comm=...) selected no chips owned by this "
-                    "process; multi-host subsets must cover every "
-                    "participating process (exclude the others at the "
-                    "launcher level)."
-                )
-        state.process_index = jax.process_index()
-        state.process_count = jax.process_count()
-        if devices is not None or comm is not None:
-            local_indices = [
-                i
-                for i, d in enumerate(state.devices)
-                if getattr(d, "process_index", 0) == jax.process_index()
-            ]
-            state.local_device_count = len(local_indices)
-            state.global_device_count = len(state.devices)
-            state.first_device_index = local_indices[0] if local_indices else 0
-        else:
-            state.local_device_count = jax.local_device_count()
-            state.global_device_count = jax.device_count()
-            state.first_device_index = jax.process_index() * jax.local_device_count()
-        state.subset_ranks = list(comm) if comm is not None else None
+        timeline.install_compile_listener()
+        with timeline.span("hvd.init"):
+            _init(state, comm, devices)
 
-        from jax.sharding import Mesh
-        import numpy as np
 
-        from horovod_tpu.parallel.logical import DATA_AXIS
+def _init(state, comm, devices) -> None:
+    """``init`` proper, under the state's lock."""
+    import jax
 
-        state.mesh = Mesh(np.asarray(state.devices), (DATA_AXIS,))
+    # Multi-host: when the launcher provides a jax coordinator
+    # (HOROVOD_JAX_COORDINATOR, set by `hvdrun --jax`), join the jax
+    # distributed runtime BEFORE the first backend query so every
+    # process sees the global device set — the analogue of the
+    # reference joining MPI_COMM_WORLD at init (operations.cc:1724).
+    # TPU pods that pre-initialize via the runtime env need nothing
+    # here, and single-process usage stays zero-config.
+    jax_coord = os.environ.get("HOROVOD_JAX_COORDINATOR", "")
+    if jax_coord and os.environ.get("HOROVOD_SIZE"):
+        # Skip only when the distributed runtime is ALREADY up (e.g.
+        # the TPU pod runtime); a connect failure must propagate —
+        # swallowing it would leave this rank world-size 1 while its
+        # peers block on the barrier, with zero diagnostics.
+        if not jax.distributed.is_initialized():
+            jax.distributed.initialize(
+                coordinator_address=jax_coord,
+                num_processes=int(os.environ["HOROVOD_SIZE"]),
+                process_id=int(os.environ.get("HOROVOD_RANK", "0")),
+            )
+    state.config = Config.from_env()
+    state.devices = list(devices) if devices is not None else list(jax.devices())
+    if comm is not None:
+        # Ranks are chips on the SPMD lane, so the reference's
+        # rank-subset semantics (horovod_init(ranks, nranks),
+        # operations.cc:1728-1746) map to subsetting the mesh device
+        # list: hvd.init(comm=[0, 2]) builds a 2-chip job from chips
+        # 0 and 2 of the global order.
+        bad = [r for r in comm if not 0 <= r < len(state.devices)]
+        if bad:
+            raise InvalidArgumentError(
+                f"comm ranks {bad} out of range for "
+                f"{len(state.devices)} devices"
+            )
+        state.devices = [state.devices[r] for r in comm]
+        if jax.process_count() > 1 and not any(
+            getattr(d, "process_index", 0) == jax.process_index()
+            for d in state.devices
+        ):
+            # A process owning NO chip of the subset has no rank; two
+            # such processes would otherwise both report rank 0 and
+            # double-run every rank-0-gated action (checkpoint writes,
+            # logs). Exclude the process at launch instead.
+            raise InvalidArgumentError(
+                "hvd.init(comm=...) selected no chips owned by this "
+                "process; multi-host subsets must cover every "
+                "participating process (exclude the others at the "
+                "launcher level)."
+            )
+    state.process_index = jax.process_index()
+    state.process_count = jax.process_count()
+    if devices is not None or comm is not None:
+        local_indices = [
+            i
+            for i, d in enumerate(state.devices)
+            if getattr(d, "process_index", 0) == jax.process_index()
+        ]
+        state.local_device_count = len(local_indices)
+        state.global_device_count = len(state.devices)
+        state.first_device_index = local_indices[0] if local_indices else 0
+    else:
+        state.local_device_count = jax.local_device_count()
+        state.global_device_count = jax.device_count()
+        state.first_device_index = jax.process_index() * jax.local_device_count()
+    state.subset_ranks = list(comm) if comm is not None else None
 
-        from horovod_tpu.utils.timeline import Timeline
+    from jax.sharding import Mesh
+    import numpy as np
 
-        state.timeline = Timeline(
-            state.config.timeline_path or None,
-            mark_cycles=state.config.timeline_mark_cycles,
-            enabled_rank=state.process_index == 0,
+    from horovod_tpu.parallel.logical import DATA_AXIS
+
+    state.mesh = Mesh(np.asarray(state.devices), (DATA_AXIS,))
+
+    from horovod_tpu.utils.timeline import Timeline
+
+    state.timeline = Timeline(
+        state.config.timeline_path or None,
+        enabled_rank=state.process_index == 0,
+    )
+
+    if state.config.autotune:
+        # HOROVOD_AUTOTUNE on the SPMD lane: sweep the fusion threshold
+        # against measured step rate (reference parameter_manager.h:
+        # 211-217 scoring semantics; see horovod_tpu/jax/autotune.py).
+        from horovod_tpu.jax.autotune import StepAutotuner
+
+        # Log on process 0 only (the reference gated tuner logging to
+        # the coordinator rank); every process still RUNS the tuner so
+        # generations stay in lockstep.
+        state.autotuner = StepAutotuner(
+            state.config,
+            log_path=(
+                state.config.autotune_log
+                if state.process_index == 0
+                else ""
+            ),
         )
 
-        if state.config.autotune:
-            # HOROVOD_AUTOTUNE on the SPMD lane: sweep the fusion threshold
-            # against measured step rate (reference parameter_manager.h:
-            # 211-217 scoring semantics; see horovod_tpu/jax/autotune.py).
-            from horovod_tpu.jax.autotune import StepAutotuner
-
-            # Log on process 0 only (the reference gated tuner logging to
-            # the coordinator rank); every process still RUNS the tuner so
-            # generations stay in lockstep.
-            state.autotuner = StepAutotuner(
-                state.config,
-                log_path=(
-                    state.config.autotune_log
-                    if state.process_index == 0
-                    else ""
-                ),
-            )
-
-        state.initialized = True
-        atexit.register(shutdown)
+    state.initialized = True
+    atexit.register(shutdown)
 
 
 def shutdown() -> None:

@@ -113,7 +113,6 @@ class Config:
     cycle_time_ms: float = DEFAULT_CYCLE_TIME_MS
     # Chrome-trace timeline output path (HOROVOD_TIMELINE).
     timeline_path: str = ""
-    timeline_mark_cycles: bool = False
     # Autotuner (HOROVOD_AUTOTUNE / HOROVOD_AUTOTUNE_LOG).
     autotune: bool = False
     autotune_log: str = ""
@@ -166,7 +165,6 @@ class Config:
             ),
             cycle_time_ms=_env_float("HOROVOD_CYCLE_TIME", DEFAULT_CYCLE_TIME_MS),
             timeline_path=os.environ.get("HOROVOD_TIMELINE", ""),
-            timeline_mark_cycles=_env_bool("HOROVOD_TIMELINE_MARK_CYCLES"),
             autotune=_env_bool("HOROVOD_AUTOTUNE"),
             autotune_log=os.environ.get("HOROVOD_AUTOTUNE_LOG", ""),
             stall_check_disable=_env_bool("HOROVOD_STALL_CHECK_DISABLE"),

@@ -89,6 +89,7 @@ the no-double-scaling contract pinned by tests/test_hierarchical.py):
 
 from __future__ import annotations
 
+import collections
 import math
 from typing import List, NamedTuple, Optional, Sequence
 
@@ -446,6 +447,37 @@ def _quantized_outer_exchange(shard_v, axis, outer_groups, quantizer,
     return out, new_res
 
 
+# The exchange's plan, as gauges keyed by the enclosing program (the
+# ``program`` of the ``hvd.spmd.dispatch`` span whose call traced it):
+# program -> (id of that span, totals). A second ``fused_reduce`` in the
+# same trace adds to the program's totals; a re-trace starts them anew.
+_plans: dict = {}
+
+
+def _record_plan(issued, n: int) -> None:
+    """``issued``: ``(collectives, bytes, tensors)`` a bucket, as ``_issue``
+    executed the plan. Sets ``hvd.exchange.calls`` / ``.bytes`` /
+    ``.tensors`` / ``.buckets``: collectives issued a step, payload bytes a
+    chip hands them (unpadded, after compression), gradient tensors and
+    buckets. All 0 on one chip, where nothing is exchanged. Which path each
+    bucket took is in its ``ALLREDUCE`` event of the Chrome timeline."""
+    from horovod_tpu.utils import timeline
+
+    tracing = timeline.enclosing(timeline.DISPATCH)
+    program = tracing.args.get("program", "") if tracing else ""
+    owner = tracing.id if tracing else None
+    held = _plans.get(program)
+    totals = (held[1] if held and owner is not None and held[0] == owner
+              else collections.Counter())
+    if n > 1:
+        for calls, nbytes, tensors in issued:
+            totals.update(calls=calls, bytes=nbytes, tensors=tensors,
+                          buckets=1)
+    _plans[program] = (owner, totals)
+    for what in ("calls", "bytes", "tensors", "buckets"):
+        timeline.gauge("hvd.exchange." + what, totals[what], key=program)
+
+
 def fused_reduce(
     tensors,
     average: bool = True,
@@ -627,6 +659,7 @@ def fused_reduce(
                 else contextlib.nullcontext())
 
     results: List = [None] * len(tensors)
+    issued: List = []       # (collectives, bytes, tensors) a bucket
     # Members whose averaging division already happened on the scattered
     # shard (the "sharded update": 1/n of the elementwise work, before
     # the all-gather) — the tail must not divide them again.
@@ -666,6 +699,9 @@ def fused_reduce(
                            "in_flight": k + 1 if use_overlap else 1,
                            "path": path,
                            **({"inner": int(hier)} if hier else {})})
+        if not hier:
+            issued.append((2 if scatter else 1, int(bucket.nbytes),
+                           len(members)))
         # The hierarchical ladder and the scatter form both hand the
         # unpack a FLAT reduced buffer; the psum forms keep shape.
         flat_form = bool(scatter or hier)
@@ -679,6 +715,13 @@ def fused_reduce(
                     pad = layout["padded_elems"] - size
                     if pad:
                         flat = jnp.pad(flat, (0, pad))
+                    # The ladder's legs: reduce-scatter, the outer exchange
+                    # (one psum; quantized, values and scales gathered, with
+                    # an all-to-all and a second gather of both when there
+                    # are more than two slices), all-gather.
+                    legs = (3 if not hier_q
+                            else 6 if layout["two_stage"] else 4)
+                    issued.append((legs, int(bucket.nbytes), len(members)))
                     # Average: divide the dequantized/summed 1/inner
                     # shard BEFORE the gather (commutes elementwise —
                     # bit-identical to a tail divide, 1/inner the work);
@@ -799,6 +842,7 @@ def fused_reduce(
     else:
         for k, bucket in enumerate(plan):
             _issue(k, k, bucket)()
+    _record_plan(issued, n)
 
     out = []
     for i, t in enumerate(tensors):

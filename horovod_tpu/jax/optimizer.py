@@ -38,6 +38,7 @@ from horovod_tpu.jax.fusion import (
     fused_reduce,
     resolve_hierarchical,
 )
+from horovod_tpu.utils.timeline import EXCHANGE
 
 
 class _AllreduceState(NamedTuple):
@@ -109,12 +110,13 @@ def allreduce_gradients_transform(
             hierarchical=hierarchical,
             name="grads",
         )
-        if state.residuals:
-            reduced, new_res = fused_reduce(
-                leaves, residuals=state.residuals, **kwargs)
-            state = _AllreduceState(residuals=new_res)
-        else:
-            reduced = fused_reduce(leaves, **kwargs)
+        with jax.named_scope(EXCHANGE):
+            if state.residuals:
+                reduced, new_res = fused_reduce(
+                    leaves, residuals=state.residuals, **kwargs)
+                state = _AllreduceState(residuals=new_res)
+            else:
+                reduced = fused_reduce(leaves, **kwargs)
         return jax.tree_util.tree_unflatten(treedef, reduced), state
 
     return optax.GradientTransformation(init_fn, update_fn)
@@ -206,7 +208,8 @@ def grad(loss_fn, argnums=0, has_aux: bool = False):
         out = gfn(*args, **kwargs)
         grads, aux = (out[0], out[1]) if has_aux else (out, None)
         leaves, treedef = jax.tree_util.tree_flatten(grads)
-        reduced = fused_reduce(leaves, average=True, name="grads")
+        with jax.named_scope(EXCHANGE):
+            reduced = fused_reduce(leaves, average=True, name="grads")
         grads = jax.tree_util.tree_unflatten(treedef, reduced)
         return (grads, aux) if has_aux else grads
 
@@ -220,7 +223,8 @@ def value_and_grad(loss_fn, argnums=0, has_aux: bool = False):
     def wrapped(*args, **kwargs):
         value, grads = vgfn(*args, **kwargs)
         leaves, treedef = jax.tree_util.tree_flatten(grads)
-        reduced = fused_reduce(leaves, average=True, name="grads")
+        with jax.named_scope(EXCHANGE):
+            reduced = fused_reduce(leaves, average=True, name="grads")
         grads = jax.tree_util.tree_unflatten(treedef, reduced)
         if current_spmd_axis() is not None:
             if has_aux:

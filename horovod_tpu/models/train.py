@@ -33,6 +33,7 @@ from horovod_tpu.common.state import current_spmd_axis
 from horovod_tpu.jax import mpi_ops
 from horovod_tpu.jax.compression import Compression
 from horovod_tpu.jax.optimizer import DistributedOptimizer
+from horovod_tpu.utils import timeline
 
 
 def cross_entropy_loss(logits, labels) -> jnp.ndarray:
@@ -91,7 +92,8 @@ def create_train_state(
     the step with :func:`state_partition_specs` so the opt-state leaves are
     physically sharded.
     """
-    variables = model.init(rng, sample_input, train=False)
+    with timeline.span("hvd.lane.model_init"):
+        variables = model.init(rng, sample_input, train=False)
     params = variables["params"]
     # Deep-freeze so the state's pytree TYPES are stable against what
     # the step emits (flax's mutable= collection comes back as a plain
@@ -116,13 +118,14 @@ def create_train_state(
             overlap=overlap,
             hierarchical=hierarchical,
         )
-    opt_state = optimizer.init(params)
-    state = TrainState(
-        params=params,
-        batch_stats=batch_stats,
-        opt_state=opt_state,
-        step=jnp.zeros((), jnp.int32),
-    )
+    with timeline.span("hvd.lane.train_state"):
+        opt_state = optimizer.init(params)
+        state = TrainState(
+            params=params,
+            batch_stats=batch_stats,
+            opt_state=opt_state,
+            step=jnp.zeros((), jnp.int32),
+        )
     return state, optimizer
 
 
@@ -136,11 +139,13 @@ def apply_gradients(
     (the DistributedOptimizer/ZeRO wrapper performs the fused cross-rank
     gradient exchange here), parameter apply, state repack with the step
     counter advanced."""
-    updates, new_opt_state = optimizer.update(
-        grads, state["opt_state"], state["params"]
-    )
+    with jax.named_scope(timeline.UPDATE):
+        updates, new_opt_state = optimizer.update(
+            grads, state["opt_state"], state["params"]
+        )
+        params = optax.apply_updates(state["params"], updates)
     return TrainState(
-        params=optax.apply_updates(state["params"], updates),
+        params=params,
         batch_stats=state["batch_stats"] if batch_stats is None else batch_stats,
         opt_state=new_opt_state,
         step=state["step"] + 1,
@@ -159,7 +164,8 @@ def read_before_update(state: TrainState, reads):
     for a loss of 10.8904; PERF.md "Bring-up"). The barrier hands the state
     to the update only once ``reads`` exist, so nothing that produces them
     can be scheduled behind an in-place write. An identity otherwise."""
-    reads, state = jax.lax.optimization_barrier((reads, state))
+    with jax.named_scope(timeline.METRICS):
+        reads, state = jax.lax.optimization_barrier((reads, state))
     return state, reads
 
 
@@ -175,14 +181,16 @@ def make_train_step(model, optimizer: optax.GradientTransformation, average_loss
     """
 
     def loss_fn(params, batch_stats, batch, rng):
-        outputs, mutated = model.apply(
-            {"params": params, "batch_stats": batch_stats},
-            batch["image"],
-            train=True,
-            mutable=["batch_stats"],
-            rngs={"dropout": rng},
-        )
-        loss = cross_entropy_loss(outputs, batch["label"])
+        with jax.named_scope(timeline.FORWARD):
+            outputs, mutated = model.apply(
+                {"params": params, "batch_stats": batch_stats},
+                batch["image"],
+                train=True,
+                mutable=["batch_stats"],
+                rngs={"dropout": rng},
+            )
+        with jax.named_scope(timeline.LOSS):
+            loss = cross_entropy_loss(outputs, batch["label"])
         # freeze: scan-carry type stability (see create_train_state).
         return loss, (freeze(mutated.get("batch_stats", FrozenDict())),
                       outputs)
@@ -197,10 +205,11 @@ def make_train_step(model, optimizer: optax.GradientTransformation, average_loss
         (loss, (new_stats, logits)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state["params"], state["batch_stats"], batch, rng
         )
-        accuracy = jnp.mean((jnp.argmax(logits, -1) == batch["label"]).astype(jnp.float32))
-        if average_loss:
-            loss = mpi_ops.allreduce(loss, average=True, name="train.loss")
-            accuracy = mpi_ops.allreduce(accuracy, average=True, name="train.accuracy")
+        with jax.named_scope(timeline.METRICS):
+            accuracy = jnp.mean((jnp.argmax(logits, -1) == batch["label"]).astype(jnp.float32))
+            if average_loss:
+                loss = mpi_ops.allreduce(loss, average=True, name="train.loss")
+                accuracy = mpi_ops.allreduce(accuracy, average=True, name="train.accuracy")
         state, metrics = read_before_update(
             state, {"loss": loss, "accuracy": accuracy})
         new_state = apply_gradients(optimizer, state, grads,

@@ -22,6 +22,7 @@ single host: the same closed-form assertions run over an N-chip mesh.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, Optional
 
 import jax
@@ -29,6 +30,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.common import state as _state
 from horovod_tpu.parallel.logical import DATA_AXIS
+from horovod_tpu.utils.timeline import DISPATCH, span
+
+# Handles built so far in this process: two of them may wrap functions of
+# one name (a train and an eval ``step_fn``), so each gets a ``program`` id.
+_handles = itertools.count()
+
 
 def _default_mesh() -> Mesh:
     st = _state.global_state()
@@ -85,15 +92,15 @@ def spmd_fn(
     in-place-update analogue of the reference's in-place ``MPI_IN_PLACE``
     allreduce path, operations.cc:1574-1584 — but for the whole model).
 
-    When ``HOROVOD_TIMELINE`` is active, each returned handle emits
-    ``XLA_COMPILE`` around its first dispatch (trace+compile happen there,
-    so that span is the real compile cost) and ``XLA_EXECUTE`` around every
-    subsequent dispatch. jax dispatch is asynchronous, so the XLA_EXECUTE
-    span measures HOST DISPATCH time (the analogue of the reference's
-    QUEUE activity), not device execution — the events carry
-    ``args.span = "host_dispatch"`` to say so; use ``jax.profiler`` for
-    device-side op time. Taxonomy parity: reference operations.h:29-50,
-    docs/timeline.md:17-62.
+    Every dispatch of a returned handle is a span ``hvd.spmd.dispatch``
+    (:mod:`horovod_tpu.utils.timeline`) with the handle's name, its
+    ``program`` id (the name plus the handle's number in this process) and
+    ``call`` = 0, 1, 2, ...: call 0 blocks through trace and compile and
+    holds their records; a later one is the HOST DISPATCH alone (jax
+    dispatch is asynchronous), not device execution — a ``jax.profiler``
+    session shows both on one clock. ``HOROVOD_TIMELINE`` exports the spans as
+    ``XLA_COMPILE`` / ``XLA_EXECUTE`` with ``args.span`` saying which
+    (taxonomy parity: reference operations.h:29-50, docs/timeline.md:17-62).
     """
     mesh = mesh or _default_mesh()
 
@@ -134,7 +141,9 @@ def spmd_fn(
         return shmapped
 
     track = getattr(fn, "__name__", "spmd_fn")
-    compiled_once = [False]
+    program = f"{track}#{next(_handles)}"   # this handle and no other
+    calls = [0]             # dispatches of this handle so far
+    rebuilt = [False]       # the autotuner swapped the program since
 
     def _globalize(args):
         """Multi-host entry: each process passes its HOST-LOCAL shard
@@ -178,31 +187,22 @@ def spmd_fn(
                     _build_shmapped(), donate_argnums=donate_argnums
                 )
                 built_gen[0] = tuner.generation
-                compiled_once[0] = False
+                rebuilt[0] = True
                 dispatch._compiled = compiled_box[0]
 
         multi_host = host_local and st.process_count > 1
-        if multi_host:
-            args = _globalize(args)
-
-        tl = getattr(st, "timeline", None)
-        if tl is None or not tl.enabled:
+        # Call 0 blocks through trace and compile and holds the compile
+        # records (utils/timeline.py); a later call is the asynchronous
+        # host dispatch alone.
+        more = {"rebuilt": True} if rebuilt[0] else {}
+        with span(DISPATCH, handle=track, program=program, call=calls[0],
+                  **more):
+            calls[0] += 1
+            rebuilt[0] = False
+            if multi_host:
+                with span("hvd.spmd.globalize"):
+                    args = _globalize(args)
             out = compiled_box[0](*args, **kwargs)
-            compiled_once[0] = True
-        else:
-            from horovod_tpu.utils import timeline as _tl_names
-
-            # The first dispatch blocks through trace+compile (a real
-            # span); later spans time only the async host dispatch.
-            act = (_tl_names.XLA_EXECUTE if compiled_once[0]
-                   else _tl_names.XLA_COMPILE)
-            span = "host_dispatch" if compiled_once[0] else "trace+compile"
-            tl.start(track, act, args={"span": span})
-            try:
-                out = compiled_box[0](*args, **kwargs)
-            finally:
-                tl.end(track, act)
-                compiled_once[0] = True
 
         if (
             tuner is not None
@@ -214,7 +214,11 @@ def spmd_fn(
             # clock (sync-honest probe; see StepAutotuner.end_window).
             tuner.end_window(out)
         if multi_host:
-            out = _localize(out)
+            # After the tuner's window, whose clock it must not run into;
+            # so a sibling of the dispatch span, named by the same call.
+            with span("hvd.spmd.localize", program=program,
+                      call=calls[0] - 1):
+                out = _localize(out)
         return out
 
     dispatch._compiled = compiled_box[0]  # escape hatch for AOT (.lower) users

@@ -1,8 +1,40 @@
-"""Chrome-tracing timeline profiler.
+"""Spans, counters and the Chrome-tracing timeline: the program's one
+tracing module.
 
-TPU-native rebuild of the reference Horovod Timeline
-(horovod/common/timeline.{h,cc}; semantics documented in the reference's
-docs/timeline.md:17-62):
+**Spans and counters** (always on, no switch):
+
+* ``span(name, **args)`` is a context manager. It opens a
+  ``jax.profiler.TraceAnnotation``, so whenever a ``jax.profiler`` session
+  is running the span lies on the profile's host plane, on the clock the
+  device's operations are on (with no session the annotation is a flag
+  test); it appends one record ``(id, parent, name, start_ns, end_ns, args)``
+  to a ring of the newest ``RING`` records (parent: the span open on this
+  thread; the oldest record is dropped and the drop counted); and, where
+  ``HOROVOD_TIMELINE`` is set, it forwards to the Chrome writer below. A
+  span under which anything compiled says so in its record: ``programs``
+  and ``compile_s`` join its arguments.
+* ``count(name, n)`` adds to, and ``gauge(name, value, key)`` sets, a
+  process-wide number.
+* ``install_compile_listener()`` (``hvd.init`` calls it) registers one
+  ``jax.monitoring`` listener that records every jaxpr trace, lowering and
+  backend compile-or-cache-load as a child record of the span open on the
+  thread that caused it (``hvd.compile.trace`` / ``.lower`` / ``.backend``,
+  the last with ``cache_hit`` where the persistent cache served it) and
+  counts ``hvd.compile.programs``, ``hvd.compile.seconds`` and
+  ``hvd.compile.cache_hits``. Of a jit traced inside another only the
+  outer trace is kept, so the seconds add up. These records have a ring of
+  their own (``RING`` too, its drops counted apart): an eager phase that
+  compiles hundreds of small programs pushes out older compile records,
+  never a span.
+* ``snapshot()`` returns all of it as plain data, ``dump(path)`` writes
+  that as JSON (when asked, never on the hot path), ``reset()`` forgets.
+
+Names in use are listed in docs/timeline.md. The reduction of a device
+profile by these names is :mod:`horovod_tpu.utils.step_profile`.
+
+**The Chrome writer** is the TPU-native rebuild of the reference Horovod
+Timeline (horovod/common/timeline.{h,cc}; semantics documented in the
+reference's docs/timeline.md:17-62), and an exporter of the spans above:
 
 * activated by ``HOROVOD_TIMELINE=/path/trace.json``; rank-0 writes
   (reference operations.cc:1824-1829);
@@ -14,7 +46,8 @@ docs/timeline.md:17-62):
   equivalent lock-free-enough primitive here — a C++ writer lives in
   csrc/timeline.cc for the native core);
 * activity taxonomy kept from reference operations.h:29-50 with XLA-flavored
-  additions.
+  additions. Its clock is its own (``time.monotonic_ns`` from its start):
+  to lay host spans beside device time, use a profiler session.
 
 The Chrome trace format is the "JSON Array Format": one event object per
 line, comma-separated, '[' prologue — loadable in chrome://tracing and
@@ -23,16 +56,15 @@ Perfetto even when truncated mid-run (same property the reference relied on).
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
-import os
 import queue
 import threading
 import time
 from typing import Optional
 
 # Activity names (reference horovod/common/operations.h:29-50).
-QUEUE = "QUEUE"
-INIT_FUSION_BUFFER = "INIT_FUSION_BUFFER"
 MEMCPY_IN_FUSION_BUFFER = "MEMCPY_IN_FUSION_BUFFER"
 MEMCPY_OUT_FUSION_BUFFER = "MEMCPY_OUT_FUSION_BUFFER"
 ALLREDUCE = "ALLREDUCE"
@@ -46,8 +78,7 @@ ALLTOALL = "ALLTOALL"
 # at collective ISSUE and closes at fusion-buffer UNPACK so the trace
 # shows every in-flight bucket.
 REDUCESCATTER = "REDUCESCATTER"
-# XLA-path additions.
-XLA_TRACE = "XLA_TRACE"
+# XLA-path additions: what the span ``hvd.spmd.dispatch`` is exported as.
 XLA_COMPILE = "XLA_COMPILE"
 XLA_EXECUTE = "XLA_EXECUTE"
 # Multi-step window activities (horovod_tpu/jax/window.py): WINDOW spans
@@ -60,23 +91,248 @@ WINDOW_SYNC = "WINDOW_SYNC"
 _NEGOTIATING = "NEGOTIATING"
 _TOP_LEVEL = "TOP_LEVEL"
 
+# ---------------------------------------------------------------- spans
+
+DISPATCH = "hvd.spmd.dispatch"
+# Device scopes (``jax.named_scope``: compile-time only): the phases of a
+# training step, as they stand in every operation's ``op_name`` in a device
+# profile. The backward pass needs none: JAX writes it as
+# ``transpose(jvp(hvd_forward))``, and recomputation as ``checkpoint`` /
+# ``rematted_computation``.
+FORWARD = "hvd_forward"     # model.apply inside the loss function
+LOSS = "hvd_loss"           # logits to scalar
+EXCHANGE = "hvd_exchange"   # fused_reduce of the gradients
+UPDATE = "hvd_update"       # the inner optimizer's update, apply_updates
+METRICS = "hvd_metrics"     # accuracy, the loss all-reduce, the read-out
+RING = 8192     # records kept: a 10 s window of 46 ms steps is 217 of them
+
+_lock = threading.Lock()
+_ring: "collections.deque" = collections.deque(maxlen=RING)
+_compiles: "collections.deque" = collections.deque(maxlen=RING)
+_ids = itertools.count(1)
+_dropped = {"dropped": 0, "dropped_compiles": 0}    # what each ring let go
+_counters: dict = {}
+_gauges: dict = {}
+_local = threading.local()      # .open: the spans open on this thread
+_listening = False
+_TraceAnnotation = None         # jax.profiler's, imported at the first span
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "hvd.compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "hvd.compile.lower",
+    "/jax/core/compile/backend_compile_duration": "hvd.compile.backend",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _open_spans() -> list:
+    try:
+        return _local.open
+    except AttributeError:
+        _local.open = []
+        return _local.open
+
+
+def _append(ring, record: tuple, drops: str) -> None:
+    with _lock:
+        if len(ring) == RING:
+            _dropped[drops] += 1
+        ring.append(record)
+
+
+def _chrome_writer():
+    from horovod_tpu.common.state import global_state
+
+    tl = global_state().timeline
+    return tl if tl is not None and tl.enabled else None
+
+
+class span:
+    """``with span("hvd.lane.build", model="resnet50"): ...`` (module
+    docstring). ``id`` and ``args`` can be read while it is open."""
+
+    __slots__ = ("name", "args", "id", "parent", "_start", "_annotation",
+                 "_exported", "_programs", "_compile_s")
+
+    def __init__(self, name: str, **args):
+        self.name, self.args = name, args
+        self._programs, self._compile_s = 0, 0.0
+
+    def __enter__(self):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            import jax.profiler
+
+            _TraceAnnotation = jax.profiler.TraceAnnotation
+        _flush_traces()             # what was traced before is not ours
+        stack = _open_spans()
+        self.id = next(_ids)
+        self.parent = stack[-1].id if stack else 0
+        stack.append(self)
+        tl = _chrome_writer()
+        self._exported = (tl, *tl.span_start(self)) if tl else None
+        self._annotation = _TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
+        self._start = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.time_ns()
+        self._annotation.__exit__(*exc)
+        if self._exported is not None:
+            tl, track, op = self._exported
+            tl.end(track, op)       # a no-op once the writer is closed
+        _flush_traces()
+        stack = _open_spans()
+        if stack and stack[-1] is self:
+            stack.pop()
+        args = self.args
+        if self._programs or self._compile_s:
+            args = dict(args, programs=self._programs,
+                        compile_s=self._compile_s)
+        _append(_ring, (self.id, self.parent, self.name, self._start, end,
+                        args), "dropped")
+        return False
+
+
+def enclosing(name: str) -> Optional[span]:
+    """The innermost span called ``name`` that is open on this thread."""
+    for open_span in reversed(_open_spans()):
+        if open_span.name == name:
+            return open_span
+    return None
+
+
+def count(name: str, n=1) -> None:
+    with _lock:
+        _counters[name] = _counters.get(name, 0) + n
+
+
+def gauge(name: str, value, key: str = "") -> None:
+    """Set the number ``name``; ``key`` tells one program's from another's
+    (the gradient exchange's plan is a gauge a compiled program)."""
+    with _lock:
+        _gauges.setdefault(name, {})[key] = value
+
+
+def _compiled(record: tuple, programs: int = 0) -> None:
+    """Keep one compile record and credit it to every span open on this
+    thread."""
+    seconds = (record[4] - record[3]) / 1e9
+    count("hvd.compile.seconds", seconds)
+    for open_span in _open_spans():
+        open_span._programs += programs
+        open_span._compile_s += seconds
+    _append(_compiles, record, "dropped_compiles")
+
+
+def _flush_traces() -> None:
+    """Hand over this thread's finished traces. They wait because JAX
+    reports a jit traced inside another before the outer one, which then
+    takes its place: only the outermost is kept and counted."""
+    waiting = getattr(_local, "traces", None)
+    if waiting:
+        for record in waiting:
+            _compiled(record)
+        waiting.clear()
+
+
+def _on_duration(event: str, duration: float, **_):
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    end = time.time_ns()
+    start = end - int(duration * 1e9)
+    stack = _open_spans()
+    parent = stack[-1].id if stack else 0
+    if name == "hvd.compile.trace":
+        waiting = getattr(_local, "traces", None)
+        if waiting is None:
+            waiting = _local.traces = []
+        while waiting and waiting[-1][3] >= start:
+            waiting.pop()                   # traced inside this one
+        waiting.append((next(_ids), parent, name, start, end, {}))
+        return
+    _flush_traces()
+    args = {}
+    if name == "hvd.compile.backend":
+        count("hvd.compile.programs")
+        if getattr(_local, "cache_hit", False):
+            _local.cache_hit = False
+            args["cache_hit"] = True
+    _compiled((next(_ids), parent, name, start, end, args),
+              programs=name == "hvd.compile.backend")
+
+
+def _on_event(event: str, **_):
+    if event == _CACHE_HIT_EVENT:
+        count("hvd.compile.cache_hits")
+        _local.cache_hit = True     # the backend record that follows
+
+
+def install_compile_listener() -> None:
+    """Once a process; ``jax.monitoring`` has no way to take one back."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
+
+
+def snapshot() -> dict:
+    """``{"spans": [{"id", "parent", "name", "start_ns", "end_ns", "args"}],
+    "dropped", "dropped_compiles", "counters": {name: n}, "gauges": {name:
+    {key: value}}}``: closed spans and compile records by their end;
+    ``parent`` 0 is none; the two ``dropped`` count what the span ring and
+    the compile records' ring let go."""
+    with _lock:
+        records = sorted(itertools.chain(_ring, _compiles),
+                         key=lambda r: r[4])
+        return {
+            "spans": [dict(zip(("id", "parent", "name", "start_ns",
+                                "end_ns", "args"), r)) for r in records],
+            **_dropped,
+            "counters": dict(_counters),
+            "gauges": {k: dict(v) for k, v in _gauges.items()},
+        }
+
+
+def dump(path: str) -> None:
+    with open(path, "w") as f:
+        json.dump(snapshot(), f, default=str)
+
+
+def reset() -> None:
+    with _lock:
+        _ring.clear()
+        _compiles.clear()
+        _counters.clear()
+        _gauges.clear()
+        _dropped.update(dict.fromkeys(_dropped, 0))
+
+
+# --------------------------------------------------------- Chrome writer
+
 
 class Timeline:
     """Thread-safe, non-blocking chrome-trace writer.
 
     API mirrors the reference (timeline.h:83-93): ``negotiate_start/
-    negotiate_rank_ready/negotiate_end``, ``start/activity_start/
-    activity_end/end``, ``mark_cycle_start``.
+    negotiate_end``, ``start/activity_start/activity_end/end``; cycle
+    markers (``HOROVOD_TIMELINE_MARK_CYCLES``) are the native core's
+    (csrc/timeline.cc), which has control cycles to mark.
     """
 
     def __init__(
         self,
         path: Optional[str],
-        mark_cycles: bool = False,
         enabled_rank: bool = True,
     ) -> None:
         self._enabled = bool(path) and enabled_rank
-        self._mark_cycles = mark_cycles
         self._path = path
         self._queue: "queue.SimpleQueue[Optional[dict]]" = queue.SimpleQueue()
         self._tensor_tracks: dict = {}
@@ -157,20 +413,6 @@ class Timeline:
             }
         )
 
-    def negotiate_rank_ready(self, tensor_name: str, rank: int) -> None:
-        if not self._enabled:
-            return
-        self._emit(
-            {
-                "name": f"{rank}",
-                "ph": "i",
-                "s": "t",
-                "pid": 0,
-                "tid": self._tid(tensor_name),
-                "ts": self._now_us(),
-            }
-        )
-
     def negotiate_end(self, tensor_name: str) -> None:
         if not self._enabled:
             return
@@ -238,10 +480,30 @@ class Timeline:
             }
         )
 
+    def span_start(self, sp: "span"):
+        """Export an opening :class:`span`; returns what ``end`` takes to
+        close it. ``hvd.spmd.dispatch`` keeps the names it has always had
+        here: ``XLA_COMPILE`` on the handle's track for a call that blocks
+        through trace and compile (a handle's first, and the first after
+        the autotuner rebuilt it), ``XLA_EXECUTE`` for the asynchronous
+        host dispatch of every other. Any other span is an activity of
+        its own name on a track of that name."""
+        if not self._enabled:
+            return None
+        if sp.name == DISPATCH:
+            compiles = sp.args.get("call") == 0 or sp.args.get("rebuilt")
+            track = sp.args.get("handle", sp.name)
+            op = XLA_COMPILE if compiles else XLA_EXECUTE
+            args = {"span": "trace+compile" if compiles else "host_dispatch"}
+        else:
+            track, op, args = sp.name, sp.name, dict(sp.args)
+        self.start(track, op, args=args)
+        return track, op
+
     def mark_window(self, index: int, steps: int) -> None:
         """Instant global marker at a multi-step window boundary
-        (horovod_tpu/jax/window.py): the window-loop analogue of
-        ``mark_cycle_start``, carrying the window index and the number
+        (horovod_tpu/jax/window.py): the window-loop analogue of the
+        reference's cycle marker, carrying the window index and the number
         of steps its single dispatch covers."""
         if not self._enabled:
             return
@@ -256,20 +518,6 @@ class Timeline:
                 "args": {"window": index, "steps": steps},
             }
         )
-
-    def mark_cycle_start(self) -> None:
-        # Reference: HOROVOD_TIMELINE_MARK_CYCLES (operations.cc:2042-2045).
-        if self._enabled and self._mark_cycles:
-            self._emit(
-                {
-                    "name": "CYCLE_START",
-                    "ph": "i",
-                    "s": "g",
-                    "pid": 0,
-                    "tid": 0,
-                    "ts": self._now_us(),
-                }
-            )
 
     def close(self) -> None:
         if self._enabled and self._writer is not None:

@@ -1,0 +1,441 @@
+"""The program's tracing module (``horovod_tpu/utils/timeline.py``): spans,
+counters, the compile listener, the exchange's plan as gauges, the device
+scopes in the lowered step, and the Chrome writer as an exporter of spans."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from horovod_tpu.common import state as _state
+from horovod_tpu.utils import timeline
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCOPES = (timeline.FORWARD, timeline.LOSS, timeline.EXCHANGE,
+          timeline.UPDATE, timeline.METRICS)
+
+
+def _spans(name=None):
+    found = timeline.snapshot()["spans"]
+    return [s for s in found if name is None or s["name"] == name]
+
+
+def _mesh(n):
+    return Mesh(np.asarray(jax.devices()[:n]), ("hvd",))
+
+
+def test_spans_nest_and_carry_their_parent(hvd):
+    timeline.reset()
+    with timeline.span("outer", why="test") as outer:
+        with timeline.span("inner") as inner:
+            assert timeline.enclosing("outer") is outer
+            assert timeline.enclosing("inner") is inner
+        assert timeline.enclosing("inner") is None
+    inner_rec, outer_rec = _spans()         # a span is recorded as it closes
+    assert (inner_rec["name"], outer_rec["name"]) == ("inner", "outer")
+    assert inner_rec["parent"] == outer_rec["id"] and outer_rec["parent"] == 0
+    assert outer_rec["args"] == {"why": "test"}
+    assert outer_rec["start_ns"] <= inner_rec["start_ns"] \
+        <= inner_rec["end_ns"] <= outer_rec["end_ns"]
+
+
+def test_a_span_closes_when_its_body_raises(hvd):
+    timeline.reset()
+    with pytest.raises(KeyError):
+        with timeline.span("fails"):
+            raise KeyError("x")
+    assert [s["name"] for s in _spans()] == ["fails"]
+    assert timeline.enclosing("fails") is None
+
+
+def test_dispatch_spans_carry_handle_and_call(hvd):
+    timeline.reset()
+
+    def counted_step(x):
+        return hvd.allreduce(x, name="t")
+
+    run = hvd.spmd_fn(counted_step, in_specs=P("hvd"), out_specs=P("hvd"))
+    x = jnp.ones((8, 4), jnp.float32)
+    with timeline.span("loop") as loop:
+        for _ in range(3):
+            run(x)
+    calls = _spans(timeline.DISPATCH)
+    assert [(s["args"]["handle"], s["args"]["call"]) for s in calls] == [
+        ("counted_step", i) for i in range(3)]
+    assert {s["parent"] for s in calls} == {loop.id}
+    # the call that compiled says so in its record, and so does what it
+    # lies in; a call that only dispatched carries nothing more
+    first, outer = calls[0]["args"], _spans("loop")[0]["args"]
+    assert first["programs"] >= 1 and first["compile_s"] > 0
+    assert outer["programs"] == first["programs"]
+    assert outer["compile_s"] == pytest.approx(first["compile_s"])
+    assert all(set(s["args"]) == {"handle", "program", "call"}
+               for s in calls[1:])
+    assert len({s["args"]["program"] for s in calls}) == 1
+
+
+def test_two_handles_of_one_name_are_two_programs(hvd):
+    """A train and an eval ``step_fn``, or a handle rebuilt for another
+    mesh: their calls and their exchange plans stay apart."""
+    from horovod_tpu.jax import fusion
+
+    timeline.reset()
+
+    def step_fn(x):
+        return fusion.fused_reduce([x], name="g")[0]
+
+    x = jnp.ones((8, 4), jnp.float32)
+    small = hvd.spmd_fn(step_fn, mesh=_mesh(2), in_specs=P(), out_specs=P())
+    large = hvd.spmd_fn(step_fn, mesh=_mesh(4), in_specs=P(), out_specs=P())
+    small(x), large(jnp.concatenate([x, x])), small(x)
+    calls = _spans(timeline.DISPATCH)
+    assert [s["args"]["handle"] for s in calls] == ["step_fn"] * 3
+    first, second = _program("step_fn", 0), _program("step_fn", 1)
+    assert first != second
+    assert [(s["args"]["program"], s["args"]["call"]) for s in calls] == [
+        (first, 0), (second, 0), (first, 1)]
+    nbytes = timeline.snapshot()["gauges"]["hvd.exchange.bytes"]
+    assert nbytes[first] == x.nbytes and nbytes[second] == 2 * x.nbytes
+
+
+def test_compile_records_lie_under_the_call_that_compiled(hvd):
+    """Call 0 holds trace, lowering and backend compile; a later call that
+    meets a new shape shows its re-trace under itself, and no other does."""
+    timeline.reset()
+
+    def reshaped_step(x):
+        return hvd.allreduce(x * 2.0, name="t")
+
+    run = hvd.spmd_fn(reshaped_step, in_specs=P("hvd"), out_specs=P("hvd"))
+    before = timeline.snapshot()["counters"].get("hvd.compile.programs", 0)
+    run(jnp.ones((8, 4), jnp.float32))
+    run(jnp.ones((8, 4), jnp.float32))
+    run(jnp.ones((8, 6), jnp.float32))      # forces a second trace
+    snap = timeline.snapshot()
+    calls = {s["args"]["call"]: s["id"] for s in snap["spans"]
+             if s["name"] == timeline.DISPATCH}
+    compiles = [s for s in snap["spans"]
+                if s["name"].startswith("hvd.compile.")]
+    by_call = {c: sorted({s["name"] for s in compiles if s["parent"] == i})
+               for c, i in calls.items()}
+    whole = ["hvd.compile.backend", "hvd.compile.lower", "hvd.compile.trace"]
+    assert by_call[0] == whole and by_call[2] == whole
+    assert by_call[1] == []
+    counters = snap["counters"]
+    assert counters["hvd.compile.programs"] - before >= 2
+    assert counters["hvd.compile.seconds"] > 0
+    spans = {s["args"]["call"]: s for s in snap["spans"]
+             if s["name"] == timeline.DISPATCH}
+    for call in (0, 2):         # the span's own sum is its records' sum
+        under = [s for s in compiles if s["parent"] == calls[call]]
+        assert spans[call]["args"]["compile_s"] == pytest.approx(
+            sum(s["end_ns"] - s["start_ns"] for s in under) / 1e9)
+        assert spans[call]["args"]["programs"] == sum(
+            s["name"] == "hvd.compile.backend" for s in under)
+    # only the outermost trace of a program is kept: the seconds add up
+    for parent in (calls[0], calls[2]):
+        traces = [s for s in compiles if s["parent"] == parent
+                  and s["name"] == "hvd.compile.trace"]
+        ordered = sorted(traces, key=lambda s: s["start_ns"])
+        assert all(a["end_ns"] <= b["start_ns"]
+                   for a, b in zip(ordered, ordered[1:]))
+
+
+def test_the_ring_drops_the_oldest_and_counts_it(hvd):
+    timeline.reset()
+    extra = 5
+    for i in range(timeline.RING + extra):
+        with timeline.span("tick", i=i):
+            pass
+    snap = timeline.snapshot()
+    assert len(snap["spans"]) == timeline.RING
+    assert snap["dropped"] == extra
+    assert snap["spans"][0]["args"] == {"i": extra}
+    assert snap["spans"][-1]["args"] == {"i": timeline.RING + extra - 1}
+    timeline.reset()
+    assert timeline.snapshot() == {"spans": [], "dropped": 0,
+                                   "dropped_compiles": 0, "counters": {},
+                                   "gauges": {}}
+
+
+def test_a_flood_of_compile_records_pushes_out_no_span(hvd):
+    """Compile records have a ring of their own: an eager phase of hundreds
+    of small programs costs older compile records, never a span."""
+    timeline.reset()
+    with timeline.span("kept"):
+        pass
+    for _ in range(timeline.RING + 3):
+        timeline._on_duration("/jax/core/compile/backend_compile_duration",
+                              1e-6)
+    snap = timeline.snapshot()
+    assert snap["dropped_compiles"] == 3 and snap["dropped"] == 0
+    assert [s["name"] for s in snap["spans"]
+            if not s["name"].startswith("hvd.compile.")] == ["kept"]
+    assert snap["counters"]["hvd.compile.programs"] == timeline.RING + 3
+    timeline.reset()
+
+
+def test_counters_add_gauges_set_and_dump_writes_json(hvd, tmp_path):
+    timeline.reset()
+    timeline.count("n")
+    timeline.count("n", 2)
+    timeline.gauge("g", 5)
+    timeline.gauge("g", 7)
+    timeline.gauge("g", 1, key="program")
+    with timeline.span("s", k="v"):
+        pass
+    path = tmp_path / "snapshot.json"
+    timeline.dump(str(path))
+    snap = json.loads(path.read_text())
+    assert snap["counters"] == {"n": 3}
+    assert snap["gauges"] == {"g": {"": 7, "program": 1}}
+    assert [s["name"] for s in snap["spans"]] == ["s"]
+    assert snap == json.loads(json.dumps(timeline.snapshot()))
+
+
+def _program(handle, nth=-1):
+    """The ``program`` id of the ``nth`` handle called ``handle`` that was
+    dispatched since the reset."""
+    seen = dict.fromkeys(s["args"]["program"]
+                         for s in _spans(timeline.DISPATCH)
+                         if s["args"]["handle"] == handle)
+    return list(seen)[nth]
+
+
+def _gauges_of(handle, nth=-1):
+    program = _program(handle, nth)
+    return {k: v[program] for k, v in timeline.snapshot()["gauges"].items()
+            if program in v}
+
+
+def _grad_leaves():
+    rng = np.random.RandomState(0)
+    shapes = [(300, 40), (40,), (64, 64), (7,), (2000,)]
+    return [jnp.asarray(rng.randn(*s), jnp.float32) for s in shapes]
+
+
+@pytest.mark.parametrize("overlap, threshold, scatter", [
+    ("off", 64 * 1024 * 1024, None), ("off", 20_000, None),
+    ("on", 20_000, 8_000), ("on", 20_000, 1 << 30)])
+def test_exchange_gauges_equal_the_bucket_plan_on_four_devices(
+        hvd, overlap, threshold, scatter):
+    from horovod_tpu.jax import fusion
+
+    timeline.reset()
+    leaves = _grad_leaves()
+
+    def plan_step(*xs):
+        return tuple(fusion.fused_reduce(
+            list(xs), fusion_threshold=threshold, overlap=overlap,
+            scatter_threshold=scatter, name="grads"))
+
+    run = hvd.spmd_fn(plan_step, mesh=_mesh(4), in_specs=P(), out_specs=P())
+    run(*leaves)
+    plan = fusion.plan_buckets(leaves, threshold)
+    scattered = [b for b in plan
+                 if overlap == "on" and b.nbytes >= scatter]
+    plain = [b for b in plan if b not in scattered]
+    got = _gauges_of("plan_step")
+    assert got["hvd.exchange.buckets"] == len(plan)
+    assert got["hvd.exchange.bytes"] == sum(b.nbytes for b in plan)
+    assert got["hvd.exchange.bytes"] == sum(x.nbytes for x in leaves)
+    assert got["hvd.exchange.tensors"] == len(leaves)
+    # 1 a psum bucket, 2 a reduce-scatter + all-gather bucket
+    assert got["hvd.exchange.calls"] == len(plain) + 2 * len(scattered)
+    assert set(got) == {"hvd.exchange." + what for what in (
+        "calls", "bytes", "tensors", "buckets")}
+
+
+def test_exchange_gauges_count_the_ladders_legs(hvd):
+    from horovod_tpu.jax import fusion
+
+    timeline.reset()
+    leaves = _grad_leaves()
+    st = _state.global_state()
+    saved = st.config.hierarchical_inner_size
+    st.config.hierarchical_inner_size = 2
+
+    def ladder_step(*xs):
+        return tuple(fusion.fused_reduce(list(xs), hierarchical="on",
+                                         name="grads"))
+
+    try:
+        hvd.spmd_fn(ladder_step, mesh=_mesh(4), in_specs=P(),
+                    out_specs=P())(*leaves)
+    finally:
+        st.config.hierarchical_inner_size = saved
+    got = _gauges_of("ladder_step")
+    assert got["hvd.exchange.buckets"] == 1
+    assert got["hvd.exchange.calls"] == 3
+    assert got["hvd.exchange.bytes"] == sum(x.nbytes for x in leaves)
+
+
+def test_exchange_gauges_are_zero_on_one_device_and_a_retrace_overwrites(hvd):
+    from horovod_tpu.jax import fusion
+
+    timeline.reset()
+    leaves = _grad_leaves()
+
+    def twice_step(*xs):
+        once = fusion.fused_reduce(list(xs), name="a")
+        return tuple(fusion.fused_reduce(once, name="b"))
+
+    hvd.spmd_fn(twice_step, mesh=_mesh(1), in_specs=P(),
+                out_specs=P())(*leaves)
+    alone = _gauges_of("twice_step")
+    assert alone and not any(alone.values())
+
+    run = hvd.spmd_fn(twice_step, mesh=_mesh(4), in_specs=P(), out_specs=P())
+    run(*leaves)
+    nbytes = sum(x.nbytes for x in leaves)
+    read = lambda: _gauges_of("twice_step", 1)["hvd.exchange.bytes"]
+    assert read() == 2 * nbytes             # two exchanges in one trace add
+    run(*[jnp.concatenate([x, x]) for x in leaves])     # a re-trace
+    assert read() == 4 * nbytes             # overwrites: not 2 + 4
+
+
+def _lowered_text(lane):
+    return lane.run_step._compiled.lower(lane.state, lane.batch).as_text(
+        debug_info=True)
+
+
+LANES = {
+    "lm": ["--model", "transformer_lm", "--lm-layers", "1", "--lm-dim", "32",
+           "--lm-heads", "2", "--vocab", "64", "--batch-size", "1",
+           "--seq-len", "16", "--remat"],
+    "image": ["--model", "resnet18", "--image-size", "32",
+              "--batch-size", "1"],
+}
+
+
+@pytest.mark.parametrize("lane_name", sorted(LANES))
+def test_the_five_scopes_are_in_the_lowered_step_of_both_lanes(
+        hvd, monkeypatch, lane_name):
+    monkeypatch.setenv("HVD_BENCH_NO_STATIC_AUDIT", "1")
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+
+    timeline.reset()
+    args = bench.build_parser().parse_args(LANES[lane_name])
+    lane = bench.build_lane(args, lambda *a, **k: None)
+    text = _lowered_text(lane)
+    for scope in SCOPES:
+        assert scope in text, scope
+    assert f"transpose(jvp({timeline.FORWARD}))" in text    # the backward
+    assert f"{timeline.UPDATE}/{timeline.EXCHANGE}" in text
+    if lane_name == "lm":
+        assert "checkpoint" in text                         # --remat
+    names = [s["name"] for s in _spans()]
+    build = next(s for s in _spans() if s["name"] == "hvd.lane.build")
+    assert build["args"]["model"] == args.model
+    programs = sum(1 for s in _spans("hvd.compile.backend")
+                   if build["start_ns"] <= s["start_ns"] <= build["end_ns"])
+    assert build["args"]["programs"] == programs > 0
+    for child in ("hvd.lane.model_init", "hvd.lane.train_state",
+                  "hvd.lane.place", "hvd.lane.audit"):
+        assert child in names
+        assert all(s["parent"] == build["id"] for s in _spans(child))
+    under_build = {s["id"] for s in _spans() if s["parent"] == build["id"]}
+    assert any(s["name"] == "hvd.compile.backend"
+               and s["parent"] in under_build for s in _spans())
+
+
+def test_windowed_train_step_has_the_same_scopes(hvd):
+    import optax
+
+    from horovod_tpu import models
+
+    model = models.MNISTNet()
+    state, opt = models.create_train_state(
+        jax.random.PRNGKey(0), model, optax.sgd(0.1),
+        jnp.zeros((1, 28, 28, 1), jnp.float32))
+    step = models.make_windowed_train_step(model, opt, 2)
+    batch = {"image": jnp.zeros((2, 8, 28, 28, 1), jnp.float32),
+             "label": jnp.zeros((2, 8), jnp.int32)}
+    run = hvd.spmd_fn(step, in_specs=(P(), P(None, "hvd")),
+                      out_specs=(P(), P()))
+    text = run._compiled.lower(state, batch).as_text(debug_info=True)
+    for scope in SCOPES:
+        assert scope in text, scope
+
+
+def test_hvd_grad_puts_its_exchange_under_the_scope(hvd):
+    def loss(w, x):
+        return jnp.sum((x @ w) ** 2)
+
+    def grad_step(w, x):
+        value, grads = hvd.value_and_grad(loss)(w, x)
+        return value, grads, hvd.grad(loss)(w, x)
+
+    run = hvd.spmd_fn(grad_step, in_specs=(P(), P("hvd")),
+                      out_specs=(P(), P(), P()))
+    text = run._compiled.lower(
+        jnp.ones((4, 2)), jnp.ones((8, 4))).as_text(debug_info=True)
+    assert text.count(timeline.EXCHANGE) >= 2
+
+
+def _chrome_events(path):
+    return json.loads(path.read_text().rstrip().rstrip(",\n") + "]")
+
+
+def test_the_chrome_writer_exports_dispatch_under_its_old_names(hvd,
+                                                                tmp_path):
+    """``HOROVOD_TIMELINE`` output for ``hvd.spmd.dispatch``: the events the
+    inline branch of ``spmd.py`` wrote, key for key."""
+    st = _state.global_state()
+    trace = tmp_path / "trace.json"
+    saved = st.timeline
+    st.timeline = timeline.Timeline(str(trace))
+
+    def exported_step(x):
+        return hvd.allreduce(x, name="t")
+
+    try:
+        run = hvd.spmd_fn(exported_step, in_specs=P("hvd"),
+                          out_specs=P("hvd"))
+        for _ in range(3):
+            run(jnp.ones((8, 4), jnp.float32))
+        with timeline.span("hvd.lane.place", rows=8):
+            pass
+    finally:
+        st.timeline.close()
+        st.timeline = saved
+    events = _chrome_events(trace)
+    tid = next(e["tid"] for e in events if e["name"] == "thread_name"
+               and e["args"]["name"] == "exported_step")
+    mine = [e for e in events if e["tid"] == tid and e["ph"] in "BE"]
+    assert [(e["name"], e["ph"]) for e in mine] == [
+        ("XLA_COMPILE", "B"), ("XLA_COMPILE", "E"),
+        ("XLA_EXECUTE", "B"), ("XLA_EXECUTE", "E"),
+        ("XLA_EXECUTE", "B"), ("XLA_EXECUTE", "E")]
+    assert [list(e) for e in mine[:2]] == [
+        ["name", "ph", "pid", "tid", "ts", "args"],
+        ["name", "ph", "pid", "tid", "ts"]]
+    assert [e["args"] for e in mine if e["ph"] == "B"] == [
+        {"span": "trace+compile"}, {"span": "host_dispatch"},
+        {"span": "host_dispatch"}]
+    other = [e for e in events if e["name"] == "hvd.lane.place"]
+    assert [e["ph"] for e in other] == ["B", "E"]
+    assert other[0]["args"] == {"rows": 8}
+
+
+def test_the_names_nothing_emitted_are_gone():
+    for name in ("QUEUE", "INIT_FUSION_BUFFER", "XLA_TRACE"):
+        assert not hasattr(timeline, name)
+    for method in ("mark_cycle_start", "negotiate_rank_ready"):
+        assert not hasattr(timeline.Timeline, method)
+    spmd = open(os.path.join(REPO, "horovod_tpu", "parallel",
+                             "spmd.py")).read()
+    assert "tl.start(track" not in spmd
+
+
+def test_profile_step_builds_no_model_of_its_own():
+    text = open(os.path.join(REPO, "tools", "profile_step.py")).read()
+    assert "bench.build_lane(" in text
+    for word in ("TransformerLM(", "models.build(", "create_train_state(",
+                 "benchmarks"):
+        assert word not in text.split('"""', 2)[2], word
