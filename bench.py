@@ -764,9 +764,9 @@ def build_parser():
                         help="backward-overlapped bucketed gradient "
                              "collectives (horovod_tpu/jax/fusion.py): "
                              "per-bucket reductions issued in reverse "
-                             "bucket order, start-all/unpack-later, "
-                             "rs+ag form for big buckets — dispatch "
-                             "shape only, numerics bit-identical. "
+                             "bucket order, start-all/unpack-later — "
+                             "dispatch shape only, numerics "
+                             "bit-identical. "
                              "Default: the HOROVOD_OVERLAP env knob "
                              "(auto). The record stamps the mode plus "
                              "the bucket plan (count/MB/oversize)")

@@ -17,13 +17,6 @@ import os
 # (horovod/common/operations.cc:1846, operations.h:56-60).
 DEFAULT_FUSION_THRESHOLD = 64 * 1024 * 1024
 DEFAULT_CYCLE_TIME_MS = 5.0
-# Overlap-shaped gradient collectives (horovod_tpu/jax/fusion.py): buckets
-# at or above this size take the reduce-scatter -> sharded-update ->
-# all-gather form (same wire bytes as one allreduce — rs+ag IS the ring
-# decomposition — but two independently schedulable halves XLA's async
-# collective pass can slide under backward compute). 4 MiB: below it the
-# per-collective latency of two ops beats the scheduling freedom.
-DEFAULT_OVERLAP_SCATTER_THRESHOLD = 4 * 1024 * 1024
 # HOROVOD_OVERLAP values (see horovod_tpu.jax.fusion.resolve_overlap).
 OVERLAP_MODES = ("auto", "on", "off")
 # HOROVOD_HIERARCHICAL values (horovod_tpu.jax.fusion.
@@ -105,9 +98,6 @@ class Config:
     # the plan has >= 2 buckets and degrades to the legacy single-pass
     # emission otherwise; never changes numerics (docs/benchmarks.md).
     overlap: str = "auto"
-    # Bucket-size floor for the reduce-scatter->sharded-update->all-gather
-    # form inside overlap mode (HOROVOD_OVERLAP_SCATTER_THRESHOLD, bytes).
-    overlap_scatter_threshold: int = DEFAULT_OVERLAP_SCATTER_THRESHOLD
     # Coordinator cycle time in ms — only meaningful for the native eager
     # backend; the XLA path has no background loop (HOROVOD_CYCLE_TIME).
     cycle_time_ms: float = DEFAULT_CYCLE_TIME_MS
@@ -159,10 +149,6 @@ class Config:
                 "HOROVOD_FUSION_THRESHOLD", DEFAULT_FUSION_THRESHOLD
             ),
             overlap=_env_choice("HOROVOD_OVERLAP", "auto", OVERLAP_MODES),
-            overlap_scatter_threshold=_env_int(
-                "HOROVOD_OVERLAP_SCATTER_THRESHOLD",
-                DEFAULT_OVERLAP_SCATTER_THRESHOLD,
-            ),
             cycle_time_ms=_env_float("HOROVOD_CYCLE_TIME", DEFAULT_CYCLE_TIME_MS),
             timeline_path=os.environ.get("HOROVOD_TIMELINE", ""),
             autotune=_env_bool("HOROVOD_AUTOTUNE"),

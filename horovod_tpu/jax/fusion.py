@@ -1,4 +1,4 @@
-"""Tensor fusion: bucketed flat-buffer collectives, overlap-scheduled.
+"""Tensor fusion: the bucketed gradient exchange, overlap-scheduled.
 
 TPU-native rebuild of the reference's fusion machinery — the 64 MB fusion
 buffer (horovod/common/fusion_buffer_manager.h:50-55), the response-merging
@@ -8,14 +8,21 @@ look-ahead that packs same-dtype tensors into one collective
 
 Mapping onto XLA:
 
-* the persistent device-side fusion buffer becomes a traced flat
-  concatenation — XLA allocates and reuses it across steps;
-* "memcpy into the fusion buffer" becomes ``ravel``+``concatenate`` which
-  XLA fuses into the collective's prologue;
-* one ``lax.psum`` per bucket amortizes ICI latency over many small
-  gradients the same way one NCCL launch amortized ring latency;
-* bucket boundaries respect HOROVOD_FUSION_THRESHOLD so the env knob (and
-  the autotuner that drives it) keeps its meaning.
+* the bucket PLAN stays (same-dtype tensors, greedily packed up to
+  HOROVOD_FUSION_THRESHOLD): it names each bucket's
+  ``hvd_allreduce_<name>_<dtype>_b<i>`` scope, orders the issue, feeds the
+  ``hvd.exchange.*`` gauges and the HVV105 byte accounting;
+* "memcpy into the fusion buffer" has NO counterpart on a single slice:
+  a bucket reduces its members in their own shapes, one ``lax.psum`` a
+  member under the bucket's scope. A ``f32[1024,4096]`` leaf lives in
+  (8,128) tiles and a flat 1-D buffer does not, so ``ravel`` +
+  ``concatenate`` in and slice + ``reshape`` out are relayouts of every
+  gradient byte in HBM (measured on the v5e: PERF.md, PR 27); grouping the
+  all-reduces on the wire is XLA's all-reduce combiner's job;
+* only the hierarchical ladder (several slices, below) still packs a
+  bucket into one flat, padded buffer: its intra-slice reduce-scatter
+  needs one. ``hvd.exchange.packed_bytes`` counts the bytes a step copies
+  that way (0 on a single slice).
 
 Overlap scheduling (HOROVOD_OVERLAP=auto|on|off): the reference hid the
 gradient exchange behind backward compute by firing an allreduce from each
@@ -24,17 +31,11 @@ DDP's reverse-order buckets, Li et al. VLDB 2020). Under XLA the step is
 one program, so the same win is a *scheduling shape* problem: with overlap
 on, per-bucket collectives are issued in REVERSE bucket order — the order
 backward produces gradients, last layers first — as a start-all/
-unpack-later sequence, so each bucket's collective depends only on its own
-members and XLA's async collective (start/done) scheduler can slide it
+unpack-later sequence, so each bucket's collectives depend only on its own
+members and XLA's async collective (start/done) scheduler can slide them
 under the remaining backward compute instead of serializing one
-post-backward block. Buckets at or above HOROVOD_OVERLAP_SCATTER_THRESHOLD
-additionally take the ``psum_scatter`` -> sharded-update -> ``all_gather``
-form: identical wire bytes (reduce-scatter + all-gather IS how a ring
-allreduce decomposes) and identical numerics, but two independently
-schedulable halves — ZeRO-shaped communication with plain-DP semantics
-(optimizer state stays replicated; contrast :mod:`horovod_tpu.jax.zero`).
-Overlap NEVER changes results: the emission order and collective shape
-change, the math does not (pinned bit-exactly in tests/test_overlap.py).
+post-backward block. Overlap NEVER changes results: the emission order
+changes, the math does not (pinned bit-exactly in tests/test_overlap.py).
 
 Same-dtype-only fusion matches the reference (it fused only responses with
 identical dtype/device signatures, operations.cc:2175-2230).
@@ -51,8 +52,7 @@ operations.cc:1284-1436, as explicit XLA collectives over
 ``hybrid_mesh``). "auto" engages only when the device set spans a DCN
 boundary (``parallel.mesh.dcn_present``). Composes with the overlap
 schedule (reverse-order issue applies per bucket regardless of its
-collective shape); hierarchical buckets never additionally take the
-rs+ag scatter form (the ladder already decomposes).
+collective shape).
 
 Low-bit DCN wire (``Compression.int8`` / ``Compression.fp8``): the DCN
 leg optionally quantizes the shard with a per-bucket absmax scale (the
@@ -455,12 +455,16 @@ _plans: dict = {}
 
 
 def _record_plan(issued, n: int) -> None:
-    """``issued``: ``(collectives, bytes, tensors)`` a bucket, as ``_issue``
-    executed the plan. Sets ``hvd.exchange.calls`` / ``.bytes`` /
-    ``.tensors`` / ``.buckets``: collectives issued a step, payload bytes a
-    chip hands them (unpadded, after compression), gradient tensors and
-    buckets. All 0 on one chip, where nothing is exchanged. Which path each
-    bucket took is in its ``ALLREDUCE`` event of the Chrome timeline."""
+    """``issued``: ``(collectives, bytes, tensors, packed bytes)`` a bucket,
+    as ``_issue`` executed the plan. Sets ``hvd.exchange.calls`` / ``.bytes``
+    / ``.tensors`` / ``.buckets`` / ``.packed_bytes``: collectives issued a
+    step (one a member on a single slice, the ladder's legs across slices),
+    payload bytes a chip hands them (unpadded, after compression), gradient
+    tensors, buckets, and the bytes a step copies into flat buffers before a
+    collective (0 where every bucket reduces its members in place, equal to
+    ``.bytes`` where every bucket takes the ladder). All 0 on one chip, where
+    nothing is exchanged. Which path each bucket took is in its ``ALLREDUCE``
+    event of the Chrome timeline."""
     from horovod_tpu.utils import timeline
 
     tracing = timeline.enclosing(timeline.DISPATCH)
@@ -470,11 +474,11 @@ def _record_plan(issued, n: int) -> None:
     totals = (held[1] if held and owner is not None and held[0] == owner
               else collections.Counter())
     if n > 1:
-        for calls, nbytes, tensors in issued:
+        for calls, nbytes, tensors, packed in issued:
             totals.update(calls=calls, bytes=nbytes, tensors=tensors,
-                          buckets=1)
+                          buckets=1, packed_bytes=packed)
     _plans[program] = (owner, totals)
-    for what in ("calls", "bytes", "tensors", "buckets"):
+    for what in ("calls", "bytes", "tensors", "buckets", "packed_bytes"):
         timeline.gauge("hvd.exchange." + what, totals[what], key=program)
 
 
@@ -486,23 +490,23 @@ def fused_reduce(
     fusion_threshold: Optional[int] = None,
     name: Optional[str] = None,
     overlap: Optional[str] = None,
-    scatter_threshold: Optional[int] = None,
     hierarchical: Optional[str] = None,
     residuals=None,
 ):
-    """Allreduce a sequence of tensors via fused flat buckets.
+    """Allreduce a sequence of tensors, bucket by bucket.
 
     Returns a list of reduced tensors in input order. Works inside an SPMD
-    region (psum per bucket) and eagerly (size()==1 identity semantics).
+    region (on a single slice a bucket reduces each member in its own shape
+    under the bucket's scope; the hierarchical ladder packs the bucket into
+    one flat buffer) and eagerly (size()==1 identity semantics).
     ``name`` labels the per-tensor collectives on the eager process-level
     path (where names drive the native negotiation and the timeline); the
     SPMD path has no per-tensor identity inside the compiled program.
 
     ``overlap`` (auto|on|off, default HOROVOD_OVERLAP) selects the
     backward-overlapped emission: reverse bucket order, start-all/
-    unpack-later, reduce-scatter+all-gather for buckets >=
-    ``scatter_threshold`` bytes (HOROVOD_OVERLAP_SCATTER_THRESHOLD).
-    Changes dispatch shape only — results are bit-identical to ``off``.
+    unpack-later. Changes dispatch shape only — results are bit-identical
+    to ``off``.
 
     ``hierarchical`` (auto|on|off, default HOROVOD_HIERARCHICAL) runs
     each Sum/Average bucket as the two-level intra-slice reduce-scatter
@@ -521,8 +525,6 @@ def fused_reduce(
     st.require_init()
     if fusion_threshold is None:
         fusion_threshold = st.config.fusion_threshold
-    if scatter_threshold is None:
-        scatter_threshold = st.config.overlap_scatter_threshold
 
     tensors = [jnp.asarray(t) for t in tensors]
     axis = current_spmd_axis()
@@ -559,8 +561,8 @@ def fused_reduce(
         return out
 
     n = mpi_ops._axis_size(axis)
-    # Min/Max/Product fuse just as well as Sum: any elementwise cross-rank
-    # reduction distributes over concatenation.
+    # Min/Max/Product bucket just as Sum does: any elementwise cross-rank
+    # reduction applies member by member.
     plain_sum = op is mpi_ops.Average or op is mpi_ops.Sum
     if plain_sum:
         reduce_fn = lax.psum
@@ -598,11 +600,6 @@ def fused_reduce(
 
     plan = plan_buckets(compressed, fusion_threshold)
     use_overlap = resolve_overlap(overlap, len(plan))
-    # The rs+ag form needs the plain flat psum semantics (Min/Max/
-    # Product have no scatter primitive) and >1 rank for the scatter to
-    # mean anything; hierarchical buckets never take it — the ladder
-    # already decomposes into schedulable halves.
-    can_scatter = use_overlap and plain_sum and not hier and n > 1
 
     # Error-feedback residual slots: plan index -> (offset, count) into
     # the ``residuals`` tuple, in plan order (the structure
@@ -634,16 +631,17 @@ def fused_reduce(
     # collective is built under a jax.named_scope — the name lands in
     # the HLO metadata, so device profiles (jax.profiler /
     # tools/profile_step.py) attribute its time by name — and, when
-    # HOROVOD_TIMELINE is active, emits MEMCPY_IN_FUSION_BUFFER /
-    # ALLREDUCE (or REDUCESCATTER+ALLGATHER on the scatter form) /
-    # MEMCPY_OUT_FUSION_BUFFER spans on a per-bucket track at TRACE time
-    # (this code runs once per compile; the spans record the bucket PLAN
-    # — members/bytes/dtype/issue order — not per-step device time,
+    # HOROVOD_TIMELINE is active, emits ALLREDUCE (on the ladder with
+    # MEMCPY_IN_FUSION_BUFFER / REDUCESCATTER / the DCN leg's ALLGATHER or
+    # ALLTOALL / MEMCPY_OUT_FUSION_BUFFER inside it) spans on a per-bucket
+    # track at TRACE time (this code runs once per compile; the spans
+    # record the bucket PLAN — members/bytes/dtype/issue order — not
+    # per-step device time,
     # which is stated in the span args; per-step device time is the
     # profiler's job, per-step host dispatch is XLA_EXECUTE's). Under
     # overlap the B span opens at ISSUE and closes at UNPACK, so the
     # trace shows every in-flight bucket between its collective start
-    # and its fusion-buffer unpack.
+    # and its unpack.
     import contextlib
 
     import jax as _jax
@@ -659,15 +657,15 @@ def fused_reduce(
                 else contextlib.nullcontext())
 
     results: List = [None] * len(tensors)
-    issued: List = []       # (collectives, bytes, tensors) a bucket
-    # Members whose averaging division already happened on the scattered
-    # shard (the "sharded update": 1/n of the elementwise work, before
-    # the all-gather) — the tail must not divide them again.
+    issued: List = []   # (collectives, bytes, tensors, packed bytes) a bucket
+    # Members whose averaging division already happened on the ladder's
+    # 1/inner shard (before the all-gather) — the tail must not divide
+    # them again.
     averaged = [False] * len(tensors)
 
     def _pack_flat(members, bucket_name):
-        """Memcpy-in: ravel+concatenate the bucket members into the flat
-        fusion buffer (shared by the hierarchical and scatter forms)."""
+        """Memcpy-in: ravel+concatenate the bucket members into the
+        ladder's flat fusion buffer."""
         with _act(bucket_name, _tl_names.MEMCPY_IN_FUSION_BUFFER):
             return (jnp.concatenate(
                 [compressed[i].ravel() for i in members])
@@ -682,13 +680,12 @@ def fused_reduce(
         bucket_name = f"{name or 'fused'}.{dtype.name}.b{bucket.index}"
         scope = f"hvd_allreduce_{bucket_name}".replace(".", "_")
         members = list(bucket.members)
-        scatter = can_scatter and bucket.nbytes >= scatter_threshold
         hier_q = hier and quantizer is not None and _ef_eligible(bucket)
         if hier:
             path = (f"hier_{jnp.dtype(quantizer.wire_dtype).name}"
                     if hier_q else "hier")
         else:
-            path = "rs_ag" if scatter else "psum"
+            path = "psum"
         if emit:
             tl.start(bucket_name, _tl_names.ALLREDUCE,
                      args={"span": "trace", "tensors": len(members),
@@ -699,12 +696,6 @@ def fused_reduce(
                            "in_flight": k + 1 if use_overlap else 1,
                            "path": path,
                            **({"inner": int(hier)} if hier else {})})
-        if not hier:
-            issued.append((2 if scatter else 1, int(bucket.nbytes),
-                           len(members)))
-        # The hierarchical ladder and the scatter form both hand the
-        # unpack a FLAT reduced buffer; the psum forms keep shape.
-        flat_form = bool(scatter or hier)
         try:
             with _jax.named_scope(scope):
                 if hier:
@@ -721,7 +712,8 @@ def fused_reduce(
                     # are more than two slices), all-gather.
                     legs = (3 if not hier_q
                             else 6 if layout["two_stage"] else 4)
-                    issued.append((legs, int(bucket.nbytes), len(members)))
+                    issued.append((legs, int(bucket.nbytes), len(members),
+                                   int(bucket.nbytes)))
                     # Average: divide the dequantized/summed 1/inner
                     # shard BEFORE the gather (commutes elementwise —
                     # bit-identical to a tail divide, 1/inner the work);
@@ -774,34 +766,15 @@ def fused_reduce(
                             averaged[i] = True
                     if pad:
                         reduced = reduced[:size]
-                elif scatter:
-                    flat = _pack_flat(members, bucket_name)
-                    size = flat.size
-                    pad = (-size) % n
-                    if pad:
-                        flat = jnp.pad(flat, (0, pad))
-                    with _act(bucket_name, _tl_names.REDUCESCATTER):
-                        shard = lax.psum_scatter(
-                            flat, axis, scatter_dimension=0, tiled=True)
-                    if op is mpi_ops.Average and compression is Compression.none:
-                        # Sharded update: divide the 1/n shard, not the
-                        # gathered whole — elementwise division commutes
-                        # with the gather, so this is bit-identical to
-                        # dividing after (and 1/n of the work). Under
-                        # wire compression the division stays in the
-                        # decompressed dtype at the tail instead.
-                        shard = shard / n
-                        for i in members:
-                            averaged[i] = True
-                    with _act(bucket_name, _tl_names.ALLGATHER):
-                        reduced = lax.all_gather(shard, axis, tiled=True)
-                    if pad:
-                        reduced = reduced[:size]
-                elif len(members) == 1:
-                    reduced = reduce_fn(compressed[members[0]], axis)
                 else:
-                    reduced = reduce_fn(_pack_flat(members, bucket_name),
-                                        axis)
+                    # The flat path (no ladder): every member reduced
+                    # in its own shape, one collective a member (grouping
+                    # them on the wire is XLA's all-reduce combiner's) —
+                    # no gradient byte is copied into a flat buffer.
+                    issued.append((len(members), int(bucket.nbytes),
+                                   len(members), 0))
+                    reduced = reduce_fn(
+                        tuple(compressed[i] for i in members), axis)
         except Exception:
             if emit:
                 tl.end(bucket_name, _tl_names.ALLREDUCE)
@@ -809,18 +782,18 @@ def fused_reduce(
 
         def _unpack():
             try:
-                with _jax.named_scope(scope):
-                    if len(members) == 1 and not flat_form:
-                        results[members[0]] = reduced
-                        return
-                    with _act(bucket_name,
-                              _tl_names.MEMCPY_OUT_FUSION_BUFFER):
-                        offset = 0
-                        for i in members:
-                            sz = compressed[i].size
-                            results[i] = reduced[offset:offset + sz].reshape(
-                                compressed[i].shape)
-                            offset += sz
+                if not hier:
+                    for i, r in zip(members, reduced):
+                        results[i] = r
+                    return
+                with _jax.named_scope(scope), _act(
+                        bucket_name, _tl_names.MEMCPY_OUT_FUSION_BUFFER):
+                    offset = 0
+                    for i in members:
+                        sz = compressed[i].size
+                        results[i] = reduced[offset:offset + sz].reshape(
+                            compressed[i].shape)
+                        offset += sz
             finally:
                 if emit:
                     tl.end(bucket_name, _tl_names.ALLREDUCE)

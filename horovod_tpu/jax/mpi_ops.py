@@ -78,10 +78,11 @@ def _axis_size(axis) -> int:
 
 
 def _pprod(tensor, axis):
-    """Cross-rank elementwise product. XLA has no product collective;
-    gather + local product keeps it exact (log/exp would lose signs)."""
-    gathered = lax.all_gather(tensor, axis)
-    return jnp.prod(gathered, axis=0)
+    """Cross-rank elementwise product of a tensor (or a tree of them, as
+    ``lax.psum`` takes). XLA has no product collective; gather + local
+    product keeps it exact (log/exp would lose signs)."""
+    return jax.tree_util.tree_map(lambda g: jnp.prod(g, axis=0),
+                                  lax.all_gather(tensor, axis))
 
 
 _REDUCE_FNS = {
@@ -330,15 +331,15 @@ def grouped_allreduce(
     overlap: Optional[str] = None,
     hierarchical: Optional[str] = None,
 ):
-    """Allreduce a list of tensors as fused flat buckets.
+    """Allreduce a list of tensors as one bucketed exchange.
 
     TPU-native equivalent of the reference's tensor fusion (operations.cc:
-    2160-2264 + fusion_buffer_manager): tensors are grouped by dtype,
-    flattened and concatenated into buckets of at most the fusion threshold
-    (HOROVOD_FUSION_THRESHOLD, default 64 MB), each bucket is one
-    ``lax.psum``, then the results are split back out. One big ICI
-    all-reduce amortizes latency exactly like the reference's fusion buffer
-    amortized NCCL launch + ring latency. ``overlap`` (auto|on|off)
+    2160-2264 + fusion_buffer_manager): tensors are grouped by dtype and
+    planned into buckets of at most the fusion threshold
+    (HOROVOD_FUSION_THRESHOLD, default 64 MB); on a single slice each
+    member is reduced in its own shape under its bucket's scope (no flat
+    buffer: XLA's all-reduce combiner batches them on the wire), on the
+    multi-slice ladder a bucket is one flat buffer. ``overlap`` (auto|on|off)
     selects the backward-overlapped bucket emission and ``hierarchical``
     (auto|on|off) the two-level ICI/DCN ladder — see
     :mod:`horovod_tpu.jax.fusion`.

@@ -13,12 +13,15 @@ Parity targets:
   arrays, so a pytree broadcast subsumes it.
 
 TPU-native design: the optimizer is an ``optax.GradientTransformation``
-wrapper whose update step fuses gradient leaves into flat buckets
-(:mod:`horovod_tpu.jax.fusion`) and reduces each with one ``lax.psum``. The
+wrapper whose update step plans gradient leaves into buckets
+(:mod:`horovod_tpu.jax.fusion`) and reduces each leaf in its own shape, one
+``lax.psum`` a leaf under its bucket's scope (only the multi-slice ladder
+packs a bucket into a flat buffer). The
 reference fired one allreduce per gradient from a backward hook as autograd
 produced them (torch/__init__.py:95-130), relying on the background fusion
 thread to batch them; under XLA the whole step is one program, so bucketing
-at trace time achieves the same overlap with zero runtime coordination.
+at trace time achieves the same overlap with zero runtime coordination, and
+XLA's all-reduce combiner does the batching on the wire.
 """
 
 from __future__ import annotations

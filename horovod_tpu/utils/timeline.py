@@ -71,12 +71,11 @@ ALLREDUCE = "ALLREDUCE"
 ALLGATHER = "ALLGATHER"
 BROADCAST = "BROADCAST"
 ALLTOALL = "ALLTOALL"
-# Overlap-shaped bucket reductions (horovod_tpu/jax/fusion.py): buckets
-# above the scatter threshold split the allreduce into its ring halves —
-# REDUCESCATTER then (after the sharded update) ALLGATHER — each its own
-# activity under the bucket's ALLREDUCE span, which under overlap opens
-# at collective ISSUE and closes at fusion-buffer UNPACK so the trace
-# shows every in-flight bucket.
+# Bucket reductions (horovod_tpu/jax/fusion.py): the hierarchical ladder
+# splits a bucket's allreduce into REDUCESCATTER, the DCN exchange and
+# ALLGATHER, each its own activity under the bucket's ALLREDUCE span,
+# which under overlap opens at collective ISSUE and closes at UNPACK so
+# the trace shows every in-flight bucket.
 REDUCESCATTER = "REDUCESCATTER"
 # XLA-path additions: what the span ``hvd.spmd.dispatch`` is exported as.
 XLA_COMPILE = "XLA_COMPILE"

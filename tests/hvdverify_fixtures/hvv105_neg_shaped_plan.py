@@ -1,9 +1,9 @@
-"""HVV105 negative: the overlap SCATTER form — every bucket takes
-psum_scatter -> sharded-update -> all_gather (scatter threshold 0). The
-reconciliation must accept the rs+ag pair per bucket: the scatter's
-payload is the bucket padded to an axis-size multiple, the gather
-returns the 1/n shard — same ring wire bytes as the allreduce it
-replaces (fusion.py's documented decomposition)."""
+"""HVV105 negative: the SHAPED form of a multi-member bucket — both
+leaves pack into one bucket of the plan, and the flat path reduces each
+in its own shape: two psums under the bucket's ``hvd_allreduce_*`` scope
+whose payloads sum to the bucket's bytes. The reconciliation must accept
+them as that bucket (no entry carries the bucket's bytes alone, and
+nothing is padded, scattered or gathered)."""
 
 import jax.numpy as jnp
 from jax import lax  # noqa: F401
@@ -12,13 +12,13 @@ from tests.hvdverify_fixtures._common import P, f32, mesh, shmap
 
 EXPECT = ()
 
-_THRESHOLD = 300
+_THRESHOLD = 1 << 20  # both leaves pack into ONE bucket
 
 
 def _leaves():
     import jax
 
-    return [jax.ShapeDtypeStruct((130,), jnp.float32),  # pads to 136
+    return [jax.ShapeDtypeStruct((16, 130), jnp.float32),
             jax.ShapeDtypeStruct((64,), jnp.float32)]
 
 
@@ -42,11 +42,10 @@ def build():
         try:
             return tuple(fused_reduce([a, b], average=True,
                                       fusion_threshold=_THRESHOLD,
-                                      overlap="on", scatter_threshold=0,
-                                      name="grads"))
+                                      overlap="on", name="grads"))
         finally:
             _state.reset_spmd_axis(tok)
 
     fn = shmap(exchange, mesh(hvd=8), in_specs=(P(), P()),
                out_specs=(P(), P()))
-    return fn, (f32(130), f32(64))
+    return fn, (f32(16, 130), f32(64))

@@ -198,8 +198,17 @@ def test_optimizer_overlap_issue_order_is_reverse(hvd):
     read directly off the verified schedules' issue indices."""
     fused, over = verify_programs(
         programs(names=["optimizer.fused", "optimizer.overlap"]))
-    fwd = [op.payload_bytes for op in fused.schedule]
-    rev = [op.payload_bytes for op in over.schedule]
+    def buckets(result):
+        # [(bucket scope, its members' payloads in issue order)], in order
+        # of first issue
+        by_scope = {}
+        for op in result.schedule:
+            scope = [p for p in op.name_stack.split("/")
+                     if p.startswith("hvd_allreduce_")][0]
+            by_scope.setdefault(scope, []).append(op.payload_bytes)
+        return list(by_scope.items())
+
+    fwd, rev = buckets(fused), buckets(over)
     assert fwd == rev[::-1], (fwd, rev)
     assert len(fwd) >= 2  # multi-bucket plan, or the pin is vacuous
 
@@ -626,6 +635,39 @@ def test_hvv105_flags_untagged_exchange_beside_tagged(hvd):
     assert [f.rule for f in res.findings] == ["HVV105"], [
         f.format() for f in res.findings]
     assert "OUTSIDE the tagged fused exchange" in res.findings[0].message
+
+
+def test_hvv105_shaped_bucket_must_sum_to_its_bytes(hvd):
+    """The flat path's bucket is the psum entries under its own scope:
+    they reconcile when they sum to the bucket's bytes, and a plan whose
+    bucket holds a leaf the traced bucket lacks (a gradient that fell out
+    of the exchange) does not — though every traced entry is tagged."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.jax.fusion import fused_reduce
+    from tools.hvdverify.rules import ReconcileSpec
+
+    traced = [jax.ShapeDtypeStruct((16, 24), jnp.float32),
+              jax.ShapeDtypeStruct((24,), jnp.float32)]
+
+    def exchange(a, b):
+        return tuple(fused_reduce([a, b], fusion_threshold=1 << 20,
+                                  name="grads"))
+
+    run = hvd.spmd_fn(exchange, in_specs=(P(), P()), out_specs=(P(), P()))
+
+    def check(leaves):
+        return verify((lambda a, b: run(a, b)), tuple(traced), name="shaped",
+                      reconcile=ReconcileSpec(leaves=leaves,
+                                              threshold=1 << 20,
+                                              axis_size=8)).findings
+
+    assert not check(traced), [f.format() for f in check(traced)]
+    dropped = check(traced + [jax.ShapeDtypeStruct((8,), jnp.float32)])
+    assert dropped and {f.rule for f in dropped} == {"HVV105"}
+    assert any("NO matching collective" in f.message for f in dropped)
 
 
 def test_hvv105_flags_flat_trace_under_declared_ladder(hvd):

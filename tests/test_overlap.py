@@ -1,6 +1,7 @@
 """Backward-overlapped bucketed collectives (horovod_tpu/jax/fusion.py):
 the overlap knob changes DISPATCH SHAPE — issue order, start-all/
-unpack-later, rs+ag split for big buckets — and NEVER numerics. Pinned
+unpack-later — and NEVER numerics; on the flat path every member is
+reduced in its own shape, whatever the knob says. Pinned
 bit-exactly over the 8-chip virtual mesh with closed-form integer-valued
 tensors (any cross-rank summation order is exact, so a single differing
 bit means a real semantic change, not float noise), across bucket counts
@@ -9,6 +10,8 @@ the full DistributedOptimizer/train-step wiring.
 """
 
 import json
+import math
+import re
 
 import numpy as np
 import pytest
@@ -35,7 +38,7 @@ def _bases(seed=0):
             for s in _SHAPES]
 
 
-def _run(bases, overlap, threshold, scatter, average, compression=None):
+def _run(bases, overlap, threshold, average, compression=None):
     comp = compression or hvd.Compression.none
 
     def fn():
@@ -43,8 +46,7 @@ def _run(bases, overlap, threshold, scatter, average, compression=None):
         return tuple(fused_reduce(ts, average=average,
                                   compression=comp,
                                   fusion_threshold=threshold,
-                                  overlap=overlap,
-                                  scatter_threshold=scatter))
+                                  overlap=overlap))
 
     return [np.asarray(o) for o in hvd.spmd_run(fn)]
 
@@ -55,21 +57,20 @@ def _run(bases, overlap, threshold, scatter, average, compression=None):
 @pytest.mark.parametrize("average", [False, True])
 def test_overlapped_matches_sequential_bitexact(hvd, threshold, average):
     bases = _bases()
-    ref = _run(bases, "off", threshold, 10**9, average)
-    for overlap, scatter in [("on", 10**9), ("on", 0), ("auto", 0)]:
-        got = _run(bases, overlap, threshold, scatter, average)
+    ref = _run(bases, "off", threshold, average)
+    for overlap in ("on", "auto"):
+        got = _run(bases, overlap, threshold, average)
         for r, g in zip(ref, got):
             np.testing.assert_array_equal(r, g)
 
 
 def test_overlap_bitexact_under_wire_compression(hvd):
-    # fp16 wire: the scatter path must NOT pre-divide the compressed
-    # shard (precision) — division stays at the decompressed tail, so
-    # both modes share one reduction + division sequence exactly.
+    # fp16 wire: each leaf is cast, reduced in its own shape and cast
+    # back; the division stays at the decompressed tail, so both modes
+    # share one reduction + division sequence exactly.
     bases = _bases(seed=1)
-    ref = _run(bases, "off", 400, 10**9, True,
-               compression=hvd.Compression.fp16)
-    got = _run(bases, "on", 400, 0, True, compression=hvd.Compression.fp16)
+    ref = _run(bases, "off", 400, True, compression=hvd.Compression.fp16)
+    got = _run(bases, "on", 400, True, compression=hvd.Compression.fp16)
     for r, g in zip(ref, got):
         np.testing.assert_array_equal(r, g)
 
@@ -79,34 +80,42 @@ def test_overlap_bitexact_mixed_dtypes_and_min(hvd):
     bases = [np.asarray(rng.randint(0, 9, (13,)), np.float32),
              np.asarray(rng.randint(0, 9, (6,)), np.int32),
              np.asarray(rng.randint(0, 9, (50,)), np.float32)]
-    ref = _run(bases, "off", 128, 10**9, False)
-    got = _run(bases, "on", 128, 0, False)
+    ref = _run(bases, "off", 128, False)
+    got = _run(bases, "on", 128, False)
     for r, g in zip(ref, got):
         assert r.dtype == g.dtype
         np.testing.assert_array_equal(r, g)
 
-    # Min has no scatter primitive: overlap mode must still produce the
-    # identical result via the psum-path fallback.
-    def fn(overlap):
+    # Min and Product reduce a bucket's members in place like Sum (a
+    # multi-member float bucket at this threshold): overlap mode must
+    # produce the identical result, and the right one.
+    def fn(overlap, op):
         def inner():
             ts = [b * (hvd.rank() + 1).astype(b.dtype) for b in bases]
-            return tuple(fused_reduce(ts, op=hvd.Min, fusion_threshold=128,
-                                      overlap=overlap, scatter_threshold=0))
+            return tuple(fused_reduce(ts, op=op, fusion_threshold=10**6,
+                                      overlap=overlap))
         return [np.asarray(o) for o in hvd.spmd_run(inner)]
 
-    for r, g in zip(fn("off"), fn("on")):
-        np.testing.assert_array_equal(r, g)
+    for op in (hvd.Min, hvd.Product):
+        for r, g in zip(fn("off", op), fn("on", op)):
+            np.testing.assert_array_equal(r, g)
+    for b, g in zip(bases, fn("on", hvd.Min)):
+        np.testing.assert_array_equal(b, g)        # rank 0 holds the least
+    for b, g in zip(bases[:1], fn("on", hvd.Product)):
+        np.testing.assert_allclose(
+            b.astype(np.float64) ** hvd.size()
+            * math.factorial(hvd.size()), g, rtol=1e-6)
 
 
-def _collect(jaxpr, names):
+def _eqns(jaxpr, names):
+    """Every equation of ``jaxpr`` (nested programs included) whose
+    primitive is one of ``names``, in program order."""
     found = []
 
     def walk(jx):
         for eqn in jx.eqns:
             if eqn.primitive.name in names:
-                nbytes = sum(v.aval.size * v.aval.dtype.itemsize
-                             for v in eqn.invars if hasattr(v.aval, "size"))
-                found.append((eqn.primitive.name, nbytes))
+                found.append(eqn)
             for v in eqn.params.values():
                 for item in (v if isinstance(v, (tuple, list)) else [v]):
                     if hasattr(item, "jaxpr"):
@@ -118,7 +127,14 @@ def _collect(jaxpr, names):
     return found
 
 
-def _trace(overlap, threshold, scatter):
+def _collect(jaxpr, names):
+    return [(eqn.primitive.name,
+             sum(v.aval.size * v.aval.dtype.itemsize
+                 for v in eqn.invars if hasattr(v.aval, "size")))
+            for eqn in _eqns(jaxpr, names)]
+
+
+def _trace(overlap, threshold):
     import jax
 
     bases = _bases()
@@ -128,8 +144,7 @@ def _trace(overlap, threshold, scatter):
               for b in bases]
         return tuple(fused_reduce(ts, average=False,
                                   fusion_threshold=threshold,
-                                  overlap=overlap,
-                                  scatter_threshold=scatter))
+                                  overlap=overlap))
 
     tok = _state.set_spmd_axis("hvd")
     try:
@@ -140,33 +155,30 @@ def _trace(overlap, threshold, scatter):
         _state.reset_spmd_axis(tok)
 
 
-def test_scatter_wire_shape(hvd):
-    """Overlap + scatter: every bucket becomes psum_scatter + all_gather
-    (the ring halves — same wire bytes as the one allreduce they
-    replace), and the big flat psum is gone."""
-    jx = _trace("on", 10**9, 0)
-    rs = _collect(jx, {"psum_scatter", "reduce_scatter"})
-    ag = _collect(jx, {"all_gather"})
-    psums = [b for _, b in _collect(jx, {"psum", "psum2"}) if b > 64]
-    assert rs and ag and not psums, (rs, ag, psums)
-    grad_bytes = sum(int(np.prod(s)) * 4 for s in _SHAPES)
-    rs_bytes = sum(b for _, b in rs)
-    # >= from the divisibility pad, < 2x on these shapes.
-    assert grad_bytes <= rs_bytes < 2 * grad_bytes, (rs_bytes, grad_bytes)
-    # The gather moves the 1/8 shards back out.
-    assert sum(b for _, b in ag) * 8 == rs_bytes
+_PACKING = {"concatenate", "pad", "psum_scatter", "reduce_scatter",
+            "all_gather"}
 
 
-def test_overlap_auto_single_bucket_keeps_legacy_wire(hvd):
-    """auto with a one-bucket plan = the historical emission: one flat
-    psum, no scatter primitives — so the pinned DP wire shapes
-    (test_wire_bytes) hold under the default knob."""
-    jx = _trace("auto", 10**9, 10**9)
-    assert not _collect(jx, {"psum_scatter", "reduce_scatter",
-                             "all_gather"})
-    big = [b for _, b in _collect(jx, {"psum", "psum2"}) if b > 64]
-    grad_bytes = sum(int(np.prod(s)) * 4 for s in _SHAPES)
-    assert big == [grad_bytes], (big, grad_bytes)
+def test_shaped_wire_shape(hvd):
+    """The flat path's wire shape: a multi-member bucket traces to one
+    psum a member under the bucket's scope, each in its member's own
+    shape, and nothing packs, scatters or gathers anywhere."""
+    jx = _trace("on", 10**9)                # one bucket of all five
+    assert not _eqns(jx, _PACKING), _eqns(jx, _PACKING)
+    psums = _eqns(jx, {"psum", "psum2"})
+    assert [tuple(e.invars[0].aval.shape) for e in psums] == _SHAPES
+    assert all(len(e.invars) == 1 and "hvd_allreduce_fused_float32_b0"
+               in str(e.source_info.name_stack) for e in psums), psums
+
+
+def test_overlap_auto_single_bucket_keeps_issue_order(hvd):
+    """auto with a one-bucket plan = the sequential emission (nothing to
+    interleave): the members' psums in input order, as under "off"."""
+    auto, off = _trace("auto", 10**9), _trace("off", 10**9)
+    assert not _eqns(auto, _PACKING)
+    assert (_collect(auto, {"psum", "psum2"})
+            == _collect(off, {"psum", "psum2"})
+            == [("psum", int(np.prod(s)) * 4) for s in _SHAPES])
 
 
 def test_overlap_issues_buckets_in_reverse_order(hvd):
@@ -174,17 +186,25 @@ def test_overlap_issues_buckets_in_reverse_order(hvd):
     program order is the LAST bucket's (the gradients backward produces
     first), so XLA's async scheduler gets each start next to its
     producers. threshold 400 makes per-bucket byte sizes distinct."""
-    sizes_off = [b for _, b in _collect(_trace("off", 400, 10**9),
-                                        {"psum", "psum2"}) if b > 64]
-    sizes_on = [b for _, b in _collect(_trace("on", 400, 10**9),
-                                       {"psum", "psum2"}) if b > 64]
-    assert len(sizes_off) >= 3
+    def bucket_bytes(jx):
+        # Payload bytes by bucket scope, in order of first issue.
+        sizes = {}
+        for e in _eqns(jx, {"psum", "psum2"}):
+            scope = [p for p in str(e.source_info.name_stack).split("/")
+                     if p.startswith("hvd_allreduce_")]
+            sizes[scope[0]] = (sizes.get(scope[0], 0)
+                               + e.invars[0].aval.size * 4)
+        return list(sizes.values())
+
+    sizes_off = bucket_bytes(_trace("off", 400))
+    sizes_on = bucket_bytes(_trace("on", 400))
+    assert len(sizes_off) >= 3 and len(set(sizes_off)) == len(sizes_off)
     assert sizes_on == list(reversed(sizes_off)), (sizes_off, sizes_on)
 
 
 def test_overlap_knob_validation(hvd):
     with pytest.raises(InvalidArgumentError):
-        _run(_bases(), "bogus", 400, 0, True)
+        _run(_bases(), "bogus", 400, True)
 
 
 def test_resolve_overlap_semantics(hvd):
@@ -264,8 +284,8 @@ def test_distributed_optimizer_overlap_bitexact(hvd):
 def test_timeline_marks_in_flight_buckets(hvd, tmp_path):
     """Per-in-flight-bucket observability: under overlap each bucket's
     ALLREDUCE span opens at issue (args carry issue order + in-flight
-    count + path) and the scatter form emits REDUCESCATTER/ALLGATHER
-    activities inside it."""
+    count + path); on the flat path nothing is packed, so no MEMCPY or
+    REDUCESCATTER / ALLGATHER activity appears inside it."""
     from horovod_tpu.utils.timeline import Timeline
 
     st = _state.global_state()
@@ -273,7 +293,7 @@ def test_timeline_marks_in_flight_buckets(hvd, tmp_path):
     saved = st.timeline
     st.timeline = Timeline(str(trace))
     try:
-        _run(_bases(), "on", 400, 0, True)
+        _run(_bases(), "on", 400, True)
     finally:
         st.timeline.close()
         st.timeline = saved
@@ -286,9 +306,50 @@ def test_timeline_marks_in_flight_buckets(hvd, tmp_path):
     assert all(e["args"]["overlap"] for e in starts)
     assert all(e["args"]["in_flight"] == e["args"]["issue"] + 1
                for e in starts)
-    assert {"rs_ag"} == {e["args"]["path"] for e in starts}
-    names = [e.get("name") for e in events]
-    assert "REDUCESCATTER" in names and "ALLGATHER" in names
+    assert {"psum"} == {e["args"]["path"] for e in starts}
+    names = {e.get("name") for e in events}
+    assert not names & {"REDUCESCATTER", "ALLGATHER",
+                        "MEMCPY_IN_FUSION_BUFFER",
+                        "MEMCPY_OUT_FUSION_BUFFER"}, names
     # Every span closes.
     ends = [e for e in events if e["ph"] == "E"]
     assert len(ends) >= len(starts)
+
+
+def test_distributed_optimizer_step_lowers_without_concatenating_gradients(
+        hvd):
+    """A DistributedOptimizer step over a tree with tiled 2-D leaves lowers
+    to one all-reduce a gradient leaf, in the leaf's own shape, and to no
+    concatenate at all: no gradient byte goes through a flat buffer."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu.jax.optimizer import DistributedOptimizer
+
+    params = {"w1": jnp.ones((256, 512)), "b1": jnp.zeros((512,)),
+              "w2": jnp.ones((512, 128)), "scale": jnp.ones((128,))}
+    opt = DistributedOptimizer(optax.adam(1e-3))
+    opt_state = opt.init(params)
+
+    def loss(p, x):
+        h = jnp.tanh(x @ p["w1"] + p["b1"])
+        return jnp.mean((h @ p["w2"]) * p["scale"])
+
+    def step(p, s, x):
+        updates, s = opt.update(jax.grad(loss)(p, x), s, p)
+        return optax.apply_updates(p, updates), s
+
+    tok = _state.set_spmd_axis("hvd")
+    try:
+        text = jax.jit(jax.shard_map(
+            step, mesh=hvd.mesh(), in_specs=(P(), P(), P("hvd")),
+            out_specs=(P(), P()), check_vma=False)).lower(
+                params, opt_state, jnp.ones((16, 256))).as_text()
+    finally:
+        _state.reset_spmd_axis(tok)
+    assert "concatenate" not in text
+    shapes = re.findall(
+        r'"stablehlo\.all_reduce"\(%[^)]*\).*?\}\) : \(tensor<([0-9x]+)xf32>\)',
+        text, flags=re.S)
+    assert sorted(shapes) == ["128", "256x512", "512", "512x128"], shapes

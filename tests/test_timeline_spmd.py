@@ -92,10 +92,10 @@ def test_spmd_timeline_content(tmp_path):
                       and e["args"]["name"] == "grads.float32.b0")
     bucket_names = {e.get("name") for e in events
                     if e.get("tid") == bucket_tid and e.get("ph") == "B"}
-    assert "ALLREDUCE" in bucket_names
-    assert "MEMCPY_IN_FUSION_BUFFER" in bucket_names
-    assert "MEMCPY_OUT_FUSION_BUFFER" in bucket_names
+    # the flat path packs nothing: no memcpy in or out of a fusion buffer
+    assert bucket_names == {"ALLREDUCE"}
     ar = next(e for e in events if e.get("name") == "ALLREDUCE"
               and e.get("tid") == bucket_tid and e["ph"] == "B")
     assert ar["args"]["tensors"] == 2
+    assert ar["args"]["path"] == "psum"
     assert ar["args"]["span"] == "trace"

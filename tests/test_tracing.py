@@ -218,11 +218,10 @@ def _grad_leaves():
     return [jnp.asarray(rng.randn(*s), jnp.float32) for s in shapes]
 
 
-@pytest.mark.parametrize("overlap, threshold, scatter", [
-    ("off", 64 * 1024 * 1024, None), ("off", 20_000, None),
-    ("on", 20_000, 8_000), ("on", 20_000, 1 << 30)])
+@pytest.mark.parametrize("threshold", [64 * 1024 * 1024, 20_000])
+@pytest.mark.parametrize("overlap", ["off", "on"])
 def test_exchange_gauges_equal_the_bucket_plan_on_four_devices(
-        hvd, overlap, threshold, scatter):
+        hvd, overlap, threshold):
     from horovod_tpu.jax import fusion
 
     timeline.reset()
@@ -231,23 +230,22 @@ def test_exchange_gauges_equal_the_bucket_plan_on_four_devices(
     def plan_step(*xs):
         return tuple(fusion.fused_reduce(
             list(xs), fusion_threshold=threshold, overlap=overlap,
-            scatter_threshold=scatter, name="grads"))
+            name="grads"))
 
     run = hvd.spmd_fn(plan_step, mesh=_mesh(4), in_specs=P(), out_specs=P())
     run(*leaves)
     plan = fusion.plan_buckets(leaves, threshold)
-    scattered = [b for b in plan
-                 if overlap == "on" and b.nbytes >= scatter]
-    plain = [b for b in plan if b not in scattered]
     got = _gauges_of("plan_step")
     assert got["hvd.exchange.buckets"] == len(plan)
     assert got["hvd.exchange.bytes"] == sum(b.nbytes for b in plan)
     assert got["hvd.exchange.bytes"] == sum(x.nbytes for x in leaves)
     assert got["hvd.exchange.tensors"] == len(leaves)
-    # 1 a psum bucket, 2 a reduce-scatter + all-gather bucket
-    assert got["hvd.exchange.calls"] == len(plain) + 2 * len(scattered)
+    # every member reduced in its own shape: one collective a tensor, and
+    # no byte copied into a flat buffer first
+    assert got["hvd.exchange.calls"] == got["hvd.exchange.tensors"]
+    assert got["hvd.exchange.packed_bytes"] == 0
     assert set(got) == {"hvd.exchange." + what for what in (
-        "calls", "bytes", "tensors", "buckets")}
+        "calls", "bytes", "tensors", "buckets", "packed_bytes")}
 
 
 def test_exchange_gauges_count_the_ladders_legs(hvd):
@@ -272,6 +270,8 @@ def test_exchange_gauges_count_the_ladders_legs(hvd):
     assert got["hvd.exchange.buckets"] == 1
     assert got["hvd.exchange.calls"] == 3
     assert got["hvd.exchange.bytes"] == sum(x.nbytes for x in leaves)
+    # the ladder packs every bucket into its flat, padded buffer
+    assert got["hvd.exchange.packed_bytes"] == got["hvd.exchange.bytes"]
 
 
 def test_exchange_gauges_are_zero_on_one_device_and_a_retrace_overwrites(hvd):

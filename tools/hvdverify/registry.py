@@ -12,7 +12,7 @@ structure). Groups:
                  transformer_lm families) through ``spmd_fn`` with the
                  state donated, plus the window / overlap / ZeRO /
                  fused-CE lane variants.
-* ``optimizer``— DistributedOptimizer's fused / overlap / scatter
+* ``optimizer``— DistributedOptimizer's fused / overlap / shaped
                  emission modes, each with an HVV105 ReconcileSpec
                  pinning the traced bytes to ``plan_buckets``.
 * ``dp``       — the hierarchical DP exchange (HOROVOD_HIERARCHICAL)
@@ -294,7 +294,7 @@ def _mnist_param_leaves():
 _OPT_THRESHOLD = 64 * 1024  # multi-bucket plan on the MNIST tree
 
 
-def _optimizer_mode(*, overlap, scatter):
+def _optimizer_mode(*, overlap, threshold=_OPT_THRESHOLD):
     """DistributedOptimizer traced in one emission mode over the MNIST
     parameter tree, inside shard_map over the "hvd" axis — the program
     tests/test_overlap.py exercises dynamically, verified statically."""
@@ -304,21 +304,16 @@ def _optimizer_mode(*, overlap, scatter):
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu.common.state import global_state
         from horovod_tpu.jax.fusion import fused_reduce
 
         hvd = _init()
-        st = global_state()
-        scatter_threshold = 0 if scatter else (
-            st.config.overlap_scatter_threshold)
         leaves = _mnist_param_leaves()
 
         def exchange(*grads):
             return tuple(fused_reduce(
                 list(grads), average=True,
-                fusion_threshold=_OPT_THRESHOLD,
+                fusion_threshold=threshold,
                 overlap=overlap,
-                scatter_threshold=scatter_threshold,
                 name="grads"))
 
         run = hvd.spmd_fn(
@@ -333,7 +328,7 @@ def _optimizer_mode(*, overlap, scatter):
     def reconcile():
         return ReconcileSpec(
             leaves=_mnist_param_leaves(),
-            threshold=_OPT_THRESHOLD,
+            threshold=threshold,
             axis_size=WORLD,
         )
 
@@ -1323,11 +1318,15 @@ def _make_registry() -> List[Program]:
     ]
 
     # DistributedOptimizer emission modes, byte-reconciled (HVV105).
-    for mode, overlap, scatter in (("fused", "off", False),
-                                   ("overlap", "on", False),
-                                   ("scatter", "on", True)):
-        build, reconcile = _optimizer_mode(overlap=overlap,
-                                           scatter=scatter)
+    # "shaped": the default threshold puts the whole tree into one
+    # bucket, whose members each go in their own shape.
+    from horovod_tpu.common.config import DEFAULT_FUSION_THRESHOLD
+
+    for mode, kwargs in (("fused", dict(overlap="off")),
+                         ("overlap", dict(overlap="on")),
+                         ("shaped", dict(overlap="auto",
+                                         threshold=DEFAULT_FUSION_THRESHOLD))):
+        build, reconcile = _optimizer_mode(**kwargs)
         progs.append(Program(f"optimizer.{mode}", "optimizer", build,
                              reconcile=reconcile))
 

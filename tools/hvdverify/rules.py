@@ -19,6 +19,7 @@ catalogue; the long-form catalogue lives in docs/static_analysis.md).
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Dict, List, Optional, Sequence
 
 from horovod_tpu.parallel.logical import DATA_AXIS
@@ -40,9 +41,9 @@ RULES: Dict[str, str] = {
               "invariant)",
     "HVV105": "static wire-byte accounting does not reconcile with the "
               "declared fusion bucket plan "
-              "(horovod_tpu.jax.fusion.plan_buckets; flat psum, "
-              "scatter rs+ag, or the hierarchical rs->exchange->ag "
-              "ladder incl. quantized DCN legs)",
+              "(horovod_tpu.jax.fusion.plan_buckets; a psum a member "
+              "under the bucket's scope, or the hierarchical "
+              "rs->exchange->ag ladder incl. quantized DCN legs)",
     "HVV201": "declared in/out/param partition specs do not reconcile "
               "with the LogicalMesh axis-rules table — the sharding "
               "analogue of HVV105's byte reconciliation",
@@ -91,7 +92,7 @@ class ReconcileSpec:
     ``leaves``: the gradient leaves (arrays or ShapeDtypeStructs) the
     bucketed exchange reduces; ``threshold``: the fusion threshold the
     plan was built with; ``axis_size``: the collective axis size (the
-    scatter form pads flat buckets to a multiple of it).
+    ladder pads its flat buckets by it and ``hier_inner``).
 
     ``hier_inner`` declares the hierarchical ladder (HOROVOD_
     HIERARCHICAL, fusion.py): each bucket must decompose into
@@ -111,10 +112,6 @@ class ReconcileSpec:
     dcn_dtype: Optional[str] = None
 
 
-def _pad_up(nbytes: int, quantum: int) -> int:
-    return ((nbytes + quantum - 1) // quantum) * quantum
-
-
 def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
                          spec: ReconcileSpec) -> List[Finding]:
     """HVV105: the traced schedule's gradient-exchange collectives must
@@ -122,8 +119,11 @@ def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
 
     Matching contract (per bucket of ``plan_buckets(leaves, threshold)``):
 
-    * a ``psum`` entry whose payload equals the bucket's bytes (the
-      fused flat allreduce), or
+    * the ``psum`` entries under that bucket's
+      ``hvd_allreduce_<name>_<dtype>_b<i>`` scope, whose payloads must sum
+      to the bucket's bytes (the flat path: every member reduced in its
+      own shape, one entry a member); where the program tags nothing, one
+      ``psum`` entry of the bucket's bytes (a hand-rolled flat bucket), or
     * when ``spec.hier_inner`` is set, the hierarchical rs->exchange->ag
       decomposition (fusion.hier_bucket_layout — the SAME layout the
       executing path computes): a ``psum_scatter`` of the
@@ -133,11 +133,7 @@ def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
       ``all_gather`` of the shard — any missing or mis-sized leg is a
       finding, and a bucket traced as one FLAT full-bytes psum under a
       declared ladder is a finding too (a ladder that silently never
-      engaged must not keep the sweep green); or
-    * a ``reduce_scatter``/``psum_scatter`` entry whose payload equals
-      the bucket's bytes padded up to ``axis_size`` elements (the
-      overlap scatter form) AND a matching ``all_gather`` of the 1/n
-      shard.
+      engaged must not keep the sweep green).
 
     Entries are pre-filtered to the fusion data plane: collectives whose
     jax name_stack carries the ``hvd_allreduce`` scope fusion.py wraps
@@ -199,6 +195,20 @@ def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
             if op.payload_bytes == nbytes:
                 return pool.pop(i)
         return None
+
+    def _take_flat(bucket) -> bool:
+        """The flat path's form of ``bucket``: the psum entries under its
+        own scope, summing to its bytes (untagged: one entry of them)."""
+        if not used_tag_filter:
+            return _take(reduces, bucket.nbytes) is not None
+        scope = re.compile(
+            rf"hvd_allreduce_\w*_{bucket.dtype}_b{bucket.index}(/|$)")
+        mine = [op for op in reduces if scope.search(op.name_stack)]
+        if not mine or sum(op.payload_bytes for op in mine) != bucket.nbytes:
+            return False
+        for op in mine:
+            reduces.remove(op)
+        return True
 
     def _match_hier(bucket, itemsize) -> Optional[List[str]]:
         """Try the hierarchical decomposition for ``bucket``: returns
@@ -274,11 +284,11 @@ def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
                         "inter-slice exchange -> ag per bucket "
                         "(fusion.py hierarchical contract)"))
                 continue
-            if _take(reduces, bucket.nbytes) is not None:
+            if _take_flat(bucket):
                 findings.append(Finding(
                     program, "HVV105",
                     f"bucket {bucket.dtype}.b{bucket.index} "
-                    f"({bucket.nbytes} B) traced as ONE FLAT psum "
+                    f"({bucket.nbytes} B) traced as the FLAT psum form "
                     f"while the plan declares the inner-"
                     f"{spec.hier_inner} hierarchical ladder: the "
                     "ladder silently did not engage, and the "
@@ -286,20 +296,7 @@ def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
                     "program promises (resolve_hierarchical config "
                     "drift)"))
                 continue
-        elif _take(reduces, bucket.nbytes) is not None:
-            continue
-        padded = _pad_up(bucket.nbytes, spec.axis_size * itemsize)
-        rs = _take(scatters, padded)
-        if rs is not None:
-            ag = _take(gathers, padded // spec.axis_size)
-            if ag is None:
-                findings.append(Finding(
-                    program, "HVV105",
-                    f"bucket {bucket.dtype}.b{bucket.index} "
-                    f"({bucket.nbytes} B) reduce-scatters but its "
-                    f"{padded // spec.axis_size} B all-gather of the "
-                    "shard is missing — the scatter form must gather "
-                    "back (fusion.py rs+ag contract)"))
+        elif _take_flat(bucket):
             continue
         findings.append(Finding(
             program, "HVV105",

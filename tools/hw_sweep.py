@@ -38,8 +38,8 @@ LANES = [
     ("resnet50_win30", ["bench.py", "--steps-per-dispatch", "30"]),
     ("resnet50_fused_bn", ["bench.py", "--fused-bn"]),
     # Overlap A/B (round-7 tentpole, horovod_tpu/jax/fusion.py):
-    # backward-overlapped bucketed collectives (reverse-order issue,
-    # rs+ag for big buckets) vs the legacy post-backward block —
+    # backward-overlapped bucketed collectives (reverse-order issue)
+    # vs the legacy post-backward block —
     # adjacent so the pair shares chip condition. A 1 MiB fusion
     # threshold gives ResNet-50's 98 MB of fp32 gradients a ~100-bucket
     # plan, the regime where issue order and async scheduling can

@@ -136,7 +136,7 @@ def ring_allreduce_us(nbytes, n, bw_gbps, hop_latency_us, dispatch_us,
     """One bucket's ring-allreduce wall time on an n-chip ring:
     2(n-1)/n of the bytes over the per-chip bandwidth, 2(n-1) hop
     latencies, plus the fixed per-collective dispatch cost
-    (``split_collectives=2`` for the overlap path's rs+ag pair)."""
+    (``split_collectives=2`` for the ladder's intra-slice rs+ag pair)."""
     if n <= 1:
         return 0.0
     wire_bytes = 2.0 * (n - 1) / n * nbytes
@@ -213,7 +213,6 @@ def predict_efficiency(name, n, fusion_threshold, overlap="auto",
                 "step_ms": step_us / 1e3, "buckets": summary["count"]}
     overlapped = overlap in ("on", "auto") and summary["count"] >= (
         1 if overlap == "on" else 2)
-    split = 2 if overlapped else 1
     if dcn_inner:
         comm_us = sum(hierarchical_allreduce_us(b.nbytes, n, dcn_inner,
                                                 dispatch_us,
@@ -221,8 +220,7 @@ def predict_efficiency(name, n, fusion_threshold, overlap="auto",
                       for b in plan)
     else:
         comm_us = sum(ring_allreduce_us(b.nbytes, n, ICI_GBPS,
-                                        ICI_HOP_LATENCY_US, dispatch_us,
-                                        split_collectives=split)
+                                        ICI_HOP_LATENCY_US, dispatch_us)
                       for b in plan)
     backward_us = BACKWARD_FRACTION * step_us
     frac = ((summary["count"] - 1) / summary["count"]) if overlapped else 0.0
