@@ -55,6 +55,7 @@ if os.environ.get("HVD_TPU_FORCE_CPU"):
 # (ResNet-class, bs=64/device). Other models have no published reference
 # throughput, so their JSON carries vs_baseline=null rather than an
 # apples-to-oranges ratio.
+LM_MODELS = ("transformer_lm", "moe_lm")
 _REF_PER_DEVICE = 1656.82 / 16.0
 REFERENCE_BASELINES = {"resnet50": _REF_PER_DEVICE, "resnet101": _REF_PER_DEVICE}
 
@@ -347,7 +348,7 @@ def build_lm_lane(args, log) -> Lane:
 
     import horovod_tpu.jax as hvd
     from horovod_tpu import models
-    from horovod_tpu.utils.timeline import FORWARD, LOSS, span
+    from horovod_tpu.utils.timeline import FORWARD, LOSS, UPDATE, span
 
     if args.fused_bn:
         raise ValueError(
@@ -361,7 +362,13 @@ def build_lm_lane(args, log) -> Lane:
     attn_fn = None
     attention = resolve_attention(args)
     flash_grid = None
-    if attention == "flash":
+    if args.model == "moe_lm":
+        # models/decoder.py calls the kernels itself: they take the layer's
+        # window and its KV heads; the grid flags are the other LM's
+        if args.flash_full_grid or args.flash_bwd is not None:
+            raise ValueError("--flash-full-grid and --flash-bwd apply to "
+                             "transformer_lm only (got --model moe_lm)")
+    elif attention == "flash":
         # Pallas flash attention (ops/attention.py): the O(L)-memory
         # kernel lane, A/B-able against the default dense attention at
         # the same protocol (VERDICT r2 item 6's throughput comparison).
@@ -409,11 +416,14 @@ def build_lm_lane(args, log) -> Lane:
     elif args.flash_bwd is not None:
         raise ValueError("--flash-bwd requires the flash attention "
                          "path (--attention flash, or auto at long seq)")
-    model = models.TransformerLM(
-        vocab_size=args.vocab, num_layers=args.lm_layers,
-        num_heads=args.lm_heads, embed_dim=args.lm_dim,
-        max_len=max(L, 2048), dtype=dtype, attn_fn=attn_fn,
-        scan_layers=args.scan_layers, remat=args.remat)
+    if args.model == "moe_lm":
+        model = build_sparse_lm(args, attention, dtype)
+    else:
+        model = models.TransformerLM(
+            vocab_size=args.vocab, num_layers=args.lm_layers,
+            num_heads=args.lm_heads, embed_dim=args.lm_dim,
+            max_len=max(L, 2048), dtype=dtype, attn_fn=attn_fn,
+            scan_layers=args.scan_layers, remat=args.remat)
     rng = jax.random.PRNGKey(42)
     sample = jnp.zeros((1, L), jnp.int32)
     # --bf16-momentum maps to adam's first-moment dtype on this lane (the
@@ -428,6 +438,18 @@ def build_lm_lane(args, log) -> Lane:
 
     def step_fn(state, batch):
         tokens = batch["tokens"]
+        # A sparse layer's state (its selection bias): read by the forward
+        # pass, which writes the step's expert counts beside it.
+        buffers = state.get("buffers")
+
+        def apply(params, **kw):
+            if buffers is None:
+                return model.apply({"params": params}, tokens, train=False,
+                                   **kw), None
+            out, wrote = model.apply(
+                {"params": params, "buffers": buffers}, tokens, train=False,
+                mutable=["buffers"], **kw)
+            return out, wrote["buffers"]
 
         if args.fused_ce:
             # Chunked fused loss (ops/xent.py): the [B, L, vocab] fp32
@@ -438,29 +460,37 @@ def build_lm_lane(args, log) -> Lane:
 
             def loss_fn(params):
                 with jax.named_scope(FORWARD):
-                    hidden = model.apply({"params": params}, tokens,
-                                         train=False, return_hidden=True)
+                    hidden, wrote = apply(params, return_hidden=True)
                 with jax.named_scope(LOSS):
                     e = hidden.shape[-1]
                     h = hidden[:, :-1].reshape(-1, e).astype(jnp.float32)
                     wv = params["lm_head"]["kernel"].astype(jnp.float32)
-                    return fused_cross_entropy(h, wv,
-                                               tokens[:, 1:].reshape(-1))
+                    return fused_cross_entropy(
+                        h, wv, tokens[:, 1:].reshape(-1)), wrote
         else:
             def loss_fn(params):
                 with jax.named_scope(FORWARD):
-                    logits = model.apply({"params": params}, tokens,
-                                         train=False)
+                    logits, wrote = apply(params)
                 with jax.named_scope(LOSS):
                     logp = jax.nn.log_softmax(
                         logits[:, :-1].astype(jnp.float32))
                     tgt = tokens[:, 1:]
                     nll = -jnp.take_along_axis(logp, tgt[..., None], -1)
-                    return jnp.mean(nll)
+                    return jnp.mean(nll), wrote
 
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"])
+        (loss, wrote), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state["params"])
+        if wrote is not None:
+            from horovod_tpu.common.state import current_spmd_axis
+            from horovod_tpu.models import decoder
+
+            with jax.named_scope(UPDATE):
+                wrote = decoder.update_buffers(
+                    wrote, args.moe_bias_coeff,
+                    current_spmd_axis() if n > 1 else None)
         state, loss = models.read_before_update(state, loss)
-        return models.apply_gradients(optimizer, state, grads), loss
+        return models.apply_gradients(optimizer, state, grads,
+                                      buffers=wrote), loss
 
     with span("hvd.lane.place"):
         batch = {"tokens": jax.random.randint(
@@ -481,7 +511,7 @@ def build_lm_lane(args, log) -> Lane:
                      f"{flash_grid['steps_full']} steps "
                      f"({'truncated' if flash_grid['truncated'] else 'full'}"
                      f", {flash_grid['block_q']}x{flash_grid['block_k']})")
-    log(f"Model: transformer_lm ({args.lm_layers}L/{args.lm_dim}d), "
+    log(f"Model: {args.model} ({args.lm_layers}L/{args.lm_dim}d), "
         f"seq {L}, batch {batch_size} seqs/chip, {n} chips "
         f"({jax.devices()[0].platform}), {attention} attention{grid_note}"
         + (f", {k}-step dispatch windows" if k > 1 else ""),
@@ -490,6 +520,43 @@ def build_lm_lane(args, log) -> Lane:
     return Lane(model, run_step, state, batch, batch_size * L,
                 "tokens/sec", {"attention": attention,
                                "flash_grid": flash_grid, **stamp})
+
+
+def build_sparse_lm(args, attention: str, dtype):
+    """``--model moe_lm``: the sparse decoder LM (models/decoder.py) from
+    the command line. The arguments say which experts this chip holds and
+    how large its slice of the vocabulary is; the router keeps every
+    expert's output and its top ``k``."""
+    from horovod_tpu.models import decoder
+
+    kinds = {"sliding": decoder.SLIDING, "full": decoder.FULL}
+    names = (args.lm_layer_types.split(",") if args.lm_layer_types
+             else ["full"] * args.lm_layers)
+    if len(names) != args.lm_layers or set(names) - set(kinds):
+        raise ValueError(
+            f"--lm-layer-types needs {args.lm_layers} of "
+            f"{sorted(kinds)}, comma-separated; got {args.lm_layer_types!r}")
+    held = args.moe_experts_held or args.moe_experts
+    if not 0 <= args.moe_first_expert <= args.moe_experts - held:
+        raise ValueError(
+            f"experts {args.moe_first_expert} to "
+            f"{args.moe_first_expert + held - 1} are not among the "
+            f"{args.moe_experts} the router scores")
+    if args.scan_layers:
+        raise ValueError("--scan-layers applies to transformer_lm only "
+                         "(got --model moe_lm)")
+    return decoder.SparseDecoderLM(
+        vocab_size=args.vocab, embed_dim=args.lm_dim,
+        layer_types=tuple(kinds[k] for k in names), heads=args.lm_heads,
+        kv_heads=args.lm_kv_heads or args.lm_heads,
+        head_dim=args.lm_head_dim or args.lm_dim // args.lm_heads,
+        window=args.lm_window, dense_layers=args.lm_dense_layers,
+        dense_width=args.lm_ffn or 4 * args.lm_dim,
+        experts=args.moe_experts, experts_held=held,
+        first_expert=args.moe_first_expert, top_k=args.moe_top_k,
+        expert_width=args.moe_width, shared_experts=args.moe_shared,
+        route_scale=args.moe_route_scale, attention=attention, dtype=dtype,
+        remat=args.remat)
 
 
 def audit_stamps(args, run_step, state, batch, log) -> dict:
@@ -510,7 +577,7 @@ def build_lane(args, log) -> Lane:
     from horovod_tpu.utils.timeline import span
 
     with span("hvd.lane.build", model=args.model):
-        if args.model == "transformer_lm":
+        if args.model in LM_MODELS:
             return build_lm_lane(args, log)
         return build_image_lane(args, log)
 
@@ -689,8 +756,8 @@ def metric_contract(args):
         # different (scanned) program than the historical 1-step
         # records — same-name rows would compare apples to oranges.
         return f"{args.model}_first_step_secs{suffix}", "secs"
-    if args.model == "transformer_lm":
-        return (f"transformer_lm_tokens_per_sec_per_chip{suffix}",
+    if args.model in LM_MODELS:
+        return (f"{args.model}_tokens_per_sec_per_chip{suffix}",
                 "tokens/sec/chip")
     return f"{args.model}_img_per_sec_per_chip{suffix}", "img/sec/chip"
 
@@ -742,6 +809,43 @@ def build_parser():
                              "width; --d-model 1024 + --lm-layers 24 + "
                              "--lm-heads 16 is the GPT-2-medium config)")
     parser.add_argument("--lm-heads", type=int, default=12)
+    # --model moe_lm (models/decoder.py): grouped-query attention with a
+    # type a layer, gated feed-forwards, and this chip's share of the
+    # experts. Widths are the model's; what is held here may be a share.
+    parser.add_argument("--lm-kv-heads", type=int, default=None,
+                        help="moe_lm: KV heads (default: --lm-heads)")
+    parser.add_argument("--lm-head-dim", type=int, default=None,
+                        help="moe_lm: size of a head (default: "
+                             "--lm-dim / --lm-heads)")
+    parser.add_argument("--lm-window", type=int, default=2048,
+                        help="moe_lm: window of a sliding layer")
+    parser.add_argument("--lm-layer-types", default=None,
+                        help="moe_lm: 'sliding' (window, rotary positions) "
+                             "or 'full' (no positional encoding) a layer, "
+                             "comma-separated (default: all full)")
+    parser.add_argument("--lm-ffn", type=int, default=None,
+                        help="moe_lm: width of a dense layer's gated "
+                             "feed-forward (default: 4 x --lm-dim)")
+    parser.add_argument("--lm-dense-layers", type=int, default=1,
+                        help="moe_lm: leading layers with a dense "
+                             "feed-forward; the others have experts")
+    parser.add_argument("--moe-experts", type=int, default=8,
+                        help="moe_lm: experts the router scores")
+    parser.add_argument("--moe-experts-held", type=int, default=None,
+                        help="moe_lm: experts this chip holds (default: "
+                             "all); tokens routed elsewhere add nothing "
+                             "here, and none is dropped")
+    parser.add_argument("--moe-first-expert", type=int, default=0,
+                        help="moe_lm: the first expert held here")
+    parser.add_argument("--moe-top-k", type=int, default=2)
+    parser.add_argument("--moe-width", type=int, default=1024,
+                        help="moe_lm: width of one expert")
+    parser.add_argument("--moe-shared", type=int, default=1,
+                        help="moe_lm: shared experts every token passes")
+    parser.add_argument("--moe-route-scale", type=float, default=1.0)
+    parser.add_argument("--moe-bias-coeff", type=float, default=0.001,
+                        help="moe_lm: step of the selection bias's "
+                             "balancing rule after every optimizer step")
     parser.add_argument("--steps-per-dispatch", type=int, default=1,
                         help="compile K training steps into ONE XLA "
                              "program (lax.scan window over a device-"

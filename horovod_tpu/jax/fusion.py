@@ -467,9 +467,7 @@ def _record_plan(issued, n: int) -> None:
     event of the Chrome timeline."""
     from horovod_tpu.utils import timeline
 
-    tracing = timeline.enclosing(timeline.DISPATCH)
-    program = tracing.args.get("program", "") if tracing else ""
-    owner = tracing.id if tracing else None
+    program, owner = timeline.tracing_program()
     held = _plans.get(program)
     totals = (held[1] if held and owner is not None and held[0] == owner
               else collections.Counter())

@@ -27,7 +27,8 @@ from horovod_tpu.models.train import (
     read_before_update,
     state_partition_specs,
 )
-from horovod_tpu.models import parallel_lm
+from horovod_tpu.models import decoder, parallel_lm
+from horovod_tpu.models.decoder import SparseDecoderLM
 from horovod_tpu.models.transformer import TransformerBlock, TransformerLM
 from horovod_tpu.models.vgg import VGG, VGG11, VGG13, VGG16, VGG19
 from horovod_tpu.models.vit import ViT_B16, ViT_S16, VisionTransformer
@@ -73,6 +74,8 @@ __all__ = [
     "InceptionV3",
     "TransformerBlock",
     "TransformerLM",
+    "SparseDecoderLM",
+    "decoder",
     "VisionTransformer",
     "ViT_S16",
     "ViT_B16",

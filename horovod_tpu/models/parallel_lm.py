@@ -501,145 +501,6 @@ def lm_apply_pp(rest: Dict, stacked_layers, tokens, axis: str = "pp",
     return _logits(rest, out.reshape(B, L, x.shape[-1]))
 
 
-def init_moe_lm_params(rng, vocab: int, max_len: int, layers: int,
-                       heads: int, head_dim: int, ffn: int,
-                       num_experts: int, dtype=jnp.float32) -> Dict:
-    """Switch-MoE variant of :func:`init_lm_params`: each block's dense
-    MLP becomes a router (``gate`` [E_dim, experts], replicated) plus
-    ``experts`` stacked expert MLPs (leading axis ``num_experts`` —
-    shard ``P(ep)`` so each chip holds E/P experts)."""
-    params = init_lm_params(rng, vocab, max_len, layers, heads, head_dim,
-                            ffn, dtype)
-    embed_dim = heads * head_dim
-    for i, layer in enumerate(params["layers"]):
-        k = jax.random.fold_in(jax.random.fold_in(rng, 1000), i)
-        kg, ku, kd = jax.random.split(k, 3)
-        for key in ("wup", "bup", "wdn", "bdn"):
-            del layer[key]
-        layer["gate"] = (jax.random.normal(kg, (embed_dim, num_experts))
-                         / math.sqrt(embed_dim)).astype(dtype)
-        layer["experts"] = {
-            "up": (jax.random.normal(ku, (num_experts, embed_dim, ffn))
-                   / math.sqrt(embed_dim)).astype(dtype),
-            "bup": jnp.zeros((num_experts, ffn), dtype),
-            "dn": (jax.random.normal(kd, (num_experts, ffn, embed_dim))
-                   / math.sqrt(ffn)).astype(dtype),
-            "bdn": jnp.zeros((num_experts, embed_dim), dtype),
-        }
-    return params
-
-
-def moe_lm_param_specs(layers: int, ep_axis: Optional[str]):
-    """Spec pytree for :func:`lm_apply_moe` under shard_map: expert
-    stacks shard their leading axis over ``ep_axis``, all else
-    replicated."""
-    from jax.sharding import PartitionSpec as P
-
-    e = ep_axis
-    layer_spec = {
-        "ln1": {"g": P(), "b": P()},
-        "wqkv": P(),
-        "wo": P(),
-        "bo": P(),
-        "ln2": {"g": P(), "b": P()},
-        "gate": P(),
-        "experts": {"up": P(e), "bup": P(e), "dn": P(e), "bdn": P(e)},
-    }
-    return {
-        "embed": P(),
-        "pos": P(),
-        "layers": [dict(layer_spec) for _ in range(layers)],
-        "ln_f": {"g": P(), "b": P()},
-        "head": P(),
-    }
-
-
-def _expert_mlp(p, tokens):
-    h = jax.nn.gelu(tokens @ p["up"] + p["bup"])
-    return h @ p["dn"] + p["bdn"]
-
-
-def lm_apply_moe(params: Dict, tokens, ep: Optional[str] = None,
-                 capacity_factor: float = 1.25):
-    """Switch-MoE LM forward: tokens [B_local, L] -> (logits, aux_loss).
-
-    Inside ``shard_map`` with ``ep`` set, the batch shards over the axis
-    (data parallel for the dense parts) and each chip's experts process
-    tokens routed to them by two all_to_alls
-    (:func:`horovod_tpu.parallel.moe.moe_layer`). ``ep=None`` runs the
-    identical routing math with every expert local — the dense reference
-    the exactness tests compare against. ``aux_loss`` is the Switch
-    load-balancing loss (mean over chips; add scaled to the main loss)."""
-    from horovod_tpu.parallel.moe import moe_layer, top1_routing
-
-    B, L = tokens.shape
-    x = params["embed"][tokens] + params["pos"][None, :L]
-    aux_total = 0.0
-    for layer in params["layers"]:
-        q, k, v = _project_qkv(layer, x, None)
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        attn = dot_product_attention(q, k, v, causal=True, scale=scale)
-        x = _attn_out_residual(layer, attn, x, None)
-
-        m = _layernorm(x, layer["ln2"]["g"], layer["ln2"]["b"])
-        flat = m.reshape(B * L, m.shape[-1])
-        if ep:
-            y, aux = moe_layer(flat, layer["gate"], _expert_mlp,
-                               layer["experts"], axis=ep,
-                               capacity_factor=capacity_factor,
-                               return_aux=True)
-        else:
-            num_experts = layer["experts"]["up"].shape[0]
-            T = flat.shape[0]
-            capacity = max(1, math.ceil(T * capacity_factor / num_experts))
-            dispatch, combine, aux = top1_routing(
-                flat, layer["gate"], num_experts, capacity)
-            slots = jnp.einsum("tec,td->ecd", dispatch,
-                               flat.astype(jnp.float32))
-            out = jax.vmap(_expert_mlp)(layer["experts"],
-                                        slots.astype(flat.dtype))
-            y = jnp.einsum("tec,ecd->td", combine,
-                           out.astype(jnp.float32)).astype(flat.dtype)
-        x = x + y.reshape(B, L, -1)
-        aux_total = aux_total + aux
-
-    return _logits(params, x), aux_total / len(params["layers"])
-
-
-def moe_reduce_grads(grads: Dict, axis: str = "ep"):
-    """Gradient reduction for :func:`lm_apply_moe`.
-
-    Loss contract: the caller differentiates the PER-CHIP mean nll over
-    its token shard (no collective inside the loss — rank-varying), and
-    the global objective is the mean of those terms. Then:
-
-    * replicated leaves (embed, attention, gates, head): MEAN over the
-      axis (vma-aware: typed grads arrive as the auto-summed total and
-      only need the /n);
-    * expert shards: the all_to_all backward already returned every
-      chip's contribution to this chip's experts, so the grad is the
-      data-complete SUM — divide by the axis size (NO collective: a
-      pmean/psum would mix gradients of *different* experts)."""
-    from horovod_tpu.parallel._vma import (
-        reduce_cotangent,
-        scale_sharded_cotangent,
-    )
-
-    out = {k: jax.tree_util.tree_map(
-               lambda g: reduce_cotangent(g, axis, mean=True), v)
-           for k, v in grads.items() if k != "layers"}
-    out["layers"] = []
-    for layer_g in grads["layers"]:
-        red = {k: jax.tree_util.tree_map(
-                   lambda g: reduce_cotangent(g, axis, mean=True), v)
-               for k, v in layer_g.items() if k != "experts"}
-        red["experts"] = jax.tree_util.tree_map(
-            lambda g: scale_sharded_cotangent(g, axis),
-            layer_g["experts"])
-        out["layers"].append(red)
-    return out
-
-
 def pp_reduce_rest_grads(g_rest: Dict, axis: str = "pp"):
     """Gradient reduction for :func:`lm_apply_pp`'s replicated params.
 

@@ -27,7 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from flax.core import FrozenDict, freeze
+from flax.core import FrozenDict, freeze, unfreeze
 
 from horovod_tpu.common.state import current_spmd_axis
 from horovod_tpu.jax import mpi_ops
@@ -45,7 +45,9 @@ def cross_entropy_loss(logits, labels) -> jnp.ndarray:
 
 class TrainState(Dict[str, Any]):
     """A plain pytree-of-arrays training state: params, batch_stats,
-    opt_state, step. Dict subclass so it flows through jax transforms."""
+    opt_state, step and, for a model that keeps state which is no parameter
+    and no batch statistic (a sparse layer's selection bias), buffers. Dict
+    subclass so it flows through jax transforms."""
 
 
 jax.tree_util.register_pytree_node(
@@ -93,13 +95,21 @@ def create_train_state(
     physically sharded.
     """
     with timeline.span("hvd.lane.model_init"):
-        variables = model.init(rng, sample_input, train=False)
+        # one program: run eagerly, flax's init is the whole forward pass
+        # operation by operation, a program each (a minute and a half of a
+        # sparse decoder's set-up), for values that the draws do not need
+        variables = jax.jit(
+            lambda rng, sample: model.init(rng, sample, train=False)
+        )(rng, sample_input)
     params = variables["params"]
     # Deep-freeze so the state's pytree TYPES are stable against what
     # the step emits (flax's mutable= collection comes back as a plain
     # dict on some versions) — lax.scan window loops require the carry
     # structure to match exactly, not just leaf-wise.
     batch_stats = freeze(variables.get("batch_stats", FrozenDict()))
+    # State that the forward pass reads and the step moves by a rule of its
+    # own, outside the optimizer; only a model that has some gets the slot.
+    buffers = unfreeze(variables.get("buffers", {}))
     if zero:
         from horovod_tpu.jax.zero import sharded_distributed_optimizer
 
@@ -126,6 +136,8 @@ def create_train_state(
             opt_state=opt_state,
             step=jnp.zeros((), jnp.int32),
         )
+        if buffers:
+            state["buffers"] = buffers
     return state, optimizer
 
 
@@ -134,22 +146,28 @@ def apply_gradients(
     state: TrainState,
     grads,
     batch_stats=None,
+    buffers=None,
 ) -> TrainState:
     """The shared update tail of every training step: optimizer update
     (the DistributedOptimizer/ZeRO wrapper performs the fused cross-rank
     gradient exchange here), parameter apply, state repack with the step
-    counter advanced."""
+    counter advanced. ``batch_stats`` and ``buffers`` replace the state's
+    where the step made new ones."""
     with jax.named_scope(timeline.UPDATE):
         updates, new_opt_state = optimizer.update(
             grads, state["opt_state"], state["params"]
         )
         params = optax.apply_updates(state["params"], updates)
-    return TrainState(
+    new_state = TrainState(
+        state,
         params=params,
         batch_stats=state["batch_stats"] if batch_stats is None else batch_stats,
         opt_state=new_opt_state,
         step=state["step"] + 1,
     )
+    if buffers is not None:
+        new_state["buffers"] = buffers
+    return new_state
 
 
 def read_before_update(state: TrainState, reads):
@@ -281,10 +299,8 @@ def state_partition_specs(state: TrainState):
         is_leaf=lambda n: isinstance(n, (_zero.ZeroState,
                                          _AllreduceState)))
     return TrainState(
-        params=P(),
-        batch_stats=P(),
+        {k: P() for k in state},
         opt_state=opt_spec,
-        step=P(),
     )
 
 

@@ -14,7 +14,11 @@ path all three streamed kernels (forward, dQ, dK/dV) execute a PACKED
 at-or-below-diagonal grid — the strictly-masked half of the (q-block,
 k-block) plane never occupies a grid step, so neither its K/V DMA bytes
 nor its loop overhead is paid (closing the traffic debt PERF.md's
-"Streamed-causal K/V traffic tradeoff" recorded).
+"Streamed-causal K/V traffic tradeoff" recorded). A ``window`` narrows the
+triangle to a band (query i sees keys j with ``0 <= i - j < window``): the
+packed grid then leaves out the blocks below the band as well. K and V may
+have fewer heads than Q (grouped-query attention): query head h reads KV
+head ``h // (H / G)``, by the kernels' index maps and with no copy of K or V.
 """
 
 from __future__ import annotations
@@ -41,22 +45,41 @@ FLASH_ATTENTION_MIN_SEQ = 4096
 
 def dot_product_attention(q, k, v, causal: bool = False,
                           scale: Optional[float] = None,
-                          q_offset: int = 0, k_offset: int = 0):
-    """Reference attention. Shapes: q [..., Lq, H, D], k/v [..., Lk, H, D].
+                          q_offset: int = 0, k_offset: int = 0,
+                          window: Optional[int] = None):
+    """Reference attention. Shapes: q [..., Lq, H, D], k/v [..., Lk, G, D]
+    with ``G`` dividing ``H`` (query head h reads KV head ``h // (H / G)``).
 
     ``q_offset``/``k_offset`` are the global positions of the first query/
     key token — block-parallel callers (ring attention) pass their shard's
-    global offset so causal masks line up across chips.
+    global offset so causal masks line up across chips. ``window`` (causal
+    only) lets query i see keys j with ``0 <= i - j < window``.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    rep = _kv_group(q.shape[-2], k.shape[-2])
+    if rep > 1:
+        k, v = (jnp.repeat(t, rep, axis=-2) for t in (k, v))
     logits = jnp.einsum("...qhd,...khd->...hqk", q, k) * scale
     if causal:
         qi = q_offset + jnp.arange(q.shape[-3])[:, None]
         ki = k_offset + jnp.arange(k.shape[-3])[None, :]
-        logits = jnp.where(qi >= ki, logits, NEG_INF)
+        seen = qi >= ki
+        if window is not None:
+            seen &= qi - ki < window
+        logits = jnp.where(seen, logits, NEG_INF)
+    elif window is not None:
+        raise ValueError("a window needs causal=True")
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("...hqk,...khd->...qhd", weights.astype(q.dtype), v)
+
+
+def _kv_group(heads: int, kv_heads: int) -> int:
+    """Query heads a KV head: ``H / G``."""
+    if kv_heads < 1 or heads % kv_heads:
+        raise ValueError(f"{kv_heads} KV heads do not divide {heads} "
+                         f"query heads")
+    return heads // kv_heads
 
 
 # --------------------------------------------------------------------------
@@ -89,34 +112,79 @@ def _grid_truncates(causal: bool, seq_q: int, seq_k: int, q_offset: int,
     return bool(truncate)
 
 
+def _first_kblock(qi, block_q: int, block_k: int, window: Optional[int],
+                  maximum=max):
+    """The first k-block a q-block's rows can see: block 0, or under a
+    window the block that holds the first row's oldest key. Plain integers
+    for the tables; the kernels pass ``jnp.maximum`` for a traced ``qi``."""
+    if window is None:
+        return 0
+    return maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def _last_qblock(kb, block_q: int, block_k: int, n_qblocks: int,
+                 window: Optional[int], minimum=min):
+    """The last q-block that can see a k-block: the last of all, or under a
+    window the block of the last row that still sees the block's last key."""
+    if window is None:
+        return n_qblocks - 1
+    return minimum(n_qblocks - 1,
+                   (kb * block_k + block_k + window - 2) // block_q)
+
+
+def _band_mask(q_pos, k_pos, window: Optional[int]):
+    """Which keys a query sees: those at or before it and, under a window,
+    fewer than ``window`` positions back."""
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return seen
+
+
+def _block_live(qi, kb, block_q: int, block_k: int, delta: int,
+                window: Optional[int]):
+    """Full grids only: whether any row of q-block ``qi`` sees any key of
+    k-block ``kb`` (a dead block skips its compute, not its DMA)."""
+    live = qi * block_q + block_q - 1 + delta >= kb * block_k
+    if window is not None:
+        live &= qi * block_q + delta - (kb * block_k + block_k - 1) < window
+    return live
+
+
 @functools.lru_cache(maxsize=None)
 def _causal_step_tables(n_qblocks: int, n_kblocks: int, block_q: int,
-                        block_k: int, k_major: bool = False):
+                        block_k: int, k_major: bool = False,
+                        window: Optional[int] = None):
     """Scalar-prefetch step tables for the packed causal grid.
 
     Enumerates ONLY the (q-block, k-block) pairs that intersect the
     at-or-below-diagonal region (``qi*block_q + block_q - 1 >=
     kb*block_k``) — on an n x n grid with square blocks that is
-    n(n+1)/2 of the n^2 full steps. q-major order streams k-blocks per
+    n(n+1)/2 of the n^2 full steps — and, under a ``window``, of those only
+    the pairs inside the band (some row of the q-block sees some key of the
+    k-block: ``0 <= i - j < window``). q-major order streams k-blocks per
     q-block (forward + dQ); ``k_major`` streams q-blocks per k-block
     (dK/dV, whose dead region is the symmetric above-diagonal half over
-    the q axis). Square-causal only: every q-block's first live k-block
-    is 0 and every k-block's last live q-block is n_qblocks - 1, which
-    is what the kernels' init/finalize conditions assume.
+    the q axis). Square-causal only: every q-block's live k-blocks run
+    from :func:`_first_kblock` to its diagonal and every k-block's live
+    q-blocks from its diagonal to :func:`_last_qblock`, which is what the
+    kernels' init/finalize conditions assume.
     """
     pairs = []
     if k_major:
         for kb in range(n_kblocks):
             # ceil((kb*bk - bq + 1) / bq) == floor(kb*bk / bq): the
             # first q-block whose last row reaches this k-block.
+            last = _last_qblock(kb, block_q, block_k, n_qblocks, window)
             pairs.extend((qi, kb)
                          for qi in range((kb * block_k) // block_q,
-                                         n_qblocks))
+                                         last + 1))
     else:
         for qi in range(n_qblocks):
             last = min(n_kblocks - 1,
                        (qi * block_q + block_q - 1) // block_k)
-            pairs.extend((qi, kb) for kb in range(last + 1))
+            first = _first_kblock(qi, block_q, block_k, window)
+            pairs.extend((qi, kb) for kb in range(first, last + 1))
     qi_tab = np.asarray([p[0] for p in pairs], np.int32)
     kb_tab = np.asarray([p[1] for p in pairs], np.int32)
     return qi_tab, kb_tab
@@ -128,7 +196,8 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
                     q_offset: int = 0, k_offset: int = 0,
                     truncate: Optional[bool] = None,
                     head_dim: Optional[int] = None,
-                    batch_heads: int = 1, dtype_bytes: int = 2):
+                    batch_heads: int = 1, dtype_bytes: int = 2,
+                    window: Optional[int] = None):
     """Static grid + K/V-DMA accounting for a ``flash_attention`` call.
 
     Mirrors exactly the tiling (:func:`_default_blocks`) and truncation
@@ -140,7 +209,8 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
 
     Returns a dict: chosen blocks, grid shape, per-``batch_heads``-step
     counts (``steps`` vs ``steps_full``), ``kv_fetch_frac`` (the
-    truncated/full step ratio — (n+1)/2n on a causal square grid), and
+    truncated/full step ratio — (n+1)/2n on a causal square grid, less
+    under a ``window``), and
     — when ``head_dim`` is given — the estimated K/V bytes the grid
     DMAs in (one [block_k, head_dim] tile each for K and V per step,
     times ``batch_heads``).
@@ -153,7 +223,7 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
                                 truncate)
     steps_full = nqb * nkb
     if truncated:
-        qi_tab, _ = _causal_step_tables(nqb, nkb, bq, bk)
+        qi_tab, _ = _causal_step_tables(nqb, nkb, bq, bk, window=window)
         steps = int(qi_tab.size)
     else:
         steps = steps_full
@@ -179,7 +249,8 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
 
 
 def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
-                  scale: float, block_q: int, delta: int, packed: bool):
+                  scale: float, block_q: int, delta: int, packed: bool,
+                  window: Optional[int] = None):
     """One streamed-forward grid step. Two grid layouts share this body:
 
     * full (``packed=False``) — grid (batch*head, q-block, K-BLOCK): the
@@ -224,9 +295,15 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
         qi = pl.program_id(1)
         kb = pl.program_id(2)
 
-    # k-block 0 is the first step of every q-block in BOTH layouts (the
-    # packed tables' q-major walk always starts a q-block at kb == 0).
-    @pl.when(kb == 0)
+    # The first step of every q-block: k-block 0 in the full layout, and in
+    # the packed one the first block the tables' q-major walk gives it
+    # (block 0 too, unless a window has left the oldest blocks out). A row
+    # that sees no key of its first blocks gathers weights of exp(0) there,
+    # which the first real score wipes out (alpha = exp(NEG_INF - m) = 0).
+    first_kb = (_first_kblock(qi, block_q, block_k, window, jnp.maximum)
+                if packed else 0)
+
+    @pl.when(kb == first_kb)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -248,7 +325,7 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_band_mask(q_pos, k_pos, window), s, NEG_INF)
         m = m_scr[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -261,10 +338,11 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
             preferred_element_type=jnp.float32)
 
     if causal and not packed:
-        # A k-block strictly past this q-block's last row is fully
-        # masked: skip its compute (its DMA is pipelined regardless).
-        pl.when(qi * block_q + block_q - 1 + delta
-                >= kb * block_k)(_compute)
+        # A k-block strictly past this q-block's last row (or wholly
+        # older than its window) is fully masked: skip its compute (its
+        # DMA is pipelined regardless).
+        pl.when(_block_live(qi, kb, block_q, block_k, delta,
+                            window))(_compute)
     else:
         _compute()  # packed grids enumerate live steps only
 
@@ -334,7 +412,8 @@ _FLASH_BWD_ENV_DEFAULT = __import__("os").environ.get("HVD_FLASH_BWD", "")
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret",
                                              "bwd_impl", "q_offset",
-                                             "k_offset", "truncate"))
+                                             "k_offset", "truncate",
+                                             "window"))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -342,8 +421,18 @@ def flash_attention(q, k, v, causal: bool = False,
                     interpret: Optional[bool] = None,
                     bwd_impl: Optional[str] = None,
                     q_offset: int = 0, k_offset: int = 0,
-                    truncate: Optional[bool] = None):
-    """Pallas flash attention. Shapes [B, L, H, D] -> [B, L, H, D].
+                    truncate: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """Pallas flash attention. Shapes q [B, L, H, D], k/v [B, L, G, D] ->
+    [B, L, H, D]; ``G`` divides ``H`` and query head h reads KV head
+    ``h // (H / G)`` (grouped-query attention; K and V are never repeated:
+    the kernels' index maps pick the group's block).
+
+    ``window`` (static; plain causal square attention only) lets query i
+    see keys j with ``0 <= i - j < window``: the mask is applied inside the
+    blocks on the band's two edges, and blocks wholly outside the band
+    never occupy a step of the packed grid (or skip their compute on the
+    full one).
 
     Sequence lengths must be multiples of the block sizes (pad upstream).
     Block sizes default to the measured-on-TPU policy in
@@ -398,28 +487,48 @@ def flash_attention(q, k, v, causal: bool = False,
             f"causal flash_attention requires q_offset >= k_offset "
             f"(got {q_offset} < {k_offset}): rows with no visible key "
             f"have no defined softmax")
+    _kv_group(q.shape[2], k.shape[2])
+    if window is not None:
+        if not (causal and q.shape[1] == k.shape[1]
+                and q_offset == k_offset) or window < 1:
+            raise ValueError(
+                f"a window needs plain causal square attention and a width "
+                f"of at least 1 (causal={causal}, Lq={q.shape[1]}, "
+                f"Lk={k.shape[1]}, q_offset={q_offset}, "
+                f"k_offset={k_offset}, window={window}): elsewhere a row "
+                f"could be left with no key at all")
+        if window >= k.shape[1]:
+            window = None              # the band is the whole triangle
     return _flash(q, k, v, causal, float(scale), block_q, block_k,
                   interpret, bwd_impl, int(q_offset), int(k_offset),
-                  truncate)
+                  truncate, window)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bwd_impl,
-           q_offset, k_offset, truncate):
+           q_offset, k_offset, truncate, window=None):
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret, q_offset, k_offset, truncate)
+                            interpret, q_offset, k_offset, truncate, window)
     return out
 
 
+# The kernels' names, as a device profile shows them (an instruction is
+# named after its kernel): the benchmark's readers find them by these.
+FWD_KERNEL = "hvd_flash_fwd"
+DQ_KERNEL = "hvd_flash_dq"
+DKV_KERNEL = "hvd_flash_dkv"
+
+
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   q_offset=0, k_offset=0, truncate=None):
+                   q_offset=0, k_offset=0, truncate=None, window=None):
     """Returns (out [B, Lq, H, D], lse [B, H, Lq])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, G = k.shape[1], k.shape[2]
+    rep = _kv_group(H, G)      # program bh = b*H + h reads KV row bh // rep
     block_q = min(block_q, Lq)
     block_k = min(block_k, Lk)
     assert Lq % block_q == 0 and Lk % block_k == 0, (Lq, Lk, block_q, block_k)
@@ -429,8 +538,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     # Collapse (B, H) into the grid's first axis; put seq minor-most for
     # contiguous VMEM tiles.
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
+    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
 
     n_qblocks = Lq // block_q
     n_kblocks = Lk // block_k
@@ -445,11 +554,12 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     ]
     if truncated:
         qi_tab, kb_tab = _causal_step_tables(n_qblocks, n_kblocks,
-                                             block_q, block_k)
+                                             block_q, block_k,
+                                             window=window)
         kernel = functools.partial(_flash_kernel, block_k=block_k,
                                    n_kblocks=n_kblocks, causal=causal,
                                    scale=scale, block_q=block_q,
-                                   delta=0, packed=True)
+                                   delta=0, packed=True, window=window)
         # The STEP axis enumerates only the live at-or-below-diagonal
         # (q-block, k-block) pairs — ~(n+1)/2n of the full causal grid.
         # Still sequential ("arbitrary") so the scratch-carried softmax
@@ -463,9 +573,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                 pl.BlockSpec((None, block_q, D),
                              lambda bh, t, qi, kb: (bh, qi[t], 0)),
                 pl.BlockSpec((None, block_k, D),
-                             lambda bh, t, qi, kb: (bh, kb[t], 0)),
+                             lambda bh, t, qi, kb: (bh // rep, kb[t], 0)),
                 pl.BlockSpec((None, block_k, D),
-                             lambda bh, t, qi, kb: (bh, kb[t], 0)),
+                             lambda bh, t, qi, kb: (bh // rep, kb[t], 0)),
             ],
             out_specs=[
                 pl.BlockSpec((None, block_q, D),
@@ -485,13 +595,13 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, name=FWD_KERNEL,
         )(jnp.asarray(qi_tab), jnp.asarray(kb_tab), qr, kr, vr)
     else:
         kernel = functools.partial(_flash_kernel, block_k=block_k,
                                    n_kblocks=n_kblocks, causal=causal,
                                    scale=scale, block_q=block_q,
-                                   delta=delta, packed=False)
+                                   delta=delta, packed=False, window=window)
         out, lse = pl.pallas_call(
             kernel,
             # K blocks ride the grid's INNERMOST axis: sequential
@@ -503,9 +613,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                 pl.BlockSpec((None, block_q, D),
                              lambda bh, qb, kb: (bh, qb, 0)),
                 pl.BlockSpec((None, block_k, D),
-                             lambda bh, qb, kb: (bh, kb, 0)),
+                             lambda bh, qb, kb: (bh // rep, kb, 0)),
                 pl.BlockSpec((None, block_k, D),
-                             lambda bh, qb, kb: (bh, kb, 0)),
+                             lambda bh, qb, kb: (bh // rep, kb, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((None, block_q, D),
@@ -517,22 +627,22 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             scratch_shapes=scratch,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, name=FWD_KERNEL,
         )(qr, kr, vr)
     return (out.reshape(B, H, Lq, D).transpose(0, 2, 1, 3),
             lse.reshape(B, H, Lq))
 
 
 def _flash_fwd_vjp(q, k, v, causal, scale, block_q, block_k, interpret,
-                   bwd_impl, q_offset, k_offset, truncate):
+                   bwd_impl, q_offset, k_offset, truncate, window=None):
     o, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret, q_offset, k_offset, truncate)
+                            interpret, q_offset, k_offset, truncate, window)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
                          block_k: int, n_kblocks: int, delta: int,
-                         packed: bool):
+                         packed: bool, window: Optional[int] = None):
     """dQ: full grid (batch*head, q-block, K-BLOCK stream) or the packed
     q-major causal grid (batch*head, STEP) — same layout split as
     :func:`_flash_kernel`. Standard FlashAttention-2 recurrence against
@@ -556,7 +666,10 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
         qi = pl.program_id(1)
         kb = pl.program_id(2)
 
-    @pl.when(kb == 0)
+    first_kb = (_first_kblock(qi, block_q, block_k, window, jnp.maximum)
+                if packed else 0)
+
+    @pl.when(kb == first_kb)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
@@ -572,7 +685,7 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_band_mask(q_pos, k_pos, window), s, NEG_INF)
         p = jnp.exp(s - lse_ref[...])                    # [bq, bk]
         dp = jnp.dot(do_blk, v_blk.T, preferred_element_type=jnp.float32)
         ds = p * (dp - d_ref[...])
@@ -580,8 +693,8 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
                                preferred_element_type=jnp.float32) * scale
 
     if causal and not packed:
-        pl.when(qi * block_q + block_q - 1 + delta
-                >= kb * block_k)(_compute)
+        pl.when(_block_live(qi, kb, block_q, block_k, delta,
+                            window))(_compute)
     else:
         _compute()  # packed grids enumerate live steps only
 
@@ -598,7 +711,7 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
 
 def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                           block_k: int, n_qblocks: int, delta: int,
-                          packed: bool):
+                          packed: bool, window: Optional[int] = None):
     """dK/dV: full grid (batch*head, k-block, Q-BLOCK stream) or the
     packed K-MAJOR causal grid — transposing the dQ kernel's roles, so
     the truncated region is the symmetric above-diagonal half over the
@@ -616,12 +729,15 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
         # First live q-block of this k-block's stream: the diagonal
         # (matches _causal_step_tables' k-major start).
         first_qi = (kb * block_k) // block_q
+        last_qi = _last_qblock(kb, block_q, block_k, n_qblocks, window,
+                               jnp.minimum)
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
         kb = pl.program_id(1)
         qi = pl.program_id(2)
         first_qi = 0
+        last_qi = n_qblocks - 1
 
     @pl.when(qi == first_qi)
     def _init():
@@ -640,7 +756,7 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_band_mask(q_pos, k_pos, window), s, NEG_INF)
         p = jnp.exp(s - lse_ref[...])                    # [bq, bk]
         dv_scr[...] += jnp.dot(p.T.astype(do_blk.dtype), do_blk,
                                preferred_element_type=jnp.float32)
@@ -650,21 +766,21 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                                preferred_element_type=jnp.float32) * scale
 
     if causal and not packed:
-        # Q-blocks fully ABOVE the diagonal (every q_pos < every k_pos)
-        # contribute nothing to this k-block.
-        pl.when(qi * block_q + block_q - 1 + delta
-                >= kb * block_k)(_compute)
+        # Q-blocks fully ABOVE the diagonal (every q_pos < every k_pos),
+        # or wholly past the window, contribute nothing to this k-block.
+        pl.when(_block_live(qi, kb, block_q, block_k, delta,
+                            window))(_compute)
     else:
         _compute()  # packed grids enumerate live steps only
 
-    @pl.when(qi == n_qblocks - 1)
+    @pl.when(qi == last_qi)
     def _finalize():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
-                    q_offset, k_offset, truncate, res, do):
+                    q_offset, k_offset, truncate, window, res, do):
     """XLA lax.scan backward (the pre-round-5 implementation, kept as a
     selectable path): one batched einsum pass per key block computing
     dq/dk/dv together. At seq <= ~4096 its [B, H, Lq, block_k] einsum
@@ -675,11 +791,16 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
     automatically at short key lengths (see _flash_bwd_vjp). Already
     grid-truncated by construction: the causal scan walks only the
     k-blocks at or below the last query row's diagonal (``truncate``
-    is accepted for signature parity and ignored)."""
+    is accepted for signature parity and ignored). Grouped K and V are
+    repeated to the query heads here (the slabs are per query head anyway)
+    and a ``window`` only masks: the kernel split is the path that skips."""
     del truncate  # no grid to truncate: the scan bound below early-exits
     q, k, v, o, lse = res
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, G = k.shape[1], k.shape[2]
+    rep = _kv_group(H, G)
+    if rep > 1:
+        k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
     bk = min(block_k, Lk)
     nkb = Lk // bk
     delta = q_offset - k_offset
@@ -704,7 +825,8 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
                        preferred_element_type=f32) * scale
         if causal:
             k_pos = jb * bk + jnp.arange(bk)[None, :]
-            s = jnp.where((q_pos >= k_pos)[None, None], s, NEG_INF)
+            s = jnp.where(_band_mask(q_pos, k_pos, window)[None, None],
+                          s, NEG_INF)
         vb = jax.lax.dynamic_slice_in_dim(v, jb * bk, bk, 1)
         p = jnp.exp(s - lse[..., None])                     # [B,H,Lq,bk]
         dp = jnp.einsum("bqhd,bkhd->bhqk", do, vb,
@@ -726,11 +848,13 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
         pad = [(0, 0), (0, Lk - nkb_live * bk), (0, 0), (0, 0)]
         dk = jnp.pad(dk, pad)
         dv = jnp.pad(dv, pad)
+    if rep > 1:
+        dk, dv = (t.reshape(B, Lk, G, rep, D).sum(3) for t in (dk, dv))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
-                      q_offset, k_offset, truncate, res, do):
+                      q_offset, k_offset, truncate, window, res, do):
     """Flash backward as two Pallas kernels (FlashAttention-2 split):
     a dQ kernel streaming k-blocks and a dK/dV kernel streaming
     q-blocks, both against the forward's persisted logsumexp and the
@@ -744,13 +868,17 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     the dead half of each grid — ~2x the K/V and Q/dO bytes actually
     needed — is never DMA'd. For causal rectangular/offset Lq != Lk the
     grids stay full and blocks entirely on the masked side of the
-    diagonal skip their compute only."""
+    diagonal skip their compute only. With grouped K and V (``G`` KV heads
+    under ``H`` query heads) both kernels read the group's K/V block by
+    index map; dK/dV come out a query head, in float32, and the group's
+    are added up outside the kernel."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, G = k.shape[1], k.shape[2]
+    rep = _kv_group(H, G)
     bq = min(block_q, Lq)
     bk = min(block_k, Lk)
     assert Lq % bq == 0 and Lk % bk == 0, (Lq, Lk, bq, bk)
@@ -759,8 +887,8 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     truncated = _grid_truncates(causal, Lq, Lk, q_offset, k_offset, truncate)
 
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
+    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
     dor = do.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     # lse arrives [B, H, Lq]; D_i rowsum in fp32. Both as [bh, Lq, 1]
     # columns — the statistics' native kernel layout.
@@ -772,27 +900,31 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_kblocks=nkb, delta=0 if truncated else delta,
-        packed=truncated)
+        packed=truncated, window=window)
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_qblocks=nqb, delta=0 if truncated else delta,
-        packed=truncated)
+        packed=truncated, window=window)
     dq_out_shape = jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype)
+    dkv_dtype = jnp.float32 if rep > 1 else k.dtype    # a group's are summed
     dkv_out_shape = [
-        jax.ShapeDtypeStruct((B * H, Lk, D), k.dtype),
-        jax.ShapeDtypeStruct((B * H, Lk, D), v.dtype),
+        jax.ShapeDtypeStruct((B * H, Lk, D), dkv_dtype),
+        jax.ShapeDtypeStruct((B * H, Lk, D), dkv_dtype),
     ]
 
     if truncated:
         # Packed causal grids: q-major steps for dQ (k-blocks stream
         # within a q-block), k-major for dK/dV (q-blocks stream within
         # a k-block, starting at the diagonal).
-        qi_q, kb_q = _causal_step_tables(nqb, nkb, bq, bk)
-        qi_k, kb_k = _causal_step_tables(nqb, nkb, bq, bk, k_major=True)
+        qi_q, kb_q = _causal_step_tables(nqb, nkb, bq, bk, window=window)
+        qi_k, kb_k = _causal_step_tables(nqb, nkb, bq, bk, k_major=True,
+                                         window=window)
         qspec = pl.BlockSpec((None, bq, D),
                              lambda bh, t, qi, kb: (bh, qi[t], 0))
         kspec = pl.BlockSpec((None, bk, D),
-                             lambda bh, t, qi, kb: (bh, kb[t], 0))
+                             lambda bh, t, qi, kb: (bh // rep, kb[t], 0))
+        dkv_spec = pl.BlockSpec((None, bk, D),
+                                lambda bh, t, qi, kb: (bh, kb[t], 0))
         col_q = pl.BlockSpec((None, bq, 1),
                              lambda bh, t, qi, kb: (bh, qi[t], 0))
         dq = pl.pallas_call(
@@ -806,7 +938,7 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
             out_shape=dq_out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, name=DQ_KERNEL,
         )(jnp.asarray(qi_q), jnp.asarray(kb_q), qr, kr, vr, dor, lser,
           d_row)
         dk, dv = pl.pallas_call(
@@ -815,18 +947,19 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
                 num_scalar_prefetch=2,
                 grid=(B * H, int(qi_k.size)),
                 in_specs=[qspec, kspec, kspec, qspec, col_q, col_q],
-                out_specs=[kspec, kspec],
+                out_specs=[dkv_spec, dkv_spec],
                 scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                                 pltpu.VMEM((bk, D), jnp.float32)]),
             out_shape=dkv_out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, name=DKV_KERNEL,
         )(jnp.asarray(qi_k), jnp.asarray(kb_k), qr, kr, vr, dor, lser,
           d_row)
     else:
         qspec = pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0))
-        kspec = pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0))
+        kspec = pl.BlockSpec((None, bk, D),
+                             lambda bh, i, j: (bh // rep, j, 0))
         col_q = pl.BlockSpec((None, bq, 1), lambda bh, i, j: (bh, i, 0))
         dq = pl.pallas_call(
             dq_kernel,
@@ -838,12 +971,13 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, name=DQ_KERNEL,
         )(qr, kr, vr, dor, lser, d_row)
 
         # dK/dV grid transposes the stream: (bh, k-block, q-stream).
         qspec_t = pl.BlockSpec((None, bq, D), lambda bh, j, i: (bh, i, 0))
-        kspec_t = pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0))
+        kspec_t = pl.BlockSpec((None, bk, D),
+                               lambda bh, j, i: (bh // rep, j, 0))
         col_q_t = pl.BlockSpec((None, bq, 1), lambda bh, j, i: (bh, i, 0))
         dk, dv = pl.pallas_call(
             dkv_kernel,
@@ -859,13 +993,19 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
                             pltpu.VMEM((bk, D), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, name=DKV_KERNEL,
         )(qr, kr, vr, dor, lser, d_row)
 
     def unflat(t, L):
         return t.reshape(B, H, L, D).transpose(0, 2, 1, 3)
 
-    return unflat(dq, Lq), unflat(dk, Lk), unflat(dv, Lk)
+    def grouped(t, like):
+        """A KV head's gradient: the sum over the query heads that read it."""
+        if rep > 1:
+            t = t.reshape(B, G, rep, Lk, D).sum(2)
+        return t.reshape(B, G, Lk, D).transpose(0, 2, 1, 3).astype(like.dtype)
+
+    return unflat(dq, Lq), grouped(dk, k), grouped(dv, v)
 
 
 # Key length at/above which the kernel backward takes over from the
@@ -892,7 +1032,7 @@ def resolve_bwd_impl(bwd_impl: Optional[str], seq_k: int) -> str:
 
 
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
-                   q_offset, k_offset, truncate, res, do):
+                   q_offset, k_offset, truncate, window, res, do):
     """Backward dispatch, measured not assumed (PERF.md pre-round): the
     scan backward's batched einsums win at short key lengths; the
     O(block)-VMEM kernel split is required at long ones (the scan's
@@ -903,7 +1043,7 @@ def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
     impl = resolve_bwd_impl(bwd_impl, res[1].shape[1])
     fn = _flash_bwd_pallas if impl == "pallas" else _flash_bwd_scan
     return fn(causal, scale, block_q, block_k, interpret,
-              q_offset, k_offset, truncate, res, do)
+              q_offset, k_offset, truncate, window, res, do)
 
 
 _flash.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)

@@ -41,4 +41,9 @@ from horovod_tpu.parallel.tp import (  # noqa: F401
     tp_region_output,
 )
 from horovod_tpu.parallel.pipeline import pipeline_apply  # noqa: F401
-from horovod_tpu.parallel.moe import moe_layer, top1_routing  # noqa: F401
+from horovod_tpu.parallel.moe import (  # noqa: F401
+    gated_mlp_grouped,
+    route,
+    routed_experts,
+    update_selection_bias,
+)

@@ -1,97 +1,267 @@
-"""Expert parallelism: Switch-style top-1 MoE with all-to-all dispatch.
+"""A chip's share of a sparse expert layer: route over all the experts, drop
+nothing, compute what the experts held here give.
 
-Beyond-reference capability (SURVEY §2.9: no EP in the reference). Experts
-shard over an ``"ep"`` mesh axis (E_local = E / P per chip). Routing
-builds dispatch/combine tensors from a top-1 softmax gate with capacity
-dropping (Switch Transformer), then two ``lax.all_to_all``s move token
-slots: tokens -> their expert's chip, expert outputs -> back. The einsum
-formulation keeps everything dense for the MXU; dropped tokens pass
-through via the residual (combine weights are zero for them).
+The layer is told which experts it holds (``first`` and the leading axis of
+the stacked expert weights). It scores every token against **all** ``E``
+experts (sigmoid scores in float32; a selection bias, a buffer and no
+parameter, is added to choose and left out to weigh), takes the top ``k``,
+and computes ``sum_e w_e MLP_e(x)`` over the chosen experts that are held
+here; what the absent experts would add is another chip's part of the sum.
+On one chip the layer runs without an exchange: nothing here stands in for
+the absent chips.
 
-Use inside shard_map with tokens sharded over the axis.
+No token is dropped, whatever the routing: the ``T x k`` (token, choice)
+pairs are sorted by expert, pairs of absent experts last, into a buffer whose
+provable bound is ``T x k`` rows (every token choosing ``k`` held experts
+fills it), and three grouped matrix products (``jax.lax.ragged_dot``, which
+XLA:TPU lowers to its own grouped-matmul kernel; the group sizes are data)
+run over it. The gathers and elementwise passes around those products cost
+by the buffer's rows and not by the occupied ones, so the step looks at how
+many rows are occupied and runs on the buffer's first ``2 x`` what even
+routing fills where that holds them all, and on the whole bound where it
+does not (:func:`buffer_sizes`). There is no capacity and nothing to tune. Gathers move the rows both
+ways, in the backward pass too: sorting is a permutation, so the transpose of
+"take rows by ``order``" is "take rows by the inverse of ``order``", and no
+scatter-add runs on the device.
+
+Scopes (docs/timeline.md): ``hvd_moe_route``, ``hvd_moe_dispatch`` (sort
+and gather), ``hvd_moe_experts`` (the grouped products), ``hvd_moe_combine``;
+gauges ``hvd.moe.*`` of the program being traced.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Callable, Optional
+import functools
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.parallel.logical import module_axis
+from horovod_tpu.utils import timeline
 
 
-def top1_routing(x, gate_w, num_experts: int, capacity: int):
-    """Switch top-1 routing. x [T, D] -> (dispatch [T, E, C] one-hot,
-    combine [T, E, C] gate-weighted, aux_loss scalar)."""
-    logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)                       # [T]
-    gate = jnp.max(probs, axis=-1)                            # [T]
-    onehot = jax.nn.one_hot(expert, num_experts, dtype=jnp.float32)
-    # Position of each token within its expert's queue.
-    position = jnp.cumsum(onehot, axis=0) * onehot - 1.0      # [T, E]
-    keep = (position >= 0) & (position < capacity)
-    pos_clamped = jnp.clip(position, 0, capacity - 1).astype(jnp.int32)
-    slot = jax.nn.one_hot(pos_clamped, capacity, dtype=jnp.float32)
-    dispatch = onehot[..., None] * slot * keep[..., None]     # [T, E, C]
-    combine = dispatch * gate[:, None, None]
-    # Load-balancing auxiliary loss (Switch eq. 4).
-    density = jnp.mean(onehot, axis=0)
-    density_proxy = jnp.mean(probs, axis=0)
-    aux = jnp.sum(density * density_proxy) * num_experts
-    return dispatch, combine, aux
+def route(x, router_w, bias, top_k: int, route_scale: float = 1.0):
+    """``x [T, d]``, ``router_w [d, E]``, ``bias [E]`` or ``None`` ->
+    ``(chosen [T, k] int32, weights [T, k] float32, counts [E] float32)``.
+
+    ``s = sigmoid(x W_r)`` in float32 at the highest precision (a bfloat16
+    product flips near-ties among the top ``k``, and the product is a
+    thousandth of the layer's work); the chosen set is the top ``k`` of
+    ``s + bias``; the weights are the chosen experts' **unbiased** scores
+    over their sum, times ``route_scale``; ``counts`` is how many tokens
+    chose each expert."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    biased = scores if bias is None else scores + lax.stop_gradient(bias)
+    _, chosen = lax.top_k(biased, top_k)
+    # by a one-hot product and not ``take_along_axis``, whose transpose is a
+    # scatter-add of T x k scalars
+    onehot = jax.nn.one_hot(chosen, router_w.shape[-1], dtype=jnp.float32)
+    picked = jnp.sum(onehot * scores[:, None, :], axis=-1)
+    weights = route_scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen, weights, jnp.sum(onehot, axis=(0, 1))
 
 
-def moe_layer(x, gate_w, expert_fn: Callable, expert_params,
-              axis: Optional[str] = None, capacity_factor: float = 1.25,
-              return_aux: bool = False):
-    """Expert-parallel MoE layer inside shard_map.
+def update_selection_bias(bias, counts, coeff: float):
+    """The balancing rule that takes the place of an auxiliary loss, once
+    after every optimizer step: ``delta = coeff x sign(mean(c) - c)``,
+    centred, added to the bias (an expert chosen less than the mean is
+    lifted, one chosen more is lowered)."""
+    delta = coeff * jnp.sign(jnp.mean(counts) - counts)
+    return bias + (delta - jnp.mean(delta))
 
-    Args:
-      x: this chip's tokens [T, D].
-      gate_w: router weights [D, E] (replicated).
-      expert_fn: ``(params_one_expert, tokens [N, D]) -> [N, D]``.
-      expert_params: this chip's experts' params, leading axis E_local
-        (pass stacked [E, ...] with ``P("ep")`` in_specs).
-    Returns y [T, D] (+ aux loss when ``return_aux``).
-    """
-    axis = module_axis("expert", axis)
-    size = lax.axis_size(axis)
-    T, D = x.shape
-    e_leaves = jax.tree_util.tree_leaves(expert_params)
-    e_local = e_leaves[0].shape[0]
-    num_experts = e_local * size
-    capacity = max(1, math.ceil(T * capacity_factor / num_experts))
 
-    dispatch, combine, aux = top1_routing(x, gate_w, num_experts, capacity)
+def _rows_of_pairs(rows, inverse):
+    """Every pair's row of the buffer's first ``len(rows)``, zeros for a
+    pair whose row lies past them."""
+    padded = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+    return padded[jnp.minimum(inverse, rows.shape[0])]
 
-    # [T, E, C] x [T, D] -> [E, C, D]: expert slots filled with tokens.
-    slots = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))
-    # Reshard tokens -> expert chips. Untiled all_to_all with split ==
-    # concat == 0 is a chip-transpose: recv[s] = sent_by_chip_s[my_rank].
-    # Chip r owns global experts [r*e_local, (r+1)*e_local); so with the
-    # leading axis indexing destination chips, recv[s, le] holds chip s's
-    # dispatched slots for my local expert le.
-    slots = slots.reshape(size, e_local, capacity, D)
-    recv = lax.all_to_all(slots, axis, split_axis=0, concat_axis=0,
-                          tiled=False)                 # [P_src, e_local, C, D]
-    # Experts process all sources' slots at once (one big MXU matmul per
-    # expert instead of P small ones).
-    tokens = recv.transpose(1, 0, 2, 3).reshape(e_local, size * capacity, D)
-    out = jax.vmap(expert_fn)(expert_params, tokens.astype(x.dtype))
-    out = out.astype(jnp.float32).reshape(e_local, size, capacity, D)
-    out = out.transpose(1, 0, 2, 3)                    # [P_src, e_local, C, D]
 
-    # Route back: the same chip-transpose returns processed slots to their
-    # dispatching chip; reassembling the leading axes as (owner chip,
-    # local expert) recovers the global expert index g = r*e_local + le.
-    back = lax.all_to_all(out, axis, split_axis=0, concat_axis=0,
-                          tiled=False)
-    back = back.reshape(num_experts, capacity, D)
-    y = jnp.einsum("tec,ecd->td", combine, back).astype(x.dtype)
-    if return_aux:
-        return y, aux
-    return y
+# Three gathers and their transposes, which are gathers too because sorting
+# is a permutation. ``order [n]``: the pair in each of the buffer's first n
+# rows; ``inverse [T k]``: each pair's row. No cotangent goes to either.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spread(x, order, inverse, top_k: int):
+    """Tokens ``x [T, d]`` into the buffer: row ``r`` is the token of pair
+    ``order[r]``."""
+    return x[order // top_k]
+
+
+def _spread_fwd(x, order, inverse, top_k):
+    return x[order // top_k], (order, inverse)
+
+
+def _spread_bwd(top_k, res, g):
+    order, inverse = res
+    return _gather_sum(g, order, inverse, top_k).astype(g.dtype), None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_sum(rows, order, inverse, top_k: int):
+    """The buffer's rows back to their tokens, a token's pairs added up in
+    float32: ``[T, d]``. The transpose of :func:`_spread`."""
+    pairs = _rows_of_pairs(rows, inverse).reshape(
+        inverse.shape[0] // top_k, top_k, -1)
+    return pairs.astype(jnp.float32).sum(1)
+
+
+def _gather_sum_fwd(rows, order, inverse, top_k):
+    return _gather_sum(rows, order, inverse, top_k), (order, inverse,
+                                                      rows[:0])
+
+
+def _gather_sum_bwd(top_k, res, g):
+    order, inverse, like = res
+    return _spread(g.astype(like.dtype), order, inverse, top_k), None, None
+
+
+_gather_sum.defvjp(_gather_sum_fwd, _gather_sum_bwd)
+
+
+@jax.custom_vjp
+def _sorted(values, order, inverse):
+    """A number a pair, ``values [T k]``, in the buffer's order: ``[n]``."""
+    return values[order]
+
+
+_sorted.defvjp(
+    lambda values, order, inverse: (values[order], inverse),
+    lambda inverse, g: (_rows_of_pairs(g[:, None], inverse)[:, 0], None,
+                        None))
+
+
+def gated_mlp_grouped(rows, sizes, experts: Dict):
+    """``(silu(rows W_gate) * (rows W_up)) W_down`` with every row under its
+    own expert's matrices: ``experts`` holds ``gate`` and ``up`` ``[held, d,
+    f]`` and ``down`` ``[held, f, d]``, ``rows`` lie sorted by expert and
+    ``sizes [held]`` says how many each has. Rows past their sum belong to
+    no expert: they come back as zeros and take no gradient, and every
+    product's operands hold zeros there, so whatever a grouped-product kernel
+    leaves or reads past the last group can reach no result."""
+    occupied = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+
+    def dot(lhs, rhs):
+        out = lax.ragged_dot(jnp.where(occupied, lhs, 0),
+                             rhs.astype(lhs.dtype), group_sizes=sizes,
+                             preferred_element_type=lhs.dtype)
+        return jnp.where(occupied, out, 0)
+
+    gate, up = dot(rows, experts["gate"]), dot(rows, experts["up"])
+    hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return dot(hidden.astype(rows.dtype), experts["down"])
+
+
+CUT = 2         # of what even routing fills; the bound is the other size
+
+
+def buffer_sizes(bound: int, expected: float):
+    """The row counts a layer's sorted buffer may be cut to, ascending: the
+    layer runs on the first where it holds the step's occupied rows and on
+    the last, ``bound`` (every pair's expert held here), where it does not,
+    so **no routing is ever cut short**. The first is twice what even routing
+    fills, in multiples of 8. Even routing is no steady state: a router that
+    scores 8,192 tokens independently fills 8,192 rows give or take a few
+    hundred, and training moves it: of a chip's share of the layer only the
+    held experts' outputs reach the loss, and the router learns to send them
+    half as many rows again within some twenty Adam steps on one batch
+    (12,100-12,700 where 8,192 were expected; PERF.md, PR 28). Past the cut
+    the layer's passes run over four times the rows. The cut is no capacity:
+    it only spares the gathers and elementwise passes over rows that no
+    expert reads. Every size is a branch of the layer that XLA compiles,
+    forward and backward, which is why there are two and no ladder."""
+    return sorted({bound, min(bound, -(-int(expected * CUT) // 8) * 8)})
+
+
+def _held_part(n: int, top_k: int, x, experts, order, inverse, sizes,
+               weights):
+    """``sum over a token's pairs of held experts of w MLP_e(x)`` with the
+    sorted buffer cut to its first ``n`` rows (all the occupied ones)."""
+    order = order[:n]
+    with jax.named_scope(timeline.MOE_DISPATCH):
+        rows = _spread(x, order, inverse, top_k)               # [n, d]
+    with jax.named_scope(timeline.MOE_EXPERTS):
+        out = gated_mlp_grouped(rows, sizes, experts)
+    with jax.named_scope(timeline.MOE_COMBINE):
+        # weighted in the buffer's order, where only n rows are: a row past
+        # the occupied ones holds zeros, whatever its pair's weight
+        w = _sorted(weights.reshape(-1), order, inverse)
+        out = (out.astype(jnp.float32) * w[:, None]).astype(out.dtype)
+        return _gather_sum(out, order, inverse, top_k)
+
+
+def routed_experts(x, router_w, experts: Dict, bias=None, *, first=0,
+                   top_k: int, route_scale: float = 1.0, dtype=None,
+                   name: str = "") -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the layer's result, and the step's counts.
+
+    ``x [T, d]``; ``router_w [d, E]`` scores all ``E`` experts; ``experts``
+    are the stacked matrices of the ``held`` experts ``first .. first +
+    held - 1`` (:func:`gated_mlp_grouped`); ``bias [E]`` or ``None``. The
+    router reads ``x`` as it is given (float32 from a norm); the experts'
+    products run in ``dtype`` (default: ``x``'s), which ``y`` comes back in.
+    Returns ``(y [T, d], counts [E])``: ``y = sum over chosen experts held
+    here of w_e MLP_e(x)``, exact for any routing (module docstring), and
+    how many tokens chose each of the ``E``."""
+    tokens, _ = x.shape
+    dtype = dtype or x.dtype
+    held, scored = experts["gate"].shape[0], router_w.shape[-1]
+    bound = tokens * top_k
+    expected = bound * held / scored
+    cuts = buffer_sizes(bound, expected)
+    _record(name, experts=scored, experts_held=held, top_k=top_k,
+            tokens=tokens, row_bound=bound, expected_rows=expected,
+            cut_rows=cuts[0])
+    with jax.named_scope(timeline.MOE_ROUTE):
+        chosen, weights, counts = route(x, router_w, bias, top_k, route_scale)
+    with jax.named_scope(timeline.MOE_DISPATCH):
+        local = chosen - first                                 # [T, k]
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held).reshape(-1)       # absent: last
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.sum(group[:, None] == jnp.arange(held)[None], axis=0,
+                        dtype=jnp.int32)
+    # Each cut recomputes itself in the backward pass: differentiated as it
+    # stands, the switch would hand out every cut's residuals on every step,
+    # zeros for all but one (AOT for the v5e: 8.2 GB of temporaries and not
+    # 2.9; PERF.md, PR 28).
+    branches = [jax.checkpoint(functools.partial(_held_part, n, top_k))
+                for n in cuts]
+    fits = jnp.sum(jnp.sum(sizes) > jnp.asarray(cuts[:-1], jnp.int32))
+    y = lax.switch(fits, branches, x.astype(dtype), experts, order, inverse,
+                   sizes, weights)
+    return y.astype(dtype), counts
+
+
+# The layers of the program being traced, as gauges keyed by that program
+# (the ``program`` of the ``hvd.spmd.dispatch`` span whose call traces it,
+# as the gradient exchange's are): program -> (id of that span, layer
+# names). Block recomputation traces a layer more than once, a re-trace
+# starts anew, so layers are told apart by name.
+_traced: dict = {}
+
+
+def _record(name: str, **sizes) -> None:
+    """``hvd.moe.layers`` (expert layers in the step's program) and, of one
+    layer: ``.experts`` (scored), ``.experts_held``, ``.top_k``, ``.tokens``
+    (a step, on this chip), ``.row_bound`` (rows of the sorted buffer: what
+    never dropping is sized for), ``.expected_rows`` (rows a step under even
+    routing: ``tokens x top_k x held / experts``) and ``.cut_rows`` (rows
+    the layer's passes run over while the occupied ones fit its first
+    cut)."""
+    program, owner = timeline.tracing_program()
+    held = _traced.get(program)
+    names = held[1] if held and held[0] == owner and owner is not None \
+        else set()
+    names.add(name)
+    _traced[program] = (owner, names)
+    timeline.gauge("hvd.moe.layers", len(names), key=program)
+    for what, value in sizes.items():
+        timeline.gauge("hvd.moe." + what, value, key=program)

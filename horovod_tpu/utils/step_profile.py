@@ -15,6 +15,13 @@ session writes and returns, for the training steps it holds:
   A fusion carries one of its operations' names, so a pass that XLA fused
   into a neighbour counts with the neighbour. Times are self times: an
   operation that contains others (a loop, a call) gives its time to them.
+* **layers**: the same self times by the layer scopes inside ``hvd_forward``
+  (``timeline.LAYER_SCOPES``: attention by the layer's type, the parts of a
+  sparse expert layer), forward, recomputed and backward together; the
+  innermost name wins. Empty for a model that names no layer. The grouped
+  products of an expert layer (``%ragged-dot-*`` instructions, XLA:TPU's own
+  lowering, which keeps no ``op_name``) count as ``hvd_moe_experts`` here
+  and, having no phase, as ``other`` above.
 * **collectives**: time of all-reduce, reduce-scatter, all-gather,
   all-to-all and collective-permute events on the lines ``XLA Ops`` and
   ``Async XLA Ops`` (start to done), the part of it that is **exposed**
@@ -58,6 +65,8 @@ _SCOPES = {timeline.FORWARD: "forward", timeline.LOSS: "loss",
            timeline.EXCHANGE: "exchange", timeline.UPDATE: "update",
            timeline.METRICS: "metrics"}
 _SCOPE = re.compile("|".join(map(re.escape, _SCOPES)))
+_LAYER = re.compile("|".join(map(re.escape, timeline.LAYER_SCOPES)))
+_GROUPED_PRODUCT = "%ragged-dot"
 _RECOMPUTED = "rematted_computation"
 _BACKWARD = f"transpose(jvp({timeline.FORWARD}"
 BETWEEN = "between spans"
@@ -228,6 +237,15 @@ def phase_of(op_name: str) -> str:
     return "backward" if _BACKWARD in op_name else "forward"
 
 
+def layer_of(op_name: str) -> Optional[str]:
+    """The last of the layer scopes in an operation's ``op_name``, or
+    ``None``."""
+    found = None
+    for found in _LAYER.finditer(op_name):
+        pass
+    return found.group(0) if found else None
+
+
 def operation(text: str) -> str:
     """``%psum.14 = bf16[8]{0} all-reduce(bf16[8] %x), channel_id=1`` ->
     ``all-reduce``; JAX names an instruction after its primitive, XLA says
@@ -318,7 +336,7 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
             for s in spans if s[2] == timeline.DISPATCH)
         steps = handles.most_common(1)[0][1] if handles else None
 
-    phases = collections.Counter()
+    phases, layers = collections.Counter(), collections.Counter()
     busy = window = collective = exposed = unnamed = 0.0
     by_operation = collections.Counter()
     for plane in chips.values():
@@ -333,6 +351,13 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
         for e, self_ns, _ in nested:
             phase = phase_of(e.op_name)
             phases[phase] += self_ns
+            layer = layer_of(e.op_name)
+            if layer is None and e.name.startswith(_GROUPED_PRODUCT):
+                # XLA:TPU's own lowering of ``lax.ragged_dot`` keeps no
+                # ``op_name``: its phase reads other, its layer is known
+                layer = timeline.MOE_EXPERTS
+            if layer:
+                layers[layer] += self_ns
             if phase == "other" and id(e) in moving:
                 unnamed += self_ns
         total, _ = union_ns((e.start_ns, e.end_ns) for e in ops)
@@ -368,6 +393,8 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
         "chips": n, "steps": steps,
         "busy_s": busy / n / 1e9, "window_s": window / n / 1e9,
         "phases_s": {p: phases[p] / n / 1e9 for p in PHASES},
+        "layers_s": {k: layers[k] / n / 1e9 for k in timeline.LAYER_SCOPES
+                     if k in layers},
         "collective_s": collective / n / 1e9,
         "collective_exposed_s": exposed / n / 1e9,
         "collective_in_other_s": unnamed / n / 1e9,
@@ -383,6 +410,8 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
             collective=1e3 * out["collective_s"] / steps,
             collective_exposed=1e3 * out["collective_exposed_s"] / steps,
             collective_in_other=1e3 * out["collective_in_other_s"] / steps)
+        out["per_step_ms"].update(
+            {k: 1e3 * v / steps for k, v in out["layers_s"].items()})
     return out
 
 
@@ -414,6 +443,10 @@ def table(result: dict) -> str:
         s = result["phases_s"][phase]
         lines.append(f"  {phase:<11}{1e3 * s / steps:10.3f} ms a step"
                      f"{100 * s / busy:7.2f}% of busy")
+    for layer, s in result.get("layers_s", {}).items():
+        lines.append(f"  {layer:<17}{1e3 * s / steps:10.3f} ms a step"
+                     f"{100 * s / busy:7.2f}% of busy (forward, recomputed "
+                     f"and backward)")
     lines.append(f"  collectives{1e3 * result['collective_s'] / steps:10.3f} "
                  f"ms a step, exposed "
                  f"{1e3 * result['collective_exposed_s'] / steps:.3f} ms, "

@@ -103,6 +103,17 @@ LOSS = "hvd_loss"           # logits to scalar
 EXCHANGE = "hvd_exchange"   # fused_reduce of the gradients
 UPDATE = "hvd_update"       # the inner optimizer's update, apply_updates
 METRICS = "hvd_metrics"     # accuracy, the loss all-reduce, the read-out
+# Layers inside ``hvd_forward`` (and so inside its backward): attention by
+# the layer's type, and the parts of a sparse expert layer.
+ATTN_WINDOW = "hvd_attn_window"     # attention of a sliding-window layer
+ATTN_FULL = "hvd_attn_full"         # attention of a full (causal) layer
+MOE_ROUTE = "hvd_moe_route"         # scores, top k, weights, counts
+MOE_DISPATCH = "hvd_moe_dispatch"   # sort by expert, gather the rows
+MOE_EXPERTS = "hvd_moe_experts"     # the grouped matrix products
+MOE_COMBINE = "hvd_moe_combine"     # rows back to tokens, weighted sum
+MOE_SHARED = "hvd_moe_shared"       # the shared expert every token passes
+LAYER_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
+                MOE_COMBINE, MOE_SHARED)
 RING = 8192     # records kept: a 10 s window of 46 ms steps is 217 of them
 
 _lock = threading.Lock()
@@ -200,6 +211,17 @@ def enclosing(name: str) -> Optional[span]:
         if open_span.name == name:
             return open_span
     return None
+
+
+def tracing_program():
+    """``(program, id)`` of the ``hvd.spmd.dispatch`` span whose call is
+    tracing the code that asks: the key of a gauge that describes a compiled
+    program, and what tells a re-trace from more of the same trace.
+    ``("", None)`` outside any dispatch."""
+    tracing = enclosing(DISPATCH)
+    if tracing is None:
+        return "", None
+    return tracing.args.get("program", ""), tracing.id
 
 
 def count(name: str, n=1) -> None:

@@ -72,8 +72,6 @@ _SLOW_TESTS = {
     "test_serve_bench.py::TestServeBenchContract::test_require_finished_fails_loudly",
     # Round-4 re-budget (fast lane had crept to 17.9 min): whole-model
     # composition pins whose per-op internals have fast stand-ins.
-    # 57s; stand-ins: test_parallel.py TestMoE per-token closed forms
-    "test_parallel_lm.py::test_moe_lm_matches_dense_routing",
     # 41s; stand-ins: test_train_step_matches_dense + decode_composes_with_tp
     "test_parallel_lm.py::test_decode_matches_naive_recompute",
     # 28s; stand-ins: the per-axis exactness pins in the same file

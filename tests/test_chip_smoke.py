@@ -177,6 +177,36 @@ def test_kernels_compile_for_a_tpu_from_here():
                          qkv, qkv, qkv)
     assert text.count("tpu_custom_call") >= 3
 
+    # The sparse decoder's kernels at its real widths (32 query heads of
+    # 128 over 4 KV heads, 4,096 tokens, blocks of 1,024): the windowed
+    # grouped flash kernels under their profile names, and the expert
+    # layer's grouped products, which XLA:TPU lowers itself.
+    def windowed_loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=2048,
+                               block_q=1024, block_k=1024, interpret=False,
+                               bwd_impl="pallas").astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(windowed_loss, argnums=(0, 1, 2))).lower(
+        spec((2, 4096, 32, 128), jnp.bfloat16),
+        spec((2, 4096, 4, 128), jnp.bfloat16),
+        spec((2, 4096, 4, 128), jnp.bfloat16))
+    text = lowered.compile().as_text()
+    for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        assert f"%{kernel}" in text, kernel
+
+    from horovod_tpu.parallel import moe
+
+    def experts_loss(x, router, experts):
+        y, _ = moe.routed_experts(x, router, experts, top_k=8,
+                                  dtype=jnp.bfloat16)
+        return y.astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(experts_loss, argnums=(0, 2))).lower(
+        spec((8192, 2048)), spec((2048, 128)),
+        {"gate": spec((16, 2048, 1024)), "up": spec((16, 2048, 1024)),
+         "down": spec((16, 1024, 2048))})
+    assert "%ragged-dot-none" in lowered.compile().as_text()
+
 
 def test_rehearsal_passes():
     proc = _run("chip_smoke.py", "--rehearsal", timeout=1500)

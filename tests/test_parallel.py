@@ -668,66 +668,3 @@ def test_vma_checking_tracks_region(hvd):
     assert seen == {"typed": True, "untyped": False}
 
 
-class TestMoE:
-    def test_top1_routing_capacity(self, hvd):
-        x = jnp.eye(4, dtype=jnp.float32)  # 4 tokens, 4 dims
-        gate_w = jnp.eye(4) * 10.0  # token i -> expert i
-        dispatch, combine, aux = par.top1_routing(x, gate_w, 4, 1)
-        # Each expert receives exactly its token.
-        np.testing.assert_allclose(np.asarray(jnp.sum(dispatch, axis=(0, 2))),
-                                   np.ones(4))
-        assert float(aux) > 0
-
-    def test_moe_matches_per_token_formula(self, hvd):
-        """With ample capacity (no drops), expert-parallel MoE must equal
-        the per-token closed form: y[t] = gate[t] * expert_{e(t)}(x[t])."""
-        mesh = _mesh({"ep": 4})
-        key = jax.random.PRNGKey(7)
-        T, D, E = 16, 8, 4
-        x = jax.random.normal(key, (T, D))
-        gate_w = jax.random.normal(jax.random.fold_in(key, 1), (D, E))
-        ew = jax.random.normal(jax.random.fold_in(key, 2), (E, D, D)) * 0.3
-
-        def expert_fn(w, tokens):
-            return tokens @ w
-
-        probs = jax.nn.softmax(x @ gate_w, axis=-1)
-        eidx = jnp.argmax(probs, axis=-1)
-        gate = jnp.max(probs, axis=-1)
-        expected = jnp.einsum("t,td->td", gate,
-                              jnp.einsum("td,tde->te", x, ew[eidx]))
-
-        out = jax.jit(jax.shard_map(
-            lambda x, gw, ew: par.moe_layer(x, gw, expert_fn, ew, "ep",
-                                            capacity_factor=float(E)),
-            mesh=mesh, in_specs=(P("ep"), P(), P("ep")),
-            out_specs=P("ep")))(x, gate_w, ew)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
-                                   atol=1e-5)
-
-    def test_moe_multiple_experts_per_chip(self, hvd):
-        """E=8 over 4 chips (e_local=2) exercises the (owner chip, local
-        expert) reassembly of the return all_to_all."""
-        mesh = _mesh({"ep": 4})
-        key = jax.random.PRNGKey(8)
-        T, D, E = 32, 4, 8
-        x = jax.random.normal(key, (T, D))
-        gate_w = jax.random.normal(jax.random.fold_in(key, 1), (D, E))
-        ew = jax.random.normal(jax.random.fold_in(key, 2), (E, D, D)) * 0.3
-
-        def expert_fn(w, tokens):
-            return tokens @ w
-
-        probs = jax.nn.softmax(x @ gate_w, axis=-1)
-        eidx = jnp.argmax(probs, axis=-1)
-        gate = jnp.max(probs, axis=-1)
-        expected = jnp.einsum("t,td->td", gate,
-                              jnp.einsum("td,tde->te", x, ew[eidx]))
-
-        out = jax.jit(jax.shard_map(
-            lambda x, gw, ew: par.moe_layer(x, gw, expert_fn, ew, "ep",
-                                            capacity_factor=float(E)),
-            mesh=mesh, in_specs=(P("ep"), P(), P("ep")),
-            out_specs=P("ep")))(x, gate_w, ew)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
-                                   atol=1e-5)

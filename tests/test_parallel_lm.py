@@ -293,82 +293,6 @@ def test_pipeline_parallel_matches_dense(hvd):
                                    rtol=3e-4, atol=3e-5)
 
 
-def test_moe_lm_matches_dense_routing(hvd):
-    """Switch-MoE LM: with drop-free capacity, the expert-parallel
-    forward (tokens batch-sharded over ep, two all_to_alls) must equal
-    the identical routing math run with every expert local; a training
-    step (main + aux loss) converges."""
-    import optax
-
-    rng = jax.random.PRNGKey(6)
-    experts = 4
-    params = plm.init_moe_lm_params(rng, V, LMAX, 2, H, DH, FFN, experts)
-    tokens = jax.random.randint(jax.random.fold_in(rng, 1), (8, L), 0, V)
-
-    dense_logits, dense_aux = plm.lm_apply_moe(
-        params, tokens, capacity_factor=float(experts))
-
-    mesh = par.make_mesh({"ep": 4}, devices=jax.devices()[:4])
-    specs = plm.moe_lm_param_specs(2, "ep")
-    fn = jax.jit(jax.shard_map(
-        lambda p, t: plm.lm_apply_moe(p, t, ep="ep",
-                                      capacity_factor=float(experts))[0],
-        mesh=mesh, in_specs=(specs, P("ep")),
-        out_specs=P("ep")))
-    sharded = fn(params, tokens)
-    np.testing.assert_allclose(np.asarray(sharded), np.asarray(dense_logits),
-                               rtol=3e-4, atol=3e-5)
-
-    # Gradient exactness (drop-free, main nll only): moe_reduce_grads'
-    # per-leaf rule — ep-mean for replicated, /n for the data-complete
-    # expert shards — must reproduce the dense autodiff.
-    def dense_loss(p):
-        return plm.next_token_nll(
-            plm.lm_apply_moe(p, tokens, capacity_factor=float(experts))[0],
-            tokens)
-
-    dense_g = jax.grad(dense_loss)(params)
-
-    def sharded_grads(p, t):
-        def loss_fn(p):
-            return plm.next_token_nll(
-                plm.lm_apply_moe(p, t, ep="ep",
-                                 capacity_factor=float(experts))[0], t)
-
-        return plm.moe_reduce_grads(jax.grad(loss_fn)(p), "ep")
-
-    gfn = jax.jit(jax.shard_map(
-        sharded_grads, mesh=mesh, in_specs=(specs, P("ep")),
-        out_specs=specs))
-    g_sharded = gfn(params, tokens)
-    for a, b in zip(jax.tree_util.tree_leaves(g_sharded),
-                    jax.tree_util.tree_leaves(dense_g)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-4, atol=5e-5)
-
-    # Default (dropping) capacity: a few training steps reduce the loss.
-    def step(p, t):
-        def loss_fn(p):
-            logits, aux = plm.lm_apply_moe(p, t, ep="ep")
-            return (plm.next_token_nll(logits, t) +
-                    0.01 * jax.lax.pmean(aux, "ep"))
-
-        loss, g = jax.value_and_grad(loss_fn)(p)
-        g = plm.moe_reduce_grads(g, "ep")
-        new_p = jax.tree_util.tree_map(lambda a, b: a - 0.05 * b, p, g)
-        return new_p, jax.lax.pmean(loss, "ep")
-
-    sfn = jax.jit(jax.shard_map(
-        step, mesh=mesh, in_specs=(specs, P("ep")),
-        out_specs=(specs, P())))
-    losses = []
-    ps = params
-    for _ in range(8):
-        ps, l = sfn(ps, tokens)
-        losses.append(float(l))
-    assert losses[-1] < losses[0], losses
-
-
 def test_pp_shape_validation_messages(hvd):
     """lm_apply_pp rejects a batch that does not divide the microbatch
     count, and a stage stack whose length mismatches the pp axis, with

@@ -265,3 +265,23 @@ def test_the_recorded_trace_carries_the_scopes_in_its_op_names():
                             "hvd_forward))/") for n in names)
     assert any("hvd_update/hvd_exchange/hvd_allreduce_grads_float32_b1/"
                "all_gather" in n for n in names)
+
+
+@pytest.mark.parametrize("op_name, layer, phase", [
+    ("jit(step_fn)/jvp(hvd_forward)/DecoderBlock_1/attn/hvd_attn_window/"
+     "jit(flash_attention)/hvd_flash_fwd/pallas_call",
+     "hvd_attn_window", "forward"),
+    ("jit(step_fn)/transpose(jvp(hvd_forward))/DecoderBlock_4/attn/"
+     "hvd_attn_full/hvd_flash_dkv/pallas_call", "hvd_attn_full", "backward"),
+    ("jit(step_fn)/transpose(jvp(hvd_forward))/checkpoint/"
+     "rematted_computation/DecoderBlock_2/moe/hvd_moe_experts/ragged_dot",
+     "hvd_moe_experts", "recomputed"),
+    ("jit(step_fn)/jvp(hvd_forward)/DecoderBlock_2/moe/hvd_moe_shared/"
+     "shared/gate/dot_general", "hvd_moe_shared", "forward"),
+    ("jit(step_fn)/hvd_update/mul", None, "update"),
+    ("jit(step_fn)/jvp(hvd_forward)/embed/take", None, "forward"),
+])
+def test_layer_scopes_split_the_forward_and_backward_passes(op_name, layer,
+                                                            phase):
+    assert sp.layer_of(op_name) == layer
+    assert sp.phase_of(op_name) == phase

@@ -289,25 +289,30 @@ def test_ulysses_four_alltoalls_of_local_tensor_bytes(hvd):
     assert colls == [("all_to_all", tensor)] * 4, (colls, tensor)
 
 
-def test_moe_two_alltoalls_of_slot_bytes(hvd):
-    """Switch MoE: wire traffic is the dispatch + return all_to_alls of
-    the capacity-bounded expert slots — never the dense token set."""
+def test_expert_shares_add_up_with_one_allreduce_of_token_bytes(hvd):
+    """The expert layer sharded over an axis (every chip routes all the
+    tokens and computes its own experts' share): the only wire traffic is
+    the one all-reduce that adds the shares up, of the tokens' bytes: no
+    capacity-bounded slots, nothing dropped."""
     import horovod_tpu.parallel as par
 
     mesh = par.make_mesh({"ep": 4}, devices=jax.devices()[:4])
-    T_local, D, experts = 16, 8, 4
-    x = jnp.zeros((4 * T_local, D))
+    T, D, F, experts = 16, 8, 16, 8
+
+    def share(x, router, held):
+        y, _ = par.routed_experts(x, router, held,
+                                  first=jax.lax.axis_index("ep") * 2,
+                                  top_k=2)
+        return jax.lax.psum(y, "ep")
+
+    stacked = {"gate": P("ep"), "up": P("ep"), "down": P("ep")}
     jx = jax.make_jaxpr(jax.shard_map(
-        lambda x, gw, ew: par.moe_layer(
-            x, gw, lambda p, t: t @ p["w"], ew, axis="ep",
-            capacity_factor=1.0),
-        mesh=mesh, in_specs=(P("ep"), P(), {"w": P("ep")}),
-        out_specs=P("ep"), check_vma=False))(
-        x, jnp.zeros((D, experts)), {"w": jnp.zeros((experts, D, D))})
-    colls = collect_collectives(jx)
-    capacity = T_local // experts  # ceil(T_local * cf / E), cf=1
-    slot_bytes = experts * capacity * D * 4
-    assert colls == [("all_to_all", slot_bytes)] * 2, (colls, slot_bytes)
+        share, mesh=mesh, in_specs=(P(), P(), stacked), out_specs=P(),
+        check_vma=False))(
+        jnp.zeros((T, D)), jnp.zeros((D, experts)),
+        {"gate": jnp.zeros((experts, D, F)), "up": jnp.zeros((experts, D, F)),
+         "down": jnp.zeros((experts, F, D))})
+    assert collect_collectives(jx) == [("psum", T * D * 4)]
 
 
 def test_static_audit_matches_dynamic_accounting(hvd):

@@ -551,22 +551,29 @@ def _build_parallel_ring_attention():
 def _build_parallel_moe():
     import jax
     import jax.numpy as jnp
+    from jax import lax
     from jax.sharding import PartitionSpec as P
 
     import horovod_tpu.parallel as par
 
     _init()
     mesh = _submesh({"ep": 4})
-    T, D, experts = 64, 8, 4
-    fn = _shmapped(
-        lambda x, gw, ew: par.moe_layer(
-            x, gw, lambda p, t: t @ p["w"], ew, axis="ep",
-            capacity_factor=1.0),
-        mesh, in_specs=(P("ep"), P(), {"w": P("ep")}),
-        out_specs=P("ep"))
-    args = (jax.ShapeDtypeStruct((T, D), jnp.float32),
-            jax.ShapeDtypeStruct((D, experts), jnp.float32),
-            {"w": jax.ShapeDtypeStruct((experts, D, D), jnp.float32)})
+    T, D, F, experts, per_chip = 64, 8, 16, 8, 2
+
+    def share(x, router, held):
+        y, _ = par.routed_experts(
+            x, router, held, first=lax.axis_index("ep") * per_chip,
+            top_k=2)
+        return lax.psum(y, "ep")
+
+    stacked = {"gate": P("ep"), "up": P("ep"), "down": P("ep")}
+    fn = _shmapped(share, mesh, in_specs=(P(), P(), stacked), out_specs=P())
+    f32 = jnp.float32
+    args = (jax.ShapeDtypeStruct((T, D), f32),
+            jax.ShapeDtypeStruct((D, experts), f32),
+            {"gate": jax.ShapeDtypeStruct((experts, D, F), f32),
+             "up": jax.ShapeDtypeStruct((experts, D, F), f32),
+             "down": jax.ShapeDtypeStruct((experts, F, D), f32)})
     return fn, args
 
 
