@@ -37,6 +37,7 @@ metrics).
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -348,6 +349,7 @@ def build_lm_lane(args, log) -> Lane:
 
     import horovod_tpu.jax as hvd
     from horovod_tpu import models
+    from horovod_tpu.ops.attention import FLASH_BWD, attend, flash_grid_info
     from horovod_tpu.utils.timeline import FORWARD, LOSS, UPDATE, span
 
     if args.fused_bn:
@@ -370,34 +372,16 @@ def build_lm_lane(args, log) -> Lane:
                              "transformer_lm only (got --model moe_lm)")
     elif attention == "flash":
         # Pallas flash attention (ops/attention.py): the O(L)-memory
-        # kernel lane, A/B-able against the default dense attention at
-        # the same protocol (VERDICT r2 item 6's throughput comparison).
-        from horovod_tpu.ops.attention import (flash_attention,
-                                               flash_grid_info,
-                                               resolve_bwd_impl)
-
-        block = min(128, L)
-        if L % block:
-            raise ValueError(
-                f"flash attention needs --seq-len divisible by the "
-                f"kernel block ({block}); got {L} — the dense lane "
-                f"accepts any length, pad or round for the A/B")
+        # kernel lane, A/B-able against the dense reference at the same
+        # protocol (--attention dense | flash).
         # --flash-full-grid pins the causal grid to full size (compute-
         # skip only) for the truncated-vs-full A/B lanes; the default
         # (None) runs the packed at-or-below-diagonal grid. --flash-bwd
-        # pins the backward implementation: below Lk 8192 "auto" runs
-        # the scan backward, which is diagonal-truncated by
-        # construction on BOTH sides of the grid A/B — pinning "pallas"
-        # makes the A/B span the backward kernels too. The unset
-        # default (None) keeps the HVD_FLASH_BWD env override working
-        # exactly as it did before this flag existed.
+        # pins the backward implementation; unset, the kernels run the
+        # policy's own (ops.attention.FLASH_BWD).
         truncate = False if args.flash_full_grid else None
-        bwd = args.flash_bwd
-
-        def attn_fn(q, k, v):
-            return flash_attention(q, k, v, causal=True, truncate=truncate,
-                                   bwd_impl=bwd)
-
+        attn_fn = functools.partial(attend, impl="flash", truncate=truncate,
+                                    bwd_impl=args.flash_bwd)
         # Grid + K/V-DMA accounting stamped into the JSON record so the
         # wall time is attributable to a concrete grid (blocks, step
         # count, bytes) and a named backward, not just a lane name.
@@ -409,13 +393,14 @@ def build_lm_lane(args, log) -> Lane:
             head_dim=args.lm_dim // args.lm_heads,
             batch_heads=batch_size * args.lm_heads,
             dtype_bytes=4 if args.fp32 else 2)
-        flash_grid["bwd"] = resolve_bwd_impl(bwd, L)
-    elif args.flash_full_grid:
-        raise ValueError("--flash-full-grid requires the flash attention "
-                         "path (--attention flash, or auto at long seq)")
-    elif args.flash_bwd is not None:
-        raise ValueError("--flash-bwd requires the flash attention "
-                         "path (--attention flash, or auto at long seq)")
+        flash_grid["bwd"] = (FLASH_BWD if args.flash_bwd in (None, "auto")
+                             else args.flash_bwd)
+    elif args.flash_full_grid or args.flash_bwd is not None:
+        raise ValueError("--flash-full-grid and --flash-bwd require the "
+                         "flash attention path (--attention flash, or "
+                         "where auto picks it)")
+    else:
+        attn_fn = functools.partial(attend, impl="dense")
     if args.model == "moe_lm":
         model = build_sparse_lm(args, attention, dtype)
     else:
@@ -720,12 +705,12 @@ def collectives_stamp(run_step, state, batch, log):
 def resolve_attention(args) -> str:
     """Resolve the LM lane's attention implementation to "dense"|"flash".
 
-    ``--attention auto`` encodes the MEASURED crossover (PERF.md
-    pre-round table: dense wins at seq 2048, flash wins from 4096 and
-    is the only compiling path beyond it) so nobody hand-picks the
-    loser at either end; the threshold is
-    ops.attention.FLASH_ATTENTION_MIN_SEQ. ``--flash-attention``
-    remains the back-compat spelling of ``--attention flash``.
+    ``--attention auto``, and an unset ``--attention``, ask
+    ``ops.attention.attention_plan`` with the lane's shapes: the flash
+    kernels where the v5e sweep found them faster (PERF.md, PR 29), the
+    dense reference elsewhere and on the CPU. ``dense`` and ``flash`` pin
+    one side for an A/B. ``--flash-attention`` remains the back-compat
+    spelling of ``--attention flash``.
     """
     mode = args.attention
     if args.flash_attention:
@@ -733,13 +718,19 @@ def resolve_attention(args) -> str:
             raise ValueError(
                 f"--flash-attention conflicts with --attention {mode}")
         mode = "flash"
-    if mode is None:
-        mode = "dense"
-    if mode == "auto":
-        from horovod_tpu.ops.attention import FLASH_ATTENTION_MIN_SEQ
+    if mode in (None, "auto"):
+        import jax.numpy as jnp
 
-        mode = ("flash" if args.seq_len >= FLASH_ATTENTION_MIN_SEQ
-                else "dense")
+        from horovod_tpu.ops.attention import attention_plan
+
+        heads = args.lm_heads
+        kv_heads, head_dim = heads, args.lm_dim // heads
+        if args.model == "moe_lm":
+            kv_heads = args.lm_kv_heads or heads
+            head_dim = args.lm_head_dim or head_dim
+        mode = attention_plan(
+            args.seq_len, args.seq_len, heads, kv_heads, head_dim,
+            dtype=jnp.float32 if args.fp32 else jnp.bfloat16).impl
     return mode
 
 
@@ -916,11 +907,13 @@ def build_parser():
                              "back-compat spelling of --attention flash")
     parser.add_argument("--attention", default=None,
                         choices=("auto", "dense", "flash"),
-                        help="transformer_lm attention policy: auto "
-                             "applies the measured crossover (dense "
-                             "below seq 4096, flash at/above — PERF.md "
-                             "pre-round adjudication); default dense "
-                             "preserves the historical lane wiring")
+                        help="LM lanes' attention: auto (the default) "
+                             "asks ops.attention.attention_plan with the "
+                             "lane's shapes (the flash kernels where the "
+                             "v5e sweep found them faster, the dense "
+                             "reference elsewhere and on the CPU; "
+                             "PERF.md, PR 29); dense | flash pin one "
+                             "side for an A/B")
     parser.add_argument("--flash-full-grid", action="store_true",
                         help="transformer_lm + flash: force the FULL "
                              "causal (q-block, k-block) grid (compute-"
@@ -930,13 +923,9 @@ def build_parser():
     parser.add_argument("--flash-bwd", default=None,
                         choices=("auto", "scan", "pallas"),
                         help="transformer_lm + flash: pin the backward "
-                             "implementation (auto = measured-crossover "
-                             "dispatch: scan below Lk 8192, kernel "
-                             "split at/above; unset defers to the "
-                             "HVD_FLASH_BWD env default). The grid A/B "
-                             "lanes pin pallas so truncated-vs-full "
-                             "spans the backward kernels at short seq "
-                             "too")
+                             "implementation for an A/B (unset or auto: "
+                             "the one ops.attention.attention_plan "
+                             "names)")
     parser.add_argument("--compile-only", action="store_true",
                         help="build + compile the train step (one first "
                              "step, metric <model>_first_step_secs) and "

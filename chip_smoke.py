@@ -103,10 +103,15 @@ def lm_reference_loss(lane, per_chip):
     lane's model with dense attention under the plain log-softmax head,
     forward only, in a plain ``jax.jit`` — on the lane's own parameters
     and rank 0's shard of its batch."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    model = lane.model.clone(attn_fn=None, remat=False)
+    from horovod_tpu.ops.attention import attend
+
+    model = lane.model.clone(
+        attn_fn=functools.partial(attend, impl="dense"), remat=False)
 
     @jax.jit
     def loss(params, tokens):
@@ -222,11 +227,11 @@ def train_one_chip(sz, kernels_compiled):
         "train resnet50", (*sz.resnet, "--batch-size", str(sz.resnet_batch)),
         sz.steps)
     lm = (*sz.lm, "--batch-size", str(sz.lm_batch))
+    # Both sides pinned: unset, the lane asks the policy, which picks the
+    # kernels at these lengths on the chip.
     train_phase(f"train lm dense seq {sz.seq_dense}",
-                (*lm, "--seq-len", str(sz.seq_dense)), sz.steps,
-                ref_tol=REF_TOL)
-    # The backward pinned to the Pallas kernels: `auto` would pick the
-    # scan backward below 8192 keys and leave dQ and dK/dV uncompiled.
+                (*lm, "--seq-len", str(sz.seq_dense), "--attention",
+                 "dense"), sz.steps, ref_tol=REF_TOL)
     flash = ("--attention", "flash", "--flash-bwd", "pallas", "--remat",
              "--fused-ce")
     train_phase(f"train lm flash+fused-ce seq {sz.seq_flash}",
@@ -253,8 +258,10 @@ def train_all_chips(sz, one_chip_resnet, n):
     say(f"train resnet50 dp={n} vs one chip",
         f"first-step loss {resnet[0]:.4f} vs {one_chip_resnet:.4f} "
         f"(tolerance {sz.dp_tol_resnet:.0%})")
+    # --attention unset: the policy's choice, on the chip the kernels under
+    # the data-parallel shard_map.
     train_phase(
-        f"train lm dense seq {sz.seq_dense} dp={n}",
+        f"train lm seq {sz.seq_dense} dp={n}",
         (*sz.lm, "--batch-size", str(sz.lm_batch // n), "--seq-len",
          str(sz.seq_dense)), sz.steps, want_collective=True,
         ref_tol=REF_TOL)

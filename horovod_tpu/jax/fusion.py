@@ -467,15 +467,11 @@ def _record_plan(issued, n: int) -> None:
     event of the Chrome timeline."""
     from horovod_tpu.utils import timeline
 
-    program, owner = timeline.tracing_program()
-    held = _plans.get(program)
-    totals = (held[1] if held and owner is not None and held[0] == owner
-              else collections.Counter())
+    program, totals = timeline.program_tally(_plans, collections.Counter)
     if n > 1:
         for calls, nbytes, tensors, packed in issued:
             totals.update(calls=calls, bytes=nbytes, tensors=tensors,
                           buckets=1, packed_bytes=packed)
-    _plans[program] = (owner, totals)
     for what in ("calls", "bytes", "tensors", "buckets", "packed_bytes"):
         timeline.gauge("hvd.exchange." + what, totals[what], key=program)
 

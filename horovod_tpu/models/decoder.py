@@ -30,16 +30,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.attention import dot_product_attention, flash_attention
+from horovod_tpu.ops.attention import attend
 from horovod_tpu.parallel import moe
 from horovod_tpu.utils import timeline
 
 SLIDING, FULL = "sliding_attention", "full_attention"
-# Rows and keys of a flash block, the largest that divides the length: at 32
-# query heads of 128 over 4,096 tokens a 256 x 256 block (the kernels' own
-# default, measured on heads of 64) spends its time on grid steps, 1,024 x
-# 1,024 takes half the time forward and backward (PERF.md, PR 28)
-FLASH_BLOCKS = (1024, 512, 256)
 
 
 class RMSNorm(nn.Module):
@@ -89,7 +84,8 @@ class GatedMLP(nn.Module):
 class GroupedAttention(nn.Module):
     """Gated grouped-query attention of one layer type (module docstring).
     ``attention``: ``"flash"`` (the Pallas kernels, which know the window
-    and the grouping) or ``"dense"`` (the masked reference)."""
+    and the grouping), ``"dense"`` (the masked reference) or None: what
+    ``ops.attention.attention_plan`` picks for the shapes."""
 
     heads: int
     kv_heads: int
@@ -97,14 +93,13 @@ class GroupedAttention(nn.Module):
     window: Optional[int] = None        # None: full attention, no rotary
     eps: float = 1e-5
     rope_base: float = 10000.0
-    attention: str = "dense"
+    attention: Optional[str] = None
     dtype: Any = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
         b, length, _ = x.shape
         h, g, d = self.heads, self.kv_heads, self.head_dim
-        block = next((b for b in FLASH_BLOCKS if length % b == 0), None)
 
         def project(heads, name):
             y = nn.Dense(heads * d, use_bias=False, dtype=self.dtype,
@@ -126,15 +121,7 @@ class GroupedAttention(nn.Module):
         scope = (timeline.ATTN_FULL if self.window is None
                  else timeline.ATTN_WINDOW)
         with jax.named_scope(scope):
-            if self.attention == "flash":
-                # the kernel split and not the scan: the scan's slabs are
-                # [B, H, L, block] float32 a step, and it cannot skip
-                out = flash_attention(q, k, v, causal=True,
-                                      window=self.window, bwd_impl="pallas",
-                                      block_q=block, block_k=block)
-            else:
-                out = dot_product_attention(q, k, v, causal=True,
-                                            window=self.window)
+            out = attend(q, k, v, window=self.window, impl=self.attention)
         out = out.reshape(b, length, h * d) * nn.sigmoid(gate)
         return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
                         name="out")(out)
@@ -239,7 +226,7 @@ class SparseDecoderLM(nn.Module):
     embed_scale: bool = True
     eps: float = 1e-5
     rope_base: float = 10000.0
-    attention: str = "dense"
+    attention: Optional[str] = None
     dtype: Any = jnp.bfloat16
     remat: bool = False
 

@@ -16,9 +16,11 @@ from __future__ import annotations
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.attention import dot_product_attention
+from horovod_tpu.ops.attention import attend
+from horovod_tpu.utils import timeline
 
 
 class TransformerBlock(nn.Module):
@@ -28,7 +30,9 @@ class TransformerBlock(nn.Module):
     # attn_fn(q, k, v) -> out, shapes [B, L, H, D]. The fn owns causality
     # and cross-shard positioning (e.g. a ring-attention closure passes
     # causal=True itself; ring/Ulysses derive offsets from the mesh axis).
-    # None = dense causal attention using q_offset.
+    # None = causal attention using q_offset, by the implementation that
+    # ops.attention.attention_plan picks for the shapes (the flash kernels
+    # or the dense reference).
     attn_fn: Optional[Callable] = None
     dropout: float = 0.0
 
@@ -41,13 +45,13 @@ class TransformerBlock(nn.Module):
         qkv = nn.Dense(3 * E, use_bias=False, dtype=self.dtype)(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         shape = (*q.shape[:-1], H, D)
-        if self.attn_fn is None:
-            attn = dot_product_attention(
-                q.reshape(shape), k.reshape(shape), v.reshape(shape),
-                causal=True, q_offset=q_offset)
-        else:
-            attn = self.attn_fn(q.reshape(shape), k.reshape(shape),
-                                v.reshape(shape))
+        with jax.named_scope(timeline.ATTN_FULL):
+            if self.attn_fn is None:
+                attn = attend(q.reshape(shape), k.reshape(shape),
+                              v.reshape(shape), q_offset=q_offset)
+            else:
+                attn = self.attn_fn(q.reshape(shape), k.reshape(shape),
+                                    v.reshape(shape))
         attn = attn.reshape(q.shape)
         x = x + nn.Dense(E, dtype=self.dtype)(attn)
 

@@ -25,22 +25,16 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from horovod_tpu.utils import timeline
 from horovod_tpu.utils.device import pallas_interpret
 
 NEG_INF = -1e30  # finite stand-in for -inf: exp() of it is exactly 0
-
-# Measured dense/flash crossover on the LM lane (PERF.md pre-round
-# adjudication #2): dense still wins at seq 2048 (-6%), flash wins 1.31x
-# at seq 4096 and is the only structurally-compiling path beyond it.
-# ``bench.py --attention auto`` selects by this threshold so nobody
-# hand-picks the measured loser at either end.
-FLASH_ATTENTION_MIN_SEQ = 4096
 
 
 def dot_product_attention(q, k, v, causal: bool = False,
@@ -200,9 +194,9 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
                     window: Optional[int] = None):
     """Static grid + K/V-DMA accounting for a ``flash_attention`` call.
 
-    Mirrors exactly the tiling (:func:`_default_blocks`) and truncation
-    (:func:`_grid_truncates`) policy the kernels use, without tracing
-    anything — ``bench.py`` stamps this into the flash-lane JSON and
+    Mirrors exactly the tiling (:func:`attention_plan`'s blocks) and
+    truncation (:func:`_grid_truncates`) policy the kernels use, without
+    tracing anything — ``bench.py`` stamps this into the flash-lane JSON and
     ``tools/tpu_flash_check.py`` into its micro A/B report so every
     wall-time record is attributable to a concrete grid, not just a
     block pair.
@@ -215,9 +209,8 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
     DMAs in (one [block_k, head_dim] tile each for K and V per step,
     times ``batch_heads``).
     """
-    dq, dk = _default_blocks(seq_q, seq_k)
-    bq = min(block_q if block_q is not None else dq, seq_q)
-    bk = min(block_k if block_k is not None else dk, seq_k)
+    block_q, block_k = _planned_blocks(seq_q, seq_k, block_q, block_k)
+    bq, bk = min(block_q, seq_q), min(block_k, seq_k)
     nqb, nkb = seq_q // bq, seq_k // bk
     truncated = _grid_truncates(causal, seq_q, seq_k, q_offset, k_offset,
                                 truncate)
@@ -370,17 +363,19 @@ _MIN_BLOCK = 8
 
 
 def _pick_block(cap: int, seq_len: int) -> int:
-    """Largest ladder block <= cap that divides ``seq_len``, floored at
-    the native 8-sublane tile.
+    """Largest power-of-two block <= cap that divides ``seq_len``, floored
+    at the native 8-sublane tile.
 
-    Lengths with no multiple-of-8 factor (L=100 -> old ladder degraded
-    to 4; L=33 -> 1) are a caller error, not a tiling choice: raise the
-    explicit "pad upstream" contract instead of emitting a sub-tile
-    kernel that only fails once it reaches a chip (ADVICE r5 #1).
+    Lengths with no multiple-of-8 factor (L=100 -> 4; L=33 -> 1) are a
+    caller error, not a tiling choice: raise the explicit "pad upstream"
+    contract instead of emitting a sub-tile kernel that only fails once it
+    reaches a chip (ADVICE r5 #1).
     """
-    for b in (cap, 256, 128, 64, 32, 16, _MIN_BLOCK):
-        if _MIN_BLOCK <= b <= cap and b <= seq_len and seq_len % b == 0:
-            return b
+    block = cap
+    while block >= _MIN_BLOCK:
+        if block <= seq_len and seq_len % block == 0:
+            return block
+        block //= 2
     raise ValueError(
         f"flash_attention has no legal default block tile for sequence "
         f"length {seq_len}: no divisor >= the native {_MIN_BLOCK}-sublane "
@@ -388,25 +383,128 @@ def _pick_block(cap: int, seq_len: int) -> int:
         f"{_MIN_BLOCK} (ideally 128), or pass explicit block_q/block_k.")
 
 
-def _default_blocks(seq_q: int, seq_k: int):
-    """Measured tiling policy (TPU v5e block sweep, PERF.md pre-round):
-    256x512 won at seq 2048 (1.29x vs the old 128x128 default) and
-    256x256 at seq 4096 (1.35x) — larger k-blocks amortize the online
-    softmax rescale until the streamed K/V footprint presses VMEM, so
-    the k-block steps down at longer key lengths. The q-block must
-    divide the QUERY length and the k-block the KEY length (they differ
-    for rectangular cross-attention / ring-attention shards), each
-    degrading down a power-of-two ladder."""
-    return (_pick_block(256, seq_q),
-            _pick_block(512 if seq_k <= 2048 else 256, seq_k))
+# --------------------------------------------------------------------------
+# The one policy: implementation, blocks and backward from the static shapes
 
 
-# Import-time default for the backward implementation ("scan" |
-# "pallas" | "" = auto-by-length). Read ONCE so the selection is part
-# of every trace's static key via the bwd_impl argument below —
-# flipping the env mid-process cannot silently desync from cached
-# traces; per-call control is the explicit bwd_impl= argument.
-_FLASH_BWD_ENV_DEFAULT = __import__("os").environ.get("HVD_FLASH_BWD", "")
+class AttentionPlan(NamedTuple):
+    """What :func:`attention_plan` chose for one attention call."""
+
+    impl: str                   # "dense" | "flash"
+    block_q: Optional[int]      # the kernels' blocks; None where no legal
+    block_k: Optional[int]      # block divides a length (then ``dense``)
+    bwd: str                    # the kernels' backward: "pallas" | "scan"
+
+
+# The policy's constants, each from ``tools/tpu_flash_check.py
+# --block-sweep`` on one v5e (chip run of PR 29; PERF.md section 6 has the
+# table): one causal attention layer of 8,192 tokens in bf16, forward +
+# backward ms, dense against the kernels over blocks of 256 to 2,048 and
+# both backwards, at 16 heads of 64 with 1,024 / 2,048 / 4,096 keys and at
+# Trinity-Mini's 32 heads of 128 over 4 KV heads (4,096 keys, window 2,048
+# and none).
+#
+# Largest block, rows and keys: 1,024 x 1,024 is the fastest tile at every
+# shape (heads of 64, 1,024 keys: 2.54 against 2.59 at 512 x 512 and 4.38 at
+# 256 x 256; 4,096 keys: 5.33 / 6.37 / 13.03; heads of 128, window 2,048:
+# 10.25 / 11.17); a longer side under the same 4 MB of f32 scores (512 x
+# 2,048, 2,048 x 512) loses 18 to 42%. Mosaic accepts it for f32 inputs and
+# heads of 32 to 512 (compiled for the v5e, not run).
+FLASH_BLOCK = 1024
+# Smallest block the kernels are chosen at: at 256 x 256 they lose to dense
+# at 1,024 keys (4.38 against 3.95) and win by 2 to 5% at 2,048 and 4,096.
+FLASH_MIN_BLOCK = 512
+# Fewest keys: the shortest length measured, where the kernels win by 1.55
+# times (2.54 against 3.95; 2.13 times at 2,048, 2.58 at 4,096, 2.83 at
+# Trinity-Mini's shapes). Below it nothing was measured: dense.
+FLASH_MIN_KEYS = 1024
+# The backward: the dQ and dK/dV kernels. The scan over key blocks, with
+# its [B, H, L, block] f32 slabs, takes 1.8 (1,024 keys) to 3.1 times (4,096)
+# their forward + backward time at equal blocks and 3.4 times at heads of
+# 128; it is kept as ``bwd_impl="scan"``, the kernels' reference in tests.
+FLASH_BWD = "pallas"
+
+
+def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
+                   head_dim: int, window: Optional[int] = None,
+                   dtype=jnp.bfloat16,
+                   backend: Optional[str] = None) -> AttentionPlan:
+    """Implementation, blocks and backward of one causal attention call, from
+    what is static about it. ``backend`` defaults to JAX's own.
+
+    The kernels run on a TPU, from :data:`FLASH_MIN_KEYS` keys on, where a
+    block of at least :data:`FLASH_MIN_BLOCK` divides both lengths; the
+    dense reference everywhere else: the CPU test platform would interpret
+    the kernels, and below those sizes nothing was measured. The sweep found
+    no dependence on the number of heads, on grouping, on the head width
+    (64 and 128), on a window or on the element type that would change a
+    choice, so today the answer depends on the lengths alone; the other
+    arguments are what a later measurement may key on without a new call
+    site.
+    """
+    _kv_group(heads, kv_heads)
+    del head_dim, window, dtype
+    try:
+        block_q, block_k = _planned_blocks(seq_q, seq_k, None, None)
+    except ValueError:
+        return AttentionPlan("dense", None, None, FLASH_BWD)
+    if backend is None:
+        backend = jax.default_backend()
+    flash = (backend == "tpu" and seq_k >= FLASH_MIN_KEYS
+             and min(block_q, block_k) >= FLASH_MIN_BLOCK)
+    return AttentionPlan("flash" if flash else "dense", block_q, block_k,
+                         FLASH_BWD)
+
+
+def _planned_blocks(seq_q: int, seq_k: int, block_q: Optional[int],
+                    block_k: Optional[int]):
+    """A kernel call's blocks: the caller's, and the plan's for one left
+    out (the pad-upstream error where no legal block divides a length)."""
+    if block_q is None:
+        block_q = _pick_block(FLASH_BLOCK, seq_q)
+    if block_k is None:
+        block_k = _pick_block(FLASH_BLOCK, seq_k)
+    return block_q, block_k
+
+
+# The attention calls of the program being traced, as gauges keyed by that
+# program (the ``program`` of the ``hvd.spmd.dispatch`` span whose call
+# traces it): program -> (id of that span, calls by implementation). A
+# re-trace starts anew.
+_traced: dict = {}
+
+
+def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
+           impl: Optional[str] = None, **flash_args):
+    """Causal attention as a model's block calls it: q ``[B, L, H, D]``, k/v
+    ``[B, L, G, D]``. :func:`attention_plan` picks the implementation from
+    the shapes unless ``impl`` (``"dense"`` | ``"flash"``) pins one;
+    ``flash_args`` go to :func:`flash_attention` (an A/B's ``truncate`` and
+    ``bwd_impl``). Sets the gauges ``hvd.attn.flash_calls`` /
+    ``.dense_calls`` (attention calls traced into the step's program, by
+    implementation) and ``.block_q`` / ``.block_k`` (the kernels' blocks)."""
+    if impl is None:
+        # an offset mask is outside what the policy was measured on
+        impl = "dense" if q_offset else attention_plan(
+            q.shape[1], k.shape[1], q.shape[2], k.shape[2], q.shape[3],
+            window, q.dtype).impl
+    if impl not in ("dense", "flash"):
+        raise ValueError(f"impl must be dense|flash, got {impl!r}")
+    program, calls = timeline.program_tally(
+        _traced, lambda: {"flash": 0, "dense": 0})
+    calls[impl] += 1
+    for name, n in calls.items():
+        timeline.gauge(f"hvd.attn.{name}_calls", n, key=program)
+    if impl == "dense":
+        return dot_product_attention(q, k, v, causal=True, q_offset=q_offset,
+                                     window=window)
+    block_q, block_k = _planned_blocks(
+        q.shape[1], k.shape[1], flash_args.get("block_q"),
+        flash_args.get("block_k"))
+    timeline.gauge("hvd.attn.block_q", block_q, key=program)
+    timeline.gauge("hvd.attn.block_k", block_k, key=program)
+    return flash_attention(q, k, v, causal=True, q_offset=q_offset,
+                           window=window, **flash_args)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
@@ -435,8 +533,9 @@ def flash_attention(q, k, v, causal: bool = False,
     full one).
 
     Sequence lengths must be multiples of the block sizes (pad upstream).
-    Block sizes default to the measured-on-TPU policy in
-    :func:`_default_blocks`; pass explicit values to override.
+    Block sizes and the backward (``bwd_impl`` None or ``"auto"``) default
+    to :func:`attention_plan`'s, measured on the v5e; pass explicit values
+    to override.
     ``interpret`` defaults to the platform's: compiled on a TPU,
     interpreted on the CPU test platform.
 
@@ -466,14 +565,11 @@ def flash_attention(q, k, v, causal: bool = False,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = pallas_interpret()
-    dq, dk = _default_blocks(q.shape[1], k.shape[1])
-    if block_q is None:
-        block_q = dq
-    if block_k is None:
-        block_k = dk
-    if bwd_impl is None:
-        bwd_impl = _FLASH_BWD_ENV_DEFAULT or "auto"
-    if bwd_impl not in ("auto", "scan", "pallas"):
+    block_q, block_k = _planned_blocks(q.shape[1], k.shape[1], block_q,
+                                       block_k)
+    if bwd_impl in (None, "auto"):
+        bwd_impl = FLASH_BWD
+    if bwd_impl not in ("scan", "pallas"):
         raise ValueError(f"bwd_impl must be auto|scan|pallas, "
                          f"got {bwd_impl!r}")
     if causal and q_offset < k_offset:
@@ -781,15 +877,12 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
 
 def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
                     q_offset, k_offset, truncate, window, res, do):
-    """XLA lax.scan backward (the pre-round-5 implementation, kept as a
-    selectable path): one batched einsum pass per key block computing
-    dq/dk/dv together. At seq <= ~4096 its [B, H, Lq, block_k] einsum
-    slabs are MXU-friendly batched matmuls and it MEASURES faster than
-    the kernel split (10.45M vs 9.68M tok/s at seq 2048, PERF.md r5);
-    at long seq those slabs become multi-hundred-MB HBM round-trips
-    per block step. Selected by ``HVD_FLASH_BWD=scan`` or
-    automatically at short key lengths (see _flash_bwd_vjp). Already
-    grid-truncated by construction: the causal scan walks only the
+    """XLA lax.scan backward (``bwd_impl="scan"``; the kernel split's
+    reference in tests): one batched einsum pass per key block computing
+    dq/dk/dv together over [B, H, Lq, block_k] f32 slabs, which are HBM
+    round trips a block step: on the v5e it takes 1.8 to 3.4 times the
+    kernel split's time at every length measured (:data:`FLASH_BWD`).
+    Already grid-truncated by construction: the causal scan walks only the
     k-blocks at or below the last query row's diagonal (``truncate``
     is accepted for signature parity and ignored). Grouped K and V are
     repeated to the query heads here (the slabs are per query head anyway)
@@ -1008,40 +1101,12 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     return unflat(dq, Lq), grouped(dk, k), grouped(dv, v)
 
 
-# Key length at/above which the kernel backward takes over from the
-# scan backward by default (measured crossover, PERF.md pre-round).
-_FLASH_BWD_PALLAS_MIN_LK = 8192
-
-
-def resolve_bwd_impl(bwd_impl: Optional[str], seq_k: int) -> str:
-    """The backward implementation a flash_attention call will actually
-    run, mirroring flash_attention's own dispatch: None defers to the
-    HVD_FLASH_BWD import-time env default, then "auto" picks the
-    measured crossover — the scan backward below the
-    _FLASH_BWD_PALLAS_MIN_LK key length, the Pallas kernel split
-    at/above. Public so bench.py can stamp the RESOLVED backward into
-    flash-lane records: the truncated-vs-full grid A/B only spans the
-    backward when this says "pallas" (the scan walk is
-    diagonal-truncated by construction on both sides)."""
-    if bwd_impl is None:
-        bwd_impl = _FLASH_BWD_ENV_DEFAULT or "auto"
-    if bwd_impl == "auto":
-        return ("pallas" if seq_k >= _FLASH_BWD_PALLAS_MIN_LK
-                else "scan")
-    return bwd_impl
-
-
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
                    q_offset, k_offset, truncate, window, res, do):
-    """Backward dispatch, measured not assumed (PERF.md pre-round): the
-    scan backward's batched einsums win at short key lengths; the
-    O(block)-VMEM kernel split is required at long ones (the scan's
-    per-block [B, H, Lq, block_k] slabs scale with Lq). ``bwd_impl``
-    arrives as a static ("auto"|"scan"|"pallas") from flash_attention —
-    part of the trace key, so selection can never desync from a cached
-    trace."""
-    impl = resolve_bwd_impl(bwd_impl, res[1].shape[1])
-    fn = _flash_bwd_pallas if impl == "pallas" else _flash_bwd_scan
+    """``bwd_impl`` arrives resolved ("scan" | "pallas") from
+    flash_attention: part of the trace key, so the selection can never
+    desync from a cached trace."""
+    fn = _flash_bwd_pallas if bwd_impl == "pallas" else _flash_bwd_scan
     return fn(causal, scale, block_q, block_k, interpret,
               q_offset, k_offset, truncate, window, res, do)
 

@@ -256,12 +256,8 @@ def _record(name: str, **sizes) -> None:
     routing: ``tokens x top_k x held / experts``) and ``.cut_rows`` (rows
     the layer's passes run over while the occupied ones fit its first
     cut)."""
-    program, owner = timeline.tracing_program()
-    held = _traced.get(program)
-    names = held[1] if held and held[0] == owner and owner is not None \
-        else set()
+    program, names = timeline.program_tally(_traced, set)
     names.add(name)
-    _traced[program] = (owner, names)
     timeline.gauge("hvd.moe.layers", len(names), key=program)
     for what, value in sizes.items():
         timeline.gauge("hvd.moe." + what, value, key=program)

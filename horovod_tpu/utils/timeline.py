@@ -224,6 +224,18 @@ def tracing_program():
     return tracing.args.get("program", ""), tracing.id
 
 
+def program_tally(store: dict, fresh):
+    """``(program, tally)`` of the program being traced: what its trace has
+    gathered in ``store`` so far for the gauges that describe it, begun anew
+    with ``fresh()`` by a re-trace (another dispatch span) and outside any
+    dispatch."""
+    program, owner = tracing_program()
+    held = store.get(program)
+    if not (held and owner is not None and held[0] == owner):
+        held = store[program] = (owner, fresh())
+    return program, held[1]
+
+
 def count(name: str, n=1) -> None:
     with _lock:
         _counters[name] = _counters.get(name, 0) + n

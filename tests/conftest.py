@@ -137,7 +137,8 @@ _SLOW_TESTS = {
     # and the overlap/bucket-plan pins in test_overlap.py +
     # tests/test_scaling_model.py respectively.
     "test_bench.py::test_snapshot_stamp_in_record",
-    "test_bench.py::test_lm_attention_auto_policy",
+    "test_bench.py::test_lm_attention_auto_policy[unset]",
+    "test_bench.py::test_lm_attention_auto_policy[auto]",
     "test_bench.py::test_overlap_and_bucket_stamps_in_record",
     # ~25s whole-bench subprocess wrapper (a real LM lane + a degraded
     # attempt-timeout run); stand-in: the parser-level --mesh

@@ -1,0 +1,159 @@
+"""The one attention policy (``ops.attention.attention_plan``): which
+implementation, blocks and backward a causal attention call gets from its
+static shapes, and that everything that used to decide for itself (the
+kernels' default blocks, ``flash_grid_info``, ``attend``, the models' blocks)
+reads it.
+
+The table is the v5e's (``tools/tpu_flash_check.py --block-sweep``, PERF.md
+PR 29), asked for with ``backend="tpu"``: no kernel runs here. Tolerances of
+the one numerical test: float32 inputs, the kernels interpreted against the
+masked dense reference, 2e-5 absolute on outputs and gradients of size one
+(online softmax against a plain one; read: 2e-6).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops.attention import (
+    FLASH_BWD,
+    AttentionPlan,
+    attend,
+    attention_plan,
+    dot_product_attention,
+    flash_attention,
+    flash_grid_info,
+)
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+# (Lq, Lk, heads, KV heads, head width, window, dtype, backend) -> plan
+TABLE = {
+    "gpt2_medium_cell": ((1024, 1024, 16, 16, 64, None, BF16, "tpu"),
+                         ("flash", 1024, 1024, "pallas")),
+    "trinity_sliding_layer": ((4096, 4096, 32, 4, 128, 2048, BF16, "tpu"),
+                              ("flash", 1024, 1024, "pallas")),
+    "trinity_full_layer": ((4096, 4096, 32, 4, 128, None, BF16, "tpu"),
+                           ("flash", 1024, 1024, "pallas")),
+    "heads_of_64_at_2048": ((2048, 2048, 16, 16, 64, None, BF16, "tpu"),
+                            ("flash", 1024, 1024, "pallas")),
+    "float32_inputs": ((4096, 4096, 8, 8, 64, None, F32, "tpu"),
+                       ("flash", 1024, 1024, "pallas")),
+    "blocks_of_512_divide": ((1536, 1536, 16, 16, 64, None, BF16, "tpu"),
+                             ("flash", 512, 512, "pallas")),
+    "only_256_divides": ((1280, 1280, 16, 16, 64, None, BF16, "tpu"),
+                         ("dense", 256, 256, "pallas")),
+    "rectangular": ((512, 768, 4, 4, 64, None, BF16, "tpu"),
+                    ("dense", 512, 256, "pallas")),
+    "below_the_measured_lengths": ((512, 512, 16, 16, 64, None, BF16, "tpu"),
+                                   ("dense", 512, 512, "pallas")),
+    "no_block_divides": ((100, 100, 4, 4, 64, None, BF16, "tpu"),
+                         ("dense", None, None, "pallas")),
+    "cpu_backend": ((1024, 1024, 16, 16, 64, None, BF16, "cpu"),
+                    ("dense", 1024, 1024, "pallas")),
+    "this_platform": ((4096, 4096, 32, 4, 128, 2048, BF16, None),
+                      ("dense", 1024, 1024, "pallas")),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TABLE))
+def test_the_plan_of_a_shape(case):
+    asked, want = TABLE[case]
+    assert attention_plan(*asked) == AttentionPlan(*want)
+
+
+def test_the_plan_refuses_heads_no_group_divides():
+    with pytest.raises(ValueError, match="KV heads"):
+        attention_plan(1024, 1024, 16, 3, 64)
+
+
+def _kernel_grids(fn, *args):
+    """The grids of the Pallas calls in ``fn``'s program."""
+    grids = []
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                visit(sub)
+
+    visit(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("length, window, blocks", [
+    (1024, None, None), (4096, 2048, None), (1536, None, None),
+    (384, None, None), (2048, None, (512, 1024))])
+def test_grid_info_agrees_with_the_grid_the_kernels_run(length, window,
+                                                        blocks):
+    """``flash_grid_info`` and a ``flash_attention`` call with no blocks
+    given read the same plan: the accounting's blocks are the plan's, and
+    its grid is the grid of the forward, dQ and dK/dV kernels traced."""
+    given = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
+    info = flash_grid_info(length, length, causal=True, window=window,
+                           batch_heads=2, **given)
+    plan = attention_plan(length, length, 2, 1, 8, window, F32)
+    assert (info["block_q"], info["block_k"]) == (
+        blocks or (plan.block_q, plan.block_k))
+    q = jax.ShapeDtypeStruct((1, length, 2, 8), F32)
+    kv = jax.ShapeDtypeStruct((1, length, 1, 8), F32)
+    grids = _kernel_grids(
+        jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, **given).sum(),
+            argnums=(0, 1, 2)), q, kv, kv)
+    assert grids == [tuple(info["grid"])] * 3       # the plan's backward
+
+
+def test_flash_equals_dense_at_heads_of_64_and_blocks_of_1024():
+    """The GPT-2 cell's tile (heads of 64, one 1,024 x 1,024 block a step of
+    the packed grid, here 3 of a 2 x 2 grid) interpreted, against the dense
+    reference: values and the three gradients."""
+    key = jax.random.PRNGKey(29)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                                 (1, 2048, 1, 64), F32) for i in range(3))
+    weight = jax.random.normal(jax.random.fold_in(key, 3), q.shape, F32)
+    info = flash_grid_info(2048, 2048, causal=True)
+    assert (info["block_q"], info["block_k"], info["steps"]) == (1024, 1024, 3)
+
+    def run(attention):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(attention(*a) * weight), argnums=(0, 1, 2))
+
+    flash, got = run(lambda *a: flash_attention(*a, causal=True))(q, k, v)
+    dense, want = run(lambda *a: dot_product_attention(*a, causal=True))(
+        q, k, v)
+    np.testing.assert_allclose(flash, dense, rtol=1e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", [None, "dense", "flash"])
+def test_attend_runs_what_the_plan_or_the_caller_says(impl):
+    """``attend`` on this platform: the plan's dense reference, or the pinned
+    side; both equal the masked reference, window and grouping included."""
+    key = jax.random.PRNGKey(7)
+    q = jax.random.normal(key, (2, 64, 4, 8), F32)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (2, 64, 2, 8), F32)
+            for i in (1, 2))
+    want = dot_product_attention(q, k, v, causal=True, window=24)
+    got = attend(q, k, v, window=24, impl=impl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    kernels = _kernel_grids(lambda *a: attend(*a, window=24, impl=impl),
+                            q, k, v)
+    assert len(kernels) == (impl == "flash")
+    with pytest.raises(ValueError, match="dense|flash"):
+        attend(q, k, v, impl="auto")
+
+
+def test_on_a_tpu_attend_traces_the_kernels_but_not_under_an_offset(
+        monkeypatch):
+    """What the chip gets at GPT-2-medium's shape (traced only: nothing is
+    compiled or run): one forward kernel over a (batch x heads, 1) grid; an
+    offset mask is outside what the policy was measured on and stays
+    dense."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((2, 1024, 16, 64), BF16)
+    assert _kernel_grids(attend, q, q, q) == [(32, 1)]
+    assert not _kernel_grids(lambda *a: attend(*a, q_offset=1024), q, q, q)
+    assert FLASH_BWD == "pallas"

@@ -83,12 +83,15 @@ def test_lm_flash_attention_lane():
     assert out["attention"] == "flash"
 
 
-def test_lm_attention_auto_policy():
-    """--attention auto encodes the measured crossover (dense < 4096,
-    flash >= 4096 — PERF.md r5 adjudication #2): below the threshold it
-    must resolve to dense, and the record says so."""
+@pytest.mark.parametrize("flags", [(), ("--attention", "auto")],
+                         ids=["unset", "auto"])
+def test_lm_attention_auto_policy(flags):
+    """An unset --attention is auto, and auto asks
+    ops.attention.attention_plan with the lane's shapes: on the CPU test
+    platform that is the dense reference (the kernels would be interpreted),
+    and the record says so."""
     out, _ = _run_bench(
-        "--model", "transformer_lm", "--attention", "auto",
+        "--model", "transformer_lm", *flags,
         "--batch-size", "2", "--seq-len", "128", "--vocab", "256",
         "--lm-layers", "1", "--lm-dim", "64", "--lm-heads", "4",
         "--num-warmup-batches", "1", "--num-batches-per-iter", "1",
@@ -100,9 +103,12 @@ def test_lm_attention_auto_policy():
 
 def test_lm_flash_grid_stamp_and_full_grid_ab():
     """Flash records carry the causal-grid accounting (blocks, step
-    counts, K/V bytes), and --flash-full-grid pins the full grid — the
-    truncated-vs-full A/B pair tools/hw_sweep.py queues. seq 384 tiles
-    as a 3x3 block grid, so the packed walk is 6 of 9 steps."""
+    counts, K/V bytes) and the backward the policy names, and
+    --flash-full-grid / --flash-bwd pin the full grid and the other
+    backward — the A/B pairs tools/hw_sweep.py queues. seq 384 tiles
+    as a 3x3 grid of 128-wide blocks, so the packed walk is 6 of 9 steps."""
+    from horovod_tpu.ops.attention import FLASH_BWD
+
     common = ("--model", "transformer_lm", "--batch-size", "2",
               "--seq-len", "384", "--vocab", "256", "--lm-layers", "1",
               "--lm-dim", "64", "--lm-heads", "4",
@@ -111,15 +117,16 @@ def test_lm_flash_grid_stamp_and_full_grid_ab():
     out, _ = _run_bench("--attention", "flash", *common)
     g = out["flash_grid"]
     assert out["attention"] == "flash" and g["truncated"]
+    assert (g["block_q"], g["block_k"]) == (128, 128)
     assert (g["steps"], g["steps_full"]) == (6, 9)
     assert g["kv_bytes"] * 3 == g["kv_bytes_full"] * 2
-    assert g["bwd"] == "scan"  # auto resolves scan below Lk 8192
+    assert g["bwd"] == FLASH_BWD == "pallas"
     out_full, _ = _run_bench("--attention", "flash", "--flash-full-grid",
-                             "--flash-bwd", "pallas", *common)
+                             "--flash-bwd", "scan", *common)
     g_full = out_full["flash_grid"]
     assert not g_full["truncated"]
     assert g_full["steps"] == g_full["steps_full"] == 9
-    assert g_full["bwd"] == "pallas"  # the A/B lanes' pinned backward
+    assert g_full["bwd"] == "scan"  # the A/B lanes' pinned backward
 
 
 def test_overlap_and_bucket_stamps_in_record():

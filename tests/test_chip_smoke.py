@@ -131,24 +131,32 @@ def test_fleet_refuses_local_chip_workers(monkeypatch):
             ServeFleet({}, cfg, FleetConfig(replicas=1, transport=transport))
 
 
-def test_kernels_compile_for_a_tpu_from_here():
+@pytest.fixture(scope="module")
+def topo():
+    """A v5e host of four chips, described and not attached: libtpu compiles
+    for it without a chip. Made inside a fixture, so that only the worker
+    that runs this file loads the library."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu in this environment
+        pytest.skip(f"no TPU compiler here: {exc}")
+
+
+def test_kernels_compile_for_a_tpu_from_here(topo):
     """libtpu compiles for a v5e topology without a chip: the paged
     decode kernel (whole-head blocks, 12 heads and the 3 a tp=4 shard
     holds) and the packed-grid flash forward/dQ/dK-dV kernels go through
     Mosaic itself, not the interpreter."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu.ops.attention import flash_attention
     from horovod_tpu.ops.paged_attention import paged_attention_decode
 
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as exc:  # no libtpu in this environment
-        pytest.skip(f"no TPU compiler here: {exc}")
     sharding = SingleDeviceSharding(topo.devices[0])
 
     def spec(shape, dtype=jnp.float32):
@@ -178,13 +186,13 @@ def test_kernels_compile_for_a_tpu_from_here():
     assert text.count("tpu_custom_call") >= 3
 
     # The sparse decoder's kernels at its real widths (32 query heads of
-    # 128 over 4 KV heads, 4,096 tokens, blocks of 1,024): the windowed
-    # grouped flash kernels under their profile names, and the expert
-    # layer's grouped products, which XLA:TPU lowers itself.
+    # 128 over 4 KV heads, 4,096 tokens, the plan's blocks of 1,024 and its
+    # kernel backward): the windowed grouped flash kernels under their
+    # profile names, and the expert layer's grouped products, which XLA:TPU
+    # lowers itself.
     def windowed_loss(q, k, v):
         return flash_attention(q, k, v, causal=True, window=2048,
-                               block_q=1024, block_k=1024, interpret=False,
-                               bwd_impl="pallas").astype(jnp.float32).sum()
+                               interpret=False).astype(jnp.float32).sum()
 
     lowered = jax.jit(jax.grad(windowed_loss, argnums=(0, 1, 2))).lower(
         spec((2, 4096, 32, 128), jnp.bfloat16),
@@ -206,6 +214,42 @@ def test_kernels_compile_for_a_tpu_from_here():
         {"gate": spec((16, 2048, 1024)), "up": spec((16, 2048, 1024)),
          "down": spec((16, 1024, 2048))})
     assert "%ragged-dot-none" in lowered.compile().as_text()
+
+
+def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
+    """GPT-2-medium's attention layer as ``attention_plan`` runs it on the
+    chip (q/k/v ``[8, 1024, 16, 64]`` bf16, blocks of 1,024, the kernel
+    backward): the three kernels through Mosaic on one chip, and inside a
+    ``shard_map`` over the host's four chips, 8 sequences each, as the
+    data-parallel cell runs them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.ops.attention import attention_plan, flash_attention
+
+    plan = attention_plan(1024, 1024, 16, 16, 64, backend="tpu")
+    assert plan == ("flash", 1024, 1024, "pallas")
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(
+            *a, causal=True, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    mesh = Mesh(np.array(topo.devices), ("hvd",))
+    spread = jax.shard_map(grads, mesh=mesh, in_specs=P("hvd"),
+                           out_specs=P("hvd"),
+                           check_vma=False)         # as hvd.spmd_fn's
+    for fn, batch, sharding in (
+            (grads, 8, SingleDeviceSharding(topo.devices[0])),
+            (spread, 32, NamedSharding(mesh, P("hvd")))):
+        qkv = jax.ShapeDtypeStruct((batch, 1024, 16, 64), jnp.bfloat16,
+                                   sharding=sharding)
+        text = jax.jit(fn).lower(qkv, qkv, qkv).compile().as_text()
+        for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+            assert f"%{kernel}" in text, (kernel, batch)
 
 
 def test_rehearsal_passes():

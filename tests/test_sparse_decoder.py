@@ -266,8 +266,12 @@ def test_band_tables_hold_exactly_the_live_blocks():
         qi_tab, kb_tab = _causal_step_tables(nq, nk, bq, bk, k_major, window)
         pairs = list(zip(qi_tab.tolist(), kb_tab.tolist()))
         assert len(pairs) == len(set(pairs)) and set(pairs) == exact
-    info = flash_grid_info(4096, 4096, causal=True, window=2048)
+    info = flash_grid_info(4096, 4096, causal=True, window=2048,
+                           block_q=256, block_k=256)
     assert info["steps"] == 108 and info["steps_full"] == 256   # 136 causal
+    info = flash_grid_info(4096, 4096, causal=True, window=2048)
+    assert (info["block_q"], info["block_k"]) == (1024, 1024)  # the plan's
+    assert info["steps"] == 9 and info["steps_full"] == 16     # 10 causal
 
 
 def test_a_window_needs_the_plain_causal_square():
@@ -279,24 +283,31 @@ def test_a_window_needs_the_plain_causal_square():
                         causal=True)
 
 
-def test_the_step_program_carries_the_layers_gauges(programs):
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_the_step_program_carries_the_layers_gauges(programs, attention):
     """``hvd.moe.*`` and ``hvd.attn.*`` of the step handle's program, as the
     exchange's gauges are keyed; block recomputation traces a layer more
-    than once and must not count it twice."""
+    than once and must not count it twice. Five attention calls, by the
+    implementation that ran, and the kernels' blocks."""
     from horovod_tpu.utils import timeline
 
-    program = programs("dense")
+    program = programs(attention)
     state, batch = program.start(11)
     program.first_steps(state, batch, 11)
     snap = timeline.snapshot()
     dispatched = [s["args"]["program"] for s in snap["spans"]
-                  if s["name"] == "hvd.spmd.dispatch"]
-    step = max(set(dispatched), key=dispatched.count)
+                  if s["name"] == "hvd.spmd.dispatch"
+                  and s["args"]["handle"] == "step_fn"]
+    step = dispatched[-1]
     want = {"hvd.moe.layers": 4, "hvd.moe.experts": 16,
             "hvd.moe.experts_held": 4, "hvd.moe.top_k": 3,
             "hvd.moe.tokens": 64, "hvd.moe.row_bound": 192,
             "hvd.moe.expected_rows": 48.0, "hvd.moe.cut_rows": 96,
             "hvd.attn.window": 16,
-            "hvd.attn.kv_heads": 2}
+            "hvd.attn.kv_heads": 2,
+            "hvd.attn.dense_calls": 5 * (attention == "dense"),
+            "hvd.attn.flash_calls": 5 * (attention == "flash")}
+    if attention == "flash":
+        want.update({"hvd.attn.block_q": 32, "hvd.attn.block_k": 32})
     got = {name: snap["gauges"].get(name, {}).get(step) for name in want}
     assert got == want

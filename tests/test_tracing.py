@@ -344,6 +344,43 @@ def test_the_five_scopes_are_in_the_lowered_step_of_both_lanes(
                and s["parent"] in under_build for s in _spans())
 
 
+@pytest.mark.parametrize("pinned", [None, "dense", "flash"])
+def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
+                                                    pinned):
+    """A GPT-2-shaped step (heads of 64, block recomputation on): the gauges
+    ``hvd.attn.*_calls`` of the step's program count its three attention
+    calls by implementation (unset, the policy's: dense on this platform),
+    ``.block_q`` / ``.block_k`` are the kernels' blocks, and the attention
+    runs under the scope ``hvd_attn_full``."""
+    monkeypatch.setenv("HVD_BENCH_NO_STATIC_AUDIT", "1")
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+
+    timeline.reset()
+    args = bench.build_parser().parse_args(
+        ["--model", "transformer_lm", "--lm-layers", "3", "--lm-dim", "128",
+         "--lm-heads", "2", "--vocab", "64", "--batch-size", "1",
+         "--seq-len", "64", "--remat"]
+        + (["--attention", pinned] if pinned else []))
+    lane = bench.build_lane(args, lambda *a, **k: None)
+    state, loss = lane.run_step(lane.state, lane.batch)   # donates
+    assert np.isfinite(float(loss))
+    assert f"/{timeline.ATTN_FULL}/" in lane.run_step._compiled.lower(
+        state, lane.batch).as_text(debug_info=True)
+    step = next(s["args"]["program"] for s in _spans("hvd.spmd.dispatch")
+                if s["args"]["handle"] == "step_fn")
+    gauges = timeline.snapshot()["gauges"]
+    got = {name.rsplit(".", 1)[1]: by_program[step]
+           for name, by_program in gauges.items()
+           if name.startswith("hvd.attn.") and step in by_program}
+    if pinned == "flash":
+        assert got == {"flash_calls": 3, "dense_calls": 0, "block_q": 64,
+                       "block_k": 64}
+    else:
+        assert got == {"flash_calls": 0, "dense_calls": 3}
+    assert lane.stamp["attention"] == (pinned or "dense")
+
+
 def test_windowed_train_step_has_the_same_scopes(hvd):
     import optax
 

@@ -334,6 +334,8 @@ def test_pick_block_floors_at_sublane_tile(hvd):
 
     assert _pick_block(256, 2048) == 256
     assert _pick_block(512, 768) == 256
+    assert _pick_block(1024, 4096) == 1024
+    assert _pick_block(1024, 1536) == 512
     assert _pick_block(256, 24) == 8
     assert _pick_block(256, 8) == 8
     for bad in (100, 33, 4):

@@ -258,11 +258,10 @@ LANES = [
     # Truncated-vs-full causal grid A/B (adjacent so the pair shares
     # chip condition): same kernel, --flash-full-grid pins the full
     # (q-block, k-block) grid whose dead half the packed default skips.
-    # BOTH sides pin --flash-bwd pallas: below Lk 8192 the auto
-    # backward is the scan, which is diagonal-truncated by construction
-    # — only the pinned kernel split makes the A/B span all three
-    # grids. The JSON's flash_grid field carries the step/byte/bwd
-    # accounting.
+    # BOTH sides pin --flash-bwd pallas (the policy's own backward
+    # since PR 29; the scan is diagonal-truncated by construction, so
+    # only the kernel split makes the A/B span all three grids). The
+    # JSON's flash_grid field carries the step/byte/bwd accounting.
     ("transformer_lm_flash_trunc_pallasbwd",
      ["bench.py", "--model", "transformer_lm", "--attention", "flash",
       "--flash-bwd", "pallas"]),
@@ -270,9 +269,8 @@ LANES = [
      ["bench.py", "--model", "transformer_lm", "--attention", "flash",
       "--flash-full-grid", "--flash-bwd", "pallas"]),
     ("flash_check", ["tools/tpu_flash_check.py"]),
-    # Block-tiling sweep at the flash/dense crossover (the 128x128
-    # default lost ~5% to dense at seq 2048 in the round-4 A/B; if a
-    # larger tile closes that, the default follows the measurement).
+    # Dense against the kernels over blocks and both backwards at the
+    # shapes ops.attention.attention_plan's constants come from.
     ("flash_block_sweep", ["tools/tpu_flash_check.py", "--block-sweep"]),
     # Flash-vs-dense ladder at constant 16k tokens/chip: flash's win
     # grows with the [L, L] score tensor, so the A/B runs at 4096 and

@@ -12,7 +12,9 @@ then runs ``--steps`` steps with two of them ahead of the loss that is read
 milliseconds a step in forward, backward, recomputed, loss, exchange, update
 and other (by the ``hvd_*`` scopes in each operation's ``op_name``),
 collective time and the part of it during which nothing else ran on the
-chip, and the idle gaps by the ``hvd.*`` host span open at their middle.
+chip, the idle gaps by the ``hvd.*`` host span open at their middle, and the
+``hvd.attn.*`` / ``hvd.moe.*`` gauges of the step's program (attention calls
+by implementation, the kernels' blocks, the expert layers' rows).
 The platform must be ``tpu`` (``HVD_TPU_FORCE_CPU=1`` runs the same code on
 a virtual CPU mesh, whose profile holds no chip: nothing is printed for it).
 """
@@ -93,6 +95,18 @@ def main():
         log("the profile holds no operation of any chip")
         return 1
     print(step_profile.table(result))
+    # what the step's program says of itself: the gauges keyed by it
+    from horovod_tpu.utils import timeline
+
+    snap = timeline.snapshot()
+    step = next((s["args"]["program"] for s in reversed(snap["spans"])
+                 if s["name"] == timeline.DISPATCH), "")
+    said = {name: by_program[step]
+            for name, by_program in sorted(snap["gauges"].items())
+            if step in by_program and name.startswith(("hvd.attn.",
+                                                       "hvd.moe."))}
+    if said:
+        print(f"  gauges of {step}: {said}")
     if args.json:
         with open(args.json, "w") as f:
             json.dump(result, f, indent=1)

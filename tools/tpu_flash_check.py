@@ -9,14 +9,19 @@ on-hardware counterpart: compile and run the actual Mosaic kernel
 backward), check numerics against the dense reference in bf16 — plus
 the packed-vs-full causal grid parity — then time fwd+bwd flash
 (truncated AND full grid) vs dense at seq 1024/2048/4096 — so one
-short chip call yields the crossover AND grid-truncation evidence
-without the full transformer_lm sweep lanes (tools/hw_sweep.py seq
-ladder). Every timed record carries its grid/K-V-bytes stamp
-(flash_grid_info) so block-sweep records are attributable to a
-concrete grid, not just a wall time.
+short chip call yields the grid-truncation evidence without the full
+transformer_lm sweep lanes (tools/hw_sweep.py seq ladder). Every ladder
+record carries its grid/K-V-bytes stamp (flash_grid_info) so it is
+attributable to a concrete grid, not just a wall time.
+
+``--block-sweep [shape ...]`` is the measurement the attention policy's
+constants come from (``ops.attention.attention_plan``; PERF.md, PR 29):
+dense against the kernels over blocks and both backwards at
+:data:`SWEEP_SHAPES`.
 
 Run on a TPU host:  python tools/tpu_flash_check.py
 """
+import functools
 import sys
 import time
 
@@ -44,19 +49,26 @@ def _grid_stamp(seq, heads, head_dim, batch=2, block_q=None, block_k=None,
             f"({g['kv_fetch_frac']:.2f}x)")
 
 
-def _time_fwd_bwd(fn, q, k, v, iters=20):
-    lossgrad = jax.jit(jax.value_and_grad(
-        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)),
-        argnums=(0, 1, 2)))
-    out = lossgrad(q, k, v)  # compile + warm
+def _time(fn, *args, iters=10, repeats=3):
+    """Median over ``repeats`` of the mean wall time of ``iters`` calls."""
     from horovod_tpu.utils.devsync import force_device_sync
 
-    force_device_sync(out)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = lossgrad(q, k, v)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / iters
+    force_device_sync(fn(*args))  # compile + warm
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        times.append((time.perf_counter() - t0) / iters)
+    return sorted(times)[len(times) // 2]
+
+
+def _grad_of(attend):
+    """The jitted gradients of ``sum(attend(q, k, v))`` by q, k and v."""
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
 
 
 def main():
@@ -100,44 +112,30 @@ def main():
         # Sweep mode: keep the cheap numerics canary above, skip the
         # flash-vs-dense ladder (the separate flash_check lane owns it
         # — re-paying its 6 timed compiles here would eat the sweep
-        # lane's budget).
-        block_sweep(key)
+        # lane's budget). Names after the flag keep those shapes only.
+        block_sweep(key, sys.argv[sys.argv.index("--block-sweep") + 1:])
         return
 
-    # Micro A/B: fwd+bwd wall time per step, GPT-2-small-ish head shape,
-    # with the causal-grid truncation priced in-line. The flash/dense
-    # columns keep the historical auto-backward protocol (crossover
-    # continuity); trunc_gain comes from a SEPARATE pair pinned to the
-    # pallas backward — below Lk 8192 the auto backward is the scan,
-    # which is diagonal-truncated by construction on both sides, so an
-    # unpinned pair would price the forward grid only. Each rung
-    # degrades independently (a seq-4096 dense OOM is itself a useful
-    # record, not a script failure).
+    # Micro A/B: fwd+bwd wall time of one layer, GPT-2-small-ish head
+    # shape, the kernels at the policy's blocks and backward against dense,
+    # with the causal-grid truncation priced in-line (the packed grid
+    # against the full one). Each rung degrades independently (a seq-4096
+    # dense OOM is itself a useful record, not a script failure).
     for seq in (1024, 2048, 4096):
-        qs, ks, vs = (jax.random.normal(jax.random.fold_in(key, 10 + i),
-                                        (2, seq, 8, 64), jnp.bfloat16)
-                      for i in range(3))
+        qkv = [jax.random.normal(jax.random.fold_in(key, 10 + i),
+                                 (2, seq, 8, 64), jnp.bfloat16)
+               for i in range(3)]
         try:
-            tf_ = _time_fwd_bwd(
-                lambda a, b, c: flash_attention(a, b, c, causal=True),
-                qs, ks, vs)
-            td = _time_fwd_bwd(
-                lambda a, b, c: dot_product_attention(a, b, c, causal=True),
-                qs, ks, vs)
-            tp = _time_fwd_bwd(
-                lambda a, b, c: flash_attention(a, b, c, causal=True,
-                                                bwd_impl="pallas"),
-                qs, ks, vs)
-            tpf = _time_fwd_bwd(
-                lambda a, b, c: flash_attention(a, b, c, causal=True,
-                                                bwd_impl="pallas",
-                                                truncate=False),
-                qs, ks, vs)
+            tf_ = _time(_grad_of(functools.partial(
+                flash_attention, causal=True)), *qkv)
+            td = _time(_grad_of(functools.partial(
+                dot_product_attention, causal=True)), *qkv)
+            tpf = _time(_grad_of(functools.partial(
+                flash_attention, causal=True, truncate=False)), *qkv)
             print(f"seq {seq}: flash {tf_ * 1e3:.3f} ms  "
                   f"dense {td * 1e3:.3f} ms  ratio {td / tf_:.2f}x  | "
-                  f"pallas-bwd trunc {tp * 1e3:.3f} ms  "
-                  f"full {tpf * 1e3:.3f} ms  "
-                  f"trunc_gain {tpf / tp:.2f}x  "
+                  f"full grid {tpf * 1e3:.3f} ms  "
+                  f"trunc_gain {tpf / tf_:.2f}x  "
                   f"[{_grid_stamp(seq, 8, 64)}]",
                   file=sys.stderr, flush=True)
         except Exception as exc:  # noqa: BLE001 — record and continue
@@ -146,56 +144,85 @@ def main():
                   flush=True)
 
 
-def block_sweep(key):
-    """Time flash fwd+bwd across (block_q, block_k) tilings at the
-    dense/flash crossover lengths. The kernel default is 128x128; the
-    round-4 A/B showed dense beating flash by ~5% at seq 2048, so if a
-    bigger tile wins there, flash wins at every length and the default
-    should follow the measurement (larger k-blocks amortize the online
-    softmax rescale; larger q-blocks raise MXU tile occupancy at the
-    cost of VMEM).  Prints one summary line LAST so a sweep-lane record
-    (tools/hw_sweep.py keeps the final line) carries the best config.
-    """
-    results = {}
-    for seq in (2048, 4096):
-        qs, ks, vs = (jax.random.normal(jax.random.fold_in(key, 20 + i),
-                                        (2, seq, 8, 64), jnp.bfloat16)
-                      for i in range(3))
-        for bq in (128, 256, 512):
-            for bk in (128, 256, 512):
-                if bq > seq or bk > seq:
+# The shapes of the sweep the attention policy's constants come from
+# (``ops/attention.py:attention_plan``; PERF.md has its table): name, q
+# shape, KV heads, window. The first is GPT-2-medium's cell, then the same
+# 8,192 tokens at longer sequences, then Trinity-Mini's two layer kinds.
+SWEEP_SHAPES = (
+    ("gpt2m_1024", (8, 1024, 16, 64), 16, None, (256, 512, 1024)),
+    ("h64_2048", (4, 2048, 16, 64), 16, None, (256, 512, 1024, 2048)),
+    ("h64_4096", (2, 4096, 16, 64), 16, None, (256, 512, 1024, 2048)),
+    # 256 x 256 at heads of 128 is PR 28's reading (PERF.md): 7.64 / 21.24
+    ("trinity_window", (2, 4096, 32, 128), 4, 2048, (512, 1024, 2048)),
+    ("trinity_full", (2, 4096, 32, 128), 4, None, (512, 1024, 2048)),
+)
+# f32 scores of one block the kernels are tried at: 1,024 x 1,024
+SWEEP_MAX_SCORES = 1024 * 1024
+
+
+def block_sweep(key, only=None):
+    """Forward and forward + backward ms of ONE attention layer, dense
+    against the flash kernels over blocks and both backwards, at
+    :data:`SWEEP_SHAPES` (``only``: names to keep). One JSON line a
+    measurement on standard output and in ``chiprun_out/flash_sweep.jsonl``;
+    the last line names the best of every shape."""
+    import json
+    import os
+
+    out_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    rows = []
+
+    def measure(shape_name, label, attend, qkv, **stamp):
+        row = dict(shape=shape_name, impl=label, **stamp)
+        try:
+            row["fwd_ms"] = 1e3 * _time(jax.jit(attend), *qkv)
+            row["fwd_bwd_ms"] = 1e3 * _time(_grad_of(attend), *qkv)
+        except Exception as exc:  # noqa: BLE001: a refusal is a record too
+            row["failed"] = f"{type(exc).__name__}: {str(exc)[:160]}"
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    for name, (b, length, h, d), g, window, blocks in SWEEP_SHAPES:
+        if only and name not in only:
+            continue
+        shapes = ((b, length, h, d), (b, length, g, d), (b, length, g, d))
+        qkv = [jax.random.normal(jax.random.fold_in(key, 20 + i), s,
+                                 jnp.bfloat16) for i, s in enumerate(shapes)]
+        measure(name, "dense", lambda *a: dot_product_attention(
+            *a, causal=True, window=window), qkv)
+        for bq in blocks:
+            for bk in blocks:
+                if max(bq, bk) > length or bq * bk > SWEEP_MAX_SCORES:
                     continue
-                try:
-                    t = _time_fwd_bwd(
-                        lambda a, b, c: flash_attention(
-                            a, b, c, causal=True, block_q=bq, block_k=bk),
-                        qs, ks, vs)
-                    results[(seq, bq, bk)] = t
-                    print(f"seq {seq} bq {bq} bk {bk}: {t * 1e3:.3f} ms "
-                          f"[{_grid_stamp(seq, 8, 64, block_q=bq, block_k=bk)}]",
-                          file=sys.stderr, flush=True)
-                except Exception as exc:  # noqa: BLE001
-                    print(f"seq {seq} bq {bq} bk {bk}: failed "
-                          f"{type(exc).__name__}: {exc}",
-                          file=sys.stderr, flush=True)
-    summary = []
-    for seq in (2048, 4096):
-        per = [(t, bq, bk) for (s, bq, bk), t in results.items()
-               if s == seq]
-        if per:
-            t, bq, bk = min(per)
-            base = results.get((seq, 128, 128))
-            gain = f" ({base / t:.2f}x vs 128x128)" if base else ""
-            summary.append(f"seq {seq}: best {bq}x{bk} "
-                           f"{t * 1e3:.3f} ms{gain} "
-                           f"[{_grid_stamp(seq, 8, 64, block_q=bq, block_k=bk)}]")
-    if not summary:
+                for bwd in ("pallas", "scan"):
+                    if bwd == "scan" and bq != bk:
+                        continue        # the scan only reads block_k
+                    measure(name, "flash", lambda *a, _q=bq, _k=bk, _b=bwd:
+                            flash_attention(*a, causal=True, window=window,
+                                            block_q=_q, block_k=_k,
+                                            bwd_impl=_b),
+                            qkv, block_q=bq, block_k=bk, bwd=bwd)
+    with open(os.path.join(out_dir, "flash_sweep.jsonl"), "w") as f:
+        f.writelines(json.dumps(r) + "\n" for r in rows)
+    done = [r for r in rows if "fwd_bwd_ms" in r]
+    if not done:
         # No measurement = no record: exit nonzero so the sweep lane
-        # (and the watcher's done-check) retries rather than filing a
-        # "flash OK" line with no data in it.
-        print("block sweep: no rung completed", file=sys.stderr,
-              flush=True)
+        # retries rather than filing a "flash OK" line with no data in it.
+        print("block sweep: no rung completed", file=sys.stderr, flush=True)
         sys.exit(4)
+    summary = []
+    for name in dict.fromkeys(r["shape"] for r in done):
+        per = [r for r in done if r["shape"] == name]
+        best = min((r for r in per if r["impl"] == "flash"),
+                   key=lambda r: r["fwd_bwd_ms"], default=None)
+        dense = next((r for r in per if r["impl"] == "dense"), None)
+        if best:
+            summary.append(
+                f"{name}: best {best['block_q']}x{best['block_k']} "
+                f"{best['bwd']} {best['fwd_bwd_ms']:.3f} ms"
+                + (f" (dense {dense['fwd_bwd_ms']:.3f})" if dense else ""))
     line = "block sweep: " + "; ".join(summary)
     # Last stderr line = the sweep-lane record (hw_sweep.py keeps it);
     # stdout carries it too for direct runs.
