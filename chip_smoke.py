@@ -158,7 +158,12 @@ def train_phase(name, argv, steps, *, want_mosaic=None,
             assert re.search(r"stablehlo\.(all_reduce|reduce_scatter)",
                              text), (
                 f"{name}: no gradient collective in the lowered train step")
-            assert lane.stamp["collectives"]["count"] > 0, lane.stamp
+            from tools.hvdverify import abstractify, audit_collectives
+
+            audit = audit_collectives(
+                lambda s, b: lane.run_step(s, b), abstractify(lane.state),
+                abstractify(lane.batch))
+            assert audit["count"] > 0, audit
     state, losses, secs = lane.state, [], []
     for _ in range(steps):
         t0 = time.perf_counter()
@@ -232,8 +237,7 @@ def train_one_chip(sz, kernels_compiled):
     train_phase(f"train lm dense seq {sz.seq_dense}",
                 (*lm, "--seq-len", str(sz.seq_dense), "--attention",
                  "dense"), sz.steps, ref_tol=REF_TOL)
-    flash = ("--attention", "flash", "--flash-bwd", "pallas", "--remat",
-             "--fused-ce")
+    flash = ("--attention", "flash", "--remat", "--fused-ce")
     train_phase(f"train lm flash+fused-ce seq {sz.seq_flash}",
                 (*lm, "--seq-len", str(sz.seq_flash), *flash), sz.steps,
                 want_mosaic=kernels_compiled)

@@ -5,8 +5,8 @@ examples/pytorch_synthetic_benchmark.py:79-110 protocol).
 Same measurement discipline as the reference's flagship benchmark —
 synthetic data, warmup, timed groups, img/sec ± CI, cross-rank averaged
 total — over the native TCP-ring core on CPU. The jax/TPU counterpart is
-`bench.py` at the repo root; this script exists so the eager torch lane
-has the same yardstick the reference shipped.
+`examples/jax_synthetic_benchmark.py`; this script exists so the eager
+torch lane has the same yardstick the reference shipped.
 
 Run:  python -m horovod_tpu.run -np 2 python examples/torch_synthetic_benchmark.py
 """

@@ -96,7 +96,7 @@ class Config:
     # unpack-later, so XLA's async collective scheduling can hide them
     # under remaining backward compute. "auto" (default) engages whenever
     # the plan has >= 2 buckets and degrades to the legacy single-pass
-    # emission otherwise; never changes numerics (docs/benchmarks.md).
+    # emission otherwise; never changes numerics (docs/tensor-fusion.md).
     overlap: str = "auto"
     # Coordinator cycle time in ms — only meaningful for the native eager
     # backend; the XLA path has no background loop (HOROVOD_CYCLE_TIME).

@@ -52,8 +52,8 @@ training code can tell a relaunch from a first launch.
 Recovery metrics: every supervised job can append one JSON line
 (``metrics_path``, CLI ``--metrics-file``) in the PERF_RUNS.tsv format
 — time-to-detect for watchdog kills, time-to-relaunch, restarts by
-exit class, the world-size trajectory — rendered by
-``tools/perf_summary.py``'s ``elastic`` column.
+exit class, the world-size trajectory — under the line's ``elastic``
+key.
 """
 
 from __future__ import annotations

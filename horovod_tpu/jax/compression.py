@@ -16,8 +16,8 @@ quantization error of step ``t`` is re-injected at step ``t+1`` (Seide
 et al. 2014; Lin et al. 2018). These apply ONLY to the inter-slice DCN
 leg of the hierarchical bucket ladder (``HOROVOD_HIERARCHICAL``,
 horovod_tpu/jax/fusion.py): the ICI legs stay at the gradients' own
-dtype — ICI at 200 GB/s/chip is not the wall, DCN at ~3 GB/s/chip is
-(tools/scaling_model.py). Their ``compress``/``decompress`` protocol
+dtype: the ICI is not the wall, the DCN is (not measured on this
+machine: nothing here spans two slices). Their ``compress``/``decompress`` protocol
 methods are identity (nothing is cast before bucketing); the
 ``quantize``/``dequantize`` classmethods are the DCN wire codec fusion
 invokes per bucket shard. Without a hierarchical DCN leg they degrade
@@ -44,9 +44,9 @@ class Compressor:
     def plan_dtype(cls, dtype):
         """The dtype a leaf of ``dtype`` enters the bucket plan with —
         what ``compress`` will hand ``fusion.plan_buckets``. Identity
-        for everything except the cast compressors; static-accounting
-        consumers (bench.py's wire stamp) use this so their plan can
-        never drift from the executing one."""
+        for everything except the cast compressors; whoever plans
+        without compressing uses this, so that its plan cannot drift
+        from the executing one."""
         return dtype
 
 

@@ -122,8 +122,9 @@ def _plan_buckets(sizes_bytes: Sequence[int], threshold: int) -> List[List[int]]
 
 
 class Bucket(NamedTuple):
-    """One fused-collective bucket of the plan (public accounting record —
-    tools/scaling_model.py and the bucket-byte tests consume these)."""
+    """One fused-collective bucket of the plan (public accounting record:
+    the exchange's gauges, hvdverify's HVV105 and the bucket-byte tests
+    consume these)."""
 
     dtype: str        # wire dtype name, e.g. "float32"
     index: int        # position within this dtype's bucket sequence
@@ -165,8 +166,8 @@ def plan_buckets(leaves, threshold: int) -> List[Bucket]:
 
 
 def plan_summary(plan: Sequence[Bucket]) -> dict:
-    """Compact accounting of a bucket plan: the numbers the scaling model
-    consumes and bench JSON stamps alongside the overlap knob."""
+    """Compact accounting of a bucket plan: count, bytes and the oversize
+    singletons."""
     total = sum(b.nbytes for b in plan)
     return {
         "count": len(plan),
@@ -346,11 +347,9 @@ def ef_residual_specs(leaves, threshold: int, axis_size: int, inner: int):
 
 def hier_wire_summary(plan: Sequence[Bucket], axis_size: int, inner: int,
                       compression=Compression.none) -> dict:
-    """Per-leg STATIC operand-byte split of a hierarchical bucket plan —
-    the ``"wire"`` stamp bench.py records and the numbers
-    tools/scaling_model.py prices, derived from the same
-    :func:`hier_bucket_layout` the executing path uses (so the stamp is
-    checkable against the HVV105-reconciled schedule).
+    """Per-leg STATIC operand-byte split of a hierarchical bucket plan,
+    derived from the same :func:`hier_bucket_layout` the executing path
+    uses (so it is checkable against the HVV105-reconciled schedule).
 
     ``ici_bytes`` = intra-slice reduce-scatter + all-gather operands;
     ``dcn_bytes`` = inter-slice exchange operands (quantized payloads +

@@ -2,9 +2,10 @@
 
 PERF.md's pre-round profiles (2026-08-01, on a backend that no longer
 exists) showed a 27-32% host-side gap on short-step models (ResNet-50:
-33.8 ms wall vs 24.8 ms device; Inception V3: 32%); whether this
-machine has it is ROADMAP S4's to re-measure. The structural fix is the
-same host/device
+33.8 ms wall vs 24.8 ms device; Inception V3: 32%); on this machine,
+with two steps in flight, the gap is 0.04% of device time (PERF.md
+section 5; ROADMAP D6 asks whether this module stays). The structural
+fix is the same host/device
 decoupling the reference got from its background coordinator thread
 (``BackgroundThreadLoop``: the training script never blocks on the
 exchange) — in XLA form: compile K training steps into ONE program with
@@ -93,38 +94,6 @@ def stack_batches(batches: Iterable):
         raise ValueError("stack_batches needs at least one batch")
     return jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
                                   *batches)
-
-
-def repeat_batch(batch, steps_per_dispatch: int):
-    """Synthetic-bench staging: one batch broadcast under a K-long
-    window axis without K host copies (``bench.py`` reuses the same
-    synthetic batch every step, so the window lane stages one broadcast
-    instead of K stacked duplicates)."""
-    k = int(steps_per_dispatch)
-    return jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (k,) + x.shape), batch)
-
-
-def stage_synthetic_window(step_fn, batch, steps_per_dispatch: int,
-                           batch_specs: Any = None):
-    """Synthetic-benchmark window staging, in one place for every timing
-    harness (bench.py, tools/profile_step.py): wrap the step in the scan
-    window, broadcast the single reusable batch under the K-long window
-    axis, and shift the batch partition specs to the stacked layout.
-    Returns ``(step_fn, batch, batch_specs)``; K=1 is the identity
-    triple — the reference protocol's per-step dispatch, untouched.
-    ``batch_specs=None`` shards the batch over the data axis resolved
-    through the bound LogicalMesh (legacy ``"hvd"`` when none is
-    bound)."""
-    if batch_specs is None:
-        batch_specs = P(module_axis("data"))
-    k = int(steps_per_dispatch)
-    if k < 1:
-        raise ValueError(f"steps_per_dispatch must be >= 1, got {k}")
-    if k == 1:
-        return step_fn, batch, batch_specs
-    return (_scan_window(step_fn), repeat_batch(batch, k),
-            stacked_specs(batch_specs))
 
 
 def stacked_specs(batch_specs):

@@ -22,6 +22,7 @@ from horovod_tpu.models.train import (
     create_train_state,
     cross_entropy_loss,
     make_eval_step,
+    make_lm_train_step,
     make_train_step,
     make_windowed_train_step,
     read_before_update,
@@ -42,6 +43,7 @@ _FAMILY.update({
     "inception_v3": InceptionV3,
     "inception3": InceptionV3,
     "transformer_lm": TransformerLM,
+    "moe_lm": SparseDecoderLM,
     "vit_s16": ViT_S16,
     "vit_b16": ViT_B16,
 })
@@ -86,6 +88,7 @@ __all__ = [
     "create_train_state",
     "cross_entropy_loss",
     "make_eval_step",
+    "make_lm_train_step",
     "make_train_step",
     "make_windowed_train_step",
     "read_before_update",

@@ -237,6 +237,82 @@ def make_train_step(model, optimizer: optax.GradientTransformation, average_loss
     return train_step
 
 
+def make_lm_train_step(model, optimizer: optax.GradientTransformation, *,
+                       fused_ce: bool = False, bias_coeff=None):
+    """Build the per-rank SPMD training step of a causal language model.
+
+    The returned function takes ``(state, batch)`` where ``batch`` is the
+    *per-rank* shard ``{"tokens": [B, L] int32}`` and returns ``(new_state,
+    loss)``: the mean next-token cross-entropy of this rank's shard, in
+    float32, a scalar. ``fused_ce`` takes the loss through
+    :func:`horovod_tpu.ops.xent.fused_cross_entropy`, so the ``[B, L, vocab]``
+    float32 logits never exist. A model that keeps ``buffers`` (a sparse
+    layer's selection bias, :mod:`horovod_tpu.models.decoder`) has them read
+    by the forward pass, which writes the step's expert counts beside them;
+    ``bias_coeff`` is the step of the balancing rule that then moves the bias.
+    """
+
+    # the name is the handle's in the spans and the profile (``step_fn#n``,
+    # ``jit_step_fn``), as the lanes have recorded it so far
+    def step_fn(state, batch):
+        tokens = batch["tokens"]
+        # A sparse layer's state (its selection bias): read by the forward
+        # pass, which writes the step's expert counts beside it.
+        buffers = state.get("buffers")
+
+        def apply(params, **kw):
+            if buffers is None:
+                return model.apply({"params": params}, tokens, train=False,
+                                   **kw), None
+            out, wrote = model.apply(
+                {"params": params, "buffers": buffers}, tokens, train=False,
+                mutable=["buffers"], **kw)
+            return out, wrote["buffers"]
+
+        if fused_ce:
+            # Chunked fused loss (ops/xent.py): the [B, L, vocab] fp32
+            # logits tensor — the step's largest single HBM sink —
+            # never materializes; the vocab projection's gradient comes
+            # out of the same scan.
+            from horovod_tpu.ops.xent import fused_cross_entropy
+
+            def loss_fn(params):
+                with jax.named_scope(timeline.FORWARD):
+                    hidden, wrote = apply(params, return_hidden=True)
+                with jax.named_scope(timeline.LOSS):
+                    e = hidden.shape[-1]
+                    h = hidden[:, :-1].reshape(-1, e).astype(jnp.float32)
+                    wv = params["lm_head"]["kernel"].astype(jnp.float32)
+                    return fused_cross_entropy(
+                        h, wv, tokens[:, 1:].reshape(-1)), wrote
+        else:
+            def loss_fn(params):
+                with jax.named_scope(timeline.FORWARD):
+                    logits, wrote = apply(params)
+                with jax.named_scope(timeline.LOSS):
+                    logp = jax.nn.log_softmax(
+                        logits[:, :-1].astype(jnp.float32))
+                    tgt = tokens[:, 1:]
+                    nll = -jnp.take_along_axis(logp, tgt[..., None], -1)
+                    return jnp.mean(nll), wrote
+
+        (loss, wrote), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state["params"])
+        if wrote is not None:
+            from horovod_tpu.models import decoder
+
+            # summed over the data axis; one chip's counts are the step's
+            axis = current_spmd_axis()
+            if axis is not None and jax.lax.axis_size(axis) == 1:
+                axis = None
+            with jax.named_scope(timeline.UPDATE):
+                wrote = decoder.update_buffers(wrote, bias_coeff, axis)
+        state, loss = read_before_update(state, loss)
+        return apply_gradients(optimizer, state, grads, buffers=wrote), loss
+
+    return step_fn
+
+
 def make_windowed_train_step(model, optimizer: optax.GradientTransformation,
                              steps_per_dispatch: int,
                              average_loss: bool = True):

@@ -196,10 +196,9 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
 
     Mirrors exactly the tiling (:func:`attention_plan`'s blocks) and
     truncation (:func:`_grid_truncates`) policy the kernels use, without
-    tracing anything — ``bench.py`` stamps this into the flash-lane JSON and
-    ``tools/tpu_flash_check.py`` into its micro A/B report so every
-    wall-time record is attributable to a concrete grid, not just a
-    block pair.
+    tracing anything: ``tools/tpu_flash_check.py`` puts it into its
+    micro A/B report so every wall-time record is attributable to a
+    concrete grid, not just a block pair.
 
     Returns a dict: chosen blocks, grid shape, per-``batch_heads``-step
     counts (``steps`` vs ``steps_full``), ``kv_fetch_frac`` (the

@@ -31,8 +31,9 @@ the spirit of its fusion buffer: restructure the computation so the
 interconnect — here HBM — moves as few bytes as the math allows.
 
 Exactness (loss AND both gradients) vs the dense composition is pinned
-in tests/test_xent.py; ``bench.py --fused-ce`` A/Bs it at protocol
-scale.
+in tests/test_xent.py; ``models.make_lm_train_step(fused_ce=True)``
+(``bench.py --fused-ce``) is the step that runs it. In no cell of the
+benchmark yet: not measured on this machine (ROADMAP D15).
 """
 
 from __future__ import annotations

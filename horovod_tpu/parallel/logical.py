@@ -123,7 +123,7 @@ _CANONICAL_ORDER: Tuple[str, ...] = (
 def parse_mesh_config(config: str) -> Dict[str, int]:
     """Parse the canonical mesh config string (``"dp=8,tp=4,sp=2"``) into
     an ordered ``{axis: size}`` dict — the hvdplan input format (ROADMAP
-    item 5a) and ``bench.py --mesh``'s argument. ``-1`` is the
+    item 5a) and ``ServeConfig.mesh``'s spelling. ``-1`` is the
     :func:`~horovod_tpu.parallel.mesh.make_mesh` wildcard (at most one).
     """
     axes: Dict[str, int] = {}
@@ -161,7 +161,7 @@ def parse_mesh_config(config: str) -> Dict[str, int]:
 def format_mesh_config(axes: Dict[str, int]) -> str:
     """Render ``{axis: size}`` as the CANONICAL config string: known
     axes in dp/tp/sp/pp/ep order, unknown axes after them alphabetically
-    — so two spellings of the same stack stamp identically into bench
+    — so two spellings of the same stack stamp identically into
     records."""
     def key(name: str):
         try:

@@ -79,8 +79,7 @@ def main(argv=None) -> int:
                         help="append one PERF_RUNS.tsv-format JSON line "
                              "of recovery metrics (restarts by class, "
                              "world trajectory, time-to-detect/"
-                             "relaunch) at job end; rendered by "
-                             "tools/perf_summary.py's elastic column")
+                             "relaunch) at job end")
     parser.add_argument("--fault-plan", default=None,
                         help="deterministic fault injection plan, e.g. "
                              "'kill:rank=1,step=7;resize:rank=0,step=9,"

@@ -6,9 +6,9 @@ nothing here overrides it. Where it is not, the cache is
 the cache key — a directory named from a pid, the time or a temporary
 name never hits. An installed package has no checkout around it (no
 ``bench.py`` beside the package) and writes nothing: JAX's own default
-stands. Every entry point that compiles (``bench.py``,
-``tools/serve_bench.py``, ``horovod_tpu.serve.worker``,
-``chip_smoke.py``) calls :func:`enable` before its first compile.
+stands. Every entry point that compiles (``benchmarks/run.py``,
+``tools/profile_step.py``, ``tools/serve_bench.py``,
+``horovod_tpu.serve.worker``, ``chip_smoke.py``) calls :func:`enable` before its first compile.
 """
 
 from __future__ import annotations

@@ -3,9 +3,10 @@
 JAX falls back to the CPU with a warning when libtpu finds no chip, and
 a Pallas kernel asked to ``interpret`` runs anywhere. Both are right for
 a library and wrong for a measurement: a number taken on the CPU must
-never be read as the chip's. The measuring entry points (``bench.py``,
-``tools/serve_bench.py``, ``tools/decode_bench.py``, ``chip_smoke.py``)
-go through :func:`require_tpu`; the kernels through
+never be read as the chip's. The measuring entry points
+(``tools/profile_step.py``, ``tools/serve_bench.py``,
+``tools/decode_bench.py``, ``chip_smoke.py``; ``benchmarks/run.py`` has
+its own look for the chip) go through :func:`require_tpu`; the kernels through
 :func:`pallas_interpret`.
 """
 
@@ -28,8 +29,8 @@ def device_stamp() -> Dict:
 def require_tpu(cpu_requested: bool = False) -> Dict:
     """The device stamp, or ``SystemExit`` when the platform is not
     ``tpu``. ``cpu_requested`` is the caller's existing explicit test
-    switch (``HVD_TPU_FORCE_CPU`` for bench.py, ``JAX_PLATFORMS=cpu``
-    for the serving tools): only then may a CPU run proceed."""
+    switch (``HVD_TPU_FORCE_CPU`` for ``tools/profile_step.py``,
+    ``JAX_PLATFORMS=cpu`` for the serving tools): only then may a CPU run proceed."""
     stamp = device_stamp()
     if stamp["platform"] == "tpu":
         return stamp

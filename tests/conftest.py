@@ -32,12 +32,6 @@ import pytest  # noqa: E402
 # still runs everything). Auto-marked here (one registry) instead of
 # per-file decorators.
 _SLOW_TESTS = {
-    "test_bench.py::test_default_lane_contract",
-    "test_bench.py::test_lm_lane_contract[dense-default]",
-    "test_bench.py::test_lm_lane_contract[r3-flags]",
-    "test_bench.py::test_zero_composes_with_lm_lane",
-    "test_bench.py::test_compile_only_lane_contract",
-    "test_bench.py::test_lm_flash_attention_lane",
     "test_chip_smoke.py::test_rehearsal_passes",
     "test_examples_models.py::TestExamples::test_flax_imagenet_resnet50_smoke",
     "test_examples_models.py::TestExamples::test_jax_transformer_zero_smoke",
@@ -109,12 +103,6 @@ _SLOW_TESTS = {
     # optimizer/parallel/elastic programs; the gate lanes run here and
     # in tools/check.sh --verify.
     "test_hvdverify.py::test_repo_sweep_is_clean",
-    # ~65s, two whole-bench subprocess runs; stand-ins: the in-process
-    # wire-summary/layout pins (test_hierarchical.py) and the traced
-    # per-leg byte conservation (test_wire_bytes.py hierarchical
-    # params) cover the stamp math — this wrapper pins only the JSON
-    # plumbing, like the other slow-marked bench contract tests.
-    "test_bench.py::test_hierarchical_wire_stamp_in_record",
     # ~35s: three 24-step LM trainings (fp32 / fp8+EF / fp8 no-EF).
     # Fast stand-ins: test_error_feedback_time_average_converges pins
     # the EF mechanics and test_ef_exact_codec_leaves_zero_residual the
@@ -127,24 +115,6 @@ _SLOW_TESTS = {
     # arbitrarily). Same discipline as round 4: whole-program
     # subprocess wrappers whose internals have fast in-process
     # stand-ins move to the slow lane (still in the full CI gate).
-    # 55s whole-bench flash A/B wrapper; stand-ins: the packed-vs-full
-    # grid exactness + grid-table pins in test_parallel.py
-    # TestFlashAttention (fast) cover the kernels, this pins JSON
-    # plumbing like its slow-marked bench siblings.
-    "test_bench.py::test_lm_flash_grid_stamp_and_full_grid_ab",
-    # 33s / 20s / 18s whole-bench subprocess wrappers; stand-ins:
-    # test_elastic.py snapshot pins, ops/attention crossover constants,
-    # and the overlap/bucket-plan pins in test_overlap.py +
-    # tests/test_scaling_model.py respectively.
-    "test_bench.py::test_snapshot_stamp_in_record",
-    "test_bench.py::test_lm_attention_auto_policy[unset]",
-    "test_bench.py::test_lm_attention_auto_policy[auto]",
-    "test_bench.py::test_overlap_and_bucket_stamps_in_record",
-    # ~25s whole-bench subprocess wrapper (a real LM lane + a degraded
-    # attempt-timeout run); stand-in: the parser-level --mesh
-    # canonicalization + mesh_cell pins in
-    # test_mesh_flag_canonicalizes_and_rejects_invalid (fast).
-    "test_bench.py::test_mesh_stamp_in_record",
     # 42s TF keras multi-process wrapper; its three TestMultiProcess
     # siblings are already slow-marked with the same justification
     # (single-process keras coverage stays fast).

@@ -1,0 +1,8 @@
+"""The benchmark's own tests of ``benchmarks/check_manifest.py``, collected by the
+tier-1 command as well: every verdict on a PR rests on that code."""
+
+import pytest
+
+pytest.register_assert_rewrite("benchmarks.tests.test_check_manifest")
+
+from benchmarks.tests.test_check_manifest import *  # noqa: E402,F401,F403

@@ -30,13 +30,12 @@ def _run(script, *args, timeout=120, **env_over):
 
 @pytest.mark.parametrize("script,args", [
     ("chip_smoke.py", ()),
-    ("bench.py", ("--probe-only",)),
+    ("tools/profile_step.py", ("--steps", "2")),
 ])
 def test_measuring_entry_points_refuse_the_cpu(script, args):
     """No TPU, no explicit CPU switch: non-zero exit naming the platform,
-    before any compile, and no result line on stdout. (For bench.py this
-    is also the replacement of the supervisor's tests: a run that did not
-    measure is a non-zero exit code, never a record.)"""
+    before any compile, and no result line on stdout: a run that did not
+    measure is a non-zero exit code, never a record."""
     proc = _run(script, *args)
     assert proc.returncode != 0
     assert "platform" in proc.stderr and "'cpu'" in proc.stderr

@@ -617,8 +617,7 @@ class TestSupervisor:
 
     def test_recovery_metrics_json_line(self, tmp_path):
         """The satellite contract: one PERF_RUNS.tsv-format line with
-        restarts-by-class, the world trajectory and timings — the input
-        tools/perf_summary.py's elastic column renders."""
+        restarts-by-class, the world trajectory and timings."""
         import json as _json
 
         path = tmp_path / "metrics.tsv"
@@ -641,11 +640,6 @@ class TestSupervisor:
         e = rec["elastic"]
         assert e["restarts_by_class"] == {"resized": 1}
         assert e["world"] == [2, 1] and e["final_np"] == 1
-        # And the perf_summary cell renders it.
-        from tools.perf_summary import elastic_cell
-
-        cell = elastic_cell(rec)
-        assert "r1" in cell and "2→1" in cell
 
     def test_heartbeat_dir_namespaced_per_supervisor(self, tmp_path):
         """Regression (round-12 satellite): HOROVOD_HEARTBEAT_DIR is

@@ -229,6 +229,16 @@ class TestExamples:
             "--num-batches-per-iter", "2", "--num-warmup-batches", "1")
         assert float(proc.stdout.strip().splitlines()[-1]) > 0
 
+    def test_jax_synthetic_benchmark(self):
+        """The jax-lane yardstick of the same protocol: warm-up, timed
+        groups, a positive rate, on whatever device it names."""
+        proc = _run_example(
+            "jax_synthetic_benchmark.py", "--model", "resnet18",
+            "--image-size", "32", "--batch-size", "2", "--num-iters", "2",
+            "--num-batches-per-iter", "2", "--num-warmup-batches", "1")
+        assert "8 x cpu" in proc.stdout
+        assert float(proc.stdout.strip().splitlines()[-1]) > 0
+
     def test_jax_transformer_zero_smoke(self, tmp_path):
         """ZeRO + orbax checkpoint LM example trains (loss falls) and a
         second invocation resumes from the saved step."""

@@ -700,7 +700,7 @@ def test_ef_convergence_small_lm(hvd):
 
 
 def test_hier_wire_summary_accounting(hvd):
-    """The bench "wire" stamp's math: per-leg operand bytes derived from
+    """The per-leg byte split: operand bytes derived from
     the same hier_bucket_layout the executing path uses. DCN bytes must
     be <= 1/inner of the flat-psum bytes, and ~4x less again under
     int8."""

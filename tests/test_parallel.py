@@ -260,8 +260,8 @@ class TestFlashAttention:
         """Causal square grids visit ONLY at-or-below-diagonal k-blocks:
         n(n+1)/2 of the n^2 full steps (the ~(n+1)/2n ratio), pinned on
         the step tables the packed grid scalar-prefetches and on the
-        public accounting (flash_grid_info) bench.py stamps into its
-        records."""
+        public accounting (flash_grid_info) tools/tpu_flash_check.py
+        puts into its report."""
         from horovod_tpu.ops.attention import (_causal_step_tables,
                                                flash_grid_info)
 
@@ -303,7 +303,7 @@ class TestFlashAttention:
     def test_truncated_matches_full_grid(self):
         """The packed causal grid is bit-identical to the full grid's
         compute-skip path — forward AND the packed Pallas backward pair
-        (truncate=False is the hw_sweep A/B lanes' pin)."""
+        (truncate=False pins the full grid)."""
         key = jax.random.PRNGKey(13)
         q, k, v = (jax.random.normal(jax.random.fold_in(key, i),
                                      (2, 64, 2, 8)) for i in range(3))

@@ -127,11 +127,6 @@ class TestServeBenchContract:
         assert ab["exact_pin"]["compared"] == 6
         assert rec["config"]["prefix_caching"] == "ab"
         assert rec["config"]["system_prompt_len"] == 32
-        # the perf_summary prefix column renders this record
-        from tools.perf_summary import prefix_cell
-
-        cell = prefix_cell(rec)
-        assert cell.startswith("hit ") and "a/b" in cell
 
     def test_ab_prefix_is_exclusive_with_other_modes(self):
         for extra in (["--ab"], ["--static"], ["--ab-attention"],
@@ -166,11 +161,6 @@ class TestServeBenchContract:
             tp["kv_bytes_per_chip_single"] / 4, rel=1e-3)
         assert tp["tp_over_single"] is not None
         assert rec["config"]["mesh"] == "dp=1,tp=4"
-        # the perf_summary serve column renders the tp tag
-        from tools.perf_summary import serve_cell
-
-        cell = serve_cell(rec)
-        assert " tp4 kv 0.25x" in cell
 
     def test_ab_tp_arg_validation(self):
         # --ab-tp without a mesh, with another A/B, a mesh that
@@ -275,11 +265,6 @@ class TestFleetBenchContract:
         assert rec["config"]["fleet"]["replicas"] == 2
         assert rec["config"]["fleet"]["fault_plan"] == \
             "kill:replica=1,at=50%"
-        # the perf_summary fleet column renders this record
-        from tools.perf_summary import fleet_cell
-
-        cell = fleet_cell(rec)
-        assert cell.startswith("2r") and "crashed1" in cell
 
     def test_fleet_process_transport_record_contract(self):
         """The round-13 acceptance e2e: the same fault A/B with one
@@ -317,10 +302,6 @@ class TestFleetBenchContract:
         assert ab["clean"]["fleet"]["transport"] == "process"
         assert ab["clean"]["fleet"]["rpc_ms"]["calls"] > 0
         assert rec["config"]["fleet"]["transport"] == "process"
-        from tools.perf_summary import fleet_cell
-
-        cell = fleet_cell(rec)
-        assert "proc" in cell and "rpc" in cell
         # no zombie/orphan workers survive the bench process (scoped:
         # only NEW pids count — a concurrent job's workers are not
         # this bench's leak)
@@ -361,9 +342,6 @@ class TestFleetBenchContract:
         assert ab["cold_prefills"] == ab["replica_homes"] >= 1
         assert ab["exact_pin"]["identical"] is True
         assert ab["exact_pin"]["compared"] == 6
-        from tools.perf_summary import prefix_cell
-
-        assert prefix_cell(rec).startswith("hit ")
 
     def test_fleet_arg_validation(self):
         cases = [
@@ -383,71 +361,6 @@ class TestFleetBenchContract:
         for bad in cases:
             p = _run("serve_bench.py", *TINY, *bad, check=False)
             assert p.returncode == 2, (bad, p.stderr[-300:])
-
-
-def test_fleet_cell_renders_synthetic_record():
-    """tools/perf_summary.py fleet column (fast, no subprocess)."""
-    from tools.perf_summary import fleet_cell
-
-    assert fleet_cell({}) == "—"
-    assert fleet_cell({"serve": {"ttft_ms": {}}}) == "—"
-    rec = {"serve": {
-        "fleet": {"replicas": 2,
-                  "incidents_by_class": {"crashed": 1, "stalled": 2},
-                  "redispatched": 3, "tokens_recomputed": 10,
-                  "detect_s": 0.8, "shed": 2},
-        "fleet_ab": {"faulted_over_clean_p99_ttft": 2.07},
-    }}
-    cell = fleet_cell(rec)
-    assert cell == "2r crashed1,stalled2 rd3/10tok det 0.8s shed2 f/c 2.07"
-    # process-transport records grow the proc tag + rpc overhead pair;
-    # inproc records tag without rpc; pre-transport records (above)
-    # stay untagged.
-    proc = {"serve": {"fleet": {
-        "replicas": 2, "transport": "process",
-        "rpc_ms": {"calls": 10, "p50": 0.3, "p99": 2.1},
-        "incidents_by_class": {"crashed": 1}, "redispatched": 1,
-        "tokens_recomputed": 4}}}
-    assert fleet_cell(proc) == "2r proc rpc 0.3/2.1ms crashed1 rd1/4tok"
-    inp = {"serve": {"fleet": {"replicas": 2, "transport": "inproc"}}}
-    assert fleet_cell(inp) == "2r inproc"
-    # tcp records tag the transport + host count; host_down incidents
-    # ride the incidents_by_class render like any other class.
-    tcp = {"serve": {"fleet": {
-        "replicas": 2, "transport": "tcp", "hosts": 2,
-        "rpc_ms": {"calls": 10, "p50": 0.4, "p99": 3.0},
-        "incidents_by_class": {"host_down": 1}, "redispatched": 4,
-        "tokens_recomputed": 18}}}
-    assert fleet_cell(tcp) == \
-        "2r tcp 2h rpc 0.4/3ms host_down1 rd4/18tok"
-
-
-def test_prefix_cell_renders_synthetic_record():
-    """tools/perf_summary.py prefix column (fast, no subprocess)."""
-    from tools.perf_summary import prefix_cell
-
-    assert prefix_cell({}) == "—"
-    assert prefix_cell({"serve": {"ttft_ms": {}}}) == "—"
-    assert prefix_cell({"serve": {"prefix": None}}) == "—"
-    # single-engine --ab-prefix record: hit accounting + A/B ratio
-    eng = {"serve": {
-        "prefix": {"hit_rate": 0.88, "prefill_tokens_saved": 224,
-                   "pages_shared": 14, "cow_copies": 0},
-        "ab_prefix": {"cached_over_cold": 1.05, "cold_prefills": 1,
-                      "unique_prefixes": 1},
-    }}
-    assert prefix_cell(eng) == "hit 0.88 sv 224tok/14pg a/b 1.05 1cold x1"
-    # fleet records read the router-side block and append the
-    # redispatch-meets-prefix savings
-    fl = {"serve": {"fleet": {"prefix": {
-        "hit_rate": 0.75, "prefill_tokens_saved": 48,
-        "pages_shared": 6, "redispatch_tokens_saved": 16}}}}
-    assert prefix_cell(fl) == "hit 0.75 sv 48tok/6pg rd16tok"
-    # COW copies surface when the defensive path ever fired
-    cow = {"serve": {"prefix": {"hit_rate": 0.5,
-                                "prefill_tokens_saved": 8,
-                                "cow_copies": 2}}}
-    assert prefix_cell(cow) == "hit 0.5 sv 8tok cow2"
 
 
 class TestDecodeBenchSatellites:

@@ -74,7 +74,6 @@ def programs(hvd):
     with dense and once with flash attention, as ``run.py`` drives it: data
     parallel over the test mesh's chips (2 sequences each), so the gradients
     and the experts' counts of the step are also summed over chips."""
-    os.environ["HVD_BENCH_NO_STATIC_AUDIT"] = "1"
     made = {}
 
     def get(attention):

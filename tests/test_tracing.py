@@ -315,7 +315,6 @@ LANES = {
 @pytest.mark.parametrize("lane_name", sorted(LANES))
 def test_the_five_scopes_are_in_the_lowered_step_of_both_lanes(
         hvd, monkeypatch, lane_name):
-    monkeypatch.setenv("HVD_BENCH_NO_STATIC_AUDIT", "1")
     monkeypatch.syspath_prepend(REPO)
     import bench
 
@@ -336,7 +335,7 @@ def test_the_five_scopes_are_in_the_lowered_step_of_both_lanes(
                    if build["start_ns"] <= s["start_ns"] <= build["end_ns"])
     assert build["args"]["programs"] == programs > 0
     for child in ("hvd.lane.model_init", "hvd.lane.train_state",
-                  "hvd.lane.place", "hvd.lane.audit"):
+                  "hvd.lane.place"):
         assert child in names
         assert all(s["parent"] == build["id"] for s in _spans(child))
     under_build = {s["id"] for s in _spans() if s["parent"] == build["id"]}
@@ -352,7 +351,6 @@ def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
     calls by implementation (unset, the policy's: dense on this platform),
     ``.block_q`` / ``.block_k`` are the kernels' blocks, and the attention
     runs under the scope ``hvd_attn_full``."""
-    monkeypatch.setenv("HVD_BENCH_NO_STATIC_AUDIT", "1")
     monkeypatch.syspath_prepend(REPO)
     import bench
 

@@ -10,9 +10,6 @@ and metric means — plus donation safety across windows, the
 K-batch device stager's ordering.
 """
 
-import importlib.util
-from pathlib import Path
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,13 +20,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import horovod_tpu.jax as hvd
 from horovod_tpu import data, models
 from horovod_tpu.jax.window import (
-    repeat_batch,
     stack_batches,
     stacked_specs,
     windowed,
 )
-
-REPO = Path(__file__).resolve().parent.parent
 
 
 def _fresh_state():
@@ -204,16 +198,12 @@ class TestWindowHelpers:
         out = stacked_specs(tree)
         assert out == {"a": P(None, "hvd"), "b": P(None)}
 
-    def test_stack_and_repeat_batch(self, hvd):
+    def test_stack_batches(self, hvd):
         batches = [{"x": jnp.full((2,), float(i))} for i in range(3)]
         stacked = stack_batches(batches)
         assert stacked["x"].shape == (3, 2)
         np.testing.assert_array_equal(np.asarray(stacked["x"])[:, 0],
                                       [0.0, 1.0, 2.0])
-        rep = repeat_batch({"x": jnp.arange(4.0)}, 5)
-        assert rep["x"].shape == (5, 4)
-        np.testing.assert_array_equal(np.asarray(rep["x"][4]),
-                                      np.arange(4.0))
         with pytest.raises(ValueError, match="at least one"):
             stack_batches([])
 
@@ -239,59 +229,6 @@ class TestWindowHelpers:
         seq_mean = jax.tree_util.tree_map(
             lambda *ms: jnp.mean(jnp.stack(ms), axis=0), *seq_metrics)
         _assert_trees_close(metrics, seq_mean)
-
-
-class TestBenchWindowWiring:
-    """Static window-lane wiring (no backend spin-up): the bench CLI's
-    contract for --steps-per-dispatch, mirroring test_sweep_lanes.py's
-    preflight philosophy."""
-
-    @pytest.fixture(scope="class")
-    def bench(self):
-        spec = importlib.util.spec_from_file_location(
-            "bench_window_mod", REPO / "bench.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_metric_contract_win_suffix(self, bench):
-        parser = bench.build_parser()
-        args = parser.parse_args(["--steps-per-dispatch", "30"])
-        assert args.steps_per_dispatch == 30
-        assert bench.metric_contract(args) == (
-            "resnet50_img_per_sec_per_chip_win30", "img/sec/chip")
-        lm = parser.parse_args(["--model", "transformer_lm",
-                                "--steps-per-dispatch", "8"])
-        assert bench.metric_contract(lm) == (
-            "transformer_lm_tokens_per_sec_per_chip_win8",
-            "tokens/sec/chip")
-        # compile-only windows are a different (scanned) program than
-        # the historical 1-step first-step rows — suffixed apart too.
-        co = parser.parse_args(["--compile-only",
-                                "--steps-per-dispatch", "30"])
-        assert bench.metric_contract(co) == (
-            "resnet50_first_step_secs_win30", "secs")
-
-    def test_default_lane_contract_unchanged(self, bench):
-        """K=1 (the reference protocol) keeps the exact historical
-        metric names — window records ride ALONGSIDE, never over."""
-        args = bench.build_parser().parse_args([])
-        assert args.steps_per_dispatch == 1
-        assert bench.metric_contract(args) == (
-            "resnet50_img_per_sec_per_chip", "img/sec/chip")
-
-    def test_apply_window_identity_and_wrap(self, bench):
-        def step(s, b):
-            return s, b
-
-        batch = {"x": jnp.zeros((4, 2))}
-        fn, out_batch, spec = bench.apply_window(step, batch, 1)
-        assert fn is step and out_batch is batch and spec == P("hvd")
-        fn, out_batch, spec = bench.apply_window(step, batch, 3)
-        assert out_batch["x"].shape == (3, 4, 2)
-        assert spec == P(None, "hvd")
-        with pytest.raises(ValueError, match=">= 1"):
-            bench.apply_window(step, batch, 0)
 
 
 class TestWindowTimeline:

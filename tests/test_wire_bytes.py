@@ -140,8 +140,8 @@ def test_hierarchical_dp_step_wire_bytes(hvd, inner, comp_name):
     buckets and its all-gather the 1/inner shards; the inter-slice
     (DCN) leg carries exactly the shard bytes — divided by ~4 again
     under int8 (quantized payloads + 4 B scales) — and the whole split
-    must agree with fusion.hier_wire_summary (the bench "wire" stamp's
-    math), so the stamp is checkable against the traced schedule."""
+    must agree with fusion.hier_wire_summary, so that summary is
+    checkable against the traced schedule."""
     import optax
 
     import horovod_tpu.jax as hvd_jax
@@ -317,7 +317,7 @@ def test_expert_shares_add_up_with_one_allreduce_of_token_bytes(hvd):
 
 def test_static_audit_matches_dynamic_accounting(hvd):
     """hvdverify cross-check (docs/static_analysis.md): the schedule
-    walker behind bench.py's ``"collectives"`` stamp and HVV105 must
+    walker behind ``audit_collectives`` and HVV105 must
     agree EXACTLY — per-op count and payload bytes — with this file's
     independent dynamic jaxpr accounting, on both step shapes it pins
     (fused DP and ZeRO-1). Two walkers, two authors, one jaxpr: any
@@ -348,8 +348,8 @@ def test_static_audit_matches_dynamic_accounting(hvd):
         walker = ScheduleWalker().walk(jaxpr)
         static = [(op.kind, op.payload_bytes) for op in walker.schedule]
         assert sorted(static) == sorted(dynamic), (zero, static, dynamic)
-        # No scan in these steps, so the summarized stamp (bench.py's
-        # "collectives" field) is the plain sum of the dynamic walk.
+        # No scan in these steps, so the summary (what
+        # audit_collectives returns) is the plain sum of the dynamic walk.
         summary = summarize(walker.schedule)
         assert summary["count"] == len(dynamic)
         assert summary["bytes"] == sum(b for _, b in dynamic)

@@ -1,7 +1,7 @@
 """Chunked fused cross-entropy (ops/xent.py) vs the dense composition.
 
 The dense reference materializes [T, V] logits and log-softmaxes them —
-exactly what the LM bench's unfused loss does (bench.py build_lm_lane); the
+exactly what the LM step's unfused loss does (models.make_lm_train_step); the
 fused op must match its loss and gradients while never building the
 full logits tensor.
 """

@@ -62,8 +62,8 @@ def main() -> int:
     force_device_sync(out)  # compile + warm
     compile_s = time.perf_counter() - t0
 
-    # run_timed's window discipline: N windows, mean +- 1.96*std
-    # (bench.py's protocol).
+    # The reference's window discipline: N windows, each ended by one
+    # device sync, mean +- 1.96*std.
     rates = []
     for x in range(args.iters):
         t0 = time.perf_counter()

@@ -34,7 +34,7 @@ Usage::
     python -m tools.hvdverify --program optimizer.overlap --schedule
 
 Library surface: :func:`verify` (one program), :func:`audit_collectives`
-(the count+bytes summary bench.py stamps), the ``REGISTRY`` of real
+(the count+bytes summary of one program), the ``REGISTRY`` of real
 repo programs, and the schedule walker itself.
 """
 

@@ -177,10 +177,11 @@ def verify(
 
 
 def audit_collectives(fn: Callable, *args) -> Dict[str, Any]:
-    """The static-audit summary of one program — collective count +
-    bytes, the numbers ``bench.py`` stamps into records as
-    ``"collectives"`` (cross-checked against the dynamic accounting in
-    tests/test_wire_bytes.py). Pure tracing; safe anywhere jax traces."""
+    """The static-audit summary of one program: collective count +
+    bytes, by kind (cross-checked against the dynamic accounting in
+    tests/test_wire_bytes.py; ``chip_smoke.py`` asks it of the
+    data-parallel lanes it steps). Pure tracing; safe anywhere jax
+    traces."""
     import jax
 
     with warnings.catch_warnings():

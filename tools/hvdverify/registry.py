@@ -7,11 +7,13 @@ execute, traced at reduced input sizes (tracing cost scales with op
 count, not tensor size; the collective schedule is size-independent in
 structure). Groups:
 
-* ``gate``     — the driver gate lanes bench.py composes: the model-zoo
-                 train steps (resnet50/vgg16/inception_v3/vit/
-                 transformer_lm families) through ``spmd_fn`` with the
-                 state donated, plus the window / overlap / ZeRO /
-                 fused-CE lane variants.
+* ``gate``     — the lanes bench.py's ``build_lane`` composes: the
+                 model-zoo train steps (``models.make_train_step`` for
+                 resnet50/vgg16/inception_v3/vit, ``models.
+                 make_lm_train_step`` for transformer_lm) through
+                 ``spmd_fn`` with the state donated, plus the window /
+                 overlap / ZeRO / fused-CE variants of the library's
+                 own options.
 * ``optimizer``— DistributedOptimizer's fused / overlap / shaped
                  emission modes, each with an HVV105 ReconcileSpec
                  pinning the traced bytes to ``plan_buckets``.
@@ -39,8 +41,7 @@ structure). Groups:
                  (``forbid_donation`` — the HVV104 class again).
 
 Abstract state comes from ``jax.eval_shape`` over the real init
-functions — zero FLOPs, no devices, runs on CPU anywhere (the same
-trick tools/scaling_model.py uses for bucket bytes).
+functions — zero FLOPs, no devices, runs on CPU anywhere.
 """
 
 from __future__ import annotations
@@ -110,7 +111,7 @@ def _init():
 
 def abstractify(tree):
     """ShapeDtypeStruct twin of an arbitrary array pytree — what every
-    registry program (and bench.py's ``collectives`` stamp) traces on:
+    registry program (and chip_smoke.py's collective audit) traces on:
     only shapes/dtypes matter, nothing is allocated or executed."""
     import jax
 
@@ -147,9 +148,9 @@ def _abstract_train_state(model, optimizer, sample):
 def _image_lane(model_name, *, image=64, per_chip=2, overlap=None,
                 zero=False, window=1, num_classes=100):
     """A driver-gate image lane: models.build -> make_train_step ->
-    spmd_fn with the state donated — bench.py's build_image_lane composition
-    (window>1 adds the stage_synthetic_window scan, the --steps-per-
-    dispatch lane)."""
+    spmd_fn with the state donated, bench.py's ``build_lane`` composition
+    (window>1 adds jax/window.py's scan over a K-stacked batch; overlap and
+    zero are ``create_train_state``'s options)."""
 
     def build():
         import jax
@@ -187,9 +188,8 @@ def _image_lane(model_name, *, image=64, per_chip=2, overlap=None,
 
         batch_spec = P(DATA_AXIS)
         if window > 1:
-            # The --steps-per-dispatch lane: the scan window over a
-            # K-stacked batch (bench.py stages concrete arrays through
-            # stage_synthetic_window; abstract tracing stacks the
+            # The scan window over a K-stacked batch (hvd.run_steps
+            # stages concrete arrays; abstract tracing stacks the
             # ShapeDtypeStructs directly).
             step_fn = windowed(step_fn, window)
             batch = jax.tree_util.tree_map(
@@ -209,9 +209,10 @@ def _image_lane(model_name, *, image=64, per_chip=2, overlap=None,
 
 def _lm_lane(*, fused_ce=False, seq=256, per_chip=1, layers=4, dim=256,
              heads=4, vocab=1024):
-    """The transformer_lm gate lane: bench.py's build_lm_lane step (dense
-    attention; the fused_ce variant routes the loss through
-    ops/xent.fused_cross_entropy exactly as --fused-ce does)."""
+    """The transformer_lm gate lane: ``models.make_lm_train_step``, the step
+    bench.py's ``build_lane`` runs, over dense attention (the fused_ce
+    variant is ``--fused-ce``'s: the loss through
+    ops/xent.fused_cross_entropy)."""
 
     def build():
         import jax
@@ -220,51 +221,21 @@ def _lm_lane(*, fused_ce=False, seq=256, per_chip=1, layers=4, dim=256,
         from jax.sharding import PartitionSpec as P
 
         from horovod_tpu import models
+        from horovod_tpu.jax.optimizer import DistributedOptimizer
+        from horovod_tpu.parallel.logical import DATA_AXIS
 
         hvd = _init()
         model = models.TransformerLM(
             vocab_size=vocab, num_layers=layers, num_heads=heads,
             embed_dim=dim, max_len=max(seq, 2048))
-        from horovod_tpu.jax.optimizer import DistributedOptimizer
-
         optimizer = DistributedOptimizer(optax.adam(1e-4))
         sample = jax.ShapeDtypeStruct((1, seq), jnp.int32)
         state = _abstract_train_state(model, optimizer, sample)
-
-        def step_fn(state, batch):
-            tokens = batch["tokens"]
-            if fused_ce:
-                from horovod_tpu.ops.xent import fused_cross_entropy
-
-                def loss_fn(params):
-                    hidden = model.apply({"params": params}, tokens,
-                                         train=False, return_hidden=True)
-                    e = hidden.shape[-1]
-                    h = hidden[:, :-1].reshape(-1, e).astype(jnp.float32)
-                    wv = params["lm_head"]["kernel"].astype(jnp.float32)
-                    return fused_cross_entropy(
-                        h, wv, tokens[:, 1:].reshape(-1))
-            else:
-                def loss_fn(params):
-                    logits = model.apply({"params": params}, tokens,
-                                         train=False)
-                    logp = jax.nn.log_softmax(
-                        logits[:, :-1].astype(jnp.float32))
-                    tgt = tokens[:, 1:]
-                    nll = -jnp.take_along_axis(logp, tgt[..., None], -1)
-                    return jnp.mean(nll)
-
-            loss, grads = jax.value_and_grad(loss_fn)(state["params"])
-            state, loss = models.read_before_update(state, loss)
-            return models.apply_gradients(optimizer, state, grads), loss
-
-        from horovod_tpu.parallel.logical import DATA_AXIS
-
         n = hvd.size()
         batch = {"tokens": jax.ShapeDtypeStruct((per_chip * n, seq),
                                                 jnp.int32)}
         run = hvd.spmd_fn(
-            step_fn,
+            models.make_lm_train_step(model, optimizer, fused_ce=fused_ce),
             in_specs=(P(), P(DATA_AXIS)),
             out_specs=(P(), P()),
             donate_argnums=(0,),

@@ -304,8 +304,8 @@ def check_reconciliation(program: str, schedule: Sequence[CollectiveOp],
             f"plan ({len(bucket.members)} tensor(s), {bucket.nbytes} B "
             f"at threshold {spec.threshold}) has NO matching collective "
             "in the traced schedule: the program does not execute the "
-            "bucket plan it claims (plan_buckets/scaling_model would "
-            "account bytes the wire never moves)"))
+            "bucket plan it claims (plan_buckets would account bytes "
+            "the wire never moves)"))
     for op in reduces + scatters + gathers + a2as:
         findings.append(Finding(
             program, "HVV105",

@@ -568,8 +568,8 @@ def sharding_constraint_refs(closed_jaxpr, *, _depth: int = 0
 def summarize(schedule: Sequence[CollectiveOp]) -> Dict[str, Any]:
     """Static audit numbers for one program: collective count and bytes
     (payload x static multiplier; while-nested ops count once and are
-    reported separately). This is the accounting bench.py stamps as
-    ``"collectives"`` and tools/perf_summary.py renders."""
+    reported separately): what :func:`tools.hvdverify.audit_collectives`
+    returns."""
     by_kind: Dict[str, int] = {}
     total = 0
     unbounded = 0

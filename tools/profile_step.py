@@ -49,9 +49,6 @@ def main():
     from horovod_tpu.utils import compile_cache, step_profile
     from horovod_tpu.utils.device import require_tpu
 
-    # the lane's static audit of collectives serves no step (bench.py's
-    # own switch)
-    os.environ.setdefault("HVD_BENCH_NO_STATIC_AUDIT", "1")
     compile_cache.enable()
     hvd.init()
     require_tpu(cpu_requested=bool(os.environ.get("HVD_TPU_FORCE_CPU")))

@@ -9,8 +9,8 @@ on-hardware counterpart: compile and run the actual Mosaic kernel
 backward), check numerics against the dense reference in bf16 — plus
 the packed-vs-full causal grid parity — then time fwd+bwd flash
 (truncated AND full grid) vs dense at seq 1024/2048/4096 — so one
-short chip call yields the grid-truncation evidence without the full
-transformer_lm sweep lanes (tools/hw_sweep.py seq ladder). Every ladder
+short chip call yields the grid-truncation evidence without a whole
+training lane. Every ladder
 record carries its grid/K-V-bytes stamp (flash_grid_info) so it is
 attributable to a concrete grid, not just a wall time.
 
@@ -224,8 +224,7 @@ def block_sweep(key, only=None):
                 f"{best['bwd']} {best['fwd_bwd_ms']:.3f} ms"
                 + (f" (dense {dense['fwd_bwd_ms']:.3f})" if dense else ""))
     line = "block sweep: " + "; ".join(summary)
-    # Last stderr line = the sweep-lane record (hw_sweep.py keeps it);
-    # stdout carries it too for direct runs.
+    # The summary is the last line of both streams.
     print(line, file=sys.stderr, flush=True)
     print(line, flush=True)
 
