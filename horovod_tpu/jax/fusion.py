@@ -28,13 +28,25 @@ Overlap scheduling (HOROVOD_OVERLAP=auto|on|off): the reference hid the
 gradient exchange behind backward compute by firing an allreduce from each
 gradient hook as autograd produced it (Sergeev & Del Balso 2018; PyTorch
 DDP's reverse-order buckets, Li et al. VLDB 2020). Under XLA the step is
-one program, so the same win is a *scheduling shape* problem: with overlap
-on, per-bucket collectives are issued in REVERSE bucket order — the order
-backward produces gradients, last layers first — as a start-all/
-unpack-later sequence, so each bucket's collectives depend only on its own
-members and XLA's async collective (start/done) scheduler can slide them
-under the remaining backward compute instead of serializing one
-post-backward block. Overlap NEVER changes results: the emission order
+one program, and what runs beside what is the compiler's scheduler's: with
+overlap on, per-bucket collectives are issued in REVERSE bucket order as a
+start-all/unpack-later sequence, so each leaf's collective depends on that
+leaf alone and nothing here stands in the scheduler's way. The emission by
+itself hides nothing: under XLA:TPU's defaults an all-reduce is a
+synchronous operation (on the v5e all 28.3 ms a step of the data-parallel
+GPT-2-medium cell stood alone on the core; PERF.md, PR 27 to 31). What
+makes them asynchronous is how the program is COMPILED:
+``hvd.spmd_fn`` passes :data:`horovod_tpu.parallel.spmd.
+ASYNC_ALL_REDUCE_OPTIONS` to ``jax.jit`` for a TPU mesh of several chips
+(asynchronous all-reduce, asynchronous collective fusion of it, also into
+loop fusions, and the all-reduce combiner held under 1 MiB, because the
+fusion pass takes an all-reduce of one operand only). XLA's
+latency-hiding scheduler then moves every all-reduce BEHIND the backward
+pass, next to the weight-gradient product of the following leaf and to the
+optimizer's passes, not under the backward pass's other work: nothing but
+the optimizer waits for a reduced gradient, and the scheduler places an
+all-reduce as late as its consumer allows (PERF.md, PR 31, has the
+schedule and the times). Overlap NEVER changes results: the emission order
 changes, the math does not (pinned bit-exactly in tests/test_overlap.py).
 
 Same-dtype-only fusion matches the reference (it fused only responses with
@@ -497,9 +509,10 @@ def fused_reduce(
     SPMD path has no per-tensor identity inside the compiled program.
 
     ``overlap`` (auto|on|off, default HOROVOD_OVERLAP) selects the
-    backward-overlapped emission: reverse bucket order, start-all/
-    unpack-later. Changes dispatch shape only — results are bit-identical
-    to ``off``.
+    emission: reverse bucket order, start-all/unpack-later. Changes
+    dispatch shape only — results are bit-identical to ``off``; what an
+    all-reduce runs beside is decided where the program is compiled
+    (module docstring).
 
     ``hierarchical`` (auto|on|off, default HOROVOD_HIERARCHICAL) runs
     each Sum/Average bucket as the two-level intra-slice reduce-scatter
@@ -794,12 +807,13 @@ def fused_reduce(
         return _unpack
 
     if use_overlap:
-        # Reverse bucket order = backward availability order (autodiff
-        # produces the LAST layers' gradients first): start every
-        # collective as its bucket's gradients become available, unpack
-        # afterwards in forward order — the start-all/done-later shape
-        # XLA's async collective scheduler hides under the remaining
-        # backward compute.
+        # Reverse bucket order (autodiff produces the LAST layers'
+        # gradients first; the plan follows the pytree's order of leaves,
+        # which is the layers' only up to nine of a name): issue every
+        # collective, unpack afterwards in forward order, so that no
+        # collective waits for another's unpack. Where each then runs is
+        # the compiler's scheduler's, under the options hvd.spmd_fn
+        # compiles the program with (module docstring).
         unpacks = [None] * len(plan)
         for k, bi in enumerate(reversed(range(len(plan)))):
             unpacks[bi] = _issue(k, bi, plan[bi])

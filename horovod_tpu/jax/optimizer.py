@@ -19,9 +19,14 @@ wrapper whose update step plans gradient leaves into buckets
 packs a bucket into a flat buffer). The
 reference fired one allreduce per gradient from a backward hook as autograd
 produced them (torch/__init__.py:95-130), relying on the background fusion
-thread to batch them; under XLA the whole step is one program, so bucketing
-at trace time achieves the same overlap with zero runtime coordination, and
-XLA's all-reduce combiner does the batching on the wire.
+thread to batch them; under XLA the whole step is one program, so there is
+no runtime coordination: which all-reduces travel together is XLA's
+all-reduce combiner's, and what an all-reduce runs beside is the
+compiler's scheduler's, under the options ``hvd.spmd_fn`` compiles a
+several-chip TPU program with (``parallel/spmd.py``; on the v5e the
+exchange hides under the weight-gradient products and the optimizer's
+passes behind the backward pass, not under the backward pass itself:
+PERF.md, PR 31).
 """
 
 from __future__ import annotations
@@ -70,10 +75,11 @@ def allreduce_gradients_transform(
 
     ``overlap`` (auto|on|off; default HOROVOD_OVERLAP) selects the
     backward-overlapped bucket emission (:mod:`horovod_tpu.jax.fusion`):
-    per-bucket collectives issued in reverse bucket order as each
-    bucket's gradients become available, so XLA's async collective
-    scheduling hides them under remaining backward compute. Dispatch
-    shape only — numerics are bit-identical across modes.
+    per-bucket collectives issued in reverse bucket order, none waiting
+    for another's unpack. Dispatch shape only — numerics are
+    bit-identical across modes, and the emission hides nothing by itself:
+    the all-reduces are asynchronous where ``hvd.spmd_fn`` compiles the
+    program so (:mod:`horovod_tpu.jax.fusion`'s docstring).
 
     ``hierarchical`` (auto|on|off; default HOROVOD_HIERARCHICAL) runs
     each bucket as the intra-slice reduce-scatter -> inter-slice (DCN)

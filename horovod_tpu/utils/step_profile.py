@@ -28,7 +28,16 @@ session writes and returns, for the training steps it holds:
   (during which no other operation ran on that chip), and the part that
   the phases count under ``other``: a collective that XLA made itself, by
   combining several or by decomposing a reduce-scatter, carries no
-  ``op_name``.
+  ``op_name``. An asynchronous collective whose ``-start`` and ``-done``
+  both stand on ``XLA Ops`` with no span on ``Async XLA Ops`` counts from
+  the start's beginning to the done's end. So does XLA:TPU's asynchronous
+  collective fusion (``%async-collective-start`` / ``%async-collective-done``,
+  fusions whose kind is read from the primitive the done's ``op_name`` ends in):
+  the fusions between the two carry the collective's steps with work of
+  their own and count as that work, so a step they stretch reads as hidden;
+  what reads as exposed there is the start, the done's wait and any gap.
+  ``collective_beside_s`` names what ran while a collective was open: the
+  eight operation families with the most time there.
 * **gaps**: the chip's idle time inside the window, each gap put down to the
   innermost ``hvd.*`` span (``hvd.spmd.dispatch`` and the others of
   docs/timeline.md) open on the host at its middle, or ``between spans``.
@@ -59,6 +68,17 @@ OPERATION = re.compile(r" ([a-z][a-z0-9-]*)\(")
 COLLECTIVE = re.compile(
     r"^(all-reduce|reduce-scatter|all-gather|all-to-all|collective-permute)"
     r"(-start|-done)?$")
+# XLA:TPU's asynchronous collective fusion: the two fusions that open and
+# close it, and the JAX primitive (the end of the done's ``op_name``) that says
+# which collective it is
+ASYNC_FUSION = re.compile(r"^%async-collective-(start|done)((?:\.\d+)?)$")
+_PRIMITIVES = {"psum": "all-reduce", "psum_invariant": "all-reduce",
+               "pmax": "all-reduce", "pmin": "all-reduce",
+               "all_gather": "all-gather", "all_gather_invariant": "all-gather",
+               "psum_scatter": "reduce-scatter",
+               "reduce_scatter": "reduce-scatter",
+               "all_to_all": "all-to-all", "ppermute": "collective-permute"}
+_PAIRED = re.compile(r"-done\([^%]*%([\w.\-]+)")
 PHASES = ("forward", "backward", "recomputed", "loss", "exchange", "update",
           "metrics", "other")
 _SCOPES = {timeline.FORWARD: "forward", timeline.LOSS: "loss",
@@ -255,6 +275,55 @@ def operation(text: str) -> str:
     return found.group(1) if found else ""
 
 
+def _instruction(text: str) -> str:
+    return text.partition(" = ")[0]
+
+
+def _family(text: str) -> str:
+    """``%multiply_add_fusion.407 = ...`` -> ``multiply_add_fusion``."""
+    return re.sub(r"[.\d]+$", "", _instruction(text).lstrip("%"))
+
+
+def _async_pairs(ops: List[Event], beside: List[Event]):
+    """``(start event, done event, which collective, held)`` of every
+    asynchronous collective whose two halves stand on ``XLA Ops``:
+    ``<collective>-start`` with the ``-done`` that names it, and
+    ``%async-collective-start[.n]`` with the next
+    ``%async-collective-done[.n]``; ``held`` where an event of ``Async XLA
+    Ops`` already holds the span from start to done."""
+    open_by_name, open_fusions, pairs = {}, {}, []
+    for e in sorted(ops, key=lambda e: e.start_ns):
+        name = _instruction(e.name)
+        fused = ASYNC_FUSION.match(name)
+        if fused:
+            half, suffix = fused.groups()
+            if half == "start":
+                open_fusions[suffix] = e
+            elif suffix in open_fusions:
+                start = open_fusions.pop(suffix)
+                # on the v5e the done carries the psum's name, the start none
+                last = (e.op_name or start.op_name).rstrip(":").rsplit(
+                    "/", 1)[-1]
+                pairs.append((start, e, _PRIMITIVES.get(
+                    last, "async-collective")))
+            continue
+        found = COLLECTIVE.match(operation(e.name))
+        if not found or not found.group(2):
+            continue
+        if found.group(2) == "-start":
+            open_by_name[name.lstrip("%")] = e
+        else:
+            named = _PAIRED.search(e.name)
+            start = open_by_name.pop(named.group(1), None) if named else None
+            if start is not None:
+                pairs.append((start, e, found.group(1)))
+    spans = [(b.start_ns, b.end_ns) for b in beside
+             if COLLECTIVE.match(operation(b.name))]
+    return [(s, d, kind, any(a <= s.start_ns and d.end_ns <= b + 1
+                             for a, b in spans))
+            for s, d, kind in pairs]
+
+
 def union_ns(intervals):
     """``(total, merged)`` of ``(start, end)`` pairs."""
     merged = []
@@ -282,17 +351,23 @@ def _self_times(events: List[Event]):
     return out
 
 
+def _overlaps(intervals, merged) -> Iterator[float]:
+    """For each of ``(start, end)`` pairs in the order of their starts, the
+    time it shares with a merged interval list."""
+    j = 0
+    for s, e in intervals:
+        while j < len(merged) and merged[j][1] <= s:
+            j += 1
+        total, k = 0.0, j
+        while k < len(merged) and merged[k][0] < e:
+            total += min(e, merged[k][1]) - max(s, merged[k][0])
+            k += 1
+        yield total
+
+
 def _overlap_ns(merged_a, merged_b) -> float:
     """Time covered by both of two merged interval lists."""
-    total, j = 0.0, 0
-    for s, e in merged_a:
-        while j < len(merged_b) and merged_b[j][1] <= s:
-            j += 1
-        k = j
-        while k < len(merged_b) and merged_b[k][0] < e:
-            total += min(e, merged_b[k][1]) - max(s, merged_b[k][0])
-            k += 1
-    return total
+    return sum(_overlaps(merged_a, merged_b))
 
 
 def host_spans(planes: List[Plane]):
@@ -338,15 +413,22 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
 
     phases, layers = collections.Counter(), collections.Counter()
     busy = window = collective = exposed = unnamed = 0.0
-    by_operation = collections.Counter()
+    by_operation, beside_ns = collections.Counter(), collections.Counter()
     for plane in chips.values():
         ops, beside = _line(plane, OPS_LINE), _line(plane, ASYNC_LINE)
         moving = {}                         # id(event) -> which collective
+        pairs = _async_pairs(ops, beside)
+        halves = {id(e) for s, d, _, _ in pairs for e in (s, d)}
         for e in ops + beside:
             found = COLLECTIVE.match(operation(e.name))
             if found:
                 moving[id(e)] = found.group(1)
-                by_operation[found.group(1)] += e.end_ns - e.start_ns
+                if id(e) not in halves:     # a pair counts start to done
+                    by_operation[found.group(1)] += e.end_ns - e.start_ns
+        for start, done, kind, held in pairs:
+            moving[id(start)] = moving[id(done)] = kind
+            if not held:
+                by_operation[kind] += done.end_ns - start.start_ns
         nested = _self_times(ops)
         for e, self_ns, _ in nested:
             phase = phase_of(e.op_name)
@@ -363,8 +445,9 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
         total, _ = union_ns((e.start_ns, e.end_ns) for e in ops)
         busy += total
         window += max(e.end_ns for e in ops) - min(e.start_ns for e in ops)
-        coll, coll_merged = union_ns((e.start_ns, e.end_ns)
-                                     for e in ops + beside if id(e) in moving)
+        coll, coll_merged = union_ns(
+            [(e.start_ns, e.end_ns) for e in ops + beside if id(e) in moving]
+            + [(s.start_ns, d.end_ns) for s, d, _, _ in pairs])
         # what else ran: the operations that contain no other (a loop's
         # own event spans its body, collectives too) and move nothing
         _, other = union_ns(
@@ -372,6 +455,13 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
             if is_leaf and id(e) not in moving)
         collective += coll
         exposed += coll - _overlap_ns(coll_merged, other)
+        leaves = sorted((e for e, _, is_leaf in nested
+                         if is_leaf and id(e) not in moving),
+                        key=lambda e: e.start_ns)
+        for e, shared in zip(leaves, _overlaps(
+                ((e.start_ns, e.end_ns) for e in leaves), coll_merged)):
+            if shared:
+                beside_ns[_family(e.name)] += shared
 
     first = chips[min(chips)]
     _, merged = union_ns((e.start_ns, e.end_ns)
@@ -399,6 +489,8 @@ def reduce(planes: List[Plane], steps: Optional[int] = None) -> Optional[dict]:
         "collective_exposed_s": exposed / n / 1e9,
         "collective_in_other_s": unnamed / n / 1e9,
         "collectives_s": {k: v / n / 1e9 for k, v in by_operation.items()},
+        "collective_beside_s": {k: v / n / 1e9
+                                for k, v in beside_ns.most_common(8)},
         "idle_gaps_s": {k: v / 1e9 for k, v in idle.most_common()},
         "gaps": len(gaps),
         "host_spans": dict(collections.Counter(s[2] for s in spans)),
@@ -454,6 +546,10 @@ def table(result: dict) -> str:
                  f"{1e3 * result['collective_in_other_s'] / steps:.3f} ms "
                  + str({k: round(1e3 * v / steps, 3)
                         for k, v in result["collectives_s"].items()}))
+    if result.get("collective_beside_s"):
+        lines.append("  beside the collectives, ms a step: "
+                     + str({k: round(1e3 * v / steps, 3) for k, v
+                            in result["collective_beside_s"].items()}))
     lines.append(f"  idle gaps ({result['gaps']}) by host span, ms in all: "
                  + str({k: round(1e3 * v, 3)
                         for k, v in result["idle_gaps_s"].items()}))

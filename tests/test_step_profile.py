@@ -124,6 +124,9 @@ def test_the_reduction_of_a_made_up_profile():
     assert ns(r["collective_exposed_s"]) == 40
     assert {k: ns(v) for k, v in r["collectives_s"].items()} == {
         "all-reduce": 40, "all-gather": 50}
+    # beside them: the update's fusion, 340-380
+    assert {k: ns(v) for k, v in r["collective_beside_s"].items()} == {
+        "fusion": 40}
     # the gap's middle (450) lies in localize, inside the dispatch
     assert r["gaps"] == 1
     assert {k: ns(v) for k, v in r["idle_gaps_s"].items()} == {
@@ -131,6 +134,88 @@ def test_the_reduction_of_a_made_up_profile():
     assert r["host_spans"] == {timeline.DISPATCH: 3, "hvd.spmd.localize": 1}
     assert r["per_step_ms"]["collective_exposed"] == pytest.approx(20e-4)
     assert "exposed" in sp.table(r)
+
+
+EXCH = STEP + "hvd_update/hvd_exchange/hvd_allreduce_grads_float32_b0/psum:"
+UPD = STEP + "hvd_update/scale_by_adam/mul:"
+AR_START = ("%all-reduce-start.9 = (f32[8]{0}, f32[8]{0}) all-reduce-start("
+            "f32[8]{0} %p), channel_id=1")
+AR_DONE = ("%all-reduce-done.9 = f32[8]{0} all-reduce-done((f32[8]{0}, "
+           "f32[8]{0}) %all-reduce-start.9)")
+ACF_START = ("%async-collective-start.1 = (f32[8]{0}, u32[]{:S(2)}) fusion("
+             "f32[8]{0} %fusion.3), kind=kCustom, calls=%fused_computation.9")
+ACF_DONE = ("%async-collective-done.1 = f32[8]{0} fusion(f32[8]{0} %gte.1, "
+            "u32[]{:S(2)} %gte.2), kind=kCustom, calls=%fused_computation.10")
+STEP_FUSION = ("%fusion.77 = (f32[8]{0}, u32[]{:S(2)}) fusion(f32[8]{0} %a, "
+               "u32[]{:S(2)} %gte.2), kind=kLoop, calls=%fused_computation.11")
+
+
+def _async(ops, beside=()):
+    """One chip: 0-100 backward, then ``ops`` (from 100 on), an update
+    300-400; ``beside`` on ``Async XLA Ops``."""
+    return [sp.Plane("/device:TPU:0", [
+        sp.Line(sp.OPS_LINE, [_event(0, 100, op_name=BACK + "dense/mul:")]
+                + list(ops) + [_event(300, 400, op_name=UPD)]),
+        sp.Line(sp.ASYNC_LINE, list(beside))])]
+
+
+@pytest.mark.parametrize("ops, beside, collective, exposed, kinds", [
+    # the synchronous form: 100-300 alone on the core
+    ([_event(100, 300, ALL_REDUCE, op_name=EXCH)], [], 200, 200,
+     {"all-reduce": 200}),
+    # start 100-102 and done 260-300 on XLA Ops with two products of the
+    # backward pass 102-260 between them: 200 from start to done, of which
+    # the start and the done's wait are exposed
+    ([_event(100, 102, AR_START, op_name=EXCH),
+      _event(102, 180, op_name=BACK + "dense/dot_general:"),
+      _event(180, 260, op_name=BACK + "dense/dot_general:"),
+      _event(260, 300, AR_DONE, op_name=EXCH)], [], 200, 42,
+     {"all-reduce": 200}),
+    # the same with the span on Async XLA Ops: counted once
+    ([_event(100, 102, AR_START, op_name=EXCH),
+      _event(102, 260, op_name=BACK + "dense/dot_general:"),
+      _event(260, 300, AR_DONE, op_name=EXCH)],
+     [_event(100, 300, AR_START, op_name=EXCH)], 200, 42,
+     {"all-reduce": 200}),
+    # an asynchronous collective fusion: its start, two loop fusions that
+    # carry the all-reduce's steps beside Adam's passes (102-290: their own
+    # work), a gap 290-295 and the done 295-300
+    ([_event(100, 102, ACF_START, op_name=EXCH),
+      _event(102, 200, STEP_FUSION, op_name=UPD),
+      _event(200, 290, STEP_FUSION, op_name=UPD),
+      _event(295, 300, ACF_DONE, op_name=EXCH)], [], 200, 12,
+     {"all-reduce": 200}),
+    # a done whose start the profile does not hold stands alone
+    ([_event(260, 300, AR_DONE, op_name=EXCH)], [], 40, 40,
+     {"all-reduce": 40}),
+], ids=["synchronous", "start_done_on_xla_ops", "span_on_async_xla_ops",
+        "async_collective_fusion", "done_alone"])
+def test_asynchronous_all_reduces_read_start_to_done(ops, beside, collective,
+                                                     exposed, kinds):
+    r = sp.reduce(_async(ops, beside), steps=1)
+    ns = lambda s: round(s * 1e7, 3)
+    assert ns(r["collective_s"]) == collective
+    assert ns(r["collective_exposed_s"]) == exposed
+    assert {k: ns(v) for k, v in r["collectives_s"].items()} == kinds
+    # no operation is counted twice: the phases add up to the busy time
+    assert sum(r["phases_s"].values()) == pytest.approx(r["busy_s"])
+    assert "all-reduce" in sp.table(r)
+    # what ran beside is what was not exposed
+    assert ns(sum(r["collective_beside_s"].values())) == collective - exposed
+    assert ("beside the collectives" in sp.table(r)) == (exposed < collective)
+
+
+def test_an_async_collective_fusion_is_named_by_its_primitive():
+    gather = STEP + "hvd_forward/all_gather:"
+    r = sp.reduce(_async([
+        _event(100, 102, ACF_START),     # as the v5e writes it: no name
+        _event(102, 250, STEP_FUSION, op_name=UPD),
+        _event(250, 300, ACF_DONE, op_name=gather)]), steps=1)
+    assert list(r["collectives_s"]) == ["all-gather"]
+    unnamed = sp.reduce(_async([
+        _event(100, 102, ACF_START), _event(250, 300, ACF_DONE)]), steps=1)
+    assert list(unnamed["collectives_s"]) == ["async-collective"]
+    assert round(unnamed["collective_in_other_s"] * 1e7, 3) == 52
 
 
 def test_a_gap_outside_every_span_and_a_profile_with_no_chip():

@@ -244,6 +244,8 @@ def test_exchange_gauges_equal_the_bucket_plan_on_four_devices(
     # no byte copied into a flat buffer first
     assert got["hvd.exchange.calls"] == got["hvd.exchange.tensors"]
     assert got["hvd.exchange.packed_bytes"] == 0
+    # and what the handle says of its own compile: no option on the CPU
+    assert got.pop("hvd.spmd.compile_options") == 0
     assert set(got) == {"hvd.exchange." + what for what in (
         "calls", "bytes", "tensors", "buckets", "packed_bytes")}
 

@@ -11,10 +11,12 @@ then runs ``--steps`` steps with two of them ahead of the loss that is read
 ``horovod_tpu.utils.step_profile`` makes of the profile: device
 milliseconds a step in forward, backward, recomputed, loss, exchange, update
 and other (by the ``hvd_*`` scopes in each operation's ``op_name``),
-collective time and the part of it during which nothing else ran on the
-chip, the idle gaps by the ``hvd.*`` host span open at their middle, and the
-``hvd.attn.*`` / ``hvd.moe.*`` gauges of the step's program (attention calls
-by implementation, the kernels' blocks, the expert layers' rows).
+collective time (an asynchronous collective from its start to its done), the
+part of it during which nothing else ran on the chip and what ran beside the
+rest, the idle gaps by the ``hvd.*`` host span open at their middle, and the
+``hvd.attn.*`` / ``hvd.moe.*`` / ``hvd.spmd.*`` gauges of the step's program
+(attention calls by implementation, the kernels' blocks, the expert layers'
+rows, the compiler options the handle passed).
 The platform must be ``tpu`` (``HVD_TPU_FORCE_CPU=1`` runs the same code on
 a virtual CPU mesh, whose profile holds no chip: nothing is printed for it).
 """
@@ -100,8 +102,8 @@ def main():
                  if s["name"] == timeline.DISPATCH), "")
     said = {name: by_program[step]
             for name, by_program in sorted(snap["gauges"].items())
-            if step in by_program and name.startswith(("hvd.attn.",
-                                                       "hvd.moe."))}
+            if step in by_program and name.startswith(
+                ("hvd.attn.", "hvd.moe.", "hvd.spmd."))}
     if said:
         print(f"  gauges of {step}: {said}")
     if args.json:
