@@ -28,7 +28,7 @@ if os.environ.get("HVD_TPU_FORCE_CPU"):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                " --xla_force_host_platform_device_count=8").strip()
 
-LM_MODELS = ("transformer_lm", "moe_lm")
+LM_MODELS = ("transformer_lm", "moe_lm", "looped_lm")
 
 
 @dataclasses.dataclass
@@ -80,21 +80,26 @@ def resolve_attention(args) -> str:
 
     from horovod_tpu.ops.attention import attention_plan
 
-    heads = args.lm_heads
-    kv_heads, head_dim = heads, args.lm_dim // heads
-    if args.model == "moe_lm":
-        kv_heads = args.lm_kv_heads or heads
-        head_dim = args.lm_head_dim or head_dim
     return attention_plan(
-        args.seq_len, args.seq_len, heads, kv_heads, head_dim,
+        args.seq_len, args.seq_len, args.lm_heads, **grouped_heads(args),
         dtype=jnp.float32 if args.fp32 else jnp.bfloat16).impl
 
 
+def grouped_heads(args) -> dict:
+    """KV heads and the size of a head, for every family: as many KV heads
+    as query heads and ``--lm-dim / --lm-heads`` where nothing else is
+    said."""
+    return dict(kv_heads=args.lm_kv_heads or args.lm_heads,
+                head_dim=args.lm_head_dim or args.lm_dim // args.lm_heads)
+
+
 def lm_model_args(args, attention: str) -> dict:
-    """What ``models.build`` takes for ``--model transformer_lm`` and for
-    ``--model moe_lm`` (models/decoder.py: the arguments say which experts
-    this chip holds and how large its slice of the vocabulary is; the router
-    keeps every expert's output and its top ``k``)."""
+    """What ``models.build`` takes for ``--model transformer_lm``, for
+    ``--model looped_lm`` (models/decoder.py: ``--lm-layers`` blocks applied
+    ``--lm-loops`` times) and for ``--model moe_lm`` (models/decoder.py: the
+    arguments say which experts this chip holds and how large its slice of
+    the vocabulary is; the router keeps every expert's output and its top
+    ``k``)."""
     if args.model == "transformer_lm":
         from horovod_tpu.ops.attention import attend
 
@@ -102,6 +107,13 @@ def lm_model_args(args, attention: str) -> dict:
             num_layers=args.lm_layers, num_heads=args.lm_heads,
             embed_dim=args.lm_dim, max_len=max(args.seq_len, 2048),
             attn_fn=functools.partial(attend, impl=attention))
+    if args.model == "looped_lm":
+        return dict(
+            embed_dim=args.lm_dim, num_layers=args.lm_layers,
+            loops=args.lm_loops, heads=args.lm_heads,
+            ffn_width=args.lm_ffn or 4 * args.lm_dim,
+            rope_base=args.lm_rope_base, exit_beta=args.lm_exit_beta,
+            attention=attention, **grouped_heads(args))
     from horovod_tpu.models import decoder
 
     kinds = {"sliding": decoder.SLIDING, "full": decoder.FULL}
@@ -120,9 +132,8 @@ def lm_model_args(args, attention: str) -> dict:
     return dict(
         embed_dim=args.lm_dim,
         layer_types=tuple(kinds[k] for k in names), heads=args.lm_heads,
-        kv_heads=args.lm_kv_heads or args.lm_heads,
-        head_dim=args.lm_head_dim or args.lm_dim // args.lm_heads,
-        window=args.lm_window, dense_layers=args.lm_dense_layers,
+        window=args.lm_window, rope_base=args.lm_rope_base,
+        **grouped_heads(args), dense_layers=args.lm_dense_layers,
         dense_width=args.lm_ffn or 4 * args.lm_dim,
         experts=args.moe_experts, experts_held=held,
         first_expert=args.moe_first_expert, top_k=args.moe_top_k,
@@ -228,14 +239,26 @@ def build_parser():
     parser.add_argument("--lm-layers", type=int, default=12)
     parser.add_argument("--lm-dim", type=int, default=768)
     parser.add_argument("--lm-heads", type=int, default=12)
-    # --model moe_lm (models/decoder.py): grouped-query attention with a
-    # type a layer, gated feed-forwards, and this chip's share of the
-    # experts. Widths are the model's; what is held here may be a share.
+    # --model moe_lm and looped_lm (models/decoder.py): grouped-query
+    # attention, gated feed-forwards; moe_lm has a type a layer and this
+    # chip's share of the experts, looped_lm applies its layers several
+    # times. Widths are the model's; what is held here may be a share.
     parser.add_argument("--lm-kv-heads", type=int, default=None,
-                        help="moe_lm: KV heads (default: --lm-heads)")
+                        help="moe_lm, looped_lm: KV heads (default: "
+                             "--lm-heads)")
     parser.add_argument("--lm-head-dim", type=int, default=None,
-                        help="moe_lm: size of a head (default: "
+                        help="moe_lm, looped_lm: size of a head (default: "
                              "--lm-dim / --lm-heads)")
+    parser.add_argument("--lm-rope-base", type=float, default=10000.0,
+                        help="moe_lm, looped_lm: base of the rotary "
+                             "positions")
+    parser.add_argument("--lm-loops", type=int, default=4,
+                        help="looped_lm: times the --lm-layers blocks are "
+                             "applied, with the same weights; an exit "
+                             "after each")
+    parser.add_argument("--lm-exit-beta", type=float, default=0.1,
+                        help="looped_lm: weight of the exit distribution's "
+                             "entropy in the loss")
     parser.add_argument("--lm-window", type=int, default=2048,
                         help="moe_lm: window of a sliding layer")
     parser.add_argument("--lm-layer-types", default=None,
@@ -243,8 +266,8 @@ def build_parser():
                              "or 'full' (no positional encoding) a layer, "
                              "comma-separated (default: all full)")
     parser.add_argument("--lm-ffn", type=int, default=None,
-                        help="moe_lm: width of a dense layer's gated "
-                             "feed-forward (default: 4 x --lm-dim)")
+                        help="moe_lm, looped_lm: width of a dense layer's "
+                             "gated feed-forward (default: 4 x --lm-dim)")
     parser.add_argument("--lm-dense-layers", type=int, default=1,
                         help="moe_lm: leading layers with a dense "
                              "feed-forward; the others have experts")

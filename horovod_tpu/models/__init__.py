@@ -29,7 +29,7 @@ from horovod_tpu.models.train import (
     state_partition_specs,
 )
 from horovod_tpu.models import decoder, parallel_lm
-from horovod_tpu.models.decoder import SparseDecoderLM
+from horovod_tpu.models.decoder import LoopedDecoderLM, SparseDecoderLM
 from horovod_tpu.models.transformer import TransformerBlock, TransformerLM
 from horovod_tpu.models.vgg import VGG, VGG11, VGG13, VGG16, VGG19
 from horovod_tpu.models.vit import ViT_B16, ViT_S16, VisionTransformer
@@ -44,6 +44,7 @@ _FAMILY.update({
     "inception3": InceptionV3,
     "transformer_lm": TransformerLM,
     "moe_lm": SparseDecoderLM,
+    "looped_lm": LoopedDecoderLM,
     "vit_s16": ViT_S16,
     "vit_b16": ViT_B16,
 })
@@ -77,6 +78,7 @@ __all__ = [
     "TransformerBlock",
     "TransformerLM",
     "SparseDecoderLM",
+    "LoopedDecoderLM",
     "decoder",
     "VisionTransformer",
     "ViT_S16",

@@ -1,18 +1,24 @@
-"""The pieces of today's decoder blocks, written once, and a sparse decoder
-LM made of them.
+"""The pieces of today's decoder blocks, written once, and two decoder LMs
+made of them: a sparse one and a looped one.
 
 * :class:`RMSNorm`; :func:`rotary` (the half-split pairing of
   ``rotate_half``); :class:`GatedMLP` (SwiGLU);
-* :class:`GroupedAttention`: ``H`` query heads over ``G`` KV heads, an RMS
-  norm a head on queries and keys, a sigmoid gate on the output, and a type
-  a layer: a ``window`` with rotary positions, or full causal attention with
-  no positional encoding at all;
+* :class:`GroupedAttention`: ``H`` query heads over ``G`` KV heads, causal,
+  under a ``window`` or over the whole sequence, and three choices that are
+  the layer's own: rotary positions, an RMS norm a head on queries and keys,
+  a sigmoid gate on the output;
 * :class:`SparseExperts`: a router over all ``E`` experts, this chip's
   ``held`` of them (:mod:`horovod_tpu.parallel.moe`: top ``k`` of sigmoid
   scores plus a selection bias, nothing dropped) and a shared expert every
   token passes;
 * :class:`DecoderBlock`: an RMS norm before **and after** each branch;
-* :class:`SparseDecoderLM`: leading dense layers, then expert layers.
+* :class:`SparseDecoderLM`: leading dense layers, then expert layers
+  (``afmoe``: a window layer rotates, a full layer has no positional
+  encoding at all; every layer has the q/k norms and the gate);
+* :class:`LoopedDecoderLM`: a stack of blocks declared once and applied
+  ``loops`` times with the same weights, an exit after each application
+  (``ouro``: full attention with rotary positions, no q/k norm, no gate), and
+  its loss: :func:`exit_log_distribution`, :func:`exit_loss`.
 
 The selection bias is state and no parameter: it lives in the collection
 ``buffers`` beside each layer's ``expert_counts`` (how many tokens of the
@@ -82,7 +88,7 @@ class GatedMLP(nn.Module):
 
 
 class GroupedAttention(nn.Module):
-    """Gated grouped-query attention of one layer type (module docstring).
+    """Grouped-query causal attention of one layer (module docstring).
     ``attention``: ``"flash"`` (the Pallas kernels, which know the window
     and the grouping), ``"dense"`` (the masked reference) or None: what
     ``ops.attention.attention_plan`` picks for the shapes."""
@@ -90,7 +96,10 @@ class GroupedAttention(nn.Module):
     heads: int
     kv_heads: int
     head_dim: int
-    window: Optional[int] = None        # None: full attention, no rotary
+    window: Optional[int] = None        # None: the whole sequence
+    rotary: bool = True                 # q and k rotated by position
+    qk_norm: bool = False               # an RMS norm a head on q and on k
+    gate: bool = False                  # o = (P v) * sigmoid(x W_gate)
     eps: float = 1e-5
     rope_base: float = 10000.0
     attention: Optional[str] = None
@@ -106,12 +115,15 @@ class GroupedAttention(nn.Module):
                          name=name)(x)
             return y.reshape(b, length, heads, d)
 
-        q = RMSNorm(self.eps, name="q_norm")(project(h, "q"))
-        k = RMSNorm(self.eps, name="k_norm")(project(g, "k"))
+        q, k = project(h, "q"), project(g, "k")
+        if self.qk_norm:
+            q = RMSNorm(self.eps, name="q_norm")(q)
+            k = RMSNorm(self.eps, name="k_norm")(k)
         v = project(g, "v")
-        gate = nn.Dense(h * d, use_bias=False, dtype=self.dtype,
-                        name="gate")(x)
-        if self.window is not None:
+        if self.gate:
+            gate = nn.Dense(h * d, use_bias=False, dtype=self.dtype,
+                            name="gate")(x)
+        if self.rotary:
             q, k = rotary(q, self.rope_base), rotary(k, self.rope_base)
         q, k = q.astype(self.dtype), k.astype(self.dtype)
         program, _ = timeline.tracing_program()
@@ -122,7 +134,9 @@ class GroupedAttention(nn.Module):
                  else timeline.ATTN_WINDOW)
         with jax.named_scope(scope):
             out = attend(q, k, v, window=self.window, impl=self.attention)
-        out = out.reshape(b, length, h * d) * nn.sigmoid(gate)
+        out = out.reshape(b, length, h * d)
+        if self.gate:
+            out = out * nn.sigmoid(gate)
         return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
                         name="out")(out)
 
@@ -245,6 +259,7 @@ class SparseDecoderLM(nn.Module):
             attn = dict(heads=self.heads, kv_heads=self.kv_heads,
                         head_dim=self.head_dim, rope_base=self.rope_base,
                         window=self.window if kind == SLIDING else None,
+                        rotary=kind == SLIDING, qk_norm=True, gate=True,
                         attention=self.attention)
             sparse = None if i < self.dense_layers else dict(
                 experts=self.experts, experts_held=self.experts_held,
@@ -258,6 +273,131 @@ class SparseDecoderLM(nn.Module):
             return h
         return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(h)
+
+
+# Block applications a looped model's forward pass has traced into each
+# program: program -> (id of its ``hvd.spmd.dispatch`` span, [count]). A
+# re-trace starts anew.
+_applied: dict = {}
+
+
+class LoopedDecoderLM(nn.Module):
+    """``num_layers`` dense :class:`DecoderBlock` declared once and applied
+    ``loops`` times with the same weights (every weight is one leaf of the
+    parameters; its gradient is the sum over its uses). After each
+    application the final norm gives the step's exit state ``x_t``, which is
+    also the next application's input; one gate ``sigmoid(x_t w + b)`` a
+    token says how much of what is left exits there.
+
+    Token ids ``[B, L]`` -> the last exit's float32 logits ``[B, L, vocab]``
+    (what a server that never exits early reads), or with ``return_hidden``
+    ``(exits [loops, B, L, embed_dim], gate_logits [loops, B, L])`` in
+    float32 for :func:`exit_loss`: the logits of all the exits never exist
+    as one tensor. ``exit_beta`` is the loss's weight on the exit
+    distribution's entropy; a step that finds it takes :func:`exit_loss`
+    (``models.make_lm_train_step``). ``remat`` recomputes each block
+    application in the backward pass, as :class:`SparseDecoderLM` does."""
+
+    vocab_size: int
+    embed_dim: int
+    num_layers: int
+    loops: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    ffn_width: int
+    exit_beta: float = 0.1
+    eps: float = 1e-6
+    rope_base: float = 1e6
+    attention: Optional[str] = None
+    dtype: Any = jnp.bfloat16
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = True,
+                 return_hidden: bool = False):
+        del train                                   # no dropout anywhere
+        h = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype,
+                     name="embed")(tokens)
+        block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
+        attn = dict(heads=self.heads, kv_heads=self.kv_heads,
+                    head_dim=self.head_dim, rope_base=self.rope_base,
+                    attention=self.attention)
+        blocks = [block(attn, self.ffn_width, None, self.eps, self.dtype,
+                        name=f"DecoderBlock_{i}")
+                  for i in range(self.num_layers)]
+        final_norm = RMSNorm(self.eps, name="final_norm")
+        # one output a token: at full precision it costs nothing, and the
+        # exit distribution is read off it
+        exit_gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate",
+                             precision=jax.lax.Precision.HIGHEST)
+        program, applied = timeline.program_tally(_applied, lambda: [0])
+        exits, gates = [], []
+        for _ in range(self.loops):
+            with jax.named_scope(timeline.LOOP_STEP):
+                for apply_block in blocks:
+                    h = apply_block(h)
+                    applied[0] += 1
+                x = final_norm(h)
+            with jax.named_scope(timeline.EXIT_GATE):
+                gates.append(exit_gate(x)[..., 0])
+            exits.append(x)
+            h = x.astype(self.dtype)
+        timeline.gauge("hvd.loop.applications", applied[0], key=program)
+        if return_hidden:
+            return jnp.stack(exits), jnp.stack(gates)
+        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                        name="lm_head")(exits[-1])
+
+
+def exit_log_distribution(gate_logits):
+    """``log p [T, ...]`` from the gates' logits ``z [T, ...]``: with
+    ``lambda_t = sigmoid(z_t)``, ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``
+    for ``t < T`` and the last step takes what is left, ``p_T = prod_{j<T}
+    (1 - lambda_j)`` (its own gate is not read), so the ``T`` sum to 1. In
+    logarithms, so that a gate that saturates gives no ``log 0``."""
+    z = gate_logits.astype(jnp.float32)
+    # log prod_{j<t} (1 - lambda_j) for t = 1..T: nothing before the first
+    stayed = jnp.concatenate(
+        [jnp.zeros_like(z[:1]), jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), 0)], 0)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(z[:-1]) + stayed[:-1], stayed[-1:]], 0)
+
+
+def exit_loss(exits, gate_logits, head, tokens, beta: float,
+              t_chunk: int = 512):
+    """The looped model's training loss, a scalar: the mean over the ``B x
+    (L - 1)`` next-token positions of ``sum_t p_t nll_t - beta H(p)``, with
+    ``nll_t`` the negative log-likelihood of the next token under exit
+    ``t``'s logits ``x_t W_head``, ``p`` the token's exit distribution
+    (:func:`exit_log_distribution`) and ``H(p) = -sum_t p_t log p_t``.
+
+    ``exits [T, B, L, E]``, ``gate_logits [T, B, L]``, ``head [E, vocab]``,
+    ``tokens [B, L]``. Every exit's per-token loss comes from one pass of
+    ``ops.xent.token_nll`` over the ``T x B x (L - 1)`` rows, which holds
+    ``t_chunk`` rows of float32 logits at a time (the gauge
+    ``hvd.exit.live_logits_bytes``) and is differentiable into the exit
+    states and the head; the weighting by ``p`` is plain arithmetic, so
+    gradients reach the gate through it."""
+    from horovod_tpu.ops.xent import token_nll
+
+    loops, _, _, e = exits.shape
+    rows = exits[:, :, :-1].reshape(-1, e)
+    program, _ = timeline.tracing_program()
+    timeline.gauge("hvd.exit.live_logits_bytes",
+                   4 * min(t_chunk, rows.shape[0]) * head.shape[-1],
+                   key=program)
+    with jax.named_scope(timeline.EXIT_LOSS):
+        nll = token_nll(rows.astype(jnp.float32), head.astype(jnp.float32),
+                        jnp.tile(tokens[:, 1:].reshape(-1), loops),
+                        t_chunk).reshape(loops, -1)
+    with jax.named_scope(timeline.EXIT_GATE):
+        log_p = exit_log_distribution(
+            gate_logits[:, :, :-1].reshape(loops, -1))
+        p = jnp.exp(log_p)
+        expected = jnp.sum(p * nll, 0)
+        entropy = -jnp.sum(p * log_p, 0)
+    return jnp.mean(expected - beta * entropy)
 
 
 def update_buffers(buffers, coeff: float, axis: Optional[str] = None):

@@ -246,11 +246,20 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, *,
     loss)``: the mean next-token cross-entropy of this rank's shard, in
     float32, a scalar. ``fused_ce`` takes the loss through
     :func:`horovod_tpu.ops.xent.fused_cross_entropy`, so the ``[B, L, vocab]``
-    float32 logits never exist. A model that keeps ``buffers`` (a sparse
+    float32 logits never exist. A model with exits (``exit_beta``: the looped
+    LM of :mod:`horovod_tpu.models.decoder`) has one loss, the exit-weighted
+    :func:`~horovod_tpu.models.decoder.exit_loss` over the exit states and
+    gate logits it hands out, which is chunked by itself. A model that keeps
+    ``buffers`` (a sparse
     layer's selection bias, :mod:`horovod_tpu.models.decoder`) has them read
     by the forward pass, which writes the step's expert counts beside them;
     ``bias_coeff`` is the step of the balancing rule that then moves the bias.
     """
+
+    exit_beta = getattr(model, "exit_beta", None)
+    if exit_beta is not None and fused_ce:
+        raise ValueError("fused_ce chooses between two losses of a one-exit "
+                         "LM; the exit loss is chunked already")
 
     # the name is the handle's in the spans and the profile (``step_fn#n``,
     # ``jit_step_fn``), as the lanes have recorded it so far
@@ -269,7 +278,17 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, *,
                 mutable=["buffers"], **kw)
             return out, wrote["buffers"]
 
-        if fused_ce:
+        if exit_beta is not None:
+            from horovod_tpu.models import decoder
+
+            def loss_fn(params):
+                with jax.named_scope(timeline.FORWARD):
+                    (exits, gates), wrote = apply(params, return_hidden=True)
+                with jax.named_scope(timeline.LOSS):
+                    return decoder.exit_loss(
+                        exits, gates, params["lm_head"]["kernel"], tokens,
+                        exit_beta), wrote
+        elif fused_ce:
             # Chunked fused loss (ops/xent.py): the [B, L, vocab] fp32
             # logits tensor — the step's largest single HBM sink —
             # never materializes; the vocab projection's gradient comes

@@ -5,10 +5,11 @@ A causal-LM training step at GPT-2-small scale (16k tokens/chip, vocab
 32k) writes a [T, V] fp32 logits tensor of ~2 GB, reads it for
 log-softmax, and touches it again on the backward — on a chip whose
 step is HBM-bound, the loss head alone is ~a third of the traffic
-(PERF.md). This op computes
+(PERF.md). :func:`token_nll` computes every token's
 
-    sum over tokens of  weight_i * -log softmax(h @ w)[target_i] / denom
+    -log softmax(h @ w)[target_i]
 
+and :func:`fused_cross_entropy` their weighted sum over ``denom``,
 by ``lax.scan`` over TOKEN chunks: each step computes one
 [t_chunk, V] logits block, reduces it to per-token (logsumexp,
 target-logit) immediately, and lets XLA recycle the block — peak live
@@ -18,10 +19,14 @@ round-trips HBM. The backward recomputes each chunk's logits
 memory-bound step) and accumulates ``dw`` in an fp32 scan carry while
 streaming ``dh`` out per chunk.
 
-``weights``/``denom`` exist for sharded callers: a sequence-parallel
+``weights``/``denom`` serve two kinds of caller. A sequence-parallel
 loss passes per-token validity weights and the GLOBAL (psum'd) token
 count so that summing the per-shard results reproduces the dense mean
-exactly (models/parallel_lm.py:next_token_nll_fused).
+exactly (models/parallel_lm.py:next_token_nll_fused). A loss that
+weighs each token by something learned (the looped LM's exit
+distribution over its exits, models/decoder.py:exit_loss) takes the
+per-token result and weighs it itself: the weighting is arithmetic
+outside the chunked scan, so it is differentiable like any operand.
 ``tp_vocab_cross_entropy`` is the Megatron-style variant for a head
 sharded [E, V/tp] over a mesh axis.
 
@@ -30,10 +35,14 @@ altogether — SURVEY §5 long-context); this is TPU-first perf work in
 the spirit of its fusion buffer: restructure the computation so the
 interconnect — here HBM — moves as few bytes as the math allows.
 
-Exactness (loss AND both gradients) vs the dense composition is pinned
-in tests/test_xent.py; ``models.make_lm_train_step(fused_ce=True)``
-(``bench.py --fused-ce``) is the step that runs it. In no cell of the
-benchmark yet: not measured on this machine (ROADMAP D15).
+Exactness (loss AND every gradient: hidden state, head, weighting) vs
+the dense composition is pinned in tests/test_xent.py. Two steps run
+it: ``models.make_lm_train_step`` takes a looped LM's exit loss
+through :func:`token_nll` always (the benchmark's cell
+``ouro_seq4096_1chip``: four exits over 49,152 columns cannot be held
+as logits), and a one-exit LM's through :func:`fused_cross_entropy`
+with ``fused_ce=True`` (``bench.py --fused-ce``; in no cell, ROADMAP
+D15).
 """
 
 from __future__ import annotations
@@ -46,16 +55,16 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _pad_all(h, targets, weights, t_chunk):
-    """Pad the token axis to a multiple of t_chunk; padded rows carry
-    weight 0 and target 0 (any valid index)."""
-    t = h.shape[0]
-    pad = (-t) % t_chunk
-    if pad:
-        h = jnp.pad(h, ((0, pad), (0, 0)))
-        targets = jnp.pad(targets, (0, pad))
-        weights = jnp.pad(weights, (0, pad))
-    return h, targets, weights
+def _pad_rows(t_chunk, *arrays):
+    """Pad the token axis of each array to a multiple of ``t_chunk`` (zero
+    rows, target 0: any valid index) and cut it into chunks."""
+    pad = (-arrays[0].shape[0]) % t_chunk
+    out = []
+    for a in arrays:
+        if pad:
+            a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        out.append(a.reshape((-1, t_chunk) + a.shape[1:]))
+    return out
 
 
 def _fill_defaults(h, weights, denom):
@@ -85,80 +94,62 @@ def fused_cross_entropy(h, w, targets, t_chunk: int = 512,
     give the plain mean NLL; sharded callers pass validity weights and
     a globally-reduced denom (module docstring).
 
-    ``weights`` and ``denom`` are NON-DIFFERENTIABLE bookkeeping
-    (validity masks, token counts): they are passed through
-    ``stop_gradient`` at entry, so differentiating w.r.t. a learnable
-    per-token weighting yields zeros by contract, not by accident. Use
-    an explicit elementwise product outside this op if you need
-    gradients through a weighting.
+    The chunked part is :func:`token_nll`; ``weights`` and ``denom``
+    meet its per-token result in plain arithmetic, so a learned
+    per-token weighting gets its gradient like any other operand (the
+    looped LM's exit distribution, ``models.decoder.exit_loss``).
     """
     weights, denom = _fill_defaults(h, weights, denom)
-    weights = lax.stop_gradient(weights)
-    denom = lax.stop_gradient(denom)
-    return _fce(h, w, targets, weights, denom, t_chunk)
+    return jnp.sum(token_nll(h, w, targets, t_chunk) * weights) / denom
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _fce(h, w, targets, weights, denom, t_chunk):
-    loss, _ = _fce_fwd(h, w, targets, weights, denom, t_chunk)
-    return loss
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def token_nll(h, w, targets, t_chunk: int = 512):
+    """``-log softmax(h @ w)[target]`` of every token, ``[T]`` fp32, holding
+    one ``[t_chunk, V]`` block of logits at a time; differentiable into
+    ``h`` and ``w`` (the backward pass recomputes each block)."""
+    nll, _ = _token_nll_fwd(h, w, targets, t_chunk)
+    return nll
 
 
-def _chunked(h, targets, weights, t_chunk):
-    hp, tp_, wp = _pad_all(h, targets, weights, t_chunk)
-    n = hp.shape[0] // t_chunk
-    return (hp.reshape(n, t_chunk, h.shape[1]), tp_.reshape(n, t_chunk),
-            wp.reshape(n, t_chunk))
+def _token_nll_fwd(h, w, targets, t_chunk):
+    hcs, tcs = _pad_rows(t_chunk, h, targets)
+
+    def step(_, xs):
+        lse, tgt = _chunk_stats(xs[0], w, xs[1])
+        return None, lse - tgt
+
+    _, nll = lax.scan(step, None, (hcs, tcs))
+    return nll.reshape(-1)[:h.shape[0]], (h, w, targets)
 
 
-def _fce_fwd(h, w, targets, weights, denom, t_chunk):
+def _token_nll_bwd(t_chunk, res, g):
     from horovod_tpu.parallel._vma import match_vma
 
-    hcs, tcs, wcs = _chunked(h, targets, weights, t_chunk)
-
-    def step(acc, xs):
-        hc, tc, wc = xs
-        lse, tgt = _chunk_stats(hc, w, tc)
-        return acc + jnp.sum((lse - tgt) * wc), None
-
-    # Scan carries must be vma-typed like the body's output (e.g. a
-    # sequence-parallel caller passes sp-varying h/targets/weights).
-    acc0 = match_vma(jnp.float32(0.0), h, w, targets, weights)
-    total, _ = lax.scan(step, acc0, (hcs, tcs, wcs))
-    return total / denom, (h, w, targets, weights, denom)
-
-
-def _fce_bwd(t_chunk, res, g):
-    from horovod_tpu.parallel._vma import match_vma
-
-    h, w, targets, weights, denom = res
-    hcs, tcs, wcs = _chunked(h, targets, weights, t_chunk)
-    e = h.shape[1]
-    scale = g / denom
+    h, w, targets = res
+    hcs, tcs, gcs = _pad_rows(t_chunk, h, targets, g.astype(jnp.float32))
 
     def step(dw_acc, xs):
-        hc, tc, wc = xs
+        hc, tc, gc = xs
         logits = jnp.dot(hc, w, preferred_element_type=jnp.float32)
         p = jax.nn.softmax(logits, axis=-1)
         onehot = jax.nn.one_hot(tc, w.shape[1], dtype=jnp.float32)
-        dl = (p - onehot) * (wc * scale)[:, None]  # [t_chunk, V] fp32
+        dl = (p - onehot) * gc[:, None]             # [t_chunk, V] fp32
         dh_c = jnp.dot(dl, w.T.astype(jnp.float32),
                        preferred_element_type=jnp.float32)
         dw_acc = dw_acc + jnp.dot(hc.astype(jnp.float32).T, dl,
                                   preferred_element_type=jnp.float32)
         return dw_acc, dh_c
 
-    dw0 = match_vma(jnp.zeros(w.shape, jnp.float32),
-                    h, w, targets, weights, denom, g)
-    dw, dhs = lax.scan(step, dw0, (hcs, tcs, wcs))
-    dh = dhs.reshape(-1, e)[:h.shape[0]]
-    # weights/denom carry data-independent bookkeeping (validity masks,
-    # token counts): their true gradients are not needed by any caller.
-    return (dh.astype(h.dtype), dw.astype(w.dtype), None,
-            jnp.zeros_like(weights), jnp.zeros_like(denom))
+    # Scan carries must be vma-typed like the body's output (e.g. a
+    # sequence-parallel caller passes sp-varying h/targets/weights).
+    dw0 = match_vma(jnp.zeros(w.shape, jnp.float32), h, w, targets, g)
+    dw, dhs = lax.scan(step, dw0, (hcs, tcs, gcs))
+    dh = dhs.reshape(-1, h.shape[1])[:h.shape[0]]
+    return dh.astype(h.dtype), dw.astype(w.dtype), None
 
 
-_fce.defvjp(_fce_fwd, _fce_bwd)
+token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
 # --------------------------------------------------------------------------
@@ -230,7 +221,7 @@ def _typed_zero(shape_like, vma):
 
 
 def _vp_fwd(h, w_local, targets, weights, denom, axis, t_chunk):
-    hcs, tcs, wcs = _chunked(h, targets, weights, t_chunk)
+    hcs, tcs, wcs = _pad_rows(t_chunk, h, targets, weights)
     v_local = w_local.shape[1]
 
     def step(acc, xs):
@@ -248,7 +239,7 @@ def _vp_fwd(h, w_local, targets, weights, denom, axis, t_chunk):
 
 def _vp_bwd(axis, t_chunk, res, g):
     h, w_local, targets, weights, denom = res
-    hcs, tcs, wcs = _chunked(h, targets, weights, t_chunk)
+    hcs, tcs, wcs = _pad_rows(t_chunk, h, targets, weights)
     e = h.shape[1]
     v_local = w_local.shape[1]
     scale = g / denom

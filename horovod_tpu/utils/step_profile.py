@@ -17,11 +17,12 @@ session writes and returns, for the training steps it holds:
   operation that contains others (a loop, a call) gives its time to them.
 * **layers**: the same self times by the layer scopes inside ``hvd_forward``
   (``timeline.LAYER_SCOPES``: attention by the layer's type, the parts of a
-  sparse expert layer), forward, recomputed and backward together; the
-  innermost name wins. Empty for a model that names no layer. The grouped
-  products of an expert layer (``%ragged-dot-*`` instructions, XLA:TPU's own
-  lowering, which keeps no ``op_name``) count as ``hvd_moe_experts`` here
-  and, having no phase, as ``other`` above.
+  sparse expert layer, a looped model's applications of its stack and its
+  exit gate; its chunked exit loss lies inside ``hvd_loss``), forward,
+  recomputed and backward together; the innermost name wins. Empty for a model
+  that names no layer. The grouped products of an expert layer (``%ragged-
+  dot-*`` instructions, XLA:TPU's own lowering, which keeps no ``op_name``)
+  count as ``hvd_moe_experts`` here and, having no phase, as ``other`` above.
 * **collectives**: time of all-reduce, reduce-scatter, all-gather,
   all-to-all and collective-permute events on the lines ``XLA Ops`` and
   ``Async XLA Ops`` (start to done), the part of it that is **exposed**

@@ -104,7 +104,9 @@ EXCHANGE = "hvd_exchange"   # fused_reduce of the gradients
 UPDATE = "hvd_update"       # the inner optimizer's update, apply_updates
 METRICS = "hvd_metrics"     # accuracy, the loss all-reduce, the read-out
 # Layers inside ``hvd_forward`` (and so inside its backward): attention by
-# the layer's type, and the parts of a sparse expert layer.
+# the layer's type, the parts of a sparse expert layer, and a looped model's
+# applications of its stack and exit gate; ``hvd_exit_loss`` lies inside
+# ``hvd_loss``.
 ATTN_WINDOW = "hvd_attn_window"     # attention of a sliding-window layer
 ATTN_FULL = "hvd_attn_full"         # attention of a full (causal) layer
 MOE_ROUTE = "hvd_moe_route"         # scores, top k, weights, counts
@@ -112,8 +114,11 @@ MOE_DISPATCH = "hvd_moe_dispatch"   # sort by expert, gather the rows
 MOE_EXPERTS = "hvd_moe_experts"     # the grouped matrix products
 MOE_COMBINE = "hvd_moe_combine"     # rows back to tokens, weighted sum
 MOE_SHARED = "hvd_moe_shared"       # the shared expert every token passes
+LOOP_STEP = "hvd_loop_step"     # one application of a looped model's stack
+EXIT_GATE = "hvd_exit_gate"     # the exit gate, and the exit distribution
+EXIT_LOSS = "hvd_exit_loss"     # every exit's per-token loss, in chunks
 LAYER_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
-                MOE_COMBINE, MOE_SHARED)
+                MOE_COMBINE, MOE_SHARED, LOOP_STEP, EXIT_GATE, EXIT_LOSS)
 RING = 8192     # records kept: a 10 s window of 46 ms steps is 217 of them
 
 _lock = threading.Lock()
@@ -346,6 +351,9 @@ def reset() -> None:
         _counters.clear()
         _gauges.clear()
         _dropped.update(dict.fromkeys(_dropped, 0))
+    # a trace this thread finished with nothing lowered after it still waits
+    # (``_flush_traces``): forgotten too, or it surfaces after the reset
+    getattr(_local, "traces", []).clear()
 
 
 # --------------------------------------------------------- Chrome writer

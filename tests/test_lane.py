@@ -47,6 +47,11 @@ TOY = {
                "8", "--moe-experts-held", "4", "--moe-top-k", "2",
                "--moe-width", "32", "--vocab", "128", "--batch-size", "2",
                "--seq-len", "32", "--remat"],
+    "looped_lm": ["--model", "looped_lm", "--lm-layers", "2", "--lm-loops",
+                  "3", "--lm-dim", "64", "--lm-heads", "4", "--lm-kv-heads",
+                  "2", "--lm-head-dim", "16", "--lm-ffn", "96",
+                  "--lm-rope-base", "1000000.0", "--vocab", "128",
+                  "--batch-size", "2", "--seq-len", "32", "--remat"],
 }
 
 
@@ -83,7 +88,7 @@ def test_removed_arguments_are_usage_errors(bench, capsys):
             parser.parse_args(argv)
         assert stop.value.code == 2, argv
         assert "unrecognized arguments" in capsys.readouterr().err, argv
-    assert len(parser._actions) - 1 == 27           # less --help
+    assert len(parser._actions) - 1 == 30           # less --help
 
 
 def _spans(name=None):
@@ -115,6 +120,35 @@ def test_lane_builds_and_steps(hvd, bench, family):
     assert first.is_deleted()                       # the state was donated
     assert int(state["step"]) == 2
     assert np.isfinite(losses).all() and losses[1] < losses[0], losses
+
+
+@pytest.mark.parametrize("argv, heads", [
+    (["--model", "transformer_lm", "--lm-dim", "1024", "--lm-heads", "16",
+      "--seq-len", "1024"], (16, 16, 64)),
+    (["--model", "moe_lm", "--lm-dim", "2048", "--lm-heads", "32",
+      "--lm-kv-heads", "4", "--lm-head-dim", "128", "--seq-len", "4096"],
+     (32, 4, 128)),
+    (["--model", "looped_lm", "--lm-dim", "2048", "--lm-heads", "16",
+      "--lm-kv-heads", "16", "--lm-head-dim", "128", "--seq-len", "4096"],
+     (16, 16, 128)),
+    (["--model", "looped_lm", "--lm-dim", "64", "--lm-heads", "4",
+      "--seq-len", "2048"], (4, 4, 16))])
+def test_the_attention_policy_is_asked_with_each_familys_heads(
+        bench, monkeypatch, argv, heads):
+    """``resolve_attention`` hands ``attention_plan`` KV heads and the size of
+    a head for every family that has them, and the defaults elsewhere."""
+    from horovod_tpu.ops import attention
+
+    asked = []
+
+    def plan(seq_q, seq_k, heads, kv_heads, head_dim, **kw):
+        asked.append((seq_q, seq_k, heads, kv_heads, head_dim))
+        return attention.AttentionPlan("dense", None, None, "pallas")
+
+    monkeypatch.setattr(attention, "attention_plan", plan)
+    args = bench.build_parser().parse_args(argv)
+    assert bench.resolve_attention(args) == "dense"
+    assert asked == [(args.seq_len, args.seq_len) + heads]
 
 
 def _lm(fused_ce):

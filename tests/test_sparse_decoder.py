@@ -282,6 +282,80 @@ def test_a_window_needs_the_plain_causal_square():
                         causal=True)
 
 
+AFMOE = dict(qk_norm=True, gate=True)
+
+
+@pytest.mark.parametrize("fields, leaves", [
+    # afmoe's layers, as SparseDecoderLM sets them: the tree PR 28 drew
+    (dict(window=16, rotary=True, **AFMOE),
+     {"q", "k", "v", "gate", "out", "q_norm", "k_norm"}),
+    (dict(window=None, rotary=False, **AFMOE),
+     {"q", "k", "v", "gate", "out", "q_norm", "k_norm"}),
+    # ouro's: rotary positions on full attention, no q/k norm, no gate
+    (dict(window=None, rotary=True), {"q", "k", "v", "out"}),
+    (dict(window=None, rotary=False, qk_norm=True),
+     {"q", "k", "v", "out", "q_norm", "k_norm"})])
+def test_rotary_norms_and_gate_are_the_layers_own(fields, leaves):
+    """Each of the three is a field of the layer, tied to nothing else: the
+    parameters are those of what is on, and the result equals the layer
+    written out."""
+    from horovod_tpu.models import decoder
+
+    layer = decoder.GroupedAttention(heads=4, kv_heads=2, head_dim=8,
+                                     dtype=jnp.float32, attention="dense",
+                                     **fields)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 24))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    assert set(params) == leaves
+    params = jax.tree_util.tree_map(
+        lambda a: a + 0.1 * jax.random.normal(jax.random.PRNGKey(2), a.shape),
+        params)
+    got = layer.apply({"params": params}, x)
+
+    def heads(name, n):
+        return (x @ params[name]["kernel"]).reshape(2, 32, n, 8)
+
+    q, k, v = heads("q", 4), heads("k", 2), heads("v", 2)
+    if fields.get("qk_norm"):
+        q = trinity._rms(q, params["q_norm"], 1e-5)
+        k = trinity._rms(k, params["k_norm"], 1e-5)
+    if fields["rotary"]:
+        q, k = trinity._rotate(q, 10000.0), trinity._rotate(k, 10000.0)
+    out = dot_product_attention(q, k, v, causal=True,
+                                window=fields["window"]).reshape(2, 32, 32)
+    if fields.get("gate"):
+        out = out * jax.nn.sigmoid(x @ params["gate"]["kernel"])
+    np.testing.assert_allclose(got, out @ params["out"]["kernel"],
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_the_sparse_lm_keeps_its_layers_choices():
+    """``SparseDecoderLM`` sets the three for ``afmoe``: a window layer
+    rotates, a full layer does not, both have the norms and the gate."""
+    from horovod_tpu.models import decoder
+
+    seen = []
+    real = decoder.GroupedAttention.__call__
+
+    def spy(self, x):
+        seen.append((self.window, self.rotary, self.qk_norm, self.gate))
+        return real(self, x)
+
+    model = decoder.SparseDecoderLM(
+        vocab_size=64, embed_dim=32, layer_types=(decoder.SLIDING,
+                                                  decoder.FULL),
+        heads=4, kv_heads=2, head_dim=8, window=8, dense_layers=2,
+        dense_width=48, experts=4, experts_held=4, top_k=2, expert_width=16,
+        dtype=jnp.float32, attention="dense")
+    decoder.GroupedAttention.__call__ = spy
+    try:
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
+                                          jnp.zeros((1, 16), jnp.int32)))
+    finally:
+        decoder.GroupedAttention.__call__ = real
+    assert seen == [(8, True, True, True), (None, False, True, True)]
+
+
 @pytest.mark.parametrize("attention", ["dense", "flash"])
 def test_the_step_program_carries_the_layers_gauges(programs, attention):
     """``hvd.moe.*`` and ``hvd.attn.*`` of the step handle's program, as the

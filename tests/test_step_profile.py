@@ -365,6 +365,19 @@ def test_the_recorded_trace_carries_the_scopes_in_its_op_names():
      "shared/gate/dot_general", "hvd_moe_shared", "forward"),
     ("jit(step_fn)/hvd_update/mul", None, "update"),
     ("jit(step_fn)/jvp(hvd_forward)/embed/take", None, "forward"),
+    # a looped model: an application of the stack less its attention, the
+    # exit gate in the forward pass and in the loss, the chunked exit loss
+    ("jit(step_fn)/jvp(hvd_forward)/hvd_loop_step/DecoderBlock_3/mlp/up/"
+     "dot_general", "hvd_loop_step", "forward"),
+    ("jit(step_fn)/transpose(jvp(hvd_forward))/hvd_loop_step/checkpoint/"
+     "rematted_computation/DecoderBlock_3/attn/hvd_attn_full/hvd_flash_fwd/"
+     "pallas_call", "hvd_attn_full", "recomputed"),
+    ("jit(step_fn)/jvp(hvd_forward)/hvd_exit_gate/exit_gate/dot_general",
+     "hvd_exit_gate", "forward"),
+    ("jit(step_fn)/jvp(hvd_loss)/hvd_exit_gate/cumsum", "hvd_exit_gate",
+     "loss"),
+    ("jit(step_fn)/transpose(jvp(hvd_loss))/hvd_exit_loss/while/body/"
+     "dot_general", "hvd_exit_loss", "loss"),
 ])
 def test_layer_scopes_split_the_forward_and_backward_passes(op_name, layer,
                                                             phase):

@@ -4,6 +4,7 @@ scopes in the lowered step, and the Chrome writer as an exporter of spans."""
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,21 @@ def test_spans_nest_and_carry_their_parent(hvd):
     assert outer_rec["args"] == {"why": "test"}
     assert outer_rec["start_ns"] <= inner_rec["start_ns"] \
         <= inner_rec["end_ns"] <= outer_rec["end_ns"]
+
+
+def test_reset_forgets_a_trace_that_still_waited(hvd):
+    """A jaxpr trace with nothing lowered after it (``jax.eval_shape``, the
+    verifier's audits) waits on its thread for an outer trace to replace it;
+    ``reset`` forgets it with the rest, so it cannot surface in the records
+    of whatever runs next (``test_lane.py`` before this file did that)."""
+    timeline.reset()
+    jax.eval_shape(jax.jit(lambda x: x * 2.0), jnp.zeros((3,)))
+    timeline.reset()
+    with timeline.span("after"):
+        pass
+    with timeline.span("after"):      # a close is what hands traces over
+        pass
+    assert [s["name"] for s in _spans()] == ["after", "after"]
 
 
 def test_a_span_closes_when_its_body_raises(hvd):
@@ -379,6 +395,49 @@ def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
     else:
         assert got == {"flash_calls": 0, "dense_calls": 3}
     assert lane.stamp["attention"] == (pinned or "dense")
+
+
+def test_the_looped_step_carries_its_scopes_and_gauges(hvd, monkeypatch):
+    """A looped LM's step: ``hvd_loop_step`` once an application of the
+    stack, ``hvd_exit_gate`` in the forward pass and in the loss,
+    ``hvd_exit_loss`` inside ``hvd_loss``, and the gauges
+    ``hvd.loop.applications`` (counted in the loop, one a block application
+    traced, which is what a step executes because the loop is unrolled: the
+    same as ``hvd.attn.dense_calls``) and ``hvd.exit.live_logits_bytes``
+    keyed by the step's program."""
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+
+    timeline.reset()
+    args = bench.build_parser().parse_args(
+        ["--model", "looped_lm", "--lm-layers", "2", "--lm-loops", "4",
+         "--lm-dim", "32", "--lm-heads", "2", "--lm-ffn", "48", "--vocab",
+         "64", "--batch-size", "1", "--seq-len", "16", "--remat"])
+    lane = bench.build_lane(args, lambda *a, **k: None)
+    state, loss = lane.run_step(lane.state, lane.batch)   # donates
+    assert np.isfinite(float(loss))
+    text = lane.run_step._compiled.lower(state, lane.batch).as_text(
+        debug_info=True)
+    for scope in SCOPES + (timeline.LOOP_STEP, timeline.EXIT_GATE,
+                           timeline.EXIT_LOSS, timeline.ATTN_FULL):
+        assert scope in text, scope
+    for outer, inner in ((timeline.FORWARD, timeline.LOOP_STEP),
+                         (timeline.FORWARD, timeline.EXIT_GATE),
+                         (timeline.LOSS, timeline.EXIT_LOSS),
+                         (timeline.LOSS, timeline.EXIT_GATE)):
+        assert re.search(rf'{outer}\)[^"]*/{inner}/', text), (outer, inner)
+    assert {timeline.LOOP_STEP, timeline.EXIT_GATE, timeline.EXIT_LOSS} \
+        <= set(timeline.LAYER_SCOPES)
+    step = next(s["args"]["program"] for s in _spans("hvd.spmd.dispatch")
+                if s["args"]["handle"] == "step_fn")
+    gauges = timeline.snapshot()["gauges"]
+    got = {name: by_program[step] for name, by_program in gauges.items()
+           if name.startswith(("hvd.loop.", "hvd.exit.", "hvd.attn."))
+           and step in by_program}
+    assert got == {"hvd.loop.applications": 8,
+                   "hvd.exit.live_logits_bytes": 4 * 4 * 15 * 64,
+                   "hvd.attn.kv_heads": 2, "hvd.attn.dense_calls": 8,
+                   "hvd.attn.flash_calls": 0}
 
 
 def test_windowed_train_step_has_the_same_scopes(hvd):

@@ -13,11 +13,11 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_TESTS = os.path.join(REPO, "benchmarks", "tests")
-for path in (REPO, BENCH_TESTS):
+for path in (REPO, os.path.dirname(os.path.abspath(__file__))):
     if path not in sys.path:
         sys.path.insert(0, path)
 
+import toy_cell  # noqa: E402
 from benchmarks import check_manifest, flops_moe, run  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
 
@@ -151,10 +151,6 @@ TOY_ARGS = {"layer_types": ["sliding_attention"] * 4 + ["full_attention"],
 def _toy_tree(root):
     """A copy of ``benchmarks/`` plus the configuration at a toy size, its
     cell and the manifest's new entries retargeted to it: new files only."""
-    import toy
-
-    toy.make_tree(root)
-    dst = os.path.join(root, "benchmarks")
     config = copy.deepcopy(_config())
     swap = {"--lm-dim": "64", "--lm-heads": "4", "--lm-kv-heads": "2",
             "--lm-head-dim": "16", "--lm-window": "16", "--lm-ffn": "96",
@@ -179,44 +175,15 @@ def _toy_tree(root):
                 limits={"loss1_gap": 0.03, "loss2_gap": 0.03,
                         "loss3_gap": 0.03, "grad_median_gap": 0.03,
                         "delta_median_gap": 0.03})
-    for rel, body in (("configs/toy_trinity.json", config),
-                      ("workloads/toy_trinity_1chip.json", cell)):
-        path = os.path.join(dst, rel)
-        assert not os.path.exists(path)
-        with open(path, "w") as f:
-            json.dump(body, f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    manifest["configs"].append(
-        {"name": "toy_trinity", "source": "toy", "reduced": config["reduced"],
-         "file": "benchmarks/configs/toy_trinity.json", "why": "toy"})
-    manifest["workloads"].append(
-        {"name": "toy_trinity_1chip", "config": "toy_trinity",
-         "traffic": "toy_1", "chips": 1, "why": "toy"})
-    for m in manifest["end_to_end"] + manifest["per_layer"]:
-        if "toy_lm_1chip" in m.get("workloads", []):
-            m["workloads"].append("toy_trinity_1chip")
-    # (the copy's manifest lists every one-chip LM metric, the five new ones
-    # among them, for its toy LM cells: where nothing is there to read, as in
-    # a dense model, their readers return nothing)
-    assert {m["name"] for m in manifest["per_layer"]} >= set(NEW_METRICS)
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(manifest, f, indent=1)
-    assert check_manifest.check(manifest, root) == []
+    toy_cell.add_toy_cell(root, "toy_trinity", config, cell, NEW_METRICS)
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 def test_the_cell_runs_end_to_end_at_a_toy_size(tmp_path, trace):
-    import toy
-
     root = str(tmp_path)
     _toy_tree(root)
-    toy.CELLS.setdefault("toy_trinity_1chip", {"chips": 1})
-    try:
-        result, err = toy.drive(root, "toy_trinity_1chip", trace=trace,
-                                seed=2 ** 31 + 13)
-    finally:
-        toy.CELLS.pop("toy_trinity_1chip", None)
+    result, err = toy_cell.drive_toy_cell(root, "toy_trinity_1chip",
+                                          trace=trace, seed=2 ** 31 + 13)
     assert result["correct"], err[-3000:]
     assert result["failed"] == 0 and result["attempted"] > 0
     assert set(result["compared"]) >= {"loss1_gap", "grad_median_gap",

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.ops.xent import fused_cross_entropy
+from horovod_tpu.ops.xent import fused_cross_entropy, token_nll
 
 
 def _dense_nll(h, w, targets):
@@ -40,6 +40,42 @@ def test_fused_ce_matches_dense(t, chunk):
     np.testing.assert_allclose(np.asarray(fdh), np.asarray(gdh),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(fdw), np.asarray(gdw),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("t,chunk", [(64, 16), (60, 16), (7, 16)])
+def test_token_nll_matches_dense_in_value_and_all_three_gradients(t, chunk):
+    """The per-token loss under a learned weighting (the looped LM's exit
+    distribution): the value and the gradients into the hidden state, the
+    head and the weighting equal the dense composition's."""
+    key = jax.random.PRNGKey(7)
+    e, v = 24, 61
+    h = jax.random.normal(key, (t, e), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (e, v), jnp.float32)
+    z = jax.random.normal(jax.random.fold_in(key, 2), (t,), jnp.float32)
+    targets = jax.random.randint(jax.random.fold_in(key, 3), (t,), 0, v)
+
+    def dense(h, w, z):
+        logp = jax.nn.log_softmax(h @ w, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[:, None], -1)[:, 0]
+        return jnp.sum(jax.nn.sigmoid(z) * nll) / t
+
+    def chunked(h, w, z):
+        return jnp.sum(jax.nn.sigmoid(z)
+                       * token_nll(h, w, targets, chunk)) / t
+
+    want, want_grads = jax.value_and_grad(dense, argnums=(0, 1, 2))(h, w, z)
+    got, got_grads = jax.value_and_grad(chunked, argnums=(0, 1, 2))(h, w, z)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    per_token = token_nll(h, w, targets, chunk)
+    assert per_token.shape == (t,) and per_token.dtype == jnp.float32
+    # and through the weighted sum, where the weighting used to read zeros
+    dz = jax.grad(lambda z: fused_cross_entropy(
+        h, w, targets, chunk, weights=jax.nn.sigmoid(z), denom=t))(z)
+    np.testing.assert_allclose(np.asarray(dz), np.asarray(want_grads[2]),
                                rtol=1e-5, atol=1e-6)
 
 
