@@ -85,6 +85,34 @@ def resolve_attention(args) -> str:
         dtype=jnp.float32 if args.fp32 else jnp.bfloat16).impl
 
 
+def resolve_remat(model, state, batch: int, length: int, fused_ce: bool):
+    """The lane's model with ``--remat`` made a count: how many of its block
+    applications the backward pass runs again, the fewest that fit.
+
+    A model of ``models/decoder.py`` (one that lists its ``applications``)
+    asks ``decoder.plan_recomputation`` with what the lane can see before
+    anything is compiled: the train state it has just made, the step's
+    shapes, the rows of float32 logits its loss holds at a time and the
+    memory the device offers. A backend that reports none (the CPU) is
+    answered "every application", which is the flag's old meaning;
+    ``transformer_lm`` keeps that meaning everywhere (ROADMAP D18).
+    """
+    if not hasattr(model, "applications"):
+        return model
+    import jax
+
+    from horovod_tpu import models
+    from horovod_tpu.models import decoder
+    from horovod_tpu.utils import device
+
+    state_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(state))
+    plan = decoder.plan_recomputation(
+        model, state["params"], batch, length, state_bytes,
+        models.lm_logits_rows(model, batch * length, fused_ce),
+        device.memory_limit())
+    return model.clone(remat=plan)
+
+
 def grouped_heads(args) -> dict:
     """KV heads and the size of a head, for every family: as many KV heads
     as query heads and ``--lm-dim / --lm-heads`` where nothing else is
@@ -198,6 +226,9 @@ def build_lane(args, log) -> Lane:
             units = per_chip
             said = f"{size}x{size}"
         state, optimizer = models.create_train_state(rng, model, base, sample)
+        if lm and args.remat:
+            model = resolve_remat(model, state, per_chip, length,
+                                  args.fused_ce)
         with span("hvd.lane.place"):
             # the one synthetic batch: integers below their range, or normal
             batch = {
@@ -303,9 +334,13 @@ def build_parser():
                              "entropy (ops/xent.py): the [B,L,vocab] "
                              "fp32 logits tensor never materializes")
     parser.add_argument("--remat", action="store_true",
-                        help="language models: rematerialize each block on "
-                             "the backward pass (activation memory O(1) "
-                             "in depth)")
+                        help="language models: run blocks again in the "
+                             "backward pass instead of keeping what they "
+                             "computed. moe_lm, looped_lm: only as many "
+                             "block applications as do not fit the device's "
+                             "memory (decoder.plan_recomputation; all of "
+                             "them where the backend reports no limit); "
+                             "transformer_lm: every block")
     parser.add_argument("--fused-bn", action="store_true",
                         help="ResNet and Inception families: compute BN "
                              "statistics in the 1x1-conv matmul epilogue "

@@ -21,6 +21,7 @@ from horovod_tpu.models.train import (
     apply_gradients,
     create_train_state,
     cross_entropy_loss,
+    lm_logits_rows,
     make_eval_step,
     make_lm_train_step,
     make_train_step,
@@ -89,6 +90,7 @@ __all__ = [
     "parallel_lm",
     "create_train_state",
     "cross_entropy_loss",
+    "lm_logits_rows",
     "make_eval_step",
     "make_lm_train_step",
     "make_train_step",

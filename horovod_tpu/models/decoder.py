@@ -18,7 +18,11 @@ made of them: a sparse one and a looped one.
 * :class:`LoopedDecoderLM`: a stack of blocks declared once and applied
   ``loops`` times with the same weights, an exit after each application
   (``ouro``: full attention with rotary positions, no q/k norm, no gate), and
-  its loss: :func:`exit_log_distribution`, :func:`exit_loss`.
+  its loss: :func:`exit_log_distribution`, :func:`exit_loss`;
+* recomputation as a plan: :func:`recompute_plan` (arithmetic over bytes),
+  :func:`plan_recomputation` (what a step of one of the two models holds,
+  from its shapes and the device's memory), :class:`RecomputePlan` (the
+  models' ``remat``).
 
 The selection bias is state and no parameter: it lives in the collection
 ``buffers`` beside each layer's ``expert_counts`` (how many tokens of the
@@ -30,13 +34,14 @@ float32 parameters, norms, softmax, router and logits, as the other LM.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from horovod_tpu.ops.attention import attend
+from horovod_tpu.ops.xent import T_CHUNK, token_nll
 from horovod_tpu.parallel import moe
 from horovod_tpu.utils import timeline
 
@@ -209,6 +214,187 @@ class DecoderBlock(nn.Module):
             m = SparseExperts(dtype=self.dtype, name="moe", **self.moe)(m)
         return h + RMSNorm(self.eps, name="norm_ffn_out")(m).astype(h.dtype)
 
+    @nn.nowrap
+    def kept_bytes(self, tokens: int, width: int) -> int:
+        """What one application over ``tokens`` tokens of ``width`` keeps for
+        the backward pass when it is not recomputed, in bytes, as a closed sum
+        over the block: the operands of its products and kernels and the
+        inputs of its norms, in the compute type. What lies between them (the
+        norms' float32, the rotation, the activations' derivatives) XLA
+        recomputes inside the fusions that read them, and the routed experts
+        recompute themselves (``parallel/moe.py``). An estimate, which
+        ``tests/test_chip_smoke.py`` holds to the compiler's count at
+        Trinity-Mini's widths (0.99 of it for the dense block, 1.23 for an
+        expert block)."""
+        e = jnp.dtype(self.dtype).itemsize
+        a = self.attn
+        q, kv = a["heads"] * a["head_dim"], a["kv_heads"] * a["head_dim"]
+        # the stream before each branch, its norm, and the branch's output
+        a_token = 6 * width * e
+        # the kernels' q, k, v and output, and their log-sum-exp a head:
+        # float32, each a lane row of 128 in HBM
+        a_token += (2 * q + 2 * kv) * e + a["heads"] * 128 * 4
+        if a.get("qk_norm"):
+            a_token += (q + kv) * e             # q and k before their norms
+        if a.get("gate"):
+            a_token += 2 * q * e                # its logits, the gated output
+        if self.moe is None:
+            hidden = self.ffn_width
+        else:
+            # the router's scores in float32 and a token's choices; what the
+            # routed experts take is the norm's output and the indices
+            hidden = self.moe["shared"] * self.moe["width"]
+            a_token += 3 * 4 * self.moe["experts"] + 4 * 4 * self.moe["top_k"]
+        return tokens * (a_token + 3 * hidden * e)  # gate, up, their product
+
+
+class RecomputePlan(NamedTuple):
+    """A model's ``remat`` as :func:`recompute_plan` answers it: how many of
+    its block applications the backward pass runs again, what the others are
+    reckoned to hold for it meanwhile and what they were allowed."""
+
+    recomputed: int
+    kept_bytes: int = 0
+    budget_bytes: int = 0
+
+
+def recompute_plan(kept: Sequence[int], grads: Sequence[int], carried: int,
+                   resident: int, head: int, limit: Optional[int],
+                   margin: int = 0) -> RecomputePlan:
+    """The fewest block applications to recompute such that the step's peak,
+    as summed here, and ``margin`` fit in ``limit`` bytes; with no ``limit``
+    (a backend that reports none: the CPU, a described topology) every one.
+
+    ``kept[i]`` is what application ``i`` (in the forward pass's order) holds
+    for the backward pass when it is not recomputed, ``carried`` what a
+    recomputed one holds (its input), ``grads[i]`` the gradients that come to
+    life when the backward pass reaches application ``i`` (a weight used by
+    several applications: at the last of them), ``resident`` what is there
+    throughout, ``head`` the loss's working set.
+
+    **The last applications are the ones kept**: the backward pass frees
+    theirs first, while the gradients it leaves behind pile up, so the peak
+    stands either at the loss (everything kept, and ``head``) or at one
+    application's backward pass (what is kept up to it, its own working set
+    if it is recomputed, and the gradients from it on). The compiler's count
+    is not additive in the applications kept for the same reason."""
+    total = len(kept)
+    if not limit:
+        return RecomputePlan(total)
+
+    def peak(first):                    # applications first.. are kept
+        base = resident + first * carried
+        worst, held, live = base + head + sum(kept[first:]), 0, sum(grads)
+        for i in range(total):          # its backward pass
+            if i >= first:
+                held += kept[i]         # kept[first:i + 1]
+            worst = max(worst,
+                        base + (held if i >= first else kept[i]) + live)
+            live -= grads[i]
+        return worst
+
+    first = next((n for n in range(total) if peak(n) + margin <= limit),
+                 total)
+    held = sum(kept[first:])
+    return RecomputePlan(first, held, max(0, limit - margin - peak(first))
+                         + held)
+
+
+# What :func:`plan_recomputation` leaves free of the device's memory beside
+# its own sum: 2.5 GiB. The sum has read from 1.4 GiB under the compiler's
+# count of a cell's whole step (Ouro's, every application recomputed: 0.65
+# of it the program's code, which no shape gives away) to 1.2 GiB over it
+# (Trinity's; PERF.md section 6, PR 33), and XLA:TPU's scheduler, given
+# memory that is free, spends it on a faster order and cuts back by its own
+# rematerialisation only at the limit, so a step planned to the brim is
+# planned twice: the fullest cell that runs every day leaves 0.85 GiB of the
+# 15.75 (`peak_hbm_gib.tok` 14.9, the GPT-2 cells). 1.4 + 0.85, rounded up.
+RECOMPUTE_MARGIN = 5 << 29
+
+
+def plan_recomputation(model, params, batch: int, length: int,
+                       state_bytes: int, logits_rows: int,
+                       limit: Optional[int]) -> RecomputePlan:
+    """:func:`recompute_plan` for one training step of ``model`` (a model of
+    this module: ``applications()``, ``block(i)``) over ``batch`` sequences
+    of ``length`` tokens a chip, from what can be seen before anything is
+    compiled: ``params`` (the model's, or their shapes), the bytes of the
+    train state, the rows of float32 logits the loss holds at a time and the
+    memory the device offers (``utils.device.memory_limit()``)."""
+    tokens, width = batch * length, model.embed_dim
+    e = jnp.dtype(model.dtype).itemsize
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(tree))
+
+    layers = model.applications()
+    kept = [model.block(i).kept_bytes(tokens, width) for i in layers]
+    blocks = {i: params[f"DecoderBlock_{i}"] for i in set(layers)}
+    # a weight's gradient is whole once the backward pass has been through
+    # its first use, and alive from its last
+    last = {i: at for at, i in enumerate(layers)}
+    grads = [nbytes(blocks[i]) if last[i] == at else 0
+             for at, i in enumerate(layers)]
+    # Beside the state: the gradients of what is no block (the embedding,
+    # the head, the last norms), and the compute type's copy of the kernels
+    # that the blocks' products read (matrices; the routed experts' stacks
+    # are cast where they are recomputed), which XLA makes once for the
+    # forward pass, the recomputation and the backward pass.
+    outside = nbytes(params) - sum(grads)
+    casts = e * sum(x.size for x in jax.tree_util.tree_leaves(blocks)
+                    if len(x.shape) == 2)
+    # What enters the loss, in float32, and its cotangent (a looped model:
+    # every exit); the logits and theirs.
+    exits = getattr(model, "loops", 1)
+    head = 2 * 4 * (exits * tokens * width + logits_rows * model.vocab_size)
+    return recompute_plan(kept, grads, tokens * width * e,
+                          state_bytes + outside + casts, head, limit,
+                          RECOMPUTE_MARGIN)
+
+
+def _apply(block, h):
+    return block(h)
+
+
+# For real: ``prevent_cse`` stays on, or XLA:TPU merges the recomputation back
+# into the forward pass.
+_apply_again = nn.remat(_apply)
+
+# Block applications traced into each program, and those of them wrapped:
+# program -> (id of its ``hvd.spmd.dispatch`` span, [applied, recomputed]).
+_wrapped: dict = {}
+
+
+def _applications(remat, total: int, counted=("hvd.remat.applications",)):
+    """``apply(block, h)`` for a forward pass of ``total`` block applications
+    under the model's ``remat``: a :class:`RecomputePlan`, a count of
+    applications to recompute, or a bool (all or none). The unit is one
+    application, so a block applied twice may be kept once and recomputed
+    once; its weights are the same leaves either way. Sets the gauges
+    ``hvd.remat.*`` of the program being traced; ``counted`` are the gauges
+    that read the applications traced so far."""
+    if isinstance(remat, bool):
+        remat = total if remat else 0
+    plan = remat if isinstance(remat, RecomputePlan) else RecomputePlan(remat)
+    recomputed = max(0, min(int(plan.recomputed), total))
+    program, tally = timeline.program_tally(_wrapped, lambda: [0, 0])
+    timeline.gauge("hvd.remat.kept_bytes", plan.kept_bytes, key=program)
+    timeline.gauge("hvd.remat.budget_bytes", plan.budget_bytes, key=program)
+    at = [0]
+
+    def apply(block, h):
+        again = at[0] < recomputed      # the last ones are kept
+        at[0] += 1
+        tally[0] += 1
+        tally[1] += again
+        for name in counted:
+            timeline.gauge(name, tally[0], key=program)
+        timeline.gauge("hvd.remat.recomputed", tally[1], key=program)
+        return (_apply_again if again else _apply)(block, h)
+
+    return apply
+
 
 class SparseDecoderLM(nn.Module):
     """Token ids ``[B, L]`` -> float32 logits ``[B, L, vocab]`` (or, with
@@ -217,9 +403,12 @@ class SparseDecoderLM(nn.Module):
     ``layer_types``: one of ``"sliding_attention"`` / ``"full_attention"`` a
     layer; the first ``dense_layers`` have a :class:`GatedMLP` of
     ``dense_width``, the others :class:`SparseExperts`. ``embed_scale``
-    multiplies the embedding by ``sqrt(embed_dim)``. ``remat`` recomputes
-    each block in the backward pass (for real: ``prevent_cse`` stays on, or
-    XLA:TPU merges the recomputation back into the forward pass)."""
+    multiplies the embedding by ``sqrt(embed_dim)``. ``remat`` says how many
+    block applications (here: blocks) the backward pass runs again instead
+    of keeping what they computed: a count or a :class:`RecomputePlan` (the
+    first so many; the last ones are kept), ``True`` all, ``False`` none. A
+    lane's ``--remat`` fills it with :func:`plan_recomputation`'s answer:
+    the fewest that fit the device's memory."""
 
     vocab_size: int
     embed_dim: int
@@ -242,7 +431,32 @@ class SparseDecoderLM(nn.Module):
     rope_base: float = 10000.0
     attention: Optional[str] = None
     dtype: Any = jnp.bfloat16
-    remat: bool = False
+    remat: Union[bool, int, RecomputePlan] = False
+
+    @nn.nowrap
+    def block(self, i: int) -> DecoderBlock:
+        """Layer ``i``'s block, by the name its parameters have."""
+        kind = self.layer_types[i]
+        if kind not in (SLIDING, FULL):
+            raise ValueError(f"layer {i}: no layer type {kind!r}")
+        attn = dict(heads=self.heads, kv_heads=self.kv_heads,
+                    head_dim=self.head_dim, rope_base=self.rope_base,
+                    window=self.window if kind == SLIDING else None,
+                    rotary=kind == SLIDING, qk_norm=True, gate=True,
+                    attention=self.attention)
+        sparse = None if i < self.dense_layers else dict(
+            experts=self.experts, experts_held=self.experts_held,
+            first_expert=self.first_expert, top_k=self.top_k,
+            width=self.expert_width, route_scale=self.route_scale,
+            shared=self.shared_experts)
+        return DecoderBlock(attn, self.dense_width, sparse, self.eps,
+                            self.dtype, name=f"DecoderBlock_{i}")
+
+    @nn.nowrap
+    def applications(self) -> list:
+        """The layer whose block each application of the forward pass
+        applies, in order: every layer once."""
+        return list(range(len(self.layer_types)))
 
     @nn.compact
     def __call__(self, tokens, train: bool = True,
@@ -252,33 +466,14 @@ class SparseDecoderLM(nn.Module):
                      name="embed")(tokens)
         if self.embed_scale:
             h = h * jnp.asarray(self.embed_dim ** 0.5, h.dtype)
-        block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
-        for i, kind in enumerate(self.layer_types):
-            if kind not in (SLIDING, FULL):
-                raise ValueError(f"layer {i}: no layer type {kind!r}")
-            attn = dict(heads=self.heads, kv_heads=self.kv_heads,
-                        head_dim=self.head_dim, rope_base=self.rope_base,
-                        window=self.window if kind == SLIDING else None,
-                        rotary=kind == SLIDING, qk_norm=True, gate=True,
-                        attention=self.attention)
-            sparse = None if i < self.dense_layers else dict(
-                experts=self.experts, experts_held=self.experts_held,
-                first_expert=self.first_expert, top_k=self.top_k,
-                width=self.expert_width, route_scale=self.route_scale,
-                shared=self.shared_experts)
-            h = block(attn, self.dense_width, sparse, self.eps, self.dtype,
-                      name=f"DecoderBlock_{i}")(h)
+        apply_block = _applications(self.remat, len(self.layer_types))
+        for i in range(len(self.layer_types)):
+            h = apply_block(self.block(i), h)
         h = RMSNorm(self.eps, name="final_norm")(h)
         if return_hidden:
             return h
         return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(h)
-
-
-# Block applications a looped model's forward pass has traced into each
-# program: program -> (id of its ``hvd.spmd.dispatch`` span, [count]). A
-# re-trace starts anew.
-_applied: dict = {}
 
 
 class LoopedDecoderLM(nn.Module):
@@ -295,8 +490,10 @@ class LoopedDecoderLM(nn.Module):
     float32 for :func:`exit_loss`: the logits of all the exits never exist
     as one tensor. ``exit_beta`` is the loss's weight on the exit
     distribution's entropy; a step that finds it takes :func:`exit_loss`
-    (``models.make_lm_train_step``). ``remat`` recomputes each block
-    application in the backward pass, as :class:`SparseDecoderLM` does."""
+    (``models.make_lm_train_step``). ``remat`` is
+    :class:`SparseDecoderLM`'s, and its unit one application: of the ``loops
+    x num_layers`` the first so many are run again in the backward pass, so
+    a block may be recomputed in one loop step and kept in another."""
 
     vocab_size: int
     embed_dim: int
@@ -311,7 +508,22 @@ class LoopedDecoderLM(nn.Module):
     rope_base: float = 1e6
     attention: Optional[str] = None
     dtype: Any = jnp.bfloat16
-    remat: bool = False
+    remat: Union[bool, int, RecomputePlan] = False
+
+    @nn.nowrap
+    def block(self, i: int) -> DecoderBlock:
+        """Layer ``i``'s block, by the name its parameters have."""
+        attn = dict(heads=self.heads, kv_heads=self.kv_heads,
+                    head_dim=self.head_dim, rope_base=self.rope_base,
+                    attention=self.attention)
+        return DecoderBlock(attn, self.ffn_width, None, self.eps, self.dtype,
+                            name=f"DecoderBlock_{i}")
+
+    @nn.nowrap
+    def applications(self) -> list:
+        """The layer whose block each application of the forward pass
+        applies, in order: the stack, ``loops`` times."""
+        return list(range(self.num_layers)) * self.loops
 
     @nn.compact
     def __call__(self, tokens, train: bool = True,
@@ -319,31 +531,27 @@ class LoopedDecoderLM(nn.Module):
         del train                                   # no dropout anywhere
         h = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype,
                      name="embed")(tokens)
-        block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
-        attn = dict(heads=self.heads, kv_heads=self.kv_heads,
-                    head_dim=self.head_dim, rope_base=self.rope_base,
-                    attention=self.attention)
-        blocks = [block(attn, self.ffn_width, None, self.eps, self.dtype,
-                        name=f"DecoderBlock_{i}")
-                  for i in range(self.num_layers)]
+        blocks = [self.block(i) for i in range(self.num_layers)]
+        # counted in the loop, one a block applied: unrolled, so what is
+        # traced is what a step executes
+        apply_block = _applications(
+            self.remat, self.loops * self.num_layers,
+            ("hvd.remat.applications", "hvd.loop.applications"))
         final_norm = RMSNorm(self.eps, name="final_norm")
         # one output a token: at full precision it costs nothing, and the
         # exit distribution is read off it
         exit_gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate",
                              precision=jax.lax.Precision.HIGHEST)
-        program, applied = timeline.program_tally(_applied, lambda: [0])
         exits, gates = [], []
         for _ in range(self.loops):
             with jax.named_scope(timeline.LOOP_STEP):
-                for apply_block in blocks:
-                    h = apply_block(h)
-                    applied[0] += 1
+                for block in blocks:
+                    h = apply_block(block, h)
                 x = final_norm(h)
             with jax.named_scope(timeline.EXIT_GATE):
                 gates.append(exit_gate(x)[..., 0])
             exits.append(x)
             h = x.astype(self.dtype)
-        timeline.gauge("hvd.loop.applications", applied[0], key=program)
         if return_hidden:
             return jnp.stack(exits), jnp.stack(gates)
         return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
@@ -365,7 +573,7 @@ def exit_log_distribution(gate_logits):
 
 
 def exit_loss(exits, gate_logits, head, tokens, beta: float,
-              t_chunk: int = 512):
+              t_chunk: int = T_CHUNK):
     """The looped model's training loss, a scalar: the mean over the ``B x
     (L - 1)`` next-token positions of ``sum_t p_t nll_t - beta H(p)``, with
     ``nll_t`` the negative log-likelihood of the next token under exit
@@ -379,8 +587,6 @@ def exit_loss(exits, gate_logits, head, tokens, beta: float,
     ``hvd.exit.live_logits_bytes``) and is differentiable into the exit
     states and the head; the weighting by ``p`` is plain arithmetic, so
     gradients reach the gate through it."""
-    from horovod_tpu.ops.xent import token_nll
-
     loops, _, _, e = exits.shape
     rows = exits[:, :, :-1].reshape(-1, e)
     program, _ = timeline.tracing_program()

@@ -237,6 +237,17 @@ def make_train_step(model, optimizer: optax.GradientTransformation, average_loss
     return train_step
 
 
+def lm_logits_rows(model, tokens: int, fused_ce: bool = False) -> int:
+    """Rows of float32 logits the loss of :func:`make_lm_train_step` holds at
+    a time for ``tokens`` tokens a chip: all of them, or one chunk of
+    ``ops/xent.py`` where the loss is chunked (``fused_ce``, or a model with
+    exits)."""
+    from horovod_tpu.ops.xent import T_CHUNK
+
+    chunked = fused_ce or getattr(model, "exit_beta", None) is not None
+    return min(tokens, T_CHUNK) if chunked else tokens
+
+
 def make_lm_train_step(model, optimizer: optax.GradientTransformation, *,
                        fused_ce: bool = False, bias_coeff=None):
     """Build the per-rank SPMD training step of a causal language model.

@@ -77,6 +77,9 @@ def _fill_defaults(h, weights, denom):
     return weights, jnp.asarray(denom, jnp.float32)
 
 
+T_CHUNK = 512   # rows of float32 logits a chunked loss holds at a time
+
+
 def _chunk_stats(hc, w, tc):
     """One chunk's per-token (lse, target_logit), fp32."""
     logits = jnp.dot(hc, w, preferred_element_type=jnp.float32)
@@ -85,7 +88,7 @@ def _chunk_stats(hc, w, tc):
     return lse, tgt
 
 
-def fused_cross_entropy(h, w, targets, t_chunk: int = 512,
+def fused_cross_entropy(h, w, targets, t_chunk: int = T_CHUNK,
                         weights=None, denom=None):
     """Weighted NLL without materializing [T, V] logits.
 
@@ -104,7 +107,7 @@ def fused_cross_entropy(h, w, targets, t_chunk: int = 512,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def token_nll(h, w, targets, t_chunk: int = 512):
+def token_nll(h, w, targets, t_chunk: int = T_CHUNK):
     """``-log softmax(h @ w)[target]`` of every token, ``[T]`` fp32, holding
     one ``[t_chunk, V]`` block of logits at a time; differentiable into
     ``h`` and ``w`` (the backward pass recomputes each block)."""

@@ -43,6 +43,15 @@ def require_tpu(cpu_requested: bool = False) -> Dict:
         "another platform")
 
 
+def memory_limit():
+    """Bytes of memory a device of this process offers, where the backend
+    says (a TPU: ``bytes_limit``); None where it does not (the CPU)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
 def pallas_interpret() -> bool:
     """Default for a kernel's ``interpret`` argument: compiled on a TPU,
     interpreted on the CPU test platform, an error anywhere else."""

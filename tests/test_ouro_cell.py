@@ -39,6 +39,7 @@ from horovod_tpu.utils import timeline  # noqa: E402
 
 CELL = "ouro_seq4096_1chip"
 NEW_METRICS = ["loop_applications_per_step.tok", "exit_live_logits_mib.tok"]
+RECOMPUTED = "recomputed_applications_per_step.tok"     # PR 33, Trinity's too
 # Ouro-2.6B's published config.json, as the model-configs catalog holds it:
 # every number has to stand in the file unchanged (the one cut, the depth
 # held here, has a key of its own)
@@ -370,7 +371,7 @@ def test_manifest_is_well_formed_and_names_the_cell():
     assert sum(1 for w in manifest["workloads"] if w["chips"] == 4) == 1
     reported = {m["name"] for m in run.metrics_of(manifest, CELL,
                                                   "per_layer")}
-    assert set(NEW_METRICS) <= reported
+    assert set(NEW_METRICS) | {RECOMPUTED} <= reported
     assert {"step_mfu_pct.tok", "device_step_ms.tok", "peak_hbm_gib.tok",
             "device_idle_pct.tok", "setup_lane_build_s"} <= reported
     # the flash kernels' readers see chip 0's ten largest families, and
@@ -487,6 +488,12 @@ def test_readers_on_made_up_records():
                                 "hvd.exit.live_logits_bytes": 512 * 49152 * 4})
     assert read["loop_applications_per_step.tok"]({}) == 32
     assert read["exit_live_logits_mib.tok"]({}) == 96.0
+    # of the applications, those the plan has the backward pass run again
+    recomputed = run.load_reader(RECOMPUTED)
+    assert recomputed({}) is None           # the parent sets no such gauge
+    timeline.gauge("hvd.remat.recomputed", 26, key="step_fn#0")
+    timeline.gauge("hvd.remat.recomputed", 5, key="step_fn#7")
+    assert recomputed({}) == 26
     # a program that has no loop (the parent's, or another model's): nothing
     # to read, and no error
     _dispatched("step_fn#1", **{"hvd.attn.flash_calls": 24})
@@ -529,7 +536,8 @@ def _toy_tree(root):
                 limits={"loss1_gap": 0.03, "loss2_gap": 0.03,
                         "loss3_gap": 0.03, "grad_median_gap": 0.03,
                         "delta_median_gap": 0.03})
-    toy_cell.add_toy_cell(root, "toy_ouro", config, cell, NEW_METRICS)
+    toy_cell.add_toy_cell(root, "toy_ouro", config, cell,
+                          NEW_METRICS + [RECOMPUTED])
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -547,6 +555,8 @@ def test_the_cell_runs_end_to_end_at_a_toy_size(tmp_path, trace):
         # a program counter reads on the CPU too; a device trace does not
         assert result["metrics"]["loop_applications_per_step.tok"]["value"] \
             == 6
+        # the CPU reports no memory limit: every application is recomputed
+        assert result["metrics"][RECOMPUTED]["value"] == 6
         # 3 exits x 2 rows x 31 positions of 128 float32 logits
         assert result["metrics"]["exit_live_logits_mib.tok"]["value"] \
             == 4 * 186 * 128 / 2 ** 20

@@ -25,6 +25,7 @@ CELL = "trinity_mini_seq4096_1chip"
 NEW_METRICS = ["moe_row_bound_ratio.tok", "moe_gmm_ms_per_step.tok",
                "moe_gmm_roofline_pct.tok", "flash_ms_per_step.tok",
                "flash_roofline_pct.tok"]
+RECOMPUTED = "recomputed_applications_per_step.tok"     # PR 33, Ouro's too
 # Trinity-Mini's published config.json, as the model-configs catalog holds
 # it: every number has to stand in the file unchanged unless `reduced` names
 # its key
@@ -54,7 +55,7 @@ def test_manifest_is_well_formed_and_names_the_cell():
         "--batch-size", "2", "--seq-len", "4096", "--remat"]
     reported = {m["name"] for m in run.metrics_of(manifest, CELL,
                                                   "per_layer")}
-    assert set(NEW_METRICS) <= reported
+    assert set(NEW_METRICS) | {RECOMPUTED} <= reported
     assert {"step_mfu_pct.tok", "device_step_ms.tok", "peak_hbm_gib.tok",
             "setup_lane_build_s"} <= reported
     assert "collective_ms_per_step.tok" not in reported
@@ -175,7 +176,8 @@ def _toy_tree(root):
                 limits={"loss1_gap": 0.03, "loss2_gap": 0.03,
                         "loss3_gap": 0.03, "grad_median_gap": 0.03,
                         "delta_median_gap": 0.03})
-    toy_cell.add_toy_cell(root, "toy_trinity", config, cell, NEW_METRICS)
+    toy_cell.add_toy_cell(root, "toy_trinity", config, cell,
+                          NEW_METRICS + [RECOMPUTED])
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -194,6 +196,8 @@ def test_the_cell_runs_end_to_end_at_a_toy_size(tmp_path, trace):
         # 2 x 32 tokens x 3 choices x 4 of 16 experts held: 48 rows expected
         assert result["metrics"]["moe_row_bound_ratio.tok"]["value"] \
             == moe.buffer_sizes(192, 48.0)[0] / 48.0
+        # the CPU reports no memory limit: all five blocks are recomputed
+        assert result["metrics"][RECOMPUTED]["value"] == 5
         assert not {"moe_gmm_ms_per_step.tok", "flash_ms_per_step.tok",
                     "moe_gmm_roofline_pct.tok", "flash_roofline_pct.tok",
                     "step_mfu_pct.tok"} & set(result["metrics"])
