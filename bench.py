@@ -80,8 +80,13 @@ def resolve_attention(args) -> str:
 
     from horovod_tpu.ops.attention import attention_plan
 
+    shapes = grouped_heads(args)
+    if "latent" in (args.lm_layer_types or ""):
+        # every head its own key and value; keys wider than values
+        shapes = dict(kv_heads=args.lm_heads, head_dim=(
+            shapes["head_dim"] + args.lm_rope_dim, args.lm_value_dim))
     return attention_plan(
-        args.seq_len, args.seq_len, args.lm_heads, **grouped_heads(args),
+        args.seq_len, args.seq_len, args.lm_heads, **shapes,
         dtype=jnp.float32 if args.fp32 else jnp.bfloat16).impl
 
 
@@ -144,7 +149,8 @@ def lm_model_args(args, attention: str) -> dict:
             attention=attention, **grouped_heads(args))
     from horovod_tpu.models import decoder
 
-    kinds = {"sliding": decoder.SLIDING, "full": decoder.FULL}
+    kinds = {"sliding": decoder.SLIDING, "full": decoder.FULL,
+             "latent": decoder.LATENT}
     names = (args.lm_layer_types.split(",") if args.lm_layer_types
              else ["full"] * args.lm_layers)
     if len(names) != args.lm_layers or set(names) - set(kinds):
@@ -166,7 +172,10 @@ def lm_model_args(args, attention: str) -> dict:
         experts=args.moe_experts, experts_held=held,
         first_expert=args.moe_first_expert, top_k=args.moe_top_k,
         expert_width=args.moe_width, shared_experts=args.moe_shared,
-        route_scale=args.moe_route_scale, attention=attention)
+        route_scale=args.moe_route_scale, attention=attention,
+        embed_scale=args.lm_embed_scale, norm_outputs=args.lm_output_norms,
+        rope_dim=args.lm_rope_dim, value_dim=args.lm_value_dim,
+        latent_dim=args.lm_latent_dim)
 
 
 def build_lane(args, log) -> Lane:
@@ -293,9 +302,27 @@ def build_parser():
     parser.add_argument("--lm-window", type=int, default=2048,
                         help="moe_lm: window of a sliding layer")
     parser.add_argument("--lm-layer-types", default=None,
-                        help="moe_lm: 'sliding' (window, rotary positions) "
-                             "or 'full' (no positional encoding) a layer, "
-                             "comma-separated (default: all full)")
+                        help="moe_lm: 'sliding' (window, rotary positions), "
+                             "'full' (no positional encoding) or 'latent' "
+                             "(keys and values expanded from a compressed "
+                             "row, one rotated key all heads share) a "
+                             "layer, comma-separated (default: all full)")
+    parser.add_argument("--lm-latent-dim", type=int, default=512,
+                        help="moe_lm, a latent layer: width of the "
+                             "compressed row")
+    parser.add_argument("--lm-rope-dim", type=int, default=64,
+                        help="moe_lm, a latent layer: width of the rotated "
+                             "key all heads share, beside --lm-head-dim of "
+                             "a key's own")
+    parser.add_argument("--lm-value-dim", type=int, default=128,
+                        help="moe_lm, a latent layer: width of a value")
+    parser.add_argument("--lm-output-norms", default=True,
+                        action=argparse.BooleanOptionalAction,
+                        help="moe_lm: an RMS norm after each branch as well "
+                             "as before it (four norms a block, not two)")
+    parser.add_argument("--lm-embed-scale", default=True,
+                        action=argparse.BooleanOptionalAction,
+                        help="moe_lm: the embedding times sqrt(--lm-dim)")
     parser.add_argument("--lm-ffn", type=int, default=None,
                         help="moe_lm, looped_lm: width of a dense layer's "
                              "gated feed-forward (default: 4 x --lm-dim)")

@@ -7,14 +7,20 @@ made of them: a sparse one and a looped one.
   under a ``window`` or over the whole sequence, and three choices that are
   the layer's own: rotary positions, an RMS norm a head on queries and keys,
   a sigmoid gate on the output;
+* :class:`LatentAttention`: keys and values expanded from one compressed
+  row a token (``deepseek_v3`` without the query's low-rank path): a head's
+  key is its own ``nope_dim`` columns beside one rotated ``rope_dim``-wide
+  key that all heads share, its value ``value_dim`` wide;
 * :class:`SparseExperts`: a router over all ``E`` experts, this chip's
   ``held`` of them (:mod:`horovod_tpu.parallel.moe`: top ``k`` of sigmoid
   scores plus a selection bias, nothing dropped) and a shared expert every
   token passes;
-* :class:`DecoderBlock`: an RMS norm before **and after** each branch;
+* :class:`DecoderBlock`: an RMS norm before each branch and, where
+  ``norm_outputs`` says so, after it;
 * :class:`SparseDecoderLM`: leading dense layers, then expert layers
   (``afmoe``: a window layer rotates, a full layer has no positional
-  encoding at all; every layer has the q/k norms and the gate);
+  encoding at all; every layer has the q/k norms and the gate;
+  ``deepseek_v3``: latent layers, two norms a block);
 * :class:`LoopedDecoderLM`: a stack of blocks declared once and applied
   ``loops`` times with the same weights, an exit after each application
   (``ouro``: full attention with rotary positions, no q/k norm, no gate), and
@@ -45,7 +51,8 @@ from horovod_tpu.ops.xent import T_CHUNK, token_nll
 from horovod_tpu.parallel import moe
 from horovod_tpu.utils import timeline
 
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, LATENT = ("sliding_attention", "full_attention",
+                         "latent_attention")
 
 
 class RMSNorm(nn.Module):
@@ -146,6 +153,77 @@ class GroupedAttention(nn.Module):
                         name="out")(out)
 
 
+class LatentAttention(nn.Module):
+    """Causal attention over keys and values expanded from a compressed row
+    (``modeling_deepseek.py`` with ``q_lora_rank: null``, the expanded form
+    that training uses): for a token's normed state ``x``
+
+        q      = x W_q          a head: q_nope [nope_dim] | q_pe [rope_dim]
+        c | kr = x W_kva        c [latent_dim], kr [rope_dim]: one a token
+        c      = RMS(c)
+        kv     = c W_kvb        a head: k_nope [nope_dim] | v [value_dim]
+        q_pe, kr rotated by position
+        k_h    = k_nope_h | kr  the same kr for all heads
+        o_h    = softmax(q_h k_h / sqrt(nope_dim + rope_dim)) v_h, causal
+        y      = (o_1 | ... | o_H) W_o
+
+    No bias, no gate, no q/k norm. The kernels take ``kr`` as it is, one
+    vector a token (``ops.attention.attend``'s ``k_shared``): no key of
+    ``heads x (nope_dim + rope_dim)`` a token is written (broadcast into K
+    before the call, the other way, the cell's step read 2.2 ms of 602
+    longer on the v5e: PERF.md section 5). ``attention`` is
+    :class:`GroupedAttention`'s."""
+
+    heads: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+    latent_dim: int
+    eps: float = 1e-5
+    rope_base: float = 10000.0
+    attention: Optional[str] = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, length, _ = x.shape
+        h, dn, dr, dv = self.heads, self.nope_dim, self.rope_dim, \
+            self.value_dim
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            name=name)
+
+        q = dense(h * (dn + dr), "q")(x).reshape(b, length, h, dn + dr)
+        q = jnp.concatenate(
+            [q[..., :dn], rotary(q[..., dn:], self.rope_base).astype(q.dtype)],
+            -1)
+        with jax.named_scope(timeline.LATENT_COMPRESS):
+            row = dense(self.latent_dim + dr, "kv_a")(x)
+            c = RMSNorm(self.eps, name="kv_norm")(
+                row[..., :self.latent_dim]).astype(self.dtype)
+        with jax.named_scope(timeline.LATENT_EXPAND):
+            kv = dense(h * (dn + dv), "kv_b")(c).reshape(b, length, h,
+                                                         dn + dv)
+            k_nope, v = kv[..., :dn], kv[..., dn:]
+            k_rope = rotary(row[..., None, self.latent_dim:],
+                            self.rope_base)[:, :, 0].astype(self.dtype)
+        program, written = timeline.program_tally(_expanded, lambda: [0])
+        written[0] += sum(t.size * t.dtype.itemsize
+                          for t in (k_nope, v, k_rope))
+        timeline.gauge("hvd.attn.latent_expanded_bytes", written[0],
+                       key=program)
+        with jax.named_scope(timeline.ATTN_LATENT):
+            out = attend(q, k_nope, v, k_shared=k_rope,
+                         scale=(dn + dr) ** -0.5, impl=self.attention)
+        return dense(x.shape[-1], "out")(out.reshape(b, length, h * dv))
+
+
+# Bytes of K and V the latent layers of the program being traced write for
+# the kernels in a forward pass: program -> (id of its dispatch span, [bytes]).
+_expanded: dict = {}
+
+
 class SparseExperts(nn.Module):
     """``MLP_shared(x) + sum over the chosen experts held here of w_e
     MLP_e(x)``: the chip's share of the layer (``first_expert`` and
@@ -193,26 +271,37 @@ class SparseExperts(nn.Module):
 
 class DecoderBlock(nn.Module):
     """``h += RMS_2(attention(RMS_1(h)))``; ``h += RMS_4(F(RMS_3(h)))``,
-    ``F`` a :class:`GatedMLP` (``moe`` None) or :class:`SparseExperts`."""
+    ``F`` a :class:`GatedMLP` (``moe`` None) or :class:`SparseExperts`;
+    without ``norm_outputs`` the two norms before the branches alone:
+    ``h += attention(RMS_1(h))``; ``h += F(RMS_2(h))``. ``attn`` holds the
+    fields of a :class:`GroupedAttention`, or of a :class:`LatentAttention`
+    where it names a ``latent_dim``."""
 
-    attn: dict                          # GroupedAttention's fields
+    attn: dict                          # the attention layer's fields
     ffn_width: int
     moe: Optional[dict] = None          # SparseExperts' fields
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    norm_outputs: bool = True           # a norm after each branch too
 
     @nn.compact
     def __call__(self, h):
+        def branch_output(y, name):
+            if self.norm_outputs:
+                y = RMSNorm(self.eps, name=name)(y)
+            return y.astype(h.dtype)
+
+        layer = LatentAttention if "latent_dim" in self.attn \
+            else GroupedAttention
         a = RMSNorm(self.eps, name="norm_attn")(h)
-        a = GroupedAttention(eps=self.eps, dtype=self.dtype, name="attn",
-                             **self.attn)(a)
-        h = h + RMSNorm(self.eps, name="norm_attn_out")(a).astype(h.dtype)
+        a = layer(eps=self.eps, dtype=self.dtype, name="attn", **self.attn)(a)
+        h = h + branch_output(a, "norm_attn_out")
         m = RMSNorm(self.eps, name="norm_ffn")(h)
         if self.moe is None:
             m = GatedMLP(self.ffn_width, self.dtype, name="mlp")(m)
         else:
             m = SparseExperts(dtype=self.dtype, name="moe", **self.moe)(m)
-        return h + RMSNorm(self.eps, name="norm_ffn_out")(m).astype(h.dtype)
+        return h + branch_output(m, "norm_ffn_out")
 
     @nn.nowrap
     def kept_bytes(self, tokens: int, width: int) -> int:
@@ -225,19 +314,31 @@ class DecoderBlock(nn.Module):
         recompute themselves (``parallel/moe.py``). An estimate, which
         ``tests/test_chip_smoke.py`` holds to the compiler's count at
         Trinity-Mini's widths (0.99 of it for the dense block, 1.23 for an
-        expert block)."""
+        expert block) and at Moonlight's (1.06 and 1.16)."""
         e = jnp.dtype(self.dtype).itemsize
         a = self.attn
-        q, kv = a["heads"] * a["head_dim"], a["kv_heads"] * a["head_dim"]
-        # the stream before each branch, its norm, and the branch's output
-        a_token = 6 * width * e
-        # the kernels' q, k, v and output, and their log-sum-exp a head:
-        # float32, each a lane row of 128 in HBM
-        a_token += (2 * q + 2 * kv) * e + a["heads"] * 128 * 4
-        if a.get("qk_norm"):
-            a_token += (q + kv) * e             # q and k before their norms
-        if a.get("gate"):
-            a_token += 2 * q * e                # its logits, the gated output
+        # the stream before each branch, its norm and the branch's output
+        # where a norm reads it; where none does, the stream alone (by the
+        # compiler's count the normed copy is then made again in the
+        # products that read it)
+        a_token = (6 if self.norm_outputs else 2) * width * e
+        if "latent_dim" in a:
+            # the compressed row with the rope key before its norm and after,
+            # then the kernels' operands: q, the expanded k and v with the
+            # one rope key, the output
+            row = a["latent_dim"] + a["rope_dim"]
+            a_token += (2 * row + a["heads"] * (
+                2 * a["nope_dim"] + a["rope_dim"] + 2 * a["value_dim"])) * e
+        else:
+            q, kv = a["heads"] * a["head_dim"], a["kv_heads"] * a["head_dim"]
+            a_token += (2 * q + 2 * kv) * e     # the kernels' q, k, v, output
+            if a.get("qk_norm"):
+                a_token += (q + kv) * e         # q and k before their norms
+            if a.get("gate"):
+                a_token += 2 * q * e            # its logits, the gated output
+        # the kernels' log-sum-exp a head: float32, each a lane row of 128 in
+        # HBM
+        a_token += a["heads"] * 128 * 4
         if self.moe is None:
             hidden = self.ffn_width
         else:
@@ -400,10 +501,14 @@ class SparseDecoderLM(nn.Module):
     """Token ids ``[B, L]`` -> float32 logits ``[B, L, vocab]`` (or, with
     ``return_hidden``, the final norm's output for a fused loss).
 
-    ``layer_types``: one of ``"sliding_attention"`` / ``"full_attention"`` a
-    layer; the first ``dense_layers`` have a :class:`GatedMLP` of
-    ``dense_width``, the others :class:`SparseExperts`. ``embed_scale``
-    multiplies the embedding by ``sqrt(embed_dim)``. ``remat`` says how many
+    ``layer_types``: one of ``"sliding_attention"`` / ``"full_attention"``
+    (:class:`GroupedAttention`) / ``"latent_attention"``
+    (:class:`LatentAttention`: ``head_dim`` is a key's own width beside the
+    shared ``rope_dim``, ``value_dim`` a value's, ``latent_dim`` the
+    compressed row's) a layer; the first ``dense_layers`` have a
+    :class:`GatedMLP` of ``dense_width``, the others :class:`SparseExperts`.
+    ``embed_scale`` multiplies the embedding by ``sqrt(embed_dim)``;
+    ``norm_outputs`` is :class:`DecoderBlock`'s. ``remat`` says how many
     block applications (here: blocks) the backward pass runs again instead
     of keeping what they computed: a count or a :class:`RecomputePlan` (the
     first so many; the last ones are kept), ``True`` all, ``False`` none. A
@@ -432,25 +537,36 @@ class SparseDecoderLM(nn.Module):
     attention: Optional[str] = None
     dtype: Any = jnp.bfloat16
     remat: Union[bool, int, RecomputePlan] = False
+    norm_outputs: bool = True
+    rope_dim: int = 0               # a latent layer's three further widths
+    value_dim: int = 0
+    latent_dim: int = 0
 
     @nn.nowrap
     def block(self, i: int) -> DecoderBlock:
         """Layer ``i``'s block, by the name its parameters have."""
         kind = self.layer_types[i]
-        if kind not in (SLIDING, FULL):
+        if kind not in (SLIDING, FULL, LATENT):
             raise ValueError(f"layer {i}: no layer type {kind!r}")
-        attn = dict(heads=self.heads, kv_heads=self.kv_heads,
-                    head_dim=self.head_dim, rope_base=self.rope_base,
-                    window=self.window if kind == SLIDING else None,
-                    rotary=kind == SLIDING, qk_norm=True, gate=True,
-                    attention=self.attention)
+        if kind == LATENT:
+            attn = dict(heads=self.heads, nope_dim=self.head_dim,
+                        rope_dim=self.rope_dim, value_dim=self.value_dim,
+                        latent_dim=self.latent_dim, rope_base=self.rope_base,
+                        attention=self.attention)
+        else:
+            attn = dict(heads=self.heads, kv_heads=self.kv_heads,
+                        head_dim=self.head_dim, rope_base=self.rope_base,
+                        window=self.window if kind == SLIDING else None,
+                        rotary=kind == SLIDING, qk_norm=True, gate=True,
+                        attention=self.attention)
         sparse = None if i < self.dense_layers else dict(
             experts=self.experts, experts_held=self.experts_held,
             first_expert=self.first_expert, top_k=self.top_k,
             width=self.expert_width, route_scale=self.route_scale,
             shared=self.shared_experts)
         return DecoderBlock(attn, self.dense_width, sparse, self.eps,
-                            self.dtype, name=f"DecoderBlock_{i}")
+                            self.dtype, self.norm_outputs,
+                            name=f"DecoderBlock_{i}")
 
     @nn.nowrap
     def applications(self) -> list:

@@ -19,6 +19,13 @@ triangle to a band (query i sees keys j with ``0 <= i - j < window``): the
 packed grid then leaves out the blocks below the band as well. K and V may
 have fewer heads than Q (grouped-query attention): query head h reads KV
 head ``h // (H / G)``, by the kernels' index maps and with no copy of K or V.
+Keys may be wider than values (a latent layer's 192 and 128): the output and
+``dv`` have the values' width, ``dq`` and ``dk`` the keys'. And the last
+columns of every head's key may be one vector a token that all heads share
+(``k_shared [B, L, Dr]``, a latent layer's rope key): the kernels read it by
+index map as they read a grouped K, add its product with the query's last
+``Dr`` columns into the same block of scores, and no ``[B, L, H, Dn + Dr]``
+key is ever written.
 """
 
 from __future__ import annotations
@@ -37,12 +44,25 @@ from horovod_tpu.utils.device import pallas_interpret
 NEG_INF = -1e30  # finite stand-in for -inf: exp() of it is exactly 0
 
 
+def _with_shared_key(k, k_shared):
+    """``k [..., Lk, G, Dn]`` with ``k_shared [..., Lk, Dr]`` appended to
+    every head's key: what the kernels compute without writing it."""
+    if k_shared is None:
+        return k
+    shared = jnp.broadcast_to(k_shared[..., None, :],
+                              k.shape[:-1] + k_shared.shape[-1:])
+    return jnp.concatenate([k, shared.astype(k.dtype)], -1)
+
+
 def dot_product_attention(q, k, v, causal: bool = False,
                           scale: Optional[float] = None,
                           q_offset: int = 0, k_offset: int = 0,
-                          window: Optional[int] = None):
-    """Reference attention. Shapes: q [..., Lq, H, D], k/v [..., Lk, G, D]
-    with ``G`` dividing ``H`` (query head h reads KV head ``h // (H / G)``).
+                          window: Optional[int] = None, k_shared=None):
+    """Reference attention. Shapes: q [..., Lq, H, Dk], k [..., Lk, G, Dk],
+    v [..., Lk, G, Dv] with ``G`` dividing ``H`` (query head h reads KV head
+    ``h // (H / G)``); the result is ``[..., Lq, H, Dv]``. With ``k_shared
+    [..., Lk, Dr]`` ``k`` holds the first ``Dk - Dr`` columns of every key
+    and ``k_shared`` the rest, the same for all heads.
 
     ``q_offset``/``k_offset`` are the global positions of the first query/
     key token — block-parallel callers (ring attention) pass their shard's
@@ -51,6 +71,7 @@ def dot_product_attention(q, k, v, causal: bool = False,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    k = _with_shared_key(k, k_shared)
     rep = _kv_group(q.shape[-2], k.shape[-2])
     if rep > 1:
         k, v = (jnp.repeat(t, rep, axis=-2) for t in (k, v))
@@ -240,9 +261,25 @@ def flash_grid_info(seq_q: int, seq_k: int, *, causal: bool,
 # Pallas flash attention
 
 
+def _block_scores(q, k_blk, ks_ref, scale: float):
+    """``q k^T * scale`` of one (q-block, k-block) pair, float32. Matmuls run
+    in the INPUT dtype with f32 accumulation (preferred_element_type): bf16
+    inputs hit the MXU's native bf16xbf16->f32 path (an f32xf32 matmul costs
+    ~3 passes on TPU); f32 test inputs keep the all-f32 exactness the CI
+    pins. With a shared key (``ks_ref [block_k, Dr]``) the query's last
+    ``Dr`` columns meet it and the others the head's own ``k_blk``: two
+    products into one block of scores."""
+    if ks_ref is None:
+        return jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
+    own = k_blk.shape[-1]
+    return (jnp.dot(q[:, :own], k_blk.T, preferred_element_type=jnp.float32)
+            + jnp.dot(q[:, own:], ks_ref[...].T,
+                      preferred_element_type=jnp.float32)) * scale
+
+
 def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
                   scale: float, block_q: int, delta: int, packed: bool,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, shared: bool = False):
     """One streamed-forward grid step. Two grid layouts share this body:
 
     * full (``packed=False``) — grid (batch*head, q-block, K-BLOCK): the
@@ -276,16 +313,16 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
 
     if packed:
         qi_tab, kb_tab = refs[:2]
-        (q_ref, k_ref, v_ref, o_ref, lse_ref,
-         m_scr, l_scr, acc_scr) = refs[2:]
+        refs = refs[2:]
         t = pl.program_id(1)
         qi = qi_tab[t]
         kb = kb_tab[t]
     else:
-        (q_ref, k_ref, v_ref, o_ref, lse_ref,
-         m_scr, l_scr, acc_scr) = refs
         qi = pl.program_id(1)
         kb = pl.program_id(2)
+    q_ref, k_ref = refs[:2]
+    ks_ref = refs[2] if shared else None
+    v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[2 + shared:]
 
     # The first step of every q-block: k-block 0 in the full layout, and in
     # the packed one the first block the tables' q-major walk gives it
@@ -302,16 +339,11 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def _compute():
-        # Matmuls run in the INPUT dtype with f32 accumulation
-        # (preferred_element_type): bf16 inputs hit the MXU's native
-        # bf16xbf16->f32 path (an f32xf32 matmul costs ~3 passes on
-        # TPU); f32 test inputs keep the all-f32 exactness the CI pins.
-        # All softmax statistics stay f32 regardless.
-        q = q_ref[...]                              # [block_q, d]
-        k_blk = k_ref[...]                          # [block_k, d]
-        v_blk = v_ref[...]
-        s = jnp.dot(q, k_blk.T,
-                    preferred_element_type=jnp.float32) * scale
+        # All softmax statistics stay f32 whatever the inputs' type.
+        q = q_ref[...]                              # [block_q, dk]
+        k_blk = k_ref[...]                          # [block_k, dk]
+        v_blk = v_ref[...]                          # [block_k, dv]
+        s = _block_scores(q, k_blk, ks_ref, scale)
         if causal:
             q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -408,7 +440,11 @@ class AttentionPlan(NamedTuple):
 # 256 x 256; 4,096 keys: 5.33 / 6.37 / 13.03; heads of 128, window 2,048:
 # 10.25 / 11.17); a longer side under the same 4 MB of f32 scores (512 x
 # 2,048, 2,048 x 512) loses 18 to 42%. Mosaic accepts it for f32 inputs and
-# heads of 32 to 512 (compiled for the v5e, not run).
+# heads of 32 to 512 (compiled for the v5e, not run). Measured again at keys
+# of 192 beside values of 128 with a shared rope key (16 heads, 8,192 keys,
+# chip run of PR 34: ``--block-sweep latent_8192``): 1,024 x 1,024 27.57
+# against 28.63 at 512 x 1,024, 30.41 at 512 x 2,048, 32.41 at 512 x 512 and
+# 32.75 at 1,024 x 512; 2,048 x 512 and larger Mosaic refuses there for VMEM.
 FLASH_BLOCK = 1024
 # Smallest block the kernels are chosen at: at 256 x 256 they lose to dense
 # at 1,024 keys (4.38 against 3.95) and win by 2 to 5% at 2,048 and 4,096.
@@ -425,21 +461,23 @@ FLASH_BWD = "pallas"
 
 
 def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
-                   head_dim: int, window: Optional[int] = None,
+                   head_dim, window: Optional[int] = None,
                    dtype=jnp.bfloat16,
                    backend: Optional[str] = None) -> AttentionPlan:
     """Implementation, blocks and backward of one causal attention call, from
-    what is static about it. ``backend`` defaults to JAX's own.
+    what is static about it. ``head_dim`` is the one width of queries, keys
+    and values, or ``(keys' width, values' width)`` where they differ.
+    ``backend`` defaults to JAX's own.
 
     The kernels run on a TPU, from :data:`FLASH_MIN_KEYS` keys on, where a
     block of at least :data:`FLASH_MIN_BLOCK` divides both lengths; the
     dense reference everywhere else: the CPU test platform would interpret
-    the kernels, and below those sizes nothing was measured. The sweep found
-    no dependence on the number of heads, on grouping, on the head width
-    (64 and 128), on a window or on the element type that would change a
-    choice, so today the answer depends on the lengths alone; the other
-    arguments are what a later measurement may key on without a new call
-    site.
+    the kernels, and below those sizes nothing was measured. The sweeps found
+    no dependence on the number of heads, on grouping, on the widths (64,
+    128, and keys of 192 beside values of 128), on a window or on the element
+    type that would change a choice, so today the answer depends on the
+    lengths alone; the other arguments are what a later measurement may key
+    on without a new call site.
     """
     _kv_group(heads, kv_heads)
     del head_dim, window, dtype
@@ -474,9 +512,13 @@ _traced: dict = {}
 
 
 def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
-           impl: Optional[str] = None, **flash_args):
-    """Causal attention as a model's block calls it: q ``[B, L, H, D]``, k/v
-    ``[B, L, G, D]``. :func:`attention_plan` picks the implementation from
+           impl: Optional[str] = None, scale: Optional[float] = None,
+           k_shared=None, **flash_args):
+    """Causal attention as a model's block calls it: q ``[B, L, H, Dk]``, k
+    ``[B, L, G, Dk]``, v ``[B, L, G, Dv]``, scores times ``scale`` (``Dk **
+    -0.5`` unless the caller says); with ``k_shared [B, L, Dr]`` ``k`` holds
+    each head's own ``Dk - Dr`` key columns and ``k_shared`` the rest, the
+    same for all heads. :func:`attention_plan` picks the implementation from
     the shapes unless ``impl`` (``"dense"`` | ``"flash"``) pins one;
     ``flash_args`` go to :func:`flash_attention` (an A/B's ``truncate`` and
     ``bwd_impl``). Sets the gauges ``hvd.attn.flash_calls`` /
@@ -485,8 +527,8 @@ def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
     if impl is None:
         # an offset mask is outside what the policy was measured on
         impl = "dense" if q_offset else attention_plan(
-            q.shape[1], k.shape[1], q.shape[2], k.shape[2], q.shape[3],
-            window, q.dtype).impl
+            q.shape[1], k.shape[1], q.shape[2], k.shape[2],
+            (q.shape[3], v.shape[3]), window, q.dtype).impl
     if impl not in ("dense", "flash"):
         raise ValueError(f"impl must be dense|flash, got {impl!r}")
     program, calls = timeline.program_tally(
@@ -495,15 +537,17 @@ def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
     for name, n in calls.items():
         timeline.gauge(f"hvd.attn.{name}_calls", n, key=program)
     if impl == "dense":
-        return dot_product_attention(q, k, v, causal=True, q_offset=q_offset,
-                                     window=window)
+        return dot_product_attention(q, k, v, causal=True, scale=scale,
+                                     q_offset=q_offset, window=window,
+                                     k_shared=k_shared)
     block_q, block_k = _planned_blocks(
         q.shape[1], k.shape[1], flash_args.get("block_q"),
         flash_args.get("block_k"))
     timeline.gauge("hvd.attn.block_q", block_q, key=program)
     timeline.gauge("hvd.attn.block_k", block_k, key=program)
-    return flash_attention(q, k, v, causal=True, q_offset=q_offset,
-                           window=window, **flash_args)
+    return flash_attention(q, k, v, causal=True, scale=scale,
+                           q_offset=q_offset, window=window,
+                           k_shared=k_shared, **flash_args)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
@@ -519,11 +563,15 @@ def flash_attention(q, k, v, causal: bool = False,
                     bwd_impl: Optional[str] = None,
                     q_offset: int = 0, k_offset: int = 0,
                     truncate: Optional[bool] = None,
-                    window: Optional[int] = None):
-    """Pallas flash attention. Shapes q [B, L, H, D], k/v [B, L, G, D] ->
-    [B, L, H, D]; ``G`` divides ``H`` and query head h reads KV head
-    ``h // (H / G)`` (grouped-query attention; K and V are never repeated:
-    the kernels' index maps pick the group's block).
+                    window: Optional[int] = None, k_shared=None):
+    """Pallas flash attention. Shapes q [B, L, H, Dk], k [B, L, G, Dk],
+    v [B, L, G, Dv] -> [B, L, H, Dv]; ``G`` divides ``H`` and query head h
+    reads KV head ``h // (H / G)`` (grouped-query attention; K and V are
+    never repeated: the kernels' index maps pick the group's block). With
+    ``k_shared [B, L, Dr]`` (needs ``G == H``) ``k`` is ``[B, L, H, Dk -
+    Dr]``, the columns of each key that are the head's own, and ``k_shared``
+    the last ``Dr``, one vector a token for all heads, read by index map
+    too. ``scale`` defaults to ``Dk ** -0.5``.
 
     ``window`` (static; plain causal square attention only) lets query i
     see keys j with ``0 <= i - j < window``: the mask is applied inside the
@@ -583,6 +631,13 @@ def flash_attention(q, k, v, causal: bool = False,
             f"(got {q_offset} < {k_offset}): rows with no visible key "
             f"have no defined softmax")
     _kv_group(q.shape[2], k.shape[2])
+    own = q.shape[-1] - (0 if k_shared is None else k_shared.shape[-1])
+    if k.shape[-1] != own:
+        raise ValueError(f"queries of {q.shape[-1]} need keys of {own} a "
+                         f"head, got k {k.shape}")
+    if k_shared is not None and k.shape[2] != q.shape[2]:
+        raise ValueError("a shared key needs a key head a query head, got "
+                         f"{k.shape[2]} under {q.shape[2]}")
     if window is not None:
         if not (causal and q.shape[1] == k.shape[1]
                 and q_offset == k_offset) or window < 1:
@@ -594,17 +649,18 @@ def flash_attention(q, k, v, causal: bool = False,
                 f"could be left with no key at all")
         if window >= k.shape[1]:
             window = None              # the band is the whole triangle
-    return _flash(q, k, v, causal, float(scale), block_q, block_k,
+    return _flash(q, k, v, k_shared, causal, float(scale), block_q, block_k,
                   interpret, bwd_impl, int(q_offset), int(k_offset),
                   truncate, window)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bwd_impl,
-           q_offset, k_offset, truncate, window=None):
-    out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret, q_offset, k_offset, truncate, window)
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
+def _flash(q, k, v, k_shared, causal, scale, block_q, block_k, interpret,
+           bwd_impl, q_offset, k_offset, truncate, window=None):
+    out, _ = _flash_forward(q, k, v, k_shared, causal, scale, block_q,
+                            block_k, interpret, q_offset, k_offset, truncate,
+                            window)
     return out
 
 
@@ -615,14 +671,17 @@ DQ_KERNEL = "hvd_flash_dq"
 DKV_KERNEL = "hvd_flash_dkv"
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   q_offset=0, k_offset=0, truncate=None, window=None):
-    """Returns (out [B, Lq, H, D], lse [B, H, Lq])."""
+def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
+                   interpret, q_offset=0, k_offset=0, truncate=None,
+                   window=None):
+    """Returns (out [B, Lq, H, Dv], lse [B, H, Lq])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, Lq, H, D = q.shape
+    B, Lq, H, D = q.shape               # D: the queries' width, the keys'
     Lk, G = k.shape[1], k.shape[2]
+    Dn, Dv = k.shape[-1], v.shape[-1]   # a head's own key columns; values
+    shared = k_shared is not None
     rep = _kv_group(H, G)      # program bh = b*H + h reads KV row bh // rep
     block_q = min(block_q, Lq)
     block_k = min(block_k, Lk)
@@ -633,19 +692,20 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     # Collapse (B, H) into the grid's first axis; put seq minor-most for
     # contiguous VMEM tiles.
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
+    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dn)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dv)
+    keys = (kr, k_shared) if shared else (kr,)  # k_shared is [B, Lk, Dr]
 
     n_qblocks = Lq // block_q
     n_kblocks = Lk // block_k
     out_shape = [
-        jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
+        jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
         jax.ShapeDtypeStruct((B * H, Lq, 1), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
         pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
-        pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
+        pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
     ]
     if truncated:
         qi_tab, kb_tab = _causal_step_tables(n_qblocks, n_kblocks,
@@ -654,7 +714,14 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         kernel = functools.partial(_flash_kernel, block_k=block_k,
                                    n_kblocks=n_kblocks, causal=causal,
                                    scale=scale, block_q=block_q,
-                                   delta=0, packed=True, window=window)
+                                   delta=0, packed=True, window=window,
+                                   shared=shared)
+        key_specs = [pl.BlockSpec((None, block_k, Dn),
+                                  lambda bh, t, qi, kb: (bh // rep, kb[t], 0))]
+        if shared:                  # program bh = b*H + h reads row b
+            key_specs.append(pl.BlockSpec(
+                (None, block_k, D - Dn),
+                lambda bh, t, qi, kb: (bh // H, kb[t], 0)))
         # The STEP axis enumerates only the live at-or-below-diagonal
         # (q-block, k-block) pairs — ~(n+1)/2n of the full causal grid.
         # Still sequential ("arbitrary") so the scratch-carried softmax
@@ -667,13 +734,12 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             in_specs=[
                 pl.BlockSpec((None, block_q, D),
                              lambda bh, t, qi, kb: (bh, qi[t], 0)),
-                pl.BlockSpec((None, block_k, D),
-                             lambda bh, t, qi, kb: (bh // rep, kb[t], 0)),
-                pl.BlockSpec((None, block_k, D),
+                *key_specs,
+                pl.BlockSpec((None, block_k, Dv),
                              lambda bh, t, qi, kb: (bh // rep, kb[t], 0)),
             ],
             out_specs=[
-                pl.BlockSpec((None, block_q, D),
+                pl.BlockSpec((None, block_q, Dv),
                              lambda bh, t, qi, kb: (bh, qi[t], 0)),
                 # [block_q, 1] column per program — the statistics'
                 # native layout (see the kernel's Mosaic-discipline
@@ -691,12 +757,18 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret, name=FWD_KERNEL,
-        )(jnp.asarray(qi_tab), jnp.asarray(kb_tab), qr, kr, vr)
+        )(jnp.asarray(qi_tab), jnp.asarray(kb_tab), qr, *keys, vr)
     else:
         kernel = functools.partial(_flash_kernel, block_k=block_k,
                                    n_kblocks=n_kblocks, causal=causal,
                                    scale=scale, block_q=block_q,
-                                   delta=delta, packed=False, window=window)
+                                   delta=delta, packed=False, window=window,
+                                   shared=shared)
+        key_specs = [pl.BlockSpec((None, block_k, Dn),
+                                  lambda bh, qb, kb: (bh // rep, kb, 0))]
+        if shared:
+            key_specs.append(pl.BlockSpec(
+                (None, block_k, D - Dn), lambda bh, qb, kb: (bh // H, kb, 0)))
         out, lse = pl.pallas_call(
             kernel,
             # K blocks ride the grid's INNERMOST axis: sequential
@@ -707,13 +779,12 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             in_specs=[
                 pl.BlockSpec((None, block_q, D),
                              lambda bh, qb, kb: (bh, qb, 0)),
-                pl.BlockSpec((None, block_k, D),
-                             lambda bh, qb, kb: (bh // rep, kb, 0)),
-                pl.BlockSpec((None, block_k, D),
+                *key_specs,
+                pl.BlockSpec((None, block_k, Dv),
                              lambda bh, qb, kb: (bh // rep, kb, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((None, block_q, D),
+                pl.BlockSpec((None, block_q, Dv),
                              lambda bh, qb, kb: (bh, qb, 0)),
                 pl.BlockSpec((None, block_q, 1),
                              lambda bh, qb, kb: (bh, qb, 0)),
@@ -723,21 +794,24 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret, name=FWD_KERNEL,
-        )(qr, kr, vr)
-    return (out.reshape(B, H, Lq, D).transpose(0, 2, 1, 3),
+        )(qr, *keys, vr)
+    return (out.reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3),
             lse.reshape(B, H, Lq))
 
 
-def _flash_fwd_vjp(q, k, v, causal, scale, block_q, block_k, interpret,
-                   bwd_impl, q_offset, k_offset, truncate, window=None):
-    o, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret, q_offset, k_offset, truncate, window)
-    return o, (q, k, v, o, lse)
+def _flash_fwd_vjp(q, k, v, k_shared, causal, scale, block_q, block_k,
+                   interpret, bwd_impl, q_offset, k_offset, truncate,
+                   window=None):
+    o, lse = _flash_forward(q, k, v, k_shared, causal, scale, block_q,
+                            block_k, interpret, q_offset, k_offset, truncate,
+                            window)
+    return o, (q, k, v, k_shared, o, lse)
 
 
 def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
                          block_k: int, n_kblocks: int, delta: int,
-                         packed: bool, window: Optional[int] = None):
+                         packed: bool, window: Optional[int] = None,
+                         shared: bool = False):
     """dQ: full grid (batch*head, q-block, K-BLOCK stream) or the packed
     q-major causal grid (batch*head, STEP) — same layout split as
     :func:`_flash_kernel`. Standard FlashAttention-2 recurrence against
@@ -750,16 +824,16 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
 
     if packed:
         qi_tab, kb_tab = refs[:2]
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
-         dq_ref, dq_scr) = refs[2:]
+        refs = refs[2:]
         t = pl.program_id(1)
         qi = qi_tab[t]
         kb = kb_tab[t]
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
-         dq_ref, dq_scr) = refs
         qi = pl.program_id(1)
         kb = pl.program_id(2)
+    q_ref, k_ref = refs[:2]
+    ks_ref = refs[2] if shared else None
+    v_ref, do_ref, lse_ref, d_ref, dq_ref, dq_scr = refs[2 + shared:]
 
     first_kb = (_first_kblock(qi, block_q, block_k, window, jnp.maximum)
                 if packed else 0)
@@ -769,12 +843,12 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
     def _compute():
-        # Input-dtype matmuls, f32 accumulation (see _flash_kernel).
+        # Input-dtype matmuls, f32 accumulation (see _block_scores).
         q = q_ref[...]
         k_blk = k_ref[...]
         v_blk = v_ref[...]
         do_blk = do_ref[...]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
+        s = _block_scores(q, k_blk, ks_ref, scale)
         if causal:
             q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -784,8 +858,15 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
         p = jnp.exp(s - lse_ref[...])                    # [bq, bk]
         dp = jnp.dot(do_blk, v_blk.T, preferred_element_type=jnp.float32)
         ds = p * (dp - d_ref[...])
-        dq_scr[...] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
-                               preferred_element_type=jnp.float32) * scale
+        if ks_ref is None:
+            dq_scr[...] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
+                                   preferred_element_type=jnp.float32) * scale
+        else:                   # the query's own columns, then the shared
+            own, ds = k_blk.shape[-1], ds.astype(k_blk.dtype)
+            dq_scr[:, :own] += jnp.dot(
+                ds, k_blk, preferred_element_type=jnp.float32) * scale
+            dq_scr[:, own:] += jnp.dot(
+                ds, ks_ref[...], preferred_element_type=jnp.float32) * scale
 
     if causal and not packed:
         pl.when(_block_live(qi, kb, block_q, block_k, delta,
@@ -806,18 +887,20 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
 
 def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                           block_k: int, n_qblocks: int, delta: int,
-                          packed: bool, window: Optional[int] = None):
+                          packed: bool, window: Optional[int] = None,
+                          shared: bool = False):
     """dK/dV: full grid (batch*head, k-block, Q-BLOCK stream) or the
     packed K-MAJOR causal grid — transposing the dQ kernel's roles, so
     the truncated region is the symmetric above-diagonal half over the
     q axis (each k-block's stream starts at its diagonal q-block):
-        dV_j = sum_i P_ij^T dO_i;  dK_j = sum_i dS_ij^T Q_i * scale"""
+        dV_j = sum_i P_ij^T dO_i;  dK_j = sum_i dS_ij^T Q_i * scale
+    With a shared key its gradient comes out a query head beside dK (the
+    query's last columns' part), to be summed over heads outside."""
     from jax.experimental import pallas as pl
 
     if packed:
         qi_tab, kb_tab = refs[:2]
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
-         dk_ref, dv_ref, dk_scr, dv_scr) = refs[2:]
+        refs = refs[2:]
         t = pl.program_id(1)
         qi = qi_tab[t]
         kb = kb_tab[t]
@@ -827,25 +910,33 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
         last_qi = _last_qblock(kb, block_q, block_k, n_qblocks, window,
                                jnp.minimum)
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
-         dk_ref, dv_ref, dk_scr, dv_scr) = refs
         kb = pl.program_id(1)
         qi = pl.program_id(2)
         first_qi = 0
         last_qi = n_qblocks - 1
+    q_ref, k_ref = refs[:2]
+    ks_ref = refs[2] if shared else None
+    v_ref, do_ref, lse_ref, d_ref = refs[2 + shared:6 + shared]
+    outs = refs[6 + shared:]
+    if shared:
+        dk_ref, dks_ref, dv_ref, dk_scr, dks_scr, dv_scr = outs
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = outs
 
     @pl.when(qi == first_qi)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        if shared:
+            dks_scr[...] = jnp.zeros(dks_scr.shape, jnp.float32)
 
     def _compute():
-        # Input-dtype matmuls, f32 accumulation (see _flash_kernel).
+        # Input-dtype matmuls, f32 accumulation (see _block_scores).
         q = q_ref[...]
         k_blk = k_ref[...]
         v_blk = v_ref[...]
         do_blk = do_ref[...]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
+        s = _block_scores(q, k_blk, ks_ref, scale)
         if causal:
             q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -857,8 +948,15 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                                preferred_element_type=jnp.float32)
         dp = jnp.dot(do_blk, v_blk.T, preferred_element_type=jnp.float32)
         ds = p * (dp - d_ref[...])
-        dk_scr[...] += jnp.dot(ds.T.astype(q.dtype), q,
-                               preferred_element_type=jnp.float32) * scale
+        if ks_ref is None:
+            dk_scr[...] += jnp.dot(ds.T.astype(q.dtype), q,
+                                   preferred_element_type=jnp.float32) * scale
+        else:
+            own, ds_t = k_blk.shape[-1], ds.T.astype(q.dtype)
+            dk_scr[...] += jnp.dot(
+                ds_t, q[:, :own], preferred_element_type=jnp.float32) * scale
+            dks_scr[...] += jnp.dot(
+                ds_t, q[:, own:], preferred_element_type=jnp.float32) * scale
 
     if causal and not packed:
         # Q-blocks fully ABOVE the diagonal (every q_pos < every k_pos),
@@ -872,6 +970,8 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
     def _finalize():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        if shared:
+            dks_ref[...] = dks_scr[...].astype(dks_ref.dtype)
 
 
 def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
@@ -887,9 +987,10 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
     repeated to the query heads here (the slabs are per query head anyway)
     and a ``window`` only masks: the kernel split is the path that skips."""
     del truncate  # no grid to truncate: the scan bound below early-exits
-    q, k, v, o, lse = res
+    q, k_own, v, k_shared, o, lse = res
+    k = _with_shared_key(k_own, k_shared)       # written out here: a slab
     B, Lq, H, D = q.shape
-    Lk, G = k.shape[1], k.shape[2]
+    Lk, G, Dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = _kv_group(H, G)
     if rep > 1:
         k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
@@ -935,14 +1036,20 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
     dq, (dks, dvs) = jax.lax.scan(
         bwd_step, jnp.zeros(q.shape, jnp.float32), jnp.arange(nkb_live))
     dk = dks.transpose(1, 0, 2, 3, 4).reshape(B, nkb_live * bk, H, D)
-    dv = dvs.transpose(1, 0, 2, 3, 4).reshape(B, nkb_live * bk, H, D)
+    dv = dvs.transpose(1, 0, 2, 3, 4).reshape(B, nkb_live * bk, H, Dv)
     if nkb_live < nkb:
         pad = [(0, 0), (0, Lk - nkb_live * bk), (0, 0), (0, 0)]
         dk = jnp.pad(dk, pad)
         dv = jnp.pad(dv, pad)
     if rep > 1:
-        dk, dv = (t.reshape(B, Lk, G, rep, D).sum(3) for t in (dk, dv))
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        dk, dv = (t.reshape(B, Lk, G, rep, t.shape[-1]).sum(3)
+                  for t in (dk, dv))
+    dks = None
+    if k_shared is not None:    # the shared columns' part, over the heads
+        own = k_own.shape[-1]
+        dk, dks = dk[..., :own], dk[..., own:].sum(2).astype(k_shared.dtype)
+    return (dq.astype(q.dtype), dk.astype(k_own.dtype), dv.astype(v.dtype),
+            dks)
 
 
 def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
@@ -967,9 +1074,11 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    q, k, v, o, lse = res
+    q, k, v, k_shared, o, lse = res
     B, Lq, H, D = q.shape
     Lk, G = k.shape[1], k.shape[2]
+    Dn, Dv = k.shape[-1], v.shape[-1]
+    shared = k_shared is not None
     rep = _kv_group(H, G)
     bq = min(block_q, Lq)
     bk = min(block_k, Lk)
@@ -979,125 +1088,107 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     truncated = _grid_truncates(causal, Lq, Lk, q_offset, k_offset, truncate)
 
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, D)
-    dor = do.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
+    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dn)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dv)
+    keys = (kr, k_shared) if shared else (kr,)
+    dor = do.transpose(0, 2, 1, 3).reshape(B * H, Lq, Dv)
     # lse arrives [B, H, Lq]; D_i rowsum in fp32. Both as [bh, Lq, 1]
     # columns — the statistics' native kernel layout.
     lser = lse.reshape(B * H, Lq, 1)
     d_row = jnp.sum(dor.astype(jnp.float32)
-                    * o.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
+                    * o.transpose(0, 2, 1, 3).reshape(B * H, Lq, Dv)
                     .astype(jnp.float32), axis=-1, keepdims=True)
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_kblocks=nkb, delta=0 if truncated else delta,
-        packed=truncated, window=window)
+        packed=truncated, window=window, shared=shared)
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_qblocks=nqb, delta=0 if truncated else delta,
-        packed=truncated, window=window)
+        packed=truncated, window=window, shared=shared)
     dq_out_shape = jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype)
     dkv_dtype = jnp.float32 if rep > 1 else k.dtype    # a group's are summed
-    dkv_out_shape = [
-        jax.ShapeDtypeStruct((B * H, Lk, D), dkv_dtype),
-        jax.ShapeDtypeStruct((B * H, Lk, D), dkv_dtype),
-    ]
+    # dK, the shared key's gradient a query head (float32: summed over the
+    # heads below), dV
+    dkv_out_shape = [jax.ShapeDtypeStruct((B * H, Lk, Dn), dkv_dtype)] \
+        + [jax.ShapeDtypeStruct((B * H, Lk, D - Dn), jnp.float32)] * shared \
+        + [jax.ShapeDtypeStruct((B * H, Lk, Dv), dkv_dtype)]
+    dkv_scratch = [pltpu.VMEM((bk, Dn), jnp.float32)] \
+        + [pltpu.VMEM((bk, D - Dn), jnp.float32)] * shared \
+        + [pltpu.VMEM((bk, Dv), jnp.float32)]
 
-    if truncated:
-        # Packed causal grids: q-major steps for dQ (k-blocks stream
-        # within a q-block), k-major for dK/dV (q-blocks stream within
-        # a k-block, starting at the diagonal).
-        qi_q, kb_q = _causal_step_tables(nqb, nkb, bq, bk, window=window)
-        qi_k, kb_k = _causal_step_tables(nqb, nkb, bq, bk, k_major=True,
+    def call(kernel, name, k_major, out_widths, out_shape, scratch):
+        """One backward kernel over its grid. The packed causal grids read
+        their (q-block, k-block) off the scalar-prefetched step tables
+        (q-major steps for dQ: k-blocks stream within a q-block; k-major for
+        dK/dV: q-blocks stream within a k-block, starting at the diagonal),
+        the full grids off their two block axes, which the dK/dV grid
+        transposes: (bh, k-block, q-stream). dQ writes a block of q rows,
+        dK/dV blocks of keys, each a query head's own, ``out_widths`` wide."""
+        if truncated:
+            at_q = lambda bh, t, qi, kb: qi[t]              # noqa: E731
+            at_k = lambda bh, t, qi, kb: kb[t]              # noqa: E731
+        elif k_major:
+            at_q = lambda bh, j, i: i                       # noqa: E731
+            at_k = lambda bh, j, i: j                       # noqa: E731
+        else:
+            at_q = lambda bh, i, j: i                       # noqa: E731
+            at_k = lambda bh, i, j: j                       # noqa: E731
+
+        def rows(width):            # a query head's block of q rows
+            return pl.BlockSpec((None, bq, width),
+                                lambda bh, *g: (bh, at_q(bh, *g), 0))
+
+        def keys_of(width, per=None):   # a block of keys: the query head's
+            if per is None:             # own row, or row ``bh // per``
+                return pl.BlockSpec((None, bk, width),
+                                    lambda bh, *g: (bh, at_k(bh, *g), 0))
+            return pl.BlockSpec((None, bk, width),
+                                lambda bh, *g: (bh // per, at_k(bh, *g), 0))
+
+        ins = [rows(D), keys_of(Dn, rep)] \
+            + [keys_of(D - Dn, H)] * shared \
+            + [keys_of(Dv, rep), rows(Dv), rows(1), rows(1)]
+        out_specs = [(keys_of if k_major else rows)(w) for w in out_widths]
+        if truncated:
+            tables = _causal_step_tables(nqb, nkb, bq, bk, k_major=k_major,
                                          window=window)
-        qspec = pl.BlockSpec((None, bq, D),
-                             lambda bh, t, qi, kb: (bh, qi[t], 0))
-        kspec = pl.BlockSpec((None, bk, D),
-                             lambda bh, t, qi, kb: (bh // rep, kb[t], 0))
-        dkv_spec = pl.BlockSpec((None, bk, D),
-                                lambda bh, t, qi, kb: (bh, kb[t], 0))
-        col_q = pl.BlockSpec((None, bq, 1),
-                             lambda bh, t, qi, kb: (bh, qi[t], 0))
-        dq = pl.pallas_call(
-            dq_kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B * H, int(qi_q.size)),
-                in_specs=[qspec, kspec, kspec, qspec, col_q, col_q],
-                out_specs=qspec,
-                scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]),
-            out_shape=dq_out_shape,
+            how = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(B * H, int(tables[0].size)),
+                in_specs=ins, out_specs=out_specs, scratch_shapes=scratch))
+            semantics = ("parallel", "arbitrary")
+        else:
+            tables = ()
+            how = dict(grid=(B * H, nkb, nqb) if k_major
+                       else (B * H, nqb, nkb),
+                       in_specs=ins, out_specs=out_specs,
+                       scratch_shapes=scratch)
+            semantics = ("parallel", "parallel", "arbitrary")
+        return pl.pallas_call(
+            kernel, out_shape=out_shape, interpret=interpret, name=name,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret, name=DQ_KERNEL,
-        )(jnp.asarray(qi_q), jnp.asarray(kb_q), qr, kr, vr, dor, lser,
-          d_row)
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B * H, int(qi_k.size)),
-                in_specs=[qspec, kspec, kspec, qspec, col_q, col_q],
-                out_specs=[dkv_spec, dkv_spec],
-                scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                                pltpu.VMEM((bk, D), jnp.float32)]),
-            out_shape=dkv_out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret, name=DKV_KERNEL,
-        )(jnp.asarray(qi_k), jnp.asarray(kb_k), qr, kr, vr, dor, lser,
-          d_row)
-    else:
-        qspec = pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0))
-        kspec = pl.BlockSpec((None, bk, D),
-                             lambda bh, i, j: (bh // rep, j, 0))
-        col_q = pl.BlockSpec((None, bq, 1), lambda bh, i, j: (bh, i, 0))
-        dq = pl.pallas_call(
-            dq_kernel,
-            grid=(B * H, nqb, nkb),
-            in_specs=[qspec, kspec, kspec, qspec, col_q, col_q],
-            out_specs=pl.BlockSpec((None, bq, D),
-                                   lambda bh, i, j: (bh, i, 0)),
-            out_shape=dq_out_shape,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret, name=DQ_KERNEL,
-        )(qr, kr, vr, dor, lser, d_row)
+                dimension_semantics=semantics), **how,
+        )(*map(jnp.asarray, tables), qr, *keys, vr, dor, lser, d_row)
 
-        # dK/dV grid transposes the stream: (bh, k-block, q-stream).
-        qspec_t = pl.BlockSpec((None, bq, D), lambda bh, j, i: (bh, i, 0))
-        kspec_t = pl.BlockSpec((None, bk, D),
-                               lambda bh, j, i: (bh // rep, j, 0))
-        col_q_t = pl.BlockSpec((None, bq, 1), lambda bh, j, i: (bh, i, 0))
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            grid=(B * H, nkb, nqb),
-            in_specs=[qspec_t, kspec_t, kspec_t, qspec_t, col_q_t,
-                      col_q_t],
-            out_specs=[
-                pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
-                pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
-            ],
-            out_shape=dkv_out_shape,
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret, name=DKV_KERNEL,
-        )(qr, kr, vr, dor, lser, d_row)
-
-    def unflat(t, L):
-        return t.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+    dq, = call(dq_kernel, DQ_KERNEL, False, [D], [dq_out_shape],
+               [pltpu.VMEM((bq, D), jnp.float32)])
+    dk, *dks, dv = call(dkv_kernel, DKV_KERNEL, True,
+                        [Dn] + [D - Dn] * shared + [Dv], dkv_out_shape,
+                        dkv_scratch)
 
     def grouped(t, like):
         """A KV head's gradient: the sum over the query heads that read it."""
+        width = t.shape[-1]
         if rep > 1:
-            t = t.reshape(B, G, rep, Lk, D).sum(2)
-        return t.reshape(B, G, Lk, D).transpose(0, 2, 1, 3).astype(like.dtype)
+            t = t.reshape(B, G, rep, Lk, width).sum(2)
+        return t.reshape(B, G, Lk, width).transpose(0, 2, 1, 3) \
+            .astype(like.dtype)
 
-    return unflat(dq, Lq), grouped(dk, k), grouped(dv, v)
+    return (dq.reshape(B, H, Lq, D).transpose(0, 2, 1, 3), grouped(dk, k),
+            grouped(dv, v),
+            dks[0].reshape(B, H, Lk, D - Dn).sum(1).astype(k_shared.dtype)
+            if shared else None)
 
 
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,

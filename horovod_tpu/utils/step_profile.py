@@ -16,8 +16,9 @@ session writes and returns, for the training steps it holds:
   into a neighbour counts with the neighbour. Times are self times: an
   operation that contains others (a loop, a call) gives its time to them.
 * **layers**: the same self times by the layer scopes inside ``hvd_forward``
-  (``timeline.LAYER_SCOPES``: attention by the layer's type, the parts of a
-  sparse expert layer, a looped model's applications of its stack and its
+  (``timeline.LAYER_SCOPES``: attention by the layer's type, a latent
+  layer's compression and expansion, the parts of a sparse expert layer, a
+  looped model's applications of its stack and its
   exit gate; its chunked exit loss lies inside ``hvd_loss``), forward,
   recomputed and backward together; the innermost name wins. Empty for a model
   that names no layer. The grouped products of an expert layer (``%ragged-

@@ -109,6 +109,9 @@ METRICS = "hvd_metrics"     # accuracy, the loss all-reduce, the read-out
 # ``hvd_loss``.
 ATTN_WINDOW = "hvd_attn_window"     # attention of a sliding-window layer
 ATTN_FULL = "hvd_attn_full"         # attention of a full (causal) layer
+ATTN_LATENT = "hvd_attn_latent"     # attention of a latent layer
+LATENT_COMPRESS = "hvd_latent_compress"     # x W_kva and the row's norm
+LATENT_EXPAND = "hvd_latent_expand"         # c W_kvb, the rope key rotated
 MOE_ROUTE = "hvd_moe_route"         # scores, top k, weights, counts
 MOE_DISPATCH = "hvd_moe_dispatch"   # sort by expert, gather the rows
 MOE_EXPERTS = "hvd_moe_experts"     # the grouped matrix products
@@ -117,7 +120,8 @@ MOE_SHARED = "hvd_moe_shared"       # the shared expert every token passes
 LOOP_STEP = "hvd_loop_step"     # one application of a looped model's stack
 EXIT_GATE = "hvd_exit_gate"     # the exit gate, and the exit distribution
 EXIT_LOSS = "hvd_exit_loss"     # every exit's per-token loss, in chunks
-LAYER_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
+LAYER_SCOPES = (ATTN_WINDOW, ATTN_FULL, ATTN_LATENT, LATENT_COMPRESS,
+                LATENT_EXPAND, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
                 MOE_COMBINE, MOE_SHARED, LOOP_STEP, EXIT_GATE, EXIT_LOSS)
 RING = 8192     # records kept: a 10 s window of 46 ms steps is 217 of them
 

@@ -201,6 +201,22 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
     for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
         assert f"%{kernel}" in text, kernel
 
+    # The latent layer's kernels at its real widths (16 heads, keys of 192 of
+    # which the last 64 are one rope key a token, values of 128, 8,192
+    # tokens, the blocks the plan picks for those widths).
+    def latent_loss(q, k, v, rope_key):
+        return flash_attention(q, k, v, causal=True, k_shared=rope_key,
+                               interpret=False).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(latent_loss, argnums=(0, 1, 2, 3))).lower(
+        spec((2, 8192, 16, 192), jnp.bfloat16),
+        spec((2, 8192, 16, 128), jnp.bfloat16),
+        spec((2, 8192, 16, 128), jnp.bfloat16),
+        spec((2, 8192, 64), jnp.bfloat16))
+    text = lowered.compile().as_text()
+    for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        assert f"%{kernel}" in text, kernel
+
     from horovod_tpu.parallel import moe
 
     def experts_loss(x, router, experts):
@@ -251,33 +267,46 @@ def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
             assert f"%{kernel}" in text, (kernel, batch)
 
 
-def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(topo):
-    """``SparseDecoderLM`` at Trinity-Mini's widths over the cell's 2 x 4,096
-    tokens, its first two blocks (the dense one, an expert one): with the
-    first recomputed and the last kept the forward kernel runs three times
-    and not four, and what the compiler counts for keeping the first as well
-    is what ``DecoderBlock.kept_bytes`` reckons for it, to the stated
-    factors (read at 0.99; an expert block reads 1.23). The plan stands on
-    that sum, so this pins it to the compiler and not to a guess."""
+TRINITY = dict(
+    layer_types=("sliding_attention", "full_attention"), heads=32, kv_heads=4,
+    head_dim=128, window=2048, dense_layers=1, dense_width=6144, experts=128,
+    experts_held=16, top_k=8, expert_width=1024, route_scale=2.826)
+MOONLIGHT = dict(
+    layer_types=("latent_attention", "latent_attention"), heads=16,
+    kv_heads=16, head_dim=128, rope_dim=64, value_dim=128, latent_dim=512,
+    window=0, dense_layers=1, dense_width=11264, experts=64, experts_held=8,
+    top_k=6, expert_width=1408, shared_experts=2, route_scale=2.446,
+    norm_outputs=False, embed_scale=False)
+
+
+@pytest.mark.parametrize("sizes, length, reckoned", [
+    (TRINITY, 4096, 998_244_352),           # 0.93 GiB; read at 0.99
+    (MOONLIGHT, 8192, 1_715_470_336),       # 1.60 GiB; read at 1.06
+], ids=["trinity", "moonlight"])
+def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(
+        topo, sizes, length, reckoned):
+    """``SparseDecoderLM`` at a cell's widths over its 2 sequences, its first
+    two blocks (the dense one, an expert one): with the first recomputed and
+    the last kept the forward kernel runs three times and not four, and what
+    the compiler counts for keeping the first as well is what
+    ``DecoderBlock.kept_bytes`` reckons for it, to the stated factors
+    (Trinity-Mini's dense block reads 0.99 and an expert block 1.23;
+    Moonlight's latent ones 1.06 and 1.16). The plan stands on that sum, so
+    this pins it to the compiler and not to a guess."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu import models
-    from horovod_tpu.models import decoder
     from horovod_tpu.ops import attention
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((2, length), jnp.int32, sharding=one_chip)
 
     def compiled(remat):
         model = models.build(
-            "moe_lm", vocab_size=256, embed_dim=2048,
-            layer_types=(decoder.SLIDING, decoder.FULL), heads=32,
-            kv_heads=4, head_dim=128, window=2048, dense_layers=1,
-            dense_width=6144, experts=128, experts_held=16, top_k=8,
-            expert_width=1024, route_scale=2.826, attention="flash",
-            remat=remat)
+            "moe_lm", vocab_size=256, embed_dim=2048, attention="flash",
+            remat=remat, **sizes)
         variables = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip),
@@ -306,8 +335,7 @@ def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(topo):
         assert calls == 2
     finally:
         attention.pallas_interpret = real
-    reckoned = model.block(0).kept_bytes(2 * 4096, 2048)
-    assert reckoned == 998_244_352          # 0.93 GiB
+    assert model.block(0).kept_bytes(2 * length, 2048) == reckoned
     counted = both_kept - one_kept
     assert 0.9 * counted <= reckoned <= 1.3 * counted, (reckoned, counted)
 

@@ -47,6 +47,19 @@ TOY = {
                "8", "--moe-experts-held", "4", "--moe-top-k", "2",
                "--moe-width", "32", "--vocab", "128", "--batch-size", "2",
                "--seq-len", "32", "--remat"],
+    # latent attention layers, two norms a block, no multiplier on the
+    # embedding: the lane's third kind of decoder layer
+    "moe_lm/latent": ["--model", "moe_lm", "--lm-layers", "2", "--lm-dim",
+                      "64", "--lm-heads", "4", "--lm-head-dim", "16",
+                      "--lm-rope-dim", "8", "--lm-value-dim", "16",
+                      "--lm-latent-dim", "32", "--lm-layer-types",
+                      "latent,latent", "--no-lm-output-norms",
+                      "--no-lm-embed-scale", "--lm-ffn", "96",
+                      "--lm-dense-layers", "1", "--moe-experts", "8",
+                      "--moe-experts-held", "4", "--moe-top-k", "2",
+                      "--moe-width", "32", "--moe-shared", "2", "--vocab",
+                      "128", "--batch-size", "2", "--seq-len", "32",
+                      "--remat"],
     "looped_lm": ["--model", "looped_lm", "--lm-layers", "2", "--lm-loops",
                   "3", "--lm-dim", "64", "--lm-heads", "4", "--lm-kv-heads",
                   "2", "--lm-head-dim", "16", "--lm-ffn", "96",
@@ -88,7 +101,7 @@ def test_removed_arguments_are_usage_errors(bench, capsys):
             parser.parse_args(argv)
         assert stop.value.code == 2, argv
         assert "unrecognized arguments" in capsys.readouterr().err, argv
-    assert len(parser._actions) - 1 == 30           # less --help
+    assert len(parser._actions) - 1 == 35           # less --help
 
 
 def _spans(name=None):
@@ -102,7 +115,7 @@ def test_lane_builds_and_steps(hvd, bench, family):
     args = bench.build_parser().parse_args(TOY[family])
     lane = bench.build_lane(args, lambda *a, **k: None)
     build, = _spans("hvd.lane.build")
-    assert build["args"]["model"] == family
+    assert build["args"]["model"] == family.split("/")[0]
     for child in ("hvd.lane.model_init", "hvd.lane.train_state",
                   "hvd.lane.place"):
         found = _spans(child)
@@ -132,7 +145,12 @@ def test_lane_builds_and_steps(hvd, bench, family):
       "--lm-kv-heads", "16", "--lm-head-dim", "128", "--seq-len", "4096"],
      (16, 16, 128)),
     (["--model", "looped_lm", "--lm-dim", "64", "--lm-heads", "4",
-      "--seq-len", "2048"], (4, 4, 16))])
+      "--seq-len", "2048"], (4, 4, 16)),
+    # latent layers: every head its own key, keys wider than values
+    (["--model", "moe_lm", "--lm-dim", "2048", "--lm-heads", "16",
+      "--lm-head-dim", "128", "--lm-rope-dim", "64", "--lm-value-dim", "128",
+      "--lm-layer-types", "latent", "--lm-layers", "1", "--seq-len", "8192"],
+     (16, 16, (192, 128)))])
 def test_the_attention_policy_is_asked_with_each_familys_heads(
         bench, monkeypatch, argv, heads):
     """``resolve_attention`` hands ``attention_plan`` KV heads and the size of

@@ -138,6 +138,8 @@ def _cell_model(bench, cell_name):
     # PERF.md section 6, PR 33
     ("trinity_mini_seq4096_1chip", 5, 1, 2.83),
     ("ouro_seq4096_1chip", 32, 27, 3.16),
+    # PR 34: the last two expert blocks of six kept
+    ("moonlight_seq8192_1chip", 6, 4, 1.67),
 ])
 def test_the_cells_real_shapes_on_a_v5e(bench, cell, applications,
                                         recomputed, kept_gib):
@@ -178,6 +180,31 @@ def test_a_blocks_kept_bytes_are_a_closed_sum():
     # float32 compute doubles what is held in the compute type
     wide = block.clone(dtype=jnp.float32).kept_bytes(8192, 2048)
     assert wide == 8192 * (2 * (per_token - 16 * 512) + 16 * 512)
+
+
+def test_a_latent_blocks_kept_bytes_are_a_closed_sum():
+    """Moonlight's expert block over the cell's 16,384 tokens, by hand: the
+    stream before each branch (two norms a block: 4,096 B a token each), the
+    compressed row with the rope key before its norm and after (576 x 2 B
+    each), q of 192 a head, the expanded k and v of 128, the kernels' output
+    of 128 (16 heads x 576 x 2 B), 16 heads' log-sum-exp in lane rows of 512
+    B, the router's float32 scores and a token's choices, and the shared
+    experts' gate, up and product (2,816 x 2 B each)."""
+    model = models.build(
+        "moe_lm", vocab_size=8, embed_dim=2048,
+        layer_types=(decoder.LATENT, decoder.LATENT), heads=16, kv_heads=16,
+        head_dim=128, rope_dim=64, value_dim=128, latent_dim=512, window=0,
+        dense_layers=1, dense_width=11264, experts=64, experts_held=8,
+        top_k=6, expert_width=1408, shared_experts=2, norm_outputs=False)
+    attention = 2 * 576 * 2 + 16 * 576 * 2 + 16 * 512
+    expert = 2 * 4096 + attention + 3 * 4 * 64 + 4 * 4 * 6 + 3 * 2816 * 2
+    assert model.block(1).kept_bytes(16384, 2048) == 16384 * expert \
+        == 899_153_920
+    dense = 2 * 4096 + attention + 3 * 11264 * 2
+    assert model.block(0).kept_bytes(16384, 2048) == 16384 * dense
+    # a norm after each branch keeps its input and the stream's normed copy
+    normed = model.clone(norm_outputs=True).block(1)
+    assert normed.kept_bytes(16384, 2048) == 16384 * (expert + 4 * 4096)
 
 
 # ----------------------------------------- what the models do with a plan

@@ -192,26 +192,38 @@ def test_expert_layer_gradients_match_the_reference():
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
-def test_the_eight_shares_add_up_to_the_uncut_layer():
-    """The share test: the routed parts of all the shares, plus what every
-    chip computes alike (the shared expert) counted once, add up to what the
-    reference gives for the whole 16-expert layer."""
-    x, params, bias = _layer(3)
+@pytest.mark.parametrize("experts, k, scale, shared_width", [
+    (16, 3, 2.0, 8),        # Trinity-Mini's: 8 shares of 2 of 16, one shared
+    (64, 6, 2.446, 16),     # Moonlight's: 8 shares of 8 of 64, top 6, the
+])                          # two shared experts as one MLP of twice the width
+def test_the_eight_shares_add_up_to_the_uncut_layer(experts, k, scale,
+                                                    shared_width):
+    """The share test: the routed parts of all eight shares, plus what every
+    chip computes alike (the shared experts) counted once, add up to what the
+    reference gives for the whole layer."""
+    x, params, bias = _layer(3, experts=experts, k=k)
+    held = experts // 8
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
-    shared = {"gate": {"kernel": jax.random.normal(ks[0], (16, 8)) * .3},
-              "up": {"kernel": jax.random.normal(ks[1], (16, 8)) * .3},
-              "down": {"kernel": jax.random.normal(ks[2], (8, 16)) * .3}}
+    w = shared_width
+    shared = {"gate": {"kernel": jax.random.normal(ks[0], (16, w)) * .3},
+              "up": {"kernel": jax.random.normal(ks[1], (16, w)) * .3},
+              "down": {"kernel": jax.random.normal(ks[2], (w, 16)) * .3}}
     einsum = common.make_einsum("float32")
     whole, _ = trinity._experts(
         x, dict(params, shared=shared), bias, einsum=einsum,
-        hyper={"top_k": 3, "first_expert": 0, "route_scale": 2.0})
-    shares = sum(_share(x, params, bias, first, 2)[0]
-                 for first in range(0, 16, 2))
+        hyper={"top_k": k, "first_expert": 0, "route_scale": scale})
+
+    def share(first):
+        stacked = {n: params["experts_" + n][first:first + held]
+                   for n in ("gate", "up", "down")}
+        return moe.routed_experts(x, params["router"], stacked, bias,
+                                  first=first, top_k=k, route_scale=scale)[0]
+
+    shares = sum(share(first) for first in range(0, experts, held))
     once = trinity._mlp(x, shared, einsum)
     np.testing.assert_allclose(shares + once, whole, atol=1e-5)
     # and a share is not the whole: the cut leaves something out
-    assert float(jnp.abs(_share(x, params, bias, 0, 2)[0] + once
-                         - whole).max()) > 1e-2
+    assert float(jnp.abs(share(0) + once - whole).max()) > 1e-2
 
 
 def test_selection_bias_rule():

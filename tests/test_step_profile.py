@@ -363,6 +363,15 @@ def test_the_recorded_trace_carries_the_scopes_in_its_op_names():
      "hvd_moe_experts", "recomputed"),
     ("jit(step_fn)/jvp(hvd_forward)/DecoderBlock_2/moe/hvd_moe_shared/"
      "shared/gate/dot_general", "hvd_moe_shared", "forward"),
+    # a latent layer: the kernels, the compression, the expansion
+    ("jit(step_fn)/transpose(jvp(hvd_forward))/DecoderBlock_5/attn/"
+     "hvd_attn_latent/jit(flash_attention)/hvd_flash_dq/pallas_call",
+     "hvd_attn_latent", "backward"),
+    ("jit(step_fn)/jvp(hvd_forward)/DecoderBlock_0/attn/hvd_latent_compress/"
+     "kv_a/dot_general", "hvd_latent_compress", "forward"),
+    ("jit(step_fn)/transpose(jvp(hvd_forward))/checkpoint/"
+     "rematted_computation/DecoderBlock_2/attn/hvd_latent_expand/kv_b/"
+     "dot_general", "hvd_latent_expand", "recomputed"),
     ("jit(step_fn)/hvd_update/mul", None, "update"),
     ("jit(step_fn)/jvp(hvd_forward)/embed/take", None, "forward"),
     # a looped model: an application of the stack less its attention, the

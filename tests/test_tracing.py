@@ -440,6 +440,54 @@ def test_the_looped_step_carries_its_scopes_and_gauges(hvd, monkeypatch):
                    "hvd.attn.flash_calls": 0}
 
 
+def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
+    """A sparse LM of latent layers: ``hvd_attn_latent`` around the attention
+    call, ``hvd_latent_compress`` around the ``W_kva`` product and the row's
+    norm, ``hvd_latent_expand`` around the ``W_kvb`` product and the rope
+    key's rotation, all inside ``hvd_forward``; ``step_profile`` attributes
+    the three; and ``hvd.attn.latent_expanded_bytes`` of the step's program
+    reads what the two layers' forward passes write for the kernels: a chip's
+    16 tokens x (2 heads x (8 + 8) + one rope key of 4) x 2 bytes, twice,
+    counted once though both blocks are traced again for recomputation."""
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+    from horovod_tpu.utils import step_profile
+
+    timeline.reset()
+    args = bench.build_parser().parse_args(
+        ["--model", "moe_lm", "--lm-layers", "2", "--lm-dim", "32",
+         "--lm-heads", "2", "--lm-head-dim", "8", "--lm-rope-dim", "4",
+         "--lm-value-dim", "8", "--lm-latent-dim", "16", "--lm-layer-types",
+         "latent,latent", "--no-lm-output-norms", "--no-lm-embed-scale",
+         "--lm-ffn", "48", "--lm-dense-layers", "1", "--moe-experts", "4",
+         "--moe-experts-held", "2", "--moe-top-k", "2", "--moe-width", "16",
+         "--vocab", "64", "--batch-size", "1", "--seq-len", "16", "--remat"])
+    lane = bench.build_lane(args, lambda *a, **k: None)
+    state, loss = lane.run_step(lane.state, lane.batch)   # donates
+    assert np.isfinite(float(loss))
+    text = lane.run_step._compiled.lower(state, lane.batch).as_text(
+        debug_info=True)
+    latent = (timeline.ATTN_LATENT, timeline.LATENT_COMPRESS,
+              timeline.LATENT_EXPAND)
+    for scope in SCOPES + latent:
+        assert scope in text, scope
+    for inner in latent:
+        assert re.search(rf'{timeline.FORWARD}\)[^"]*/{inner}/', text), inner
+        assert step_profile.layer_of(
+            f"jit(step_fn)/jvp(hvd_forward)/DecoderBlock_1/attn/{inner}/"
+            f"dot_general") == inner
+    assert set(latent) <= set(timeline.LAYER_SCOPES)
+    assert timeline.ATTN_WINDOW not in text and timeline.ATTN_FULL not in text
+    step = next(s["args"]["program"] for s in _spans("hvd.spmd.dispatch")
+                if s["args"]["handle"] == "step_fn")
+    gauges = timeline.snapshot()["gauges"]
+    got = {name: by_program[step] for name, by_program in gauges.items()
+           if name.startswith("hvd.attn.") and step in by_program}
+    assert got == {"hvd.attn.latent_expanded_bytes":
+                   2 * 16 * (2 * (8 + 8) + 4) * 2,
+                   "hvd.attn.dense_calls": 2, "hvd.attn.flash_calls": 0}
+
+
 def test_windowed_train_step_has_the_same_scopes(hvd):
     import optax
 

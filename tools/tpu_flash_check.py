@@ -146,8 +146,11 @@ def main():
 
 # The shapes of the sweep the attention policy's constants come from
 # (``ops/attention.py:attention_plan``; PERF.md has its table): name, q
-# shape, KV heads, window. The first is GPT-2-medium's cell, then the same
-# 8,192 tokens at longer sequences, then Trinity-Mini's two layer kinds.
+# shape, KV heads, window, blocks and, where keys and values differ, the
+# values' width and the width of the key all heads share. The first is
+# GPT-2-medium's cell, then the same 8,192 tokens at longer sequences, then
+# Trinity-Mini's two layer kinds, then Moonlight's latent layer (keys of 192,
+# the last 64 of them one rope key a token, values of 128).
 SWEEP_SHAPES = (
     ("gpt2m_1024", (8, 1024, 16, 64), 16, None, (256, 512, 1024)),
     ("h64_2048", (4, 2048, 16, 64), 16, None, (256, 512, 1024, 2048)),
@@ -155,6 +158,8 @@ SWEEP_SHAPES = (
     # 256 x 256 at heads of 128 is PR 28's reading (PERF.md): 7.64 / 21.24
     ("trinity_window", (2, 4096, 32, 128), 4, 2048, (512, 1024, 2048)),
     ("trinity_full", (2, 4096, 32, 128), 4, None, (512, 1024, 2048)),
+    ("latent_8192", (2, 8192, 16, 192), 16, None, (512, 1024, 2048),
+     (128, 64)),
 )
 # f32 scores of one block the kernels are tried at: 1,024 x 1,024
 SWEEP_MAX_SCORES = 1024 * 1024
@@ -184,14 +189,23 @@ def block_sweep(key, only=None):
         rows.append(row)
         print(json.dumps(row), flush=True)
 
-    for name, (b, length, h, d), g, window, blocks in SWEEP_SHAPES:
+    for name, (b, length, h, d), g, window, blocks, *widths in SWEEP_SHAPES:
         if only and name not in only:
             continue
-        shapes = ((b, length, h, d), (b, length, g, d), (b, length, g, d))
+        value, shared = widths[0] if widths else (d, 0)
+        shapes = ((b, length, h, d), (b, length, g, d - shared),
+                  (b, length, g, value)) + ((b, length, shared),) * bool(shared)
         qkv = [jax.random.normal(jax.random.fold_in(key, 20 + i), s,
                                  jnp.bfloat16) for i, s in enumerate(shapes)]
-        measure(name, "dense", lambda *a: dot_product_attention(
-            *a, causal=True, window=window), qkv)
+
+        def shared_key(fn, **kw):
+            """``fn(q, k, v)``, or ``fn(q, k, v, k_shared=...)``."""
+            return lambda q, k, v, *rest: fn(
+                q, k, v, causal=True, window=window,
+                **(dict(k_shared=rest[0]) if rest else {}), **kw)
+
+        if length <= 4096:      # the scores of 8,192 keys do not fit
+            measure(name, "dense", shared_key(dot_product_attention), qkv)
         for bq in blocks:
             for bk in blocks:
                 if max(bq, bk) > length or bq * bk > SWEEP_MAX_SCORES:
@@ -199,11 +213,9 @@ def block_sweep(key, only=None):
                 for bwd in ("pallas", "scan"):
                     if bwd == "scan" and bq != bk:
                         continue        # the scan only reads block_k
-                    measure(name, "flash", lambda *a, _q=bq, _k=bk, _b=bwd:
-                            flash_attention(*a, causal=True, window=window,
-                                            block_q=_q, block_k=_k,
-                                            bwd_impl=_b),
-                            qkv, block_q=bq, block_k=bk, bwd=bwd)
+                    measure(name, "flash", shared_key(
+                        flash_attention, block_q=bq, block_k=bk,
+                        bwd_impl=bwd), qkv, block_q=bq, block_k=bk, bwd=bwd)
     with open(os.path.join(out_dir, "flash_sweep.jsonl"), "w") as f:
         f.writelines(json.dumps(r) + "\n" for r in rows)
     done = [r for r in rows if "fwd_bwd_ms" in r]
