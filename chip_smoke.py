@@ -188,9 +188,9 @@ def train_phase(name, argv, steps, *, want_mosaic=None,
 
 
 def check_flash_kernels():
-    """The packed-grid flash forward, dQ and dK/dV kernels against the
-    dense reference on seeded bf16 inputs (4 x 2 blocks, so the packed
-    walk, the diagonal mask and the k-major twin all engage). The
+    """The packed-grid flash forward and both backwards (the one kernel the
+    policy answers, and the dQ / dK+dV split it answers past its VMEM
+    budget) against the dense reference on seeded bf16 inputs. The
     reference runs in f32 at highest matmul precision; the bounds are
     bf16's (tools/tpu_flash_check.py's)."""
     import jax
@@ -209,16 +209,18 @@ def check_flash_kernels():
             lambda *a: attend(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(*qkv)
 
-    _, got = run(lambda *a: flash_attention(*a, causal=True,
-                                            bwd_impl="pallas"), q, k, v)
+    (_, got), (_, split) = (
+        run(lambda *a: flash_attention(*a, causal=True, bwd_impl=bwd),
+            q, k, v) for bwd in ("fused", "pallas"))
     out = flash_attention(q, k, v, causal=True)
     with jax.default_matmul_precision("highest"):
         f32 = [x.astype(jnp.float32) for x in (q, k, v)]
         _, want = run(lambda *a: dot_product_attention(*a, causal=True),
                       *f32)
         ref = dot_product_attention(*f32, causal=True)
-    errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
-            for a, b in zip((out, *got), (ref, *want))]
+    errs = [max(float(jnp.max(jnp.abs(x.astype(jnp.float32) - b)))
+                for x in a)
+            for a, b in zip(((out,), *zip(got, split)), (ref, *want))]
     assert errs[0] < 2e-2 and max(errs[1:]) < 5e-2, (
         f"flash vs dense reference: max |err| out/dq/dk/dv = {errs}")
     say("train flash kernels", "max |err| vs the f32 dense reference: out "

@@ -10,8 +10,9 @@ long-context support is a first-class extension of this rebuild (SURVEY
 ``flash_attention`` is a Pallas TPU kernel (online-softmax tiling so the
 L x L score matrix never materializes in HBM); on the CPU test platform
 it runs in interpreter mode so tests cover the same code path. On the causal square
-path all three streamed kernels (forward, dQ, dK/dV) execute a PACKED
-at-or-below-diagonal grid — the strictly-masked half of the (q-block,
+path every streamed kernel (the forward; the backward, one kernel or the dQ
+and dK/dV pair) executes a PACKED at-or-below-diagonal grid — the
+strictly-masked half of the (q-block,
 k-block) plane never occupies a grid step, so neither its K/V DMA bytes
 nor its loop overhead is paid (closing the traffic debt PERF.md's
 "Streamed-causal K/V traffic tradeoff" recorded). A ``window`` narrows the
@@ -145,6 +146,20 @@ def _last_qblock(kb, block_q: int, block_k: int, n_qblocks: int,
         return n_qblocks - 1
     return minimum(n_qblocks - 1,
                    (kb * block_k + block_k + window - 2) // block_q)
+
+
+def _kblock_span(qi, kb, block_q: int, block_k: int, n_kblocks: int,
+                 window: Optional[int], packed: bool):
+    """Inside a kernel: whether ``kb`` is the first and the last k-block
+    that q-block ``qi`` meets on its grid: on a packed grid from
+    :func:`_first_kblock` to its diagonal (whichever way the tables walk:
+    k-blocks ascend for a q-block on both), on a full one from 0 to the last
+    of all, live or not."""
+    if not packed:
+        return kb == 0, kb == n_kblocks - 1
+    return (kb == _first_kblock(qi, block_q, block_k, window, jnp.maximum),
+            kb == jnp.minimum(n_kblocks - 1,
+                              (qi * block_q + block_q - 1) // block_k))
 
 
 def _band_mask(q_pos, k_pos, window: Optional[int]):
@@ -424,7 +439,7 @@ class AttentionPlan(NamedTuple):
     impl: str                   # "dense" | "flash"
     block_q: Optional[int]      # the kernels' blocks; None where no legal
     block_k: Optional[int]      # block divides a length (then ``dense``)
-    bwd: str                    # the kernels' backward: "pallas" | "scan"
+    bwd: str                    # the kernels' backward: "fused" | "pallas"
 
 
 # The policy's constants, each from ``tools/tpu_flash_check.py
@@ -453,11 +468,54 @@ FLASH_MIN_BLOCK = 512
 # times (2.54 against 3.95; 2.13 times at 2,048, 2.58 at 4,096, 2.83 at
 # Trinity-Mini's shapes). Below it nothing was measured: dense.
 FLASH_MIN_KEYS = 1024
-# The backward: the dQ and dK/dV kernels. The scan over key blocks, with
-# its [B, H, L, block] f32 slabs, takes 1.8 (1,024 keys) to 3.1 times (4,096)
-# their forward + backward time at equal blocks and 3.4 times at heads of
-# 128; it is kept as ``bwd_impl="scan"``, the kernels' reference in tests.
+# The backward where the one kernel is not chosen: the dQ and dK/dV kernels
+# (``bwd_impl="pallas"``, also the one kernel's second reference in tests).
+# The scan over key blocks, with its [B, H, L, block] f32 slabs, takes 1.8
+# (1,024 keys) to 3.1 times (4,096) their forward + backward time at equal
+# blocks and 3.4 times at heads of 128; it is kept as ``bwd_impl="scan"``,
+# the kernels' reference in tests.
 FLASH_BWD = "pallas"
+# VMEM the split's kernels compile under at every shape above: Mosaic's
+# default scoped limit on the v5e, which they never raise.
+FLASH_SPLIT_VMEM = 16 << 20
+# The one-kernel backward's VMEM budget (:func:`fused_bwd_vmem_bytes`): 16 MiB
+# plus 65,536 queries of 128 in float32 and twice in bf16, the longest query
+# side measured (``--block-sweep``, chip run of PR 35; PERF.md section 6 has
+# the table). Device ms of the backward at blocks of 1,024 x 1,024, one
+# kernel against dQ + dK/dV: 1.048 against 1.419 at GPT-2's q [8, 1024, 16,
+# 64] (0.74); 2.533 / 3.466 at 4,096 keys; 4.650 / 6.542 and 5.020 / 6.924 at
+# Trinity-Mini's window and full layers (0.71, 0.73); 14.106 / 18.983 at keys
+# of 192 with the shared rope key over 8,192 (0.74); 4.157 / 5.672, 8.072 /
+# 10.984 and 15.901 / 21.623 at 16,384, 32,768 and 65,536 queries of 128
+# (0.73 to 0.74, sums of 32, 48 and 80 MiB). It won at every one of the 54
+# pairs of blocks swept as well (0.76 to 0.88 of the split's forward +
+# backward ms), so nothing but this budget selects the split; past it nothing
+# was measured (131,072 queries of 128 would ask Mosaic for 144 of the v5e's
+# 128 MiB).
+FLASH_FUSED_VMEM_BUDGET = 80 << 20
+
+
+def fused_bwd_vmem_bytes(seq_q: int, key_width: int, itemsize: int) -> int:
+    """VMEM of the one-kernel backward for ``seq_q`` queries of ``key_width``
+    (lanes of 128): what the split's kernels hold (:data:`FLASH_SPLIT_VMEM`)
+    plus dQ for all of a (batch, head) program's rows, once in float32 (the
+    sum) and twice in the gradient's type (the output block, which Pallas
+    double-buffers). The plan compares it with
+    :data:`FLASH_FUSED_VMEM_BUDGET`; the kernel asks Mosaic for it."""
+    lanes = -(-key_width // 128) * 128
+    return FLASH_SPLIT_VMEM + seq_q * lanes * (4 + 2 * itemsize)
+
+
+def _planned_bwd(seq_q: int, key_width: int, dtype,
+                 pin: Optional[str] = None) -> str:
+    """The kernels' backward for a call's shapes: the caller's ``pin``, or
+    (``None`` | ``"auto"``) one kernel where its dQ fits the budget and the
+    split where it does not."""
+    if pin not in (None, "auto"):
+        return pin
+    fits = fused_bwd_vmem_bytes(
+        seq_q, key_width, jnp.dtype(dtype).itemsize) <= FLASH_FUSED_VMEM_BUDGET
+    return "fused" if fits else FLASH_BWD
 
 
 def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
@@ -480,17 +538,19 @@ def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
     on without a new call site.
     """
     _kv_group(heads, kv_heads)
-    del head_dim, window, dtype
+    del window
+    key_width = head_dim[0] if isinstance(head_dim, tuple) else head_dim
+    bwd = _planned_bwd(seq_q, key_width, dtype)
     try:
         block_q, block_k = _planned_blocks(seq_q, seq_k, None, None)
     except ValueError:
-        return AttentionPlan("dense", None, None, FLASH_BWD)
+        return AttentionPlan("dense", None, None, bwd)
     if backend is None:
         backend = jax.default_backend()
     flash = (backend == "tpu" and seq_k >= FLASH_MIN_KEYS
              and min(block_q, block_k) >= FLASH_MIN_BLOCK)
     return AttentionPlan("flash" if flash else "dense", block_q, block_k,
-                         FLASH_BWD)
+                         bwd)
 
 
 def _planned_blocks(seq_q: int, seq_k: int, block_q: Optional[int],
@@ -523,7 +583,9 @@ def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
     ``flash_args`` go to :func:`flash_attention` (an A/B's ``truncate`` and
     ``bwd_impl``). Sets the gauges ``hvd.attn.flash_calls`` /
     ``.dense_calls`` (attention calls traced into the step's program, by
-    implementation) and ``.block_q`` / ``.block_k`` (the kernels' blocks)."""
+    implementation), ``.fused_bwd_calls`` (those of the kernels' calls whose
+    backward is the one kernel) and ``.block_q`` / ``.block_k`` (the
+    kernels' blocks)."""
     if impl is None:
         # an offset mask is outside what the policy was measured on
         impl = "dense" if q_offset else attention_plan(
@@ -532,8 +594,10 @@ def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
     if impl not in ("dense", "flash"):
         raise ValueError(f"impl must be dense|flash, got {impl!r}")
     program, calls = timeline.program_tally(
-        _traced, lambda: {"flash": 0, "dense": 0})
+        _traced, lambda: {"flash": 0, "dense": 0, "fused_bwd": 0})
     calls[impl] += 1
+    calls["fused_bwd"] += impl == "flash" and _planned_bwd(
+        q.shape[1], q.shape[3], q.dtype, flash_args.get("bwd_impl")) == "fused"
     for name, n in calls.items():
         timeline.gauge(f"hvd.attn.{name}_calls", n, key=program)
     if impl == "dense":
@@ -574,15 +638,15 @@ def flash_attention(q, k, v, causal: bool = False,
     too. ``scale`` defaults to ``Dk ** -0.5``.
 
     ``window`` (static; plain causal square attention only) lets query i
-    see keys j with ``0 <= i - j < window``: the mask is applied inside the
-    blocks on the band's two edges, and blocks wholly outside the band
-    never occupy a step of the packed grid (or skip their compute on the
-    full one).
+    see keys j with ``0 <= i - j < window``: the mask is applied inside
+    every live block (those the band's edges do not cross included), and
+    blocks wholly outside the band never occupy a step of the packed grid
+    (or skip their compute on the full one).
 
     Sequence lengths must be multiples of the block sizes (pad upstream).
     Block sizes and the backward (``bwd_impl`` None or ``"auto"``) default
     to :func:`attention_plan`'s, measured on the v5e; pass explicit values
-    to override.
+    to override (``"fused"`` | ``"pallas"``, the split | ``"scan"``).
     ``interpret`` defaults to the platform's: compiled on a TPU,
     interpreted on the CPU test platform.
 
@@ -600,13 +664,18 @@ def flash_attention(q, k, v, causal: bool = False,
     ``truncate=True`` asserts eligibility; the accounting twin is
     :func:`flash_grid_info`.
 
-    Differentiable: the backward is two Pallas kernels (the
-    FlashAttention-2 dQ / dK+dV split), recomputing scores blockwise
-    against the forward's persisted logsumexp with O(block) VMEM per
-    program — the [Lq, Lk] matrix is never materialized in either pass;
-    both backward kernels ride the same truncated grid (the dK/dV dead
-    region is the symmetric above-diagonal half over the q axis);
-    gradient exactness vs the dense reference is pinned in
+    Differentiable: the backward is one Pallas kernel (``hvd_flash_bwd``:
+    dQ, dK and dV from one walk over the score blocks, five products a
+    pair, dQ summed in a float32 ``[Lq, Dk]`` scratch that stays in VMEM a
+    (batch, head) program) where that scratch fits
+    :data:`FLASH_FUSED_VMEM_BUDGET`, and two elsewhere (the
+    FlashAttention-2 dQ / dK+dV split, seven products a pair, O(block)
+    VMEM per program), recomputing scores blockwise against the forward's
+    persisted logsumexp — the [Lq, Lk] matrix is never materialized in
+    either pass; every backward kernel rides the truncated grid (k-major
+    for the one kernel and for dK/dV, whose dead region is the symmetric
+    above-diagonal half over the q axis); gradient exactness vs the dense
+    reference, and the one kernel's equality with the split, are pinned in
     tests/test_parallel.py::TestFlashAttention."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -614,10 +683,9 @@ def flash_attention(q, k, v, causal: bool = False,
         interpret = pallas_interpret()
     block_q, block_k = _planned_blocks(q.shape[1], k.shape[1], block_q,
                                        block_k)
-    if bwd_impl in (None, "auto"):
-        bwd_impl = FLASH_BWD
-    if bwd_impl not in ("scan", "pallas"):
-        raise ValueError(f"bwd_impl must be auto|scan|pallas, "
+    bwd_impl = _planned_bwd(q.shape[1], q.shape[-1], q.dtype, bwd_impl)
+    if bwd_impl not in ("scan", "pallas", "fused"):
+        raise ValueError(f"bwd_impl must be auto|scan|pallas|fused, "
                          f"got {bwd_impl!r}")
     if causal and q_offset < k_offset:
         # Query rows before the first key have NO unmasked key: their
@@ -669,6 +737,7 @@ def _flash(q, k, v, k_shared, causal, scale, block_q, block_k, interpret,
 FWD_KERNEL = "hvd_flash_fwd"
 DQ_KERNEL = "hvd_flash_dq"
 DKV_KERNEL = "hvd_flash_dkv"
+BWD_KERNEL = "hvd_flash_bwd"
 
 
 def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
@@ -835,10 +904,10 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
     ks_ref = refs[2] if shared else None
     v_ref, do_ref, lse_ref, d_ref, dq_ref, dq_scr = refs[2 + shared:]
 
-    first_kb = (_first_kblock(qi, block_q, block_k, window, jnp.maximum)
-                if packed else 0)
+    at_first, at_last = _kblock_span(qi, kb, block_q, block_k, n_kblocks,
+                                     window, packed)
 
-    @pl.when(kb == first_kb)
+    @pl.when(at_first)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
@@ -874,13 +943,7 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
     else:
         _compute()  # packed grids enumerate live steps only
 
-    if packed:
-        last_kb = jnp.minimum(n_kblocks - 1,
-                              (qi * block_q + block_q - 1) // block_k)
-    else:
-        last_kb = n_kblocks - 1
-
-    @pl.when(kb == last_kb)
+    @pl.when(at_last)
     def _finalize():
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -888,14 +951,26 @@ def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float, block_q: int,
 def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                           block_k: int, n_qblocks: int, delta: int,
                           packed: bool, window: Optional[int] = None,
-                          shared: bool = False):
+                          shared: bool = False, fused: bool = False,
+                          n_kblocks: int = 0):
     """dK/dV: full grid (batch*head, k-block, Q-BLOCK stream) or the
     packed K-MAJOR causal grid — transposing the dQ kernel's roles, so
     the truncated region is the symmetric above-diagonal half over the
     q axis (each k-block's stream starts at its diagonal q-block):
         dV_j = sum_i P_ij^T dO_i;  dK_j = sum_i dS_ij^T Q_i * scale
     With a shared key its gradient comes out a query head beside dK (the
-    query's last columns' part), to be summed over heads outside."""
+    query's last columns' part), to be summed over heads outside.
+
+    ``fused`` makes it the whole backward pass (:data:`BWD_KERNEL`): the
+    same walk also adds ``dS_ij K_j * scale`` into q-block i's rows of a
+    float32 ``[Lq, Dk]`` scratch that stays in VMEM for the whole (batch,
+    head) program, so ``S``, the mask, ``P``, ``dP`` and ``dS`` are
+    computed once a pair: five products where the two kernels run seven.
+    K-blocks ascend on the k-major walk, so a q-block's rows are summed in
+    the order the dQ kernel sums them; they are zeroed at the q-block's
+    first k-block and cast into the ``[Lq, Dk]`` output block (whose index
+    is constant over the program, so it leaves VMEM once) at its last: the
+    dQ kernel's two conditions."""
     from jax.experimental import pallas as pl
 
     if packed:
@@ -917,7 +992,10 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
     q_ref, k_ref = refs[:2]
     ks_ref = refs[2] if shared else None
     v_ref, do_ref, lse_ref, d_ref = refs[2 + shared:6 + shared]
-    outs = refs[6 + shared:]
+    outs = list(refs[6 + shared:])
+    if fused:       # outputs (dq, dk, [dks], dv), then their scratch
+        dq_ref, dq_scr = outs.pop(0), outs.pop(2 + shared)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
     if shared:
         dk_ref, dks_ref, dv_ref, dk_scr, dks_scr, dv_scr = outs
     else:
@@ -929,6 +1007,15 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
         if shared:
             dks_scr[...] = jnp.zeros(dks_scr.shape, jnp.float32)
+
+    if fused:
+        at_first, at_last = _kblock_span(qi, kb, block_q, block_k, n_kblocks,
+                                         window, packed)
+
+        @pl.when(at_first)
+        def _init_dq():
+            dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[-1]),
+                                        jnp.float32)
 
     def _compute():
         # Input-dtype matmuls, f32 accumulation (see _block_scores).
@@ -957,6 +1044,17 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                 ds_t, q[:, :own], preferred_element_type=jnp.float32) * scale
             dks_scr[...] += jnp.dot(
                 ds_t, q[:, own:], preferred_element_type=jnp.float32) * scale
+        if not fused:
+            return
+        ds = ds.astype(k_blk.dtype)     # the dQ kernel's products
+        if ks_ref is None:
+            dq_scr[rows, :] += jnp.dot(
+                ds, k_blk, preferred_element_type=jnp.float32) * scale
+        else:
+            dq_scr[rows, :own] += jnp.dot(
+                ds, k_blk, preferred_element_type=jnp.float32) * scale
+            dq_scr[rows, own:] += jnp.dot(
+                ds, ks_ref[...], preferred_element_type=jnp.float32) * scale
 
     if causal and not packed:
         # Q-blocks fully ABOVE the diagonal (every q_pos < every k_pos),
@@ -972,6 +1070,11 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
         if shared:
             dks_ref[...] = dks_scr[...].astype(dks_ref.dtype)
+
+    if fused:
+        @pl.when(at_last)
+        def _finalize_dq():
+            dq_ref[rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
 
 
 def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
@@ -1053,8 +1156,12 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
 
 
 def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
-                      q_offset, k_offset, truncate, window, res, do):
-    """Flash backward as two Pallas kernels (FlashAttention-2 split):
+                      q_offset, k_offset, truncate, window, res, do,
+                      fused=False):
+    """Flash backward as one Pallas kernel (``fused``: dQ, dK and dV from
+    one walk over the k-major grid, dQ summed in a float32 ``[Lq, Dk]``
+    scratch that stays in VMEM a (batch, head) program; see
+    :func:`_flash_bwd_dkv_kernel`) or as two (the FlashAttention-2 split):
     a dQ kernel streaming k-blocks and a dK/dV kernel streaming
     q-blocks, both against the forward's persisted logsumexp and the
     precomputed row dot D_i = rowsum(dO_i * O_i). The score matrix is
@@ -1106,7 +1213,8 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_qblocks=nqb, delta=0 if truncated else delta,
-        packed=truncated, window=window, shared=shared)
+        packed=truncated, window=window, shared=shared, fused=fused,
+        n_kblocks=nkb)
     dq_out_shape = jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype)
     dkv_dtype = jnp.float32 if rep > 1 else k.dtype    # a group's are summed
     # dK, the shared key's gradient a query head (float32: summed over the
@@ -1118,14 +1226,19 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
         + [pltpu.VMEM((bk, D - Dn), jnp.float32)] * shared \
         + [pltpu.VMEM((bk, Dv), jnp.float32)]
 
-    def call(kernel, name, k_major, out_widths, out_shape, scratch):
+    def call(kernel, name, k_major, out_widths, out_shape, scratch,
+             whole_q=()):
         """One backward kernel over its grid. The packed causal grids read
         their (q-block, k-block) off the scalar-prefetched step tables
         (q-major steps for dQ: k-blocks stream within a q-block; k-major for
         dK/dV: q-blocks stream within a k-block, starting at the diagonal),
         the full grids off their two block axes, which the dK/dV grid
         transposes: (bh, k-block, q-stream). dQ writes a block of q rows,
-        dK/dV blocks of keys, each a query head's own, ``out_widths`` wide."""
+        dK/dV blocks of keys, each a query head's own, ``out_widths`` wide.
+        The fused kernel's dQ (``whole_q``: its width) comes first, all
+        ``Lq`` rows of a query head as one block that stays a program long,
+        which is why no axis but the first is parallel there and the
+        kernel's VMEM limit is the plan's sum."""
         if truncated:
             at_q = lambda bh, t, qi, kb: qi[t]              # noqa: E731
             at_k = lambda bh, t, qi, kb: kb[t]              # noqa: E731
@@ -1150,7 +1263,13 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
         ins = [rows(D), keys_of(Dn, rep)] \
             + [keys_of(D - Dn, H)] * shared \
             + [keys_of(Dv, rep), rows(Dv), rows(1), rows(1)]
-        out_specs = [(keys_of if k_major else rows)(w) for w in out_widths]
+        out_specs = [pl.BlockSpec((None, Lq, w), lambda bh, *g: (bh, 0, 0))
+                     for w in whole_q] \
+            + [(keys_of if k_major else rows)(w) for w in out_widths]
+        params = {}
+        if whole_q:
+            params["vmem_limit_bytes"] = fused_bwd_vmem_bytes(
+                Lq, D, q.dtype.itemsize)
         if truncated:
             tables = _causal_step_tables(nqb, nkb, bq, bk, k_major=k_major,
                                          window=window)
@@ -1164,18 +1283,25 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
                        else (B * H, nqb, nkb),
                        in_specs=ins, out_specs=out_specs,
                        scratch_shapes=scratch)
-            semantics = ("parallel", "parallel", "arbitrary")
+            semantics = ("parallel", "arbitrary" if whole_q else "parallel",
+                         "arbitrary")
         return pl.pallas_call(
             kernel, out_shape=out_shape, interpret=interpret, name=name,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=semantics), **how,
+                dimension_semantics=semantics, **params), **how,
         )(*map(jnp.asarray, tables), qr, *keys, vr, dor, lser, d_row)
 
-    dq, = call(dq_kernel, DQ_KERNEL, False, [D], [dq_out_shape],
-               [pltpu.VMEM((bq, D), jnp.float32)])
-    dk, *dks, dv = call(dkv_kernel, DKV_KERNEL, True,
-                        [Dn] + [D - Dn] * shared + [Dv], dkv_out_shape,
-                        dkv_scratch)
+    if fused:
+        dq, dk, *dks, dv = call(
+            dkv_kernel, BWD_KERNEL, True, [Dn] + [D - Dn] * shared + [Dv],
+            [dq_out_shape] + dkv_out_shape,
+            [pltpu.VMEM((Lq, D), jnp.float32)] + dkv_scratch, whole_q=[D])
+    else:
+        dq, = call(dq_kernel, DQ_KERNEL, False, [D], [dq_out_shape],
+                   [pltpu.VMEM((bq, D), jnp.float32)])
+        dk, *dks, dv = call(dkv_kernel, DKV_KERNEL, True,
+                            [Dn] + [D - Dn] * shared + [Dv], dkv_out_shape,
+                            dkv_scratch)
 
     def grouped(t, like):
         """A KV head's gradient: the sum over the query heads that read it."""
@@ -1193,10 +1319,11 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
 
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
                    q_offset, k_offset, truncate, window, res, do):
-    """``bwd_impl`` arrives resolved ("scan" | "pallas") from
+    """``bwd_impl`` arrives resolved ("scan" | "pallas" | "fused") from
     flash_attention: part of the trace key, so the selection can never
     desync from a cached trace."""
-    fn = _flash_bwd_pallas if bwd_impl == "pallas" else _flash_bwd_scan
+    fn = {"scan": _flash_bwd_scan, "pallas": _flash_bwd_pallas,
+          "fused": functools.partial(_flash_bwd_pallas, fused=True)}[bwd_impl]
     return fn(causal, scale, block_q, block_k, interpret,
               q_offset, k_offset, truncate, window, res, do)
 

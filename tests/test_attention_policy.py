@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import attention
 from horovod_tpu.ops.attention import (
     FLASH_BWD,
     AttentionPlan,
@@ -30,29 +31,36 @@ BF16, F32 = jnp.bfloat16, jnp.float32
 # (Lq, Lk, heads, KV heads, head width, window, dtype, backend) -> plan
 TABLE = {
     "gpt2_medium_cell": ((1024, 1024, 16, 16, 64, None, BF16, "tpu"),
-                         ("flash", 1024, 1024, "pallas")),
+                         ("flash", 1024, 1024, "fused")),
     "trinity_sliding_layer": ((4096, 4096, 32, 4, 128, 2048, BF16, "tpu"),
-                              ("flash", 1024, 1024, "pallas")),
+                              ("flash", 1024, 1024, "fused")),
     "trinity_full_layer": ((4096, 4096, 32, 4, 128, None, BF16, "tpu"),
-                           ("flash", 1024, 1024, "pallas")),
+                           ("flash", 1024, 1024, "fused")),
     "heads_of_64_at_2048": ((2048, 2048, 16, 16, 64, None, BF16, "tpu"),
-                            ("flash", 1024, 1024, "pallas")),
+                            ("flash", 1024, 1024, "fused")),
     "float32_inputs": ((4096, 4096, 8, 8, 64, None, F32, "tpu"),
-                       ("flash", 1024, 1024, "pallas")),
+                       ("flash", 1024, 1024, "fused")),
     "blocks_of_512_divide": ((1536, 1536, 16, 16, 64, None, BF16, "tpu"),
-                             ("flash", 512, 512, "pallas")),
+                             ("flash", 512, 512, "fused")),
     "only_256_divides": ((1280, 1280, 16, 16, 64, None, BF16, "tpu"),
-                         ("dense", 256, 256, "pallas")),
+                         ("dense", 256, 256, "fused")),
     "rectangular": ((512, 768, 4, 4, 64, None, BF16, "tpu"),
-                    ("dense", 512, 256, "pallas")),
+                    ("dense", 512, 256, "fused")),
     "below_the_measured_lengths": ((512, 512, 16, 16, 64, None, BF16, "tpu"),
-                                   ("dense", 512, 512, "pallas")),
+                                   ("dense", 512, 512, "fused")),
     "no_block_divides": ((100, 100, 4, 4, 64, None, BF16, "tpu"),
-                         ("dense", None, None, "pallas")),
+                         ("dense", None, None, "fused")),
     "cpu_backend": ((1024, 1024, 16, 16, 64, None, BF16, "cpu"),
-                    ("dense", 1024, 1024, "pallas")),
+                    ("dense", 1024, 1024, "fused")),
     "this_platform": ((4096, 4096, 32, 4, 128, 2048, BF16, None),
-                      ("dense", 1024, 1024, "pallas")),
+                      ("dense", 1024, 1024, "fused")),
+    # the one-kernel backward's resident dQ: 65,536 queries of 128 are the
+    # budget, a Ulysses shard of 131,072 is past it
+    "longest_side_measured": ((65536, 65536, 2, 2, 128, None, BF16, "tpu"),
+                              ("flash", 1024, 1024, "fused")),
+    "query_side_past_the_budget": ((131072, 131072, 2, 2, 128, None, BF16,
+                                    "tpu"),
+                                   ("flash", 1024, 1024, "pallas")),
 }
 
 
@@ -60,6 +68,60 @@ TABLE = {
 def test_the_plan_of_a_shape(case):
     asked, want = TABLE[case]
     assert attention_plan(*asked) == AttentionPlan(*want)
+
+
+def test_the_plan_answers_the_backward_from_the_shapes():
+    """One kernel where a (batch, head) program's float32 dQ, with its
+    output block, fits the VMEM budget beside what the split holds: at
+    the five language cells' shapes (16 MiB plus 1, 1, 4, 4 and 16 MiB).
+    The split where it does not, whatever else the call looks like; a
+    pin is a pin; and ``attend`` counts the calls it hands the one
+    kernel."""
+    from horovod_tpu.utils import timeline
+
+    cells = [(1024, 16, 16, 64, None),             # both GPT-2 cells
+             (4096, 32, 4, 128, 2048),             # Trinity-Mini
+             (4096, 16, 16, 128, None),            # Ouro
+             (8192, 16, 16, (192, 128), None)]     # Moonlight
+    for length, heads, kv_heads, width, window in cells:
+        assert attention.attention_plan(
+            length, length, heads, kv_heads, width, window,
+            backend="tpu") == ("flash", 1024, 1024, "fused")
+    mib = 1 << 20
+    assert attention.fused_bwd_vmem_bytes(1024, 64, 2) == 17 * mib
+    assert attention.fused_bwd_vmem_bytes(4096, 128, 2) == 20 * mib
+    assert attention.fused_bwd_vmem_bytes(8192, 192, 2) == 32 * mib
+    assert attention.fused_bwd_vmem_bytes(65536, 128, 2) \
+        == attention.FLASH_FUSED_VMEM_BUDGET == 80 * mib
+    for length, width, dtype, bwd in [
+            (65536, 128, jnp.bfloat16, "fused"),
+            (65536 + 1024, 128, jnp.bfloat16, "pallas"),
+            (65536, 192, jnp.bfloat16, "pallas"),   # two lanes of 128
+            (65536, 128, jnp.float32, "pallas"),    # a wider gradient
+            (32768, 128, jnp.float32, "fused"),
+            (131072, 64, jnp.bfloat16, "pallas")]:
+        assert attention.attention_plan(
+            length, length, 2, 2, width, dtype=dtype,
+            backend="tpu").bwd == bwd, (length, width, dtype)
+        # the CPU's dense answer names the same backward: shapes alone
+        assert attention.attention_plan(
+            length, length, 2, 2, width, dtype=dtype,
+            backend="cpu").bwd == bwd
+
+    q = jnp.ones((1, 32, 2, 8))
+    for pin, counted in [(None, 1), ("fused", 1), ("pallas", 0),
+                         ("scan", 0)]:
+        attention.attend(q, q, q, impl="flash", block_q=8, block_k=8,
+                         **({"bwd_impl": pin} if pin else {}))
+        gauges = timeline.snapshot()["gauges"]
+        program = timeline.tracing_program()[0]
+        assert gauges["hvd.attn.fused_bwd_calls"][program] == counted
+        assert gauges["hvd.attn.flash_calls"][program] == 1
+    attention.attend(q, q, q, impl="dense")
+    gauges = timeline.snapshot()["gauges"]
+    assert gauges["hvd.attn.fused_bwd_calls"][program] == 0
+    with pytest.raises(ValueError, match="auto|scan|pallas|fused"):
+        flash_attention(q, q, q, causal=True, bwd_impl="split")
 
 
 def test_the_plan_refuses_heads_no_group_divides():
@@ -89,7 +151,8 @@ def test_grid_info_agrees_with_the_grid_the_kernels_run(length, window,
                                                         blocks):
     """``flash_grid_info`` and a ``flash_attention`` call with no blocks
     given read the same plan: the accounting's blocks are the plan's, and
-    its grid is the grid of the forward, dQ and dK/dV kernels traced."""
+    its grid is the grid of the forward and the backward kernel traced (of
+    the forward, dQ and dK/dV kernels where the split is pinned)."""
     given = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
     info = flash_grid_info(length, length, causal=True, window=window,
                            batch_heads=2, **given)
@@ -98,11 +161,12 @@ def test_grid_info_agrees_with_the_grid_the_kernels_run(length, window,
         blocks or (plan.block_q, plan.block_k))
     q = jax.ShapeDtypeStruct((1, length, 2, 8), F32)
     kv = jax.ShapeDtypeStruct((1, length, 1, 8), F32)
-    grids = _kernel_grids(
-        jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, window=window, **given).sum(),
-            argnums=(0, 1, 2)), q, kv, kv)
-    assert grids == [tuple(info["grid"])] * 3       # the plan's backward
+    for pin, kernels in (({}, 2), ({"bwd_impl": "pallas"}, 3)):
+        grids = _kernel_grids(
+            jax.grad(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, window=window, **given, **pin).sum(),
+                argnums=(0, 1, 2)), q, kv, kv)
+        assert grids == [tuple(info["grid"])] * kernels
 
 
 def test_flash_equals_dense_at_heads_of_64_and_blocks_of_1024():
@@ -157,3 +221,54 @@ def test_on_a_tpu_attend_traces_the_kernels_but_not_under_an_offset(
     assert _kernel_grids(attend, q, q, q) == [(32, 1)]
     assert not _kernel_grids(lambda *a: attend(*a, q_offset=1024), q, q, q)
     assert FLASH_BWD == "pallas"
+
+
+FUSED_CALLS = "flash_fused_bwd_calls_per_step.tok"
+
+
+@pytest.mark.parametrize("cell, listed", [
+    ("gpt2m_seq1024_1chip", True), ("gpt2m_seq1024_dp4", True),
+    ("trinity_mini_seq4096_1chip", True), ("ouro_seq4096_1chip", True),
+    ("moonlight_seq8192_1chip", True), ("resnet50_bs128_1chip", False)])
+def test_the_fused_backward_calls_are_a_metric_of_the_language_cells(
+        cell, listed):
+    """``flash_fused_bwd_calls_per_step.tok`` (``BENCHMARK.json``; the reader
+    ``benchmarks/metrics/``) is listed by the five cells that train through
+    the kernels and reads the gauge ``hvd.attn.fused_bwd_calls`` of the step
+    handle's program: nothing on a program that sets no such gauge (the
+    parent of PR 35) or whose calls all take the split."""
+    import json
+    import os
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks import run
+    from horovod_tpu.utils import timeline
+
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    reported = {m["name"]: m for m in run.metrics_of(manifest, cell,
+                                                     "per_layer")}
+    assert (FUSED_CALLS in reported) == listed
+    if not listed:
+        return
+    entry = reported[FUSED_CALLS]
+    assert (entry["unit"], entry["better"], entry["source"], entry["layer"],
+            entry["moves"]) == ("calls", "higher", "program_counter",
+                                "training_kernels", "tok_per_s_per_chip")
+    read = run.load_reader(FUSED_CALLS)
+    timeline.reset()
+    for call in range(3):
+        with timeline.span("hvd.spmd.dispatch", handle="step_fn",
+                           program="step_fn#0", call=call):
+            pass
+    timeline.gauge("hvd.attn.flash_calls", 24, key="step_fn#0")
+    assert read({}) is None             # the parent: no such gauge
+    timeline.gauge("hvd.attn.fused_bwd_calls", 0, key="step_fn#0")
+    assert read({}) is None             # every call took the split
+    timeline.gauge("hvd.attn.fused_bwd_calls", 24, key="step_fn#0")
+    timeline.gauge("hvd.attn.fused_bwd_calls", 3, key="other#1")
+    assert read({}) == 24
+    timeline.reset()

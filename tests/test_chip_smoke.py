@@ -130,6 +130,13 @@ def test_fleet_refuses_local_chip_workers(monkeypatch):
             ServeFleet({}, cfg, FleetConfig(replicas=1, transport=transport))
 
 
+# The attention kernels' names in a compiled program: the forward and the one
+# backward kernel that ``attention_plan`` answers at every cell's shapes, and
+# the split it answers where the resident dQ does not fit (and a pin asks for)
+FLASH_KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd")
+SPLIT_KERNELS = ("hvd_flash_dq", "hvd_flash_dkv")
+
+
 @pytest.fixture(scope="module")
 def topo():
     """A v5e host of four chips, described and not attached: libtpu compiles
@@ -147,13 +154,14 @@ def topo():
 def test_kernels_compile_for_a_tpu_from_here(topo):
     """libtpu compiles for a v5e topology without a chip: the paged
     decode kernel (whole-head blocks, 12 heads and the 3 a tp=4 shard
-    holds) and the packed-grid flash forward/dQ/dK-dV kernels go through
-    Mosaic itself, not the interpreter."""
+    holds) and the packed-grid flash forward and backward kernels (the one
+    kernel the plan answers, the dQ / dK-dV split a pin or a very long query
+    side gets) go through Mosaic itself, not the interpreter."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops.attention import flash_attention
+    from horovod_tpu.ops.attention import attention_plan, flash_attention
     from horovod_tpu.ops.paged_attention import paged_attention_decode
 
     sharding = SingleDeviceSharding(topo.devices[0])
@@ -179,6 +187,10 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
         return flash_attention(q, k, v, causal=True, interpret=False,
                                bwd_impl="pallas").astype(jnp.float32).sum()
 
+    def flash_loss_planned(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
     qkv = spec((1, 512, 2, 64), jnp.bfloat16)
     text = compiled_text(jax.grad(flash_loss, argnums=(0, 1, 2)),
                          qkv, qkv, qkv)
@@ -186,7 +198,7 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
 
     # The sparse decoder's kernels at its real widths (32 query heads of
     # 128 over 4 KV heads, 4,096 tokens, the plan's blocks of 1,024 and its
-    # kernel backward): the windowed grouped flash kernels under their
+    # one-kernel backward): the windowed grouped flash kernels under their
     # profile names, and the expert layer's grouped products, which XLA:TPU
     # lowers itself.
     def windowed_loss(q, k, v):
@@ -198,8 +210,10 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
         spec((2, 4096, 4, 128), jnp.bfloat16),
         spec((2, 4096, 4, 128), jnp.bfloat16))
     text = lowered.compile().as_text()
-    for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+    for kernel in FLASH_KERNELS:
         assert f"%{kernel}" in text, kernel
+    for kernel in SPLIT_KERNELS:
+        assert f"%{kernel}" not in text, kernel
 
     # The latent layer's kernels at its real widths (16 heads, keys of 192 of
     # which the last 64 are one rope key a token, values of 128, 8,192
@@ -214,7 +228,17 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
         spec((2, 8192, 16, 128), jnp.bfloat16),
         spec((2, 8192, 64), jnp.bfloat16))
     text = lowered.compile().as_text()
-    for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+    for kernel in FLASH_KERNELS:
+        assert f"%{kernel}" in text, kernel
+
+    # A query side past the one-kernel backward's VMEM budget (a Ulysses
+    # shard's): the plan answers the split, which compiles as before.
+    long_q = spec((1, 131072, 1, 128), jnp.bfloat16)
+    assert attention_plan(131072, 131072, 1, 1, 128,
+                          backend="tpu").bwd == "pallas"
+    text = jax.jit(jax.grad(flash_loss_planned, argnums=(0, 1, 2))).lower(
+        long_q, long_q, long_q).compile().as_text()
+    for kernel in ("hvd_flash_fwd",) + SPLIT_KERNELS:
         assert f"%{kernel}" in text, kernel
 
     from horovod_tpu.parallel import moe
@@ -233,8 +257,8 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
 
 def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
     """GPT-2-medium's attention layer as ``attention_plan`` runs it on the
-    chip (q/k/v ``[8, 1024, 16, 64]`` bf16, blocks of 1,024, the kernel
-    backward): the three kernels through Mosaic on one chip, and inside a
+    chip (q/k/v ``[8, 1024, 16, 64]`` bf16, blocks of 1,024, the one-kernel
+    backward): the two kernels through Mosaic on one chip, and inside a
     ``shard_map`` over the host's four chips, 8 sequences each, as the
     data-parallel cell runs them."""
     import jax
@@ -246,7 +270,7 @@ def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
     from horovod_tpu.ops.attention import attention_plan, flash_attention
 
     plan = attention_plan(1024, 1024, 16, 16, 64, backend="tpu")
-    assert plan == ("flash", 1024, 1024, "pallas")
+    assert plan == ("flash", 1024, 1024, "fused")
 
     def grads(q, k, v):
         return jax.grad(lambda *a: flash_attention(
@@ -263,7 +287,7 @@ def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
         qkv = jax.ShapeDtypeStruct((batch, 1024, 16, 64), jnp.bfloat16,
                                    sharding=sharding)
         text = jax.jit(fn).lower(qkv, qkv, qkv).compile().as_text()
-        for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        for kernel in FLASH_KERNELS:
             assert f"%{kernel}" in text, (kernel, batch)
 
 

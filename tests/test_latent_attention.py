@@ -169,6 +169,9 @@ def _operands(heads=4, own=16, shared=8, value=16, length=64):
     (8, 16, "pallas", None),        # bq < bk
     (16, 16, "pallas", False),      # the full grid: compute skips
     (16, 8, "scan", None),          # the scan backward
+    (16, 8, "fused", None),         # one backward kernel, the packed grid
+    (8, 16, "fused", None),
+    (16, 16, "fused", False),       # ... and the full one
 ])
 def test_flash_with_a_shared_rope_key_matches_dense(bq, bk, bwd, truncate):
     """Keys of 24 (16 a head's own, 8 one vector a token for all heads) and
@@ -193,9 +196,31 @@ def test_flash_with_a_shared_rope_key_matches_dense(bq, bk, bwd, truncate):
         np.testing.assert_allclose(a, b, atol=3e-5)
 
 
+@pytest.mark.parametrize("bq, bk, truncate", [
+    (16, 16, None), (16, 8, None), (8, 32, None), (16, 16, False)])
+def test_the_fused_backward_equals_the_split_with_a_shared_key(bq, bk,
+                                                               truncate):
+    """Keys of 24 beside values of 16 and the shared rope key, four or more
+    k-blocks under the last q-block: the one kernel's ``dq`` (its own columns
+    and the shared ones), ``dk``, ``dv`` and the shared key's gradient are
+    the two kernels' to the last bit."""
+    q, k, v, shared, w = _operands()
+
+    def grads(bwd):
+        return jax.grad(lambda q, k, v, s: jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=bq, block_k=bk, bwd_impl=bwd,
+            truncate=truncate, scale=0.3, k_shared=s) * w), (0, 1, 2, 3))(
+                q, k, v, shared)
+
+    for a, b in zip(grads("fused"), grads("pallas")):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("bwd", ["pallas", "fused"])
 @pytest.mark.parametrize("heads, kv_heads, window", [
     (4, 4, None), (4, 2, None), (4, 2, 24)])
-def test_flash_at_key_and_value_widths_that_differ(heads, kv_heads, window):
+def test_flash_at_key_and_value_widths_that_differ(heads, kv_heads, window,
+                                                   bwd):
     """No shared key: keys of 24 and values of 16, also grouped and under a
     window, against the dense path."""
     q, _, _, _, w = _operands(heads)
@@ -203,7 +228,7 @@ def test_flash_at_key_and_value_widths_that_differ(heads, kv_heads, window):
     k = jax.random.normal(ks[0], (2, 64, kv_heads, 24))
     v = jax.random.normal(ks[1], (2, 64, kv_heads, 16))
     flash = functools.partial(flash_attention, causal=True, block_q=16,
-                              block_k=8, window=window)
+                              block_k=8, window=window, bwd_impl=bwd)
     dense = functools.partial(dot_product_attention, causal=True,
                               window=window)
     np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5)
@@ -237,8 +262,8 @@ def test_the_plan_takes_the_two_widths():
     at 192 beside 128 chose the blocks it had chosen at 64 and 128, so the
     answer is the lengths' alone."""
     assert attention_plan(4096, 4096, 32, 4, 128, 2048, backend="tpu") \
-        == ("flash", 1024, 1024, "pallas")
+        == ("flash", 1024, 1024, "fused")
     assert attention_plan(8192, 8192, 16, 16, (192, 128), backend="tpu") \
-        == ("flash", 1024, 1024, "pallas")
+        == ("flash", 1024, 1024, "fused")
     assert attention_plan(8192, 8192, 16, 16, (192, 128),
                           backend="cpu").impl == "dense"

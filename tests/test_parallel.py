@@ -181,7 +181,7 @@ class TestFlashAttention:
     # routes small test shapes to the scan path, so every gradient test
     # pins the Pallas kernel split explicitly too (review r5: without
     # this, the ~200-line kernel backward had zero CI coverage).
-    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas"])
+    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
     @pytest.mark.parametrize("causal", [False, True])
     def test_gradients_match_reference(self, causal, bwd_impl):
         """flash_attention is trainable: its custom-VJP blockwise
@@ -206,7 +206,7 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
 
-    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas"])
+    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
     def test_gradients_block_q_not_multiple_of_block_k(self, bwd_impl):
         """Gradient twin of the partial-diagonal forward regression: the
         backward kernels' causal block-skip conditions must keep blocks
@@ -230,7 +230,7 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
 
-    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas"])
+    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
     @pytest.mark.parametrize("causal", [False, True])
     def test_gradients_rectangular(self, causal, bwd_impl):
         """Lq < Lk (decode-style): with causal=True the key blocks past
@@ -323,7 +323,7 @@ class TestFlashAttention:
         for a, b in zip(g_t, g_f):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas"])
+    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
     def test_offset_causal_matches_reference(self, bwd_impl):
         """Global-offset causal (the ring/Ulysses shard geometry):
         queries are a suffix block at q_offset over a longer key range —
@@ -356,7 +356,7 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
 
-    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas"])
+    @pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
     def test_truncated_odd_seq_default_blocks(self, bwd_impl):
         """Seq not a multiple of the preferred block ladder (40 -> the
         8-sublane floor): the truncated causal path must stay exact vs
@@ -374,6 +374,39 @@ class TestFlashAttention:
             q, k, v, causal=True, bwd_impl=bwd_impl) ** 2))(q)
         np.testing.assert_allclose(np.asarray(g_fl), np.asarray(g_ref),
                                    rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("case", [
+        dict(shape=(2, 64, 2, 8), causal=True, block_q=16, block_k=16),
+        dict(shape=(1, 96, 1, 8), causal=True, block_q=16, block_k=48),
+        dict(shape=(1, 96, 1, 8), causal=True, block_q=48, block_k=16),
+        dict(shape=(1, 64, 2, 8), causal=True, block_q=16, block_k=16,
+             truncate=False),
+        dict(shape=(1, 64, 2, 8), causal=False, block_q=16, block_k=32),
+        dict(shape=(1, 32, 1, 8), causal=True, block_q=8, block_k=16,
+             keys=64, q_offset=32),
+    ], ids=["packed", "wide_keys", "tall_rows", "full_grid", "not_causal",
+            "offset"])
+    def test_fused_backward_equals_the_split(self, case):
+        """The one-kernel backward runs the split's products in the split's
+        order (a q-block's dQ rows summed over ascending k-blocks in float32,
+        cast once): with several k-blocks a q-block, so that the resident dQ
+        rows are revisited, its three gradients equal the two kernels' to
+        the last bit, on the packed grid and on the full one."""
+        case = dict(case)
+        B, L, H, D = case.pop("shape")
+        keys = case.pop("keys", L)
+        key = jax.random.PRNGKey(23)
+        q = jax.random.normal(key, (B, L, H, D))
+        k, v = (jax.random.normal(jax.random.fold_in(key, i), (B, keys, H, D))
+                for i in (1, 2))
+
+        def grads(bwd_impl):
+            return jax.grad(lambda *a: jnp.sum(flash_attention(
+                *a, bwd_impl=bwd_impl, **case) ** 2), argnums=(0, 1, 2))(
+                    q, k, v)
+
+        for a, b in zip(grads("fused"), grads("pallas")):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_causal_rejects_fully_masked_rows(self):
         """q_offset < k_offset leaves query rows with NO visible key —

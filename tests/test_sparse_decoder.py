@@ -243,6 +243,11 @@ def test_selection_bias_rule():
     (4, 2, 24, 16, 8, "pallas", False),     # the full grid: compute skips
     (2, 1, 1, 8, 8, "pallas", None),        # a window of the token itself
     (4, 2, 24, 16, 8, "scan", None),        # the scan backward
+    (4, 2, 24, 16, 8, "fused", None),       # one backward kernel: window,
+    (4, 1, 20, 8, 16, "fused", None),       # grouped, both block orders,
+    (2, 2, 33, 16, 16, "fused", None),      # a window no multiple of a block
+    (4, 2, 24, 16, 8, "fused", False),      # and the full grid
+    (2, 1, 1, 8, 8, "fused", None),
 ])
 def test_windowed_grouped_flash_matches_masked_dense(heads, kv_heads, window,
                                                      bq, bk, bwd, truncate):
@@ -262,6 +267,30 @@ def test_windowed_grouped_flash_matches_masked_dense(heads, kv_heads, window,
     for a, b in zip(got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads, kv_heads, window, bq, bk, truncate", [
+    (4, 2, 24, 16, 8, None), (4, 1, 20, 8, 16, None), (2, 2, 33, 16, 16, None),
+    (4, 2, None, 16, 16, None), (4, 2, 24, 16, 8, False)])
+def test_the_fused_backward_equals_the_split_under_a_window(
+        heads, kv_heads, window, bq, bk, truncate):
+    """Window and grouped heads: the q-blocks' resident ``dq`` rows start at
+    the band's first k-block and not at block 0, and a KV head's ``dk`` /
+    ``dv`` are summed over its query heads outside the kernel, in float32,
+    as the split's are. Equal to the last bit."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q = jax.random.normal(ks[0], (2, 64, heads, 16))
+    k = jax.random.normal(ks[1], (2, 64, kv_heads, 16))
+    v = jax.random.normal(ks[2], (2, 64, kv_heads, 16))
+    w = jax.random.normal(ks[3], q.shape)
+
+    def grads(bwd):
+        return jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, window=window, block_q=bq, block_k=bk,
+            bwd_impl=bwd, truncate=truncate) * w), (0, 1, 2))(q, k, v)
+
+    for a, b in zip(grads("fused"), grads("pallas")):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_band_tables_hold_exactly_the_live_blocks():

@@ -391,9 +391,10 @@ def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
            if name.startswith("hvd.attn.") and step in by_program}
     if pinned == "flash":
         assert got == {"flash_calls": 3, "dense_calls": 0, "block_q": 64,
-                       "block_k": 64}
+                       "block_k": 64, "fused_bwd_calls": 3}
     else:
-        assert got == {"flash_calls": 0, "dense_calls": 3}
+        assert got == {"flash_calls": 0, "dense_calls": 3,
+                       "fused_bwd_calls": 0}
     assert lane.stamp["attention"] == (pinned or "dense")
 
 
@@ -437,7 +438,7 @@ def test_the_looped_step_carries_its_scopes_and_gauges(hvd, monkeypatch):
     assert got == {"hvd.loop.applications": 8,
                    "hvd.exit.live_logits_bytes": 4 * 4 * 15 * 64,
                    "hvd.attn.kv_heads": 2, "hvd.attn.dense_calls": 8,
-                   "hvd.attn.flash_calls": 0}
+                   "hvd.attn.flash_calls": 0, "hvd.attn.fused_bwd_calls": 0}
 
 
 def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
@@ -485,7 +486,8 @@ def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
            if name.startswith("hvd.attn.") and step in by_program}
     assert got == {"hvd.attn.latent_expanded_bytes":
                    2 * 16 * (2 * (8 + 8) + 4) * 2,
-                   "hvd.attn.dense_calls": 2, "hvd.attn.flash_calls": 0}
+                   "hvd.attn.dense_calls": 2, "hvd.attn.flash_calls": 0,
+                   "hvd.attn.fused_bwd_calls": 0}
 
 
 def test_windowed_train_step_has_the_same_scopes(hvd):

@@ -15,9 +15,10 @@ record carries its grid/K-V-bytes stamp (flash_grid_info) so it is
 attributable to a concrete grid, not just a wall time.
 
 ``--block-sweep [shape ...]`` is the measurement the attention policy's
-constants come from (``ops.attention.attention_plan``; PERF.md, PR 29):
-dense against the kernels over blocks and both backwards at
-:data:`SWEEP_SHAPES`.
+constants come from (``ops.attention.attention_plan``; PERF.md, PR 29 and
+PR 35): dense against the kernels over blocks and the three backwards (one
+kernel, the dQ / dK+dV split, the scan) at :data:`SWEEP_SHAPES`, each
+kernel's own device time beside the wall times.
 
 Run on a TPU host:  python tools/tpu_flash_check.py
 """
@@ -64,6 +65,35 @@ def _time(fn, *args, iters=10, repeats=3):
     return sorted(times)[len(times) // 2]
 
 
+def _kernel_ms(fn, *args, calls=4):
+    """Device ms a call of each ``hvd_flash_*`` kernel, from a profile of
+    ``calls`` calls of ``fn`` (compiled already): what the wall times hold
+    beside the kernels (the transposes around them, the rows' ``dO . O``)
+    is not in it."""
+    import collections
+    import tempfile
+
+    from horovod_tpu.utils import step_profile
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        planes = step_profile.read_xspace(
+            step_profile.newest_xplane(trace_dir))
+    ns = collections.Counter()
+    for e in (e for plane in planes
+              if step_profile.DEVICE_PLANE.match(plane.name)
+              for line in plane.lines if line.name == step_profile.OPS_LINE
+              for e in line.events):
+        # ``%hvd_flash_bwd.3 = ...``: an instruction is named after its kernel
+        name = e.name.partition(" = ")[0].lstrip("%").rstrip(".0123456789")
+        if name.startswith("hvd_flash_"):
+            ns[name] += e.end_ns - e.start_ns
+    return {k: round(v / calls / 1e6, 4) for k, v in sorted(ns.items())}
+
+
 def _grad_of(attend):
     """The jitted gradients of ``sum(attend(q, k, v))`` by q, k and v."""
     return jax.jit(jax.grad(
@@ -103,6 +133,19 @@ def main():
     print(f"truncated-vs-full grid max err: {terr:.2e} "
           f"[{_grid_stamp(L, H, D)}]", file=sys.stderr)
     assert terr == 0.0, terr
+    # The one-kernel backward against the split ON HARDWARE: the same
+    # products summed in the same order, at four blocks a side so that the
+    # resident dQ rows are revisited.
+    qkv4 = [jax.random.normal(jax.random.fold_in(key, 5 + i),
+                              (1, 2048, 2, D), jnp.bfloat16) for i in range(3)]
+    fused, split = (_grad_of(functools.partial(
+        flash_attention, causal=True, block_q=512, block_k=512,
+        bwd_impl=bwd))(*qkv4) for bwd in ("fused", "pallas"))
+    ferr = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) -
+                                     b.astype(jnp.float32))))
+               for a, b in zip(fused, split))
+    print(f"fused-vs-split backward max err: {ferr:.2e}", file=sys.stderr)
+    assert ferr < 1e-2, ferr
     # Sentinel BEFORE the timing ladder: the kernel validation above is
     # the scarce evidence — a dense-path OOM in the secondary
     # benchmark below must not make it read as a failure.
@@ -160,6 +203,14 @@ SWEEP_SHAPES = (
     ("trinity_full", (2, 4096, 32, 128), 4, None, (512, 1024, 2048)),
     ("latent_8192", (2, 8192, 16, 192), 16, None, (512, 1024, 2048),
      (128, 64)),
+    # Ouro's layer (16 heads of 128, no grouping), the plan's blocks alone
+    ("ouro_4096", (2, 4096, 16, 128), 16, None, (1024,)),
+    # Long query sides at heads of 128 (a Ulysses shard's), the plan's blocks
+    # alone: where the one-kernel backward's resident dQ stops fitting or
+    # stops winning (``ops.attention.FLASH_FUSED_VMEM_BUDGET``).
+    ("h128_16384", (1, 16384, 4, 128), 4, None, (1024,)),
+    ("h128_32768", (1, 32768, 2, 128), 2, None, (1024,)),
+    ("h128_65536", (1, 65536, 1, 128), 1, None, (1024,)),
 )
 # f32 scores of one block the kernels are tried at: 1,024 x 1,024
 SWEEP_MAX_SCORES = 1024 * 1024
@@ -167,8 +218,9 @@ SWEEP_MAX_SCORES = 1024 * 1024
 
 def block_sweep(key, only=None):
     """Forward and forward + backward ms of ONE attention layer, dense
-    against the flash kernels over blocks and both backwards, at
-    :data:`SWEEP_SHAPES` (``only``: names to keep). One JSON line a
+    against the flash kernels over blocks and the three backwards, at
+    :data:`SWEEP_SHAPES` (``only``: names to keep); for the kernels also
+    each one's device ms a call (``kernels_ms``). One JSON line a
     measurement on standard output and in ``chiprun_out/flash_sweep.jsonl``;
     the last line names the best of every shape."""
     import json
@@ -183,7 +235,10 @@ def block_sweep(key, only=None):
         row = dict(shape=shape_name, impl=label, **stamp)
         try:
             row["fwd_ms"] = 1e3 * _time(jax.jit(attend), *qkv)
-            row["fwd_bwd_ms"] = 1e3 * _time(_grad_of(attend), *qkv)
+            grad = _grad_of(attend)
+            row["fwd_bwd_ms"] = 1e3 * _time(grad, *qkv)
+            if label == "flash":
+                row["kernels_ms"] = _kernel_ms(grad, *qkv)
         except Exception as exc:  # noqa: BLE001: a refusal is a record too
             row["failed"] = f"{type(exc).__name__}: {str(exc)[:160]}"
         rows.append(row)
@@ -210,8 +265,8 @@ def block_sweep(key, only=None):
             for bk in blocks:
                 if max(bq, bk) > length or bq * bk > SWEEP_MAX_SCORES:
                     continue
-                for bwd in ("pallas", "scan"):
-                    if bwd == "scan" and bq != bk:
+                for bwd in ("fused", "pallas", "scan"):
+                    if bwd == "scan" and (bq != bk or length > 8192):
                         continue        # the scan only reads block_k
                     measure(name, "flash", shared_key(
                         flash_attention, block_q=bq, block_k=bk,
