@@ -46,8 +46,27 @@ def init(comm: Optional[Sequence[int]] = None, devices=None) -> None:
         from horovod_tpu.utils import timeline
 
         timeline.install_compile_listener()
+        age = _process_age_s()
+        if age is not None:
+            # interpreter, imports and whatever reached the backend before
+            # the program's first line: set-up that no span of ours covers
+            timeline.gauge("hvd.init.process_age_s", age)
         with timeline.span("hvd.init"):
             _init(state, comm, devices)
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since this process started, by ``/proc``; ``None`` where
+    there is none."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command, which may hold spaces
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+    except (OSError, ValueError, IndexError):
+        return None
+    return up - started / os.sysconf("SC_CLK_TCK")
 
 
 def _init(state, comm, devices) -> None:

@@ -30,7 +30,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.common import state as _state
 from horovod_tpu.parallel.logical import DATA_AXIS
-from horovod_tpu.utils.timeline import DISPATCH, gauge, span
+from horovod_tpu.utils.timeline import DISPATCH, StepClock, gauge, span
 
 # Handles built so far in this process: two of them may wrap functions of
 # one name (a train and an eval ``step_fn``), so each gets a ``program`` id.
@@ -142,7 +142,12 @@ def spmd_fn(
     ``call`` = 0, 1, 2, ...: call 0 blocks through trace and compile and
     holds their records; a later one is the HOST DISPATCH alone (jax
     dispatch is asynchronous), not device execution — a ``jax.profiler``
-    session shows both on one clock. ``HOROVOD_TIMELINE`` exports the spans as
+    session shows both on one clock. From call 1 on a record also carries
+    the host's step clock (``period_ms`` since the same thread's last
+    dispatch of the handle, and the thread's ``cpu_ms``, ``runq_ms``,
+    ``vol`` / ``invol`` context switches and ``majflt`` over it), and a handle whose steps have
+    an even pace gets a record ``hvd.host.stall`` for a dispatch that comes
+    late (``timeline.StepClock``). ``HOROVOD_TIMELINE`` exports the spans as
     ``XLA_COMPILE`` / ``XLA_EXECUTE`` with ``args.span`` saying which
     (taxonomy parity: reference operations.h:29-50, docs/timeline.md:17-62).
     """
@@ -196,6 +201,7 @@ def spmd_fn(
                        **({"compiler_options": options} if options else {}))
     calls = [0]             # dispatches of this handle so far
     rebuilt = [False]       # the autotuner swapped the program since
+    clock = StepClock(track, program)   # the host's step period, and stalls
 
     def _globalize(args):
         """Multi-host entry: each process passes its HOST-LOCAL shard
@@ -246,10 +252,12 @@ def spmd_fn(
         # was built with; a later call is the asynchronous host dispatch
         # alone.
         more = {"rebuilt": True} if rebuilt[0] else {}
-        if calls[0] == 0 or rebuilt[0]:
+        compiles = calls[0] == 0 or rebuilt[0]
+        if compiles:
             more["compile_options"] = applied
         with span(DISPATCH, handle=track, program=program, call=calls[0],
-                  **more):
+                  **more) as dispatching:
+            clock.tick(dispatching, compiles)
             calls[0] += 1
             rebuilt[0] = False
             if multi_host:

@@ -26,6 +26,17 @@ tracing module.
   their own (``RING`` too, its drops counted apart): an eager phase that
   compiles hundreds of small programs pushes out older compile records,
   never a span.
+* **What the host did in a late step** (always on too; installed with the
+  compile listener). Every ``hvd.spmd_fn`` handle has a :class:`StepClock`:
+  its dispatch records carry the host's step period and what the thread
+  spent over it (``period_ms``, ``cpu_ms``, ``runq_ms``, ``vol``,
+  ``invol``, ``majflt``). One ``gc.callbacks`` entry puts every collection of the
+  Python heap on the profile's clock (``hvd.host.gc``). One daemon thread
+  sleeps until the armed handle's next dispatch is overdue and then samples
+  where the dispatching thread stands; the dispatch that ends the wait
+  writes one record ``hvd.host.stall`` that names a ``cause``, and one
+  WARNING line. The rule and its constants are below, under "the host's
+  clock"; docs/timeline.md, "A late step", reads one.
 * ``snapshot()`` returns all of it as plain data, ``dump(path)`` writes
   that as JSON (when asked, never on the hot path), ``reset()`` forgets.
 
@@ -57,11 +68,18 @@ Perfetto even when truncated mid-run (same property the reference relied on).
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
+import logging
+import os
 import queue
+import resource
+import statistics
+import sys
 import threading
 import time
+import weakref
 from typing import Optional
 
 # Activity names (reference horovod/common/operations.h:29-50).
@@ -128,8 +146,10 @@ RING = 8192     # records kept: a 10 s window of 46 ms steps is 217 of them
 _lock = threading.Lock()
 _ring: "collections.deque" = collections.deque(maxlen=RING)
 _compiles: "collections.deque" = collections.deque(maxlen=RING)
+_gcs: "collections.deque" = collections.deque(maxlen=RING)
 _ids = itertools.count(1)
-_dropped = {"dropped": 0, "dropped_compiles": 0}    # what each ring let go
+# what each ring let go
+_dropped = {"dropped": 0, "dropped_compiles": 0, "dropped_gcs": 0}
 _counters: dict = {}
 _gauges: dict = {}
 _local = threading.local()      # .open: the spans open on this thread
@@ -168,14 +188,16 @@ def _chrome_writer():
 
 class span:
     """``with span("hvd.lane.build", model="resnet50"): ...`` (module
-    docstring). ``id`` and ``args`` can be read while it is open."""
+    docstring). ``id`` and ``args`` can be read while it is open, and
+    ``end_ns`` is ``None`` until it has closed."""
 
-    __slots__ = ("name", "args", "id", "parent", "_start", "_annotation",
-                 "_exported", "_programs", "_compile_s")
+    __slots__ = ("name", "args", "id", "parent", "_start", "end_ns",
+                 "_annotation", "_exported", "_programs", "_compile_s")
 
     def __init__(self, name: str, **args):
         self.name, self.args = name, args
         self._programs, self._compile_s = 0, 0.0
+        self.end_ns = None
 
     def __enter__(self):
         global _TraceAnnotation
@@ -196,7 +218,7 @@ class span:
         return self
 
     def __exit__(self, *exc):
-        end = time.time_ns()
+        end = self.end_ns = time.time_ns()
         self._annotation.__exit__(*exc)
         if self._exported is not None:
             tl, track, op = self._exported
@@ -313,32 +335,46 @@ def _on_event(event: str, **_):
 
 
 def install_compile_listener() -> None:
-    """Once a process; ``jax.monitoring`` has no way to take one back."""
-    global _listening
+    """Once a process; ``jax.monitoring`` has no way to take one back. The
+    host's clock (below) is installed with it: the collections' callback,
+    and the stall counters at 0, which is how a reader tells "no stall" from
+    "no detector"."""
+    global _listening, _TraceAnnotation
     with _lock:
         if _listening:
             return
         _listening = True
+        _counters.update(dict.fromkeys(_HOST_COUNTERS, 0))
     import jax.monitoring
+    import jax.profiler
 
+    _TraceAnnotation = jax.profiler.TraceAnnotation
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
     jax.monitoring.register_event_listener(_on_event)
+    gc.callbacks.append(_on_gc)
 
 
 def snapshot() -> dict:
     """``{"spans": [{"id", "parent", "name", "start_ns", "end_ns", "args"}],
-    "dropped", "dropped_compiles", "counters": {name: n}, "gauges": {name:
-    {key: value}}}``: closed spans and compile records by their end;
-    ``parent`` 0 is none; the two ``dropped`` count what the span ring and
-    the compile records' ring let go."""
+    "dropped", "dropped_compiles", "dropped_gcs", "counters": {name: n},
+    "gauges": {name: {key: value}}}``: closed spans, compile records and
+    the collections' records by their end; ``parent`` 0 is none; the three
+    ``dropped`` count what the span ring, the compile records' ring and the
+    collections' ring let go."""
     with _lock:
-        records = sorted(itertools.chain(_ring, _compiles),
+        # the collections' ring is written with no lock held (``_on_gc``):
+        # copied whole before anything walks it
+        records = sorted(itertools.chain(_ring, _compiles, list(_gcs)),
                          key=lambda r: r[4])
+        counters = dict(_counters)
+        if _listening:
+            counters["hvd.host.gc_collections"] = _gc_seen[0]
+            counters["hvd.host.gc_s"] = _gc_seen[1]
         return {
             "spans": [dict(zip(("id", "parent", "name", "start_ns",
                                 "end_ns", "args"), r)) for r in records],
             **_dropped,
-            "counters": dict(_counters),
+            "counters": counters,
             "gauges": {k: dict(v) for k, v in _gauges.items()},
         }
 
@@ -349,15 +385,327 @@ def dump(path: str) -> None:
 
 
 def reset() -> None:
+    global _warned
     with _lock:
         _ring.clear()
         _compiles.clear()
+        _gcs.clear()
         _counters.clear()
+        if _listening:
+            _counters.update(dict.fromkeys(_HOST_COUNTERS, 0))
         _gauges.clear()
         _dropped.update(dict.fromkeys(_dropped, 0))
+        _gc_seen[:] = [0, 0.0]
+        _warned = 0
+        for clock in list(_clocks):
+            clock.forget()
     # a trace this thread finished with nothing lowered after it still waits
     # (``_flush_traces``): forgotten too, or it surfaces after the reset
     getattr(_local, "traces", []).clear()
+
+
+# ------------------------------------------------------- the host's clock
+#
+# What the host did in a late step. The rule (docs/timeline.md has it for the
+# operator): a handle is ARMED once ARM_PERIODS of its step periods in a row
+# lie within ARM_WITHIN of their median; a period further off (the late step
+# itself, the short ones in which a loop with steps in flight catches up)
+# stays out of that history, and ARM_PERIODS of those in a row are a new
+# pace, learnt from nothing. A step of an armed handle is LATE when its
+# dispatch starts more than max(LATE_MS, LATE_SHARE x median) after the
+# median's time; past PAUSE_TIMES medians the loop had left (``pause``).
+
+STALL = "hvd.host.stall"
+GC = "hvd.host.gc"
+ARM_PERIODS = 8
+ARM_WITHIN = 0.25
+LATE_MS = 20.0
+LATE_SHARE = 0.10
+PAUSE_TIMES = 20
+SAMPLE_EVERY_S = 0.05   # the watcher's samples of a late step: how often,
+SAMPLES = 40            # how many at most
+SAMPLE_FOR_S = 5.0      # and for how long
+STACK_FRAMES = 12       # frames kept of a sample, from the innermost
+GC_RECORD_NS = 200_000  # a collection of generation 0 has a record from here
+WARNINGS = 20           # stalls logged a process; every one is recorded
+
+_HOST_COUNTERS = ("hvd.host.stalls", "hvd.host.stall_s")
+_log = logging.getLogger("horovod_tpu")
+_warned = 0
+_clocks: "weakref.WeakSet" = weakref.WeakSet()  # every handle's, for reset
+# Collections so far and their seconds, and the one that runs now. ``_on_gc``
+# alone writes them, with no lock: a collection can start wherever this
+# module holds ``_lock``, and the interpreter runs one at a time.
+_gc_seen = [0, 0.0]
+_gc_open: list = []
+# The watcher: the armed clock that ticked last, the thread, what wakes it
+# where it waits for no deadline, and whether it does.
+_armed: list = [None]
+_watcher: Optional[threading.Thread] = None
+_wake = threading.Event()
+_watcher_waits = False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry: every collection counted
+    (``hvd.host.gc_collections``, ``hvd.host.gc_s``) and annotated for a
+    profiler session; one of generation 1 or 2, or of ``GC_RECORD_NS`` or
+    longer, recorded (``generation``, ``collected``)."""
+    if phase == "start":
+        annotation = _TraceAnnotation(GC, generation=info["generation"])
+        annotation.__enter__()
+        _gc_open[:] = (time.time_ns(), annotation)
+        return
+    if not _gc_open:
+        return                      # installed while this collection ran
+    end = time.time_ns()
+    start, annotation = _gc_open
+    del _gc_open[:]
+    annotation.__exit__(None, None, None)
+    _gc_seen[0] += 1
+    _gc_seen[1] += (end - start) / 1e9
+    if info["generation"] or end - start >= GC_RECORD_NS:
+        if len(_gcs) == RING:
+            _dropped["dropped_gcs"] += 1
+        _gcs.append((next(_ids), 0, GC, start, end,
+                     {"generation": info["generation"],
+                      "collected": info["collected"]}))
+
+
+class _RunQueue:
+    """This thread's ``/proc/thread-self/schedstat``, held open: its second
+    number is the time the thread has stood ready to run with no core to
+    run on. ``None`` from ``wait_ns`` where ``/proc`` has no such file."""
+
+    def __init__(self):
+        try:
+            self.fd = os.open("/proc/thread-self/schedstat", os.O_RDONLY)
+        except OSError:
+            self.fd = None
+
+    def wait_ns(self) -> Optional[int]:
+        if self.fd is None:
+            return None
+        return int(os.pread(self.fd, 64, 0).split()[1])
+
+    def close(self) -> None:
+        if self.fd is not None:
+            os.close(self.fd)
+            self.fd = None
+
+    __del__ = close     # a thread's goes with the thread's locals
+
+
+class StepClock:
+    """The host's clock on one ``hvd.spmd_fn`` handle. ``tick`` runs inside
+    every dispatch span, at its entry: it reads the thread's CPU time,
+    ``getrusage(RUSAGE_THREAD)`` and the thread's wait for a core (a
+    microsecond and a half together), writes what passed since the same
+    thread's last dispatch of the handle into the span's record
+    (``period_ms``, ``cpu_ms``, ``runq_ms``, ``vol`` / ``invol``: context
+    switches the thread asked for and did not, ``majflt``), keeps the
+    handle's pace, and where this dispatch came late writes the record
+    ``hvd.host.stall``. Everything else here is read by the watcher."""
+
+    def __init__(self, handle: str, program: str):
+        self.handle, self.program = handle, program
+        self.forget()
+        _clocks.add(self)
+
+    def _disarm(self) -> None:
+        # the last even periods and the CPU time of each
+        self.periods = collections.deque(maxlen=ARM_PERIODS)
+        self.cpus = collections.deque(maxlen=ARM_PERIODS)
+        self.median = None          # of ``periods``, while the handle is armed
+        self.strays = 0             # periods off the pace, in a row
+        self.deadline_ns = None     # when the next dispatch will be late
+        self.samples: list = []     # the watcher's: (deadline, stack)
+
+    def forget(self) -> None:
+        """What a rebuilt program and ``reset()`` leave: no pace, and no
+        thread's last readings."""
+        self._disarm()
+        self._last = threading.local()      # .seen: this thread's readings
+        self.thread = None          # the thread that dispatched last
+
+    def tick(self, sp: "span", fresh: bool) -> None:
+        """``sp`` is the dispatch span just opened; ``fresh`` says that it
+        compiles (call 0, or the first call of a rebuilt program): such a
+        call carries no period and the pace is learnt anew after it."""
+        cpu_ns = time.thread_time_ns()
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
+        try:
+            runq = _local.runq
+        except AttributeError:
+            runq = _local.runq = _RunQueue()
+        # 0 start, 1 CPU ns, 2 vol, 3 invol, 4 majflt, 5 wait for a core,
+        # 6 seconds of collections, 7 seconds of compiles, 8 the span
+        seen = (sp._start, cpu_ns, usage.ru_nvcsw, usage.ru_nivcsw,
+                usage.ru_majflt, runq.wait_ns(), _gc_seen[1],
+                _counters.get("hvd.compile.seconds", 0.0), sp)
+        if fresh:
+            self.forget()
+        last = getattr(self._last, "seen", None)
+        self._last.seen = seen
+        deadline, self.thread = self.deadline_ns, threading.get_ident()
+        if last is not None:
+            spent = {"period_ms": (seen[0] - last[0]) / 1e6,
+                     "cpu_ms": (seen[1] - last[1]) / 1e6,
+                     "vol": seen[2] - last[2], "invol": seen[3] - last[3],
+                     "majflt": seen[4] - last[4]}
+            if seen[5] is not None:
+                spent["runq_ms"] = (seen[5] - last[5]) / 1e6
+            sp.args.update(spent)
+            cause = None
+            if deadline is not None and seen[0] > deadline:
+                cause = self._stall(sp, last, seen, spent, deadline)
+            elif self.samples:
+                self.samples = []   # the watcher woke as this one came in
+            if cause != "pause":
+                self._learn(spent["period_ms"], spent["cpu_ms"])
+        median = self.median
+        if median is None:
+            self.deadline_ns = None
+            return
+        self.deadline_ns = seen[0] + int(
+            (median + max(LATE_MS, LATE_SHARE * median)) * 1e6)
+        _armed[0] = self
+        if _watcher_waits:
+            _wake.set()
+        elif _watcher is None:
+            _start_watcher()
+
+    def _learn(self, period_ms: float, cpu_ms: float) -> None:
+        median = self.median
+        if median is not None and abs(period_ms - median) > ARM_WITHIN * median:
+            self.strays += 1
+            if self.strays >= ARM_PERIODS:
+                self._disarm()
+            return
+        self.strays = 0
+        self.periods.append(period_ms)
+        self.cpus.append(cpu_ms)
+        if len(self.periods) == ARM_PERIODS:
+            ordered = sorted(self.periods)
+            median = (ordered[ARM_PERIODS // 2 - 1]
+                      + ordered[ARM_PERIODS // 2]) / 2
+            even = ordered[-1] - median <= ARM_WITHIN * median \
+                and median - ordered[0] <= ARM_WITHIN * median
+            self.median = median if even else None
+
+    def _stall(self, sp, last, seen, spent, deadline) -> str:
+        """The dispatch ``sp`` of an armed handle started after ``deadline``:
+        one record from the last dispatch's start to this one's. Returns
+        its ``cause``, decided in the order of the tests below."""
+        median, period_ms = self.median, spent["period_ms"]
+        late_ms = period_ms - median
+        stacks = collections.Counter(
+            stack for late_from, stack in self.samples if late_from == deadline)
+        self.samples = []
+        stack = stacks.most_common(1)[0][0] if stacks else ()
+        frames = [f"{code.co_filename}:{line} {code.co_name}"
+                  for code, line in stack]
+        # was the last dispatch span itself open for half of the late time
+        closed = last[8].end_ns or seen[0]
+        inside = 2 * (min(closed, seen[0]) - deadline) >= seen[0] - deadline
+        gc_ms, compile_s = 1e3 * (seen[6] - last[6]), seen[7] - last[7]
+        # the thread's CPU time over what an even step of this handle takes
+        more_cpu_ms = spent["cpu_ms"] - statistics.median(self.cpus)
+        if period_ms > PAUSE_TIMES * median:
+            cause = "pause"         # the loop had left
+        elif compile_s > 0:
+            cause = "compile"       # a compile record lies in the period
+        elif 2 * gc_ms >= late_ms:
+            cause = "gc"
+        elif inside:
+            cause = "dispatch"      # the runtime's enqueue held the thread
+        elif 2 * spent.get("runq_ms", 0.0) >= late_ms or spent["majflt"]:
+            cause = "off_cpu"       # ready to run with no core, or paged in
+        elif 2 * more_cpu_ms >= late_ms:
+            cause = "python"        # host code ran on this thread
+        else:
+            cause = "waiting"       # it slept of its own accord: for a result
+        args = dict(
+            spent, handle=self.handle, program=self.program,
+            call=sp.args.get("call"), late_ms=late_ms, median_ms=median,
+            cause=cause, where=frames[0] if frames else "", stack=frames,
+            samples=sum(stacks.values()), inside_dispatch=inside,
+            gc_ms=gc_ms, compile_s=compile_s)
+        _append(_ring, (next(_ids), sp.id, STALL, last[0], seen[0], args),
+                "dropped")
+        if cause == "pause":
+            self._disarm()          # and so no stall of a step
+        else:
+            count("hvd.host.stalls")
+            count("hvd.host.stall_s", late_ms / 1e3)
+            _warn(args)
+        return cause
+
+
+
+def _warn(stall: dict) -> None:
+    """The SPMD path's stall warning (the reference's is
+    operations.cc:1625-1672, for tensors that wait for a rank)."""
+    global _warned
+    with _lock:
+        _warned += 1
+        nth = _warned
+    if nth > WARNINGS:
+        return
+    _log.warning(
+        "hvd.host.stall: call %s of %s started %.1f ms late (a step takes "
+        "%.1f ms): cause %s, the thread stood at %s%s",
+        stall["call"], stall["program"], stall["late_ms"],
+        stall["median_ms"], stall["cause"],
+        stall["where"] or "(no sample)",
+        "" if nth < WARNINGS else f"; this is the {WARNINGS}th such line "
+        "and the last, later stalls are in timeline.snapshot()")
+
+
+def _stack_of(thread: int) -> tuple:
+    frame, stack = sys._current_frames().get(thread), []
+    while frame is not None and len(stack) < STACK_FRAMES:
+        stack.append((frame.f_code, frame.f_lineno))
+        frame = frame.f_back
+    return tuple(stack)
+
+
+def _watch() -> None:
+    """The watcher thread. It sleeps until the armed handle's deadline, so
+    it wakes about once a step and finds a later deadline; where the
+    deadline has passed it samples the dispatching thread's Python stack
+    every ``SAMPLE_EVERY_S`` until the handle's next dispatch (at most
+    ``SAMPLES`` times and ``SAMPLE_FOR_S``), then waits to be woken."""
+    global _watcher_waits
+    while True:
+        clock = _armed[0]
+        deadline = clock.deadline_ns if clock is not None else None
+        if deadline is not None:
+            ahead = deadline - time.time_ns()
+            if ahead > 0:
+                time.sleep(ahead / 1e9)
+                continue
+            until = time.monotonic() + SAMPLE_FOR_S
+            for _ in range(SAMPLES):
+                if clock.deadline_ns != deadline or time.monotonic() > until:
+                    break       # the next dispatch has come, or the caps
+                clock.samples.append((deadline, _stack_of(clock.thread)))
+                time.sleep(SAMPLE_EVERY_S)
+            if clock.deadline_ns != deadline:
+                continue
+        _watcher_waits = True       # nothing armed, or the loop stands still
+        _wake.wait()
+        _watcher_waits = False
+        _wake.clear()
+
+
+def _start_watcher() -> None:
+    global _watcher
+    with _lock:
+        if _watcher is None:
+            _watcher = threading.Thread(target=_watch, name="hvd-step-watcher",
+                                        daemon=True)
+            _watcher.start()
 
 
 # --------------------------------------------------------- Chrome writer

@@ -20,9 +20,13 @@ SCOPES = (timeline.FORWARD, timeline.LOSS, timeline.EXCHANGE,
           timeline.UPDATE, timeline.METRICS)
 
 
-def _spans(name=None):
-    found = timeline.snapshot()["spans"]
-    return [s for s in found if name is None or s["name"] == name]
+def _spans(name=None, snap=None):
+    """The records called ``name``; with no name all of them but the
+    collections of the heap, which come when they will."""
+    found = (snap or timeline.snapshot())["spans"]
+    return [s for s in found
+            if (s["name"] != timeline.GC if name is None
+                else s["name"] == name)]
 
 
 def _mesh(n):
@@ -84,13 +88,15 @@ def test_dispatch_spans_carry_handle_and_call(hvd):
         ("counted_step", i) for i in range(3)]
     assert {s["parent"] for s in calls} == {loop.id}
     # the call that compiled says so in its record, and so does what it
-    # lies in; a call that only dispatched carries nothing more
+    # lies in; a call that only dispatched carries the step clock's readings
+    # and nothing more
     first, outer = calls[0]["args"], _spans("loop")[0]["args"]
     assert first["programs"] >= 1 and first["compile_s"] > 0
     assert outer["programs"] == first["programs"]
     assert outer["compile_s"] == pytest.approx(first["compile_s"])
-    assert all(set(s["args"]) == {"handle", "program", "call"}
-               for s in calls[1:])
+    assert all(set(s["args"]) - {"runq_ms"} == {
+        "handle", "program", "call", "period_ms", "cpu_ms", "vol", "invol",
+        "majflt"} for s in calls[1:])
     assert len({s["args"]["program"] for s in calls}) == 1
 
 
@@ -168,14 +174,21 @@ def test_the_ring_drops_the_oldest_and_counts_it(hvd):
         with timeline.span("tick", i=i):
             pass
     snap = timeline.snapshot()
-    assert len(snap["spans"]) == timeline.RING
+    ticks = _spans(snap=snap)
+    assert len(ticks) == timeline.RING
     assert snap["dropped"] == extra
-    assert snap["spans"][0]["args"] == {"i": extra}
-    assert snap["spans"][-1]["args"] == {"i": timeline.RING + extra - 1}
+    assert ticks[0]["args"] == {"i": extra}
+    assert ticks[-1]["args"] == {"i": timeline.RING + extra - 1}
     timeline.reset()
-    assert timeline.snapshot() == {"spans": [], "dropped": 0,
-                                   "dropped_compiles": 0, "counters": {},
-                                   "gauges": {}}
+    # what is left is the detector's own: its counters at 0, which say that
+    # it is installed, and the collections since the reset
+    snap = timeline.snapshot()
+    assert snap.pop("counters").keys() == {
+        "hvd.host.stalls", "hvd.host.stall_s", "hvd.host.gc_collections",
+        "hvd.host.gc_s"}
+    assert all(s["name"] == timeline.GC for s in snap.pop("spans"))
+    assert snap == {"dropped": 0, "dropped_compiles": 0, "dropped_gcs": 0,
+                    "gauges": {}}
 
 
 def test_a_flood_of_compile_records_pushes_out_no_span(hvd):
@@ -189,7 +202,7 @@ def test_a_flood_of_compile_records_pushes_out_no_span(hvd):
                               1e-6)
     snap = timeline.snapshot()
     assert snap["dropped_compiles"] == 3 and snap["dropped"] == 0
-    assert [s["name"] for s in snap["spans"]
+    assert [s["name"] for s in _spans(snap=snap)
             if not s["name"].startswith("hvd.compile.")] == ["kept"]
     assert snap["counters"]["hvd.compile.programs"] == timeline.RING + 3
     timeline.reset()
@@ -207,7 +220,8 @@ def test_counters_add_gauges_set_and_dump_writes_json(hvd, tmp_path):
     path = tmp_path / "snapshot.json"
     timeline.dump(str(path))
     snap = json.loads(path.read_text())
-    assert snap["counters"] == {"n": 3}
+    assert {k: v for k, v in snap["counters"].items()
+            if not k.startswith("hvd.host.")} == {"n": 3}
     assert snap["gauges"] == {"g": {"": 7, "program": 1}}
     assert [s["name"] for s in snap["spans"]] == ["s"]
     assert snap == json.loads(json.dumps(timeline.snapshot()))
