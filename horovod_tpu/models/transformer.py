@@ -32,7 +32,9 @@ class TransformerBlock(nn.Module):
     # causal=True itself; ring/Ulysses derive offsets from the mesh axis).
     # None = causal attention using q_offset, by the implementation that
     # ops.attention.attention_plan picks for the shapes (the flash kernels
-    # or the dense reference).
+    # or the dense reference). ``attend`` itself (None, or a partial of it
+    # that pins an argument) takes the fused projection whole: at heads of
+    # 64 its kernels read q, k and v out of it where it lies.
     attn_fn: Optional[Callable] = None
     dropout: float = 0.0
 
@@ -43,17 +45,16 @@ class TransformerBlock(nn.Module):
         D = E // H
         h = nn.LayerNorm(dtype=jnp.float32)(x)
         qkv = nn.Dense(3 * E, use_bias=False, dtype=self.dtype)(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (*q.shape[:-1], H, D)
         with jax.named_scope(timeline.ATTN_FULL):
             if self.attn_fn is None:
-                attn = attend(q.reshape(shape), k.reshape(shape),
-                              v.reshape(shape), q_offset=q_offset)
+                attn = attend(qkv, heads=H, q_offset=q_offset)
+            elif getattr(self.attn_fn, "func", None) is attend:
+                attn = self.attn_fn(qkv, heads=H)
             else:
-                attn = self.attn_fn(q.reshape(shape), k.reshape(shape),
-                                    v.reshape(shape))
-        attn = attn.reshape(q.shape)
-        x = x + nn.Dense(E, dtype=self.dtype)(attn)
+                shape = (*x.shape[:-1], H, D)
+                attn = self.attn_fn(*(t.reshape(shape) for t in
+                                      jnp.split(qkv, 3, axis=-1)))
+        x = x + nn.Dense(E, dtype=self.dtype)(attn.reshape(x.shape))
 
         h = nn.LayerNorm(dtype=jnp.float32)(x)
         h = nn.Dense(self.mlp_ratio * E, dtype=self.dtype)(h)

@@ -9,7 +9,13 @@ long-context support is a first-class extension of this rebuild (SURVEY
 
 ``flash_attention`` is a Pallas TPU kernel (online-softmax tiling so the
 L x L score matrix never materializes in HBM); on the CPU test platform
-it runs in interpreter mode so tests cover the same code path. On the causal square
+it runs in interpreter mode so tests cover the same code path. Where a head
+is 64 wide a kernel program serves the two heads that share a 128-lane column
+block and reads q, k, v and dO, and writes o, dQ, dK and dV, in the layout the
+projections use (``[B, L, columns]``, a fused ``qkv`` projection taken whole),
+by index map: nothing is transposed or sliced between a projection and a
+kernel (``flash_attention(..., heads=)``; :func:`attention_plan` decides from
+the shapes). On the causal square
 path every streamed kernel (the forward; the backward, one kernel or the dQ
 and dK/dV pair) executes a PACKED at-or-below-diagonal grid — the
 strictly-masked half of the (q-block,
@@ -292,9 +298,27 @@ def _block_scores(q, k_blk, ks_ref, scale: float):
                       preferred_element_type=jnp.float32)) * scale
 
 
+def _head_lanes(shape, heads: int, h: int):
+    """Which lanes of a ``[rows, 128]`` tile belong to head ``h`` of the
+    ``heads`` that lie side by side in it."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return lane // (shape[1] // heads) == h
+
+
+def _own_lanes(x, heads: int, h: int):
+    """``x`` with the lanes of every head but ``h`` zeroed (``x`` itself in a
+    one-head program): a product that contracts over the lanes then sees head
+    ``h`` alone, and one that keeps them is zero outside the head's columns,
+    so that the heads' results add up into one 128-lane tile."""
+    if heads == 1:
+        return x
+    return jnp.where(_head_lanes(x.shape, heads, h), x, jnp.zeros_like(x))
+
+
 def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
                   scale: float, block_q: int, delta: int, packed: bool,
-                  window: Optional[int] = None, shared: bool = False):
+                  window: Optional[int] = None, shared: bool = False,
+                  heads: int = 1):
     """One streamed-forward grid step. Two grid layouts share this body:
 
     * full (``packed=False``) — grid (batch*head, q-block, K-BLOCK): the
@@ -314,6 +338,14 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
     ``delta = q_offset - k_offset`` shifts the causal mask for
     global-offset callers (always 0 on the packed path, which
     _grid_truncates restricts to equal offsets).
+
+    ``heads`` is the heads a program serves. At 2 the q, k, v and o tiles are
+    128 lanes wide and hold two heads of 64 side by side (one column block
+    of the projections' ``[B, L, columns]``, no transpose before the call),
+    the statistics are ``[2, block_q, 1]``, and the body runs once a head
+    over the same tiles and the same mask: the head's scores are ``(q with
+    the other head's lanes zeroed) k^T``, the MXU pass a 64-wide contraction
+    costs, and of ``p v`` the head keeps its own lanes of the accumulator.
 
     VMEM is O(block) — the pre-streaming design mapped the FULL [Lk, d]
     K/V into each program's VMEM, which hit the 16 MB scoped limit at
@@ -358,23 +390,32 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
         q = q_ref[...]                              # [block_q, dk]
         k_blk = k_ref[...]                          # [block_k, dk]
         v_blk = v_ref[...]                          # [block_k, dv]
-        s = _block_scores(q, k_blk, ks_ref, scale)
-        if causal:
-            q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(_band_mask(q_pos, k_pos, window), s, NEG_INF)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32)
+        seen = None
+        for h in range(heads):
+            at = (h,) if heads > 1 else (Ellipsis,)     # head h's statistics
+            s = _block_scores(_own_lanes(q, heads, h), k_blk, ks_ref, scale)
+            if causal:
+                if seen is None:            # one mask for the tile's heads
+                    q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 0)
+                    k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 1)
+                    seen = _band_mask(q_pos, k_pos, window)
+                s = jnp.where(seen, s, NEG_INF)
+            m = m_scr[at]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            m_scr[at] = m_new
+            l_scr[at] = l_scr[at] * alpha + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+            acc = acc_scr[...] * alpha + jnp.dot(
+                p.astype(v_blk.dtype), v_blk,
+                preferred_element_type=jnp.float32)
+            if heads > 1:   # p v is head h's in its own lanes alone
+                acc = jnp.where(_head_lanes(acc.shape, heads, h), acc,
+                                acc_scr[...])
+            acc_scr[...] = acc
 
     if causal and not packed:
         # A k-block strictly past this q-block's last row (or wholly
@@ -393,13 +434,22 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
 
     @pl.when(kb == last_kb)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
         # Per-row logsumexp (scores already include `scale`): persisted
         # so the backward never re-derives it with an extra pass over
         # the key blocks. Written in the statistics' native
         # [block_q, 1] layout — no cross-lane reshape inside the kernel.
-        lse_ref[...] = m_scr[...] + jnp.log(l)
+        if heads == 1:
+            l = jnp.maximum(l_scr[...], 1e-30)
+            o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+            lse_ref[...] = m_scr[...] + jnp.log(l)
+            return
+        sums = [jnp.maximum(l_scr[h], 1e-30) for h in range(heads)]
+        l = sums[0]                     # each lane's own head's sum
+        for h in range(1, heads):
+            l = jnp.where(_head_lanes(acc_scr.shape, heads, h), sums[h], l)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+        for h in range(heads):
+            lse_ref[h] = m_scr[h] + jnp.log(sums[h])
 
 
 # Native TPU sublane tile: the f32 min tile is (8, 128), so blocks
@@ -440,6 +490,7 @@ class AttentionPlan(NamedTuple):
     block_q: Optional[int]      # the kernels' blocks; None where no legal
     block_k: Optional[int]      # block divides a length (then ``dense``)
     bwd: str                    # the kernels' backward: "fused" | "pallas"
+    heads_per_program: int = 1  # heads a kernel program serves: 1 | 2
 
 
 # The policy's constants, each from ``tools/tpu_flash_check.py
@@ -460,6 +511,19 @@ class AttentionPlan(NamedTuple):
 # chip run of PR 34: ``--block-sweep latent_8192``): 1,024 x 1,024 27.57
 # against 28.63 at 512 x 1,024, 30.41 at 512 x 2,048, 32.41 at 512 x 512 and
 # 32.75 at 1,024 x 512; 2,048 x 512 and larger Mosaic refuses there for VMEM.
+#
+# Those ms are of calls on separate ``[B, L, H, D]`` tensors and hold the
+# ``[B, L, H, D]`` to ``[B x H, L, D]`` transposes around the kernels: at heads
+# of 64 and 1,024 keys the two kernels alone were 0.57 + 1.05 of the 2.54 (the
+# one-kernel backward since PR 35). A layer THROUGH ``attend``, from the fused
+# ``qkv`` projection ``[8, 1024, 3072]`` to what the output projection reads
+# and back to the projection's gradient (``--block-sweep gpt2m_1024_layer``,
+# chip run of PR 37; PERF.md section 6): one head a program 0.973 forward and
+# 2.334 forward + backward, of which the kernels are 0.573 + 1.047; two heads
+# a program, read where the projection wrote them, 0.644 and 1.776 (kernels
+# 0.573 + 1.047: the same MXU passes in half the programs): what is left
+# around them, 0.16, is the three gradients laid side by side. That is
+# :func:`_planned_heads`' one measurement; dense there: 1.32 and 3.96.
 FLASH_BLOCK = 1024
 # Smallest block the kernels are chosen at: at 256 x 256 they lose to dense
 # at 1,024 keys (4.38 against 3.95) and win by 2 to 5% at 2,048 and 4,096.
@@ -506,6 +570,16 @@ def fused_bwd_vmem_bytes(seq_q: int, key_width: int, itemsize: int) -> int:
     return FLASH_SPLIT_VMEM + seq_q * lanes * (4 + 2 * itemsize)
 
 
+def pair_vmem_bytes(itemsize: int) -> int:
+    """VMEM a two-head program holds beyond a one-head program's, at blocks
+    of :data:`FLASH_BLOCK`: the second head's two blocks of statistics, each
+    a 128-lane float32 column and double-buffered, and each head's copies of
+    three operand tiles with the other head's lanes zeroed (3.5 MiB in bf16,
+    5 in float32; the float32 kernels pass the one-head limits by 1.1 to 3.4
+    MiB: compiles for a described v5e, PR 37)."""
+    return FLASH_BLOCK * 128 * (2 * 2 * 4 + 3 * 2 * itemsize)
+
+
 def _planned_bwd(seq_q: int, key_width: int, dtype,
                  pin: Optional[str] = None) -> str:
     """The kernels' backward for a call's shapes: the caller's ``pin``, or
@@ -518,14 +592,33 @@ def _planned_bwd(seq_q: int, key_width: int, dtype,
     return "fused" if fits else FLASH_BWD
 
 
+def _planned_heads(seq_q: int, seq_k: int, heads: int, kv_heads: int,
+                   head_dim, bwd: str, shared_key: bool = False,
+                   q_offset: int = 0) -> int:
+    """The heads a kernel program serves: two where they fill a 128-lane
+    tile between them and the kernels can read it where a projection wrote it
+    (keys and values both :data:`PAIR_WIDTH` wide, an even number of heads, a
+    KV head a query head, no shared key, the causal square call with no
+    offset, the one-kernel backward); one everywhere else."""
+    widths = head_dim if isinstance(head_dim, tuple) else (head_dim,) * 2
+    paired = (widths == (PAIR_WIDTH,) * 2 and heads % 2 == 0
+              and kv_heads == heads and not shared_key and seq_q == seq_k
+              and not q_offset and bwd == "fused")
+    return 2 if paired else 1
+
+
 def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
                    head_dim, window: Optional[int] = None,
                    dtype=jnp.bfloat16,
-                   backend: Optional[str] = None) -> AttentionPlan:
-    """Implementation, blocks and backward of one causal attention call, from
-    what is static about it. ``head_dim`` is the one width of queries, keys
-    and values, or ``(keys' width, values' width)`` where they differ.
-    ``backend`` defaults to JAX's own.
+                   backend: Optional[str] = None, *,
+                   shared_key: bool = False,
+                   q_offset: int = 0) -> AttentionPlan:
+    """Implementation, blocks, backward and heads a program of one causal
+    attention call, from what is static about it. ``head_dim`` is the one
+    width of queries, keys and values, or ``(keys' width, values' width)``
+    where they differ; ``shared_key`` says that the keys' last columns are
+    one vector a token for all heads, ``q_offset`` where the first query
+    stands. ``backend`` defaults to JAX's own.
 
     The kernels run on a TPU, from :data:`FLASH_MIN_KEYS` keys on, where a
     block of at least :data:`FLASH_MIN_BLOCK` divides both lengths; the
@@ -536,21 +629,32 @@ def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
     type that would change a choice, so today the answer depends on the
     lengths alone; the other arguments are what a later measurement may key
     on without a new call site.
+
+    ``heads_per_program`` is the kernels' layout (:func:`_planned_heads`):
+    at heads of 64 the ``[B, L, H, 64]`` to ``[B x H, L, 64]`` transposes
+    around one-head programs are copies XLA folds nowhere (a head is half a
+    lane row), a layer's cost as much as its forward kernel
+    (``tools/tpu_flash_check.py --block-sweep``, chip run of PR 37; PERF.md
+    section 6), so two heads share a program that reads the projections'
+    layout by index map; at 128 the transposes fold into the projections
+    and one head a program stays.
     """
     _kv_group(heads, kv_heads)
     del window
     key_width = head_dim[0] if isinstance(head_dim, tuple) else head_dim
     bwd = _planned_bwd(seq_q, key_width, dtype)
+    paired = _planned_heads(seq_q, seq_k, heads, kv_heads, head_dim, bwd,
+                            shared_key, q_offset)
     try:
         block_q, block_k = _planned_blocks(seq_q, seq_k, None, None)
     except ValueError:
-        return AttentionPlan("dense", None, None, bwd)
+        return AttentionPlan("dense", None, None, bwd, paired)
     if backend is None:
         backend = jax.default_backend()
     flash = (backend == "tpu" and seq_k >= FLASH_MIN_KEYS
              and min(block_q, block_k) >= FLASH_MIN_BLOCK)
     return AttentionPlan("flash" if flash else "dense", block_q, block_k,
-                         bwd)
+                         bwd, paired)
 
 
 def _planned_blocks(seq_q: int, seq_k: int, block_q: Optional[int],
@@ -571,55 +675,89 @@ def _planned_blocks(seq_q: int, seq_k: int, block_q: Optional[int],
 _traced: dict = {}
 
 
-def attend(q, k, v, *, window: Optional[int] = None, q_offset: int = 0,
+def attend(q, k=None, v=None, *, heads: Optional[int] = None,
+           window: Optional[int] = None, q_offset: int = 0,
            impl: Optional[str] = None, scale: Optional[float] = None,
            k_shared=None, **flash_args):
     """Causal attention as a model's block calls it: q ``[B, L, H, Dk]``, k
     ``[B, L, G, Dk]``, v ``[B, L, G, Dv]``, scores times ``scale`` (``Dk **
     -0.5`` unless the caller says); with ``k_shared [B, L, Dr]`` ``k`` holds
     each head's own ``Dk - Dr`` key columns and ``k_shared`` the rest, the
-    same for all heads. :func:`attention_plan` picks the implementation from
-    the shapes unless ``impl`` (``"dense"`` | ``"flash"``) pins one;
+    same for all heads. Or, with ``heads`` and no ``k`` and ``v``, ``q`` is a
+    fused projection ``[B, L, 3 x heads x D]`` (q | k | v along its columns)
+    taken whole, and the result is ``[B, L, heads x D]``, what the output
+    projection reads: where the plan answers two heads a program the kernels
+    read and write those arrays as they stand, and elsewhere the projection
+    is split here. :func:`attention_plan` picks the implementation and the
+    heads a program from the shapes unless ``impl`` (``"dense"`` |
+    ``"flash"``) pins the first;
     ``flash_args`` go to :func:`flash_attention` (an A/B's ``truncate`` and
     ``bwd_impl``). Sets the gauges ``hvd.attn.flash_calls`` /
     ``.dense_calls`` (attention calls traced into the step's program, by
     implementation), ``.fused_bwd_calls`` (those of the kernels' calls whose
-    backward is the one kernel) and ``.block_q`` / ``.block_k`` (the
-    kernels' blocks)."""
+    backward is the one kernel), ``.paired_calls`` (those whose programs
+    serve two heads) and ``.block_q`` / ``.block_k`` (the kernels'
+    blocks)."""
+    whole_projection = k is None
+    if whole_projection:
+        if heads is None or v is not None or q.shape[-1] % (3 * heads):
+            raise ValueError(
+                f"a fused projection is [B, L, 3 x heads x D] with heads "
+                f"given and no k or v, got {q.shape} and heads={heads}")
+        kv_heads, widths = heads, (q.shape[-1] // (3 * heads),) * 2
+    else:
+        heads, kv_heads = q.shape[2], k.shape[2]
+        widths = (q.shape[3], v.shape[3])
+    seq = q.shape[1]
     if impl is None:
         # an offset mask is outside what the policy was measured on
         impl = "dense" if q_offset else attention_plan(
-            q.shape[1], k.shape[1], q.shape[2], k.shape[2],
-            (q.shape[3], v.shape[3]), window, q.dtype).impl
+            seq, seq, heads, kv_heads, widths, window, q.dtype).impl
     if impl not in ("dense", "flash"):
         raise ValueError(f"impl must be dense|flash, got {impl!r}")
+    bwd = _planned_bwd(seq, widths[0], q.dtype, flash_args.get("bwd_impl"))
+    paired = impl == "flash" and _planned_heads(
+        seq, seq, heads, kv_heads, widths, bwd, k_shared is not None,
+        q_offset) == 2
     program, calls = timeline.program_tally(
-        _traced, lambda: {"flash": 0, "dense": 0, "fused_bwd": 0})
+        _traced, lambda: {"flash": 0, "dense": 0, "fused_bwd": 0,
+                          "paired": 0})
     calls[impl] += 1
-    calls["fused_bwd"] += impl == "flash" and _planned_bwd(
-        q.shape[1], q.shape[3], q.dtype, flash_args.get("bwd_impl")) == "fused"
+    calls["fused_bwd"] += impl == "flash" and bwd == "fused"
+    calls["paired"] += paired
     for name, n in calls.items():
         timeline.gauge(f"hvd.attn.{name}_calls", n, key=program)
+    if impl == "flash":
+        block_q, block_k = _planned_blocks(
+            seq, seq, flash_args.get("block_q"), flash_args.get("block_k"))
+        timeline.gauge("hvd.attn.block_q", block_q, key=program)
+        timeline.gauge("hvd.attn.block_k", block_k, key=program)
+    if paired:          # the projections' layout, read where it lies
+        operands = (q,) if whole_projection else tuple(
+            t.reshape(*t.shape[:2], -1) for t in (q, k, v))
+        out = flash_attention(*operands, causal=True, scale=scale,
+                              window=window, heads=heads, **flash_args)
+        return out if whole_projection else out.reshape(q.shape)
+    if whole_projection:
+        q, k, v = (t.reshape(*t.shape[:2], heads, -1)
+                   for t in jnp.split(q, 3, axis=-1))
     if impl == "dense":
-        return dot_product_attention(q, k, v, causal=True, scale=scale,
-                                     q_offset=q_offset, window=window,
-                                     k_shared=k_shared)
-    block_q, block_k = _planned_blocks(
-        q.shape[1], k.shape[1], flash_args.get("block_q"),
-        flash_args.get("block_k"))
-    timeline.gauge("hvd.attn.block_q", block_q, key=program)
-    timeline.gauge("hvd.attn.block_k", block_k, key=program)
-    return flash_attention(q, k, v, causal=True, scale=scale,
-                           q_offset=q_offset, window=window,
-                           k_shared=k_shared, **flash_args)
+        out = dot_product_attention(q, k, v, causal=True, scale=scale,
+                                    q_offset=q_offset, window=window,
+                                    k_shared=k_shared)
+    else:
+        out = flash_attention(q, k, v, causal=True, scale=scale,
+                              q_offset=q_offset, window=window,
+                              k_shared=k_shared, **flash_args)
+    return out.reshape(*out.shape[:2], -1) if whole_projection else out
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret",
                                              "bwd_impl", "q_offset",
                                              "k_offset", "truncate",
-                                             "window"))
-def flash_attention(q, k, v, causal: bool = False,
+                                             "window", "heads"))
+def flash_attention(q, k=None, v=None, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
@@ -627,7 +765,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     bwd_impl: Optional[str] = None,
                     q_offset: int = 0, k_offset: int = 0,
                     truncate: Optional[bool] = None,
-                    window: Optional[int] = None, k_shared=None):
+                    window: Optional[int] = None, k_shared=None,
+                    heads: Optional[int] = None):
     """Pallas flash attention. Shapes q [B, L, H, Dk], k [B, L, G, Dk],
     v [B, L, G, Dv] -> [B, L, H, Dv]; ``G`` divides ``H`` and query head h
     reads KV head ``h // (H / G)`` (grouped-query attention; K and V are
@@ -636,6 +775,25 @@ def flash_attention(q, k, v, causal: bool = False,
     Dr]``, the columns of each key that are the head's own, and ``k_shared``
     the last ``Dr``, one vector a token for all heads, read by index map
     too. ``scale`` defaults to ``Dk ** -0.5``.
+
+    In this form a program of the kernels serves one (batch, head): q, k, v
+    and dO are transposed to ``[B x H, L, D]`` before a kernel and its
+    results back after it, copies that XLA folds into the projections where
+    a head is 128 wide and writes out where it is 64. ``heads`` (static; an
+    even number of heads of 64, a KV head a query head, no shared key, the
+    one-kernel backward) selects the other form, **two heads a program**: q,
+    k and v are ``[B, L, heads x 64]``, the layout a projection writes, or q
+    alone is a fused projection ``[B, L, 3 x heads x 64]`` (q | k | v along
+    the columns; ``k`` and ``v`` None), and the result is ``[B, L, heads x
+    64]``. A program reads the 128-lane column block that holds its two
+    heads by index map from those arrays and writes its block of the result,
+    and of dQ, dK and dV, the same way, so no transpose or slice lies between
+    a projection and a kernel. Inside, head ``h``'s products take one
+    operand with the other head's lanes zeroed: a contraction over 128 lanes
+    of which 64 are zero is the MXU pass a 64-wide contraction costs, and the
+    two heads' parts of a 128-lane result add up with exact zeros, so o, dQ,
+    dK and dV equal the one-head programs'. :func:`attend` chooses the form
+    from the shapes (:func:`attention_plan`).
 
     ``window`` (static; plain causal square attention only) lets query i
     see keys j with ``0 <= i - j < window``: the mask is applied inside
@@ -677,13 +835,14 @@ def flash_attention(q, k, v, causal: bool = False,
     above-diagonal half over the q axis); gradient exactness vs the dense
     reference, and the one kernel's equality with the split, are pinned in
     tests/test_parallel.py::TestFlashAttention."""
+    width = q.shape[-1] if heads is None else PAIR_WIDTH
+    seq_k = (q if k is None else k).shape[1]
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(width)
     if interpret is None:
         interpret = pallas_interpret()
-    block_q, block_k = _planned_blocks(q.shape[1], k.shape[1], block_q,
-                                       block_k)
-    bwd_impl = _planned_bwd(q.shape[1], q.shape[-1], q.dtype, bwd_impl)
+    block_q, block_k = _planned_blocks(q.shape[1], seq_k, block_q, block_k)
+    bwd_impl = _planned_bwd(q.shape[1], width, q.dtype, bwd_impl)
     if bwd_impl not in ("scan", "pallas", "fused"):
         raise ValueError(f"bwd_impl must be auto|scan|pallas|fused, "
                          f"got {bwd_impl!r}")
@@ -698,37 +857,54 @@ def flash_attention(q, k, v, causal: bool = False,
             f"causal flash_attention requires q_offset >= k_offset "
             f"(got {q_offset} < {k_offset}): rows with no visible key "
             f"have no defined softmax")
-    _kv_group(q.shape[2], k.shape[2])
-    own = q.shape[-1] - (0 if k_shared is None else k_shared.shape[-1])
-    if k.shape[-1] != own:
-        raise ValueError(f"queries of {q.shape[-1]} need keys of {own} a "
-                         f"head, got k {k.shape}")
-    if k_shared is not None and k.shape[2] != q.shape[2]:
-        raise ValueError("a shared key needs a key head a query head, got "
-                         f"{k.shape[2]} under {q.shape[2]}")
+    if heads is not None:
+        whole = k is None and v is None     # one fused projection: q | k | v
+        operands = (q,) if whole else (q, k, v)
+        columns = heads * PAIR_WIDTH * (3 if whole else 1)
+        if (heads < 2 or heads % 2 or k_shared is not None
+                or bwd_impl != "fused"
+                or any(t is None or t.shape[2:] != (columns,)
+                       for t in operands)):
+            raise ValueError(
+                f"two heads a program need an even number of heads of "
+                f"{PAIR_WIDTH} in the projections' layout (q, k, v [B, L, "
+                f"heads x {PAIR_WIDTH}] or one fused [B, L, 3 x heads x "
+                f"{PAIR_WIDTH}]), no shared key and the one-kernel backward; "
+                f"got heads={heads}, shapes "
+                f"{[getattr(t, 'shape', None) for t in (q, k, v)]}, "
+                f"bwd_impl={bwd_impl!r}")
+    else:
+        _kv_group(q.shape[2], k.shape[2])
+        own = q.shape[-1] - (0 if k_shared is None else k_shared.shape[-1])
+        if k.shape[-1] != own:
+            raise ValueError(f"queries of {q.shape[-1]} need keys of {own} "
+                             f"a head, got k {k.shape}")
+        if k_shared is not None and k.shape[2] != q.shape[2]:
+            raise ValueError("a shared key needs a key head a query head, "
+                             f"got {k.shape[2]} under {q.shape[2]}")
     if window is not None:
-        if not (causal and q.shape[1] == k.shape[1]
+        if not (causal and q.shape[1] == seq_k
                 and q_offset == k_offset) or window < 1:
             raise ValueError(
                 f"a window needs plain causal square attention and a width "
                 f"of at least 1 (causal={causal}, Lq={q.shape[1]}, "
-                f"Lk={k.shape[1]}, q_offset={q_offset}, "
+                f"Lk={seq_k}, q_offset={q_offset}, "
                 f"k_offset={k_offset}, window={window}): elsewhere a row "
                 f"could be left with no key at all")
-        if window >= k.shape[1]:
+        if window >= seq_k:
             window = None              # the band is the whole triangle
     return _flash(q, k, v, k_shared, causal, float(scale), block_q, block_k,
                   interpret, bwd_impl, int(q_offset), int(k_offset),
-                  truncate, window)
+                  truncate, window, heads)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
 def _flash(q, k, v, k_shared, causal, scale, block_q, block_k, interpret,
-           bwd_impl, q_offset, k_offset, truncate, window=None):
+           bwd_impl, q_offset, k_offset, truncate, window=None, heads=None):
     out, _ = _flash_forward(q, k, v, k_shared, causal, scale, block_q,
                             block_k, interpret, q_offset, k_offset, truncate,
-                            window)
+                            window, heads)
     return out
 
 
@@ -740,16 +916,90 @@ DKV_KERNEL = "hvd_flash_dkv"
 BWD_KERNEL = "hvd_flash_bwd"
 
 
+# Two heads a program: the width at which two heads fill a 128-lane tile.
+PAIR_WIDTH = 64
+
+
+def _paired_operands(q, k, v, heads: int):
+    """q, k and v of a two-heads-a-program call as the arrays the kernels
+    read and the column block of 128 at which each one's first pair of heads
+    stands: three ``[B, L, H x 64]`` arrays, or (``k`` and ``v`` None) the
+    fused projection ``[B, L, 3 x H x 64]`` three times, q | k | v along its
+    columns."""
+    if k is None:
+        return (q, q, q), (0, heads // 2, heads)
+    return (q, k, v), (0, 0, 0)
+
+
+def _block_specs(block_q: int, block_k: int, seq_q: int, at_q, at_k,
+                 pairs: Optional[int] = None):
+    """The BlockSpec makers of one kernel call: ``rows(width, col)`` for a
+    block of query rows, ``keys(width, per, col)`` for a block of keys,
+    ``whole(width)`` for all of a program's query rows, ``stats()`` for a
+    block of per-row statistics. ``at_q`` / ``at_k`` give the q-block and the
+    k-block of a grid step (off the step tables or off the grid's axes).
+
+    One head a program (``pairs`` None): the operands are ``[B x H, L,
+    width]`` and program ``bh`` reads row ``bh`` (``bh // per`` where ``per``
+    query heads share a row of keys). Two heads a program: the operands are
+    ``[B, L, n x 128]`` as a projection writes them, and program ``p`` of the
+    ``pairs`` a batch row reads column block ``col + p % pairs`` of row ``p
+    // pairs``: no copy lies between the projection and the kernel. The
+    statistics are ``[B x H, L, 1]`` in both; a pair's program takes the two
+    rows ``2p`` and ``2p + 1`` of them."""
+    from jax.experimental import pallas as pl
+
+    if pairs is None:
+        def rows(width, col=0):
+            return pl.BlockSpec((None, block_q, width),
+                                lambda bh, *g: (bh, at_q(bh, *g), 0))
+
+        def keys(width, per=None, col=0):
+            if per is None:
+                return pl.BlockSpec((None, block_k, width),
+                                    lambda bh, *g: (bh, at_k(bh, *g), 0))
+            return pl.BlockSpec((None, block_k, width),
+                                lambda bh, *g: (bh // per, at_k(bh, *g), 0))
+
+        def whole(width):
+            return pl.BlockSpec((None, seq_q, width),
+                                lambda bh, *g: (bh, 0, 0))
+
+        return rows, keys, whole, lambda: rows(1)
+
+    def tile(block, at, col):
+        return pl.BlockSpec(
+            (None, block, 128),
+            lambda p, *g: (p // pairs, at(p, *g), col + p % pairs))
+
+    return (lambda width, col=0: tile(block_q, at_q, col),
+            lambda width, per=None, col=0: tile(block_k, at_k, col),
+            lambda width: pl.BlockSpec(
+                (None, seq_q, 128), lambda p, *g: (p // pairs, 0, p % pairs)),
+            lambda: pl.BlockSpec((2, block_q, 1),
+                                 lambda p, *g: (p, at_q(p, *g), 0)))
+
+
 def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
                    interpret, q_offset=0, k_offset=0, truncate=None,
-                   window=None):
-    """Returns (out [B, Lq, H, Dv], lse [B, H, Lq])."""
+                   window=None, heads=None):
+    """Returns (out, lse [B, H, Lq]). One head a program (``heads`` None): q
+    ``[B, Lq, H, D]``, out ``[B, Lq, H, Dv]``; the operands are transposed
+    to ``[B x H, L, D]`` around the call. Two heads a program (``heads`` the
+    number of heads, each :data:`PAIR_WIDTH` wide): q, k, v and out are the
+    projections' ``[B, L, H x 64]`` (or q the fused ``[B, L, 3 x H x 64]``,
+    k and v None) and the kernel reads and writes them as they stand."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, Lq, H, D = q.shape               # D: the queries' width, the keys'
-    Lk, G = k.shape[1], k.shape[2]
-    Dn, Dv = k.shape[-1], v.shape[-1]   # a head's own key columns; values
+    if heads is None:
+        B, Lq, H, D = q.shape           # D: the queries' width, the keys'
+        Lk, G = k.shape[1], k.shape[2]
+        Dn, Dv = k.shape[-1], v.shape[-1]   # a head's own key columns; values
+    else:
+        (q, k, v), cols = _paired_operands(q, k, v, heads)
+        B, Lq, Lk, H, G = q.shape[0], q.shape[1], k.shape[1], heads, heads
+        D = Dn = Dv = PAIR_WIDTH
     shared = k_shared is not None
     rep = _kv_group(H, G)      # program bh = b*H + h reads KV row bh // rep
     block_q = min(block_q, Lq)
@@ -758,39 +1008,58 @@ def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
     delta = q_offset - k_offset
     truncated = _grid_truncates(causal, Lq, Lk, q_offset, k_offset, truncate)
 
-    # Collapse (B, H) into the grid's first axis; put seq minor-most for
-    # contiguous VMEM tiles.
-    qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dn)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dv)
+    if heads is None:
+        # Collapse (B, H) into the grid's first axis; put seq minor-most for
+        # contiguous VMEM tiles.
+        qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
+        kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dn)
+        vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dv)
+        programs, per, cols = B * H, 1, (0, 0, 0)
+    else:
+        qr, kr, vr = q, k, v
+        programs, per = B * H // 2, 2   # a program: two heads of a batch row
     keys = (kr, k_shared) if shared else (kr,)  # k_shared is [B, Lk, Dr]
 
     n_qblocks = Lq // block_q
     n_kblocks = Lk // block_k
     out_shape = [
-        jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype),
+        jax.ShapeDtypeStruct((B * H, Lq, Dv) if heads is None
+                             else (B, Lq, H * Dv), q.dtype),
         jax.ShapeDtypeStruct((B * H, Lq, 1), jnp.float32),
     ]
+    stat = (block_q, 1) if heads is None else (per, block_q, 1)
     scratch = [
-        pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
-        pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
-        pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
+        pltpu.VMEM(stat, jnp.float32),                  # running max m
+        pltpu.VMEM(stat, jnp.float32),                  # running sum l
+        pltpu.VMEM((block_q, per * Dv), jnp.float32),   # output accumulator
     ]
+    kernel = functools.partial(
+        _flash_kernel, block_k=block_k, n_kblocks=n_kblocks, causal=causal,
+        scale=scale, block_q=block_q, delta=0 if truncated else delta,
+        packed=truncated, window=window, shared=shared, heads=per)
+    if truncated:
+        at_q = lambda bh, t, qi, kb: qi[t]                  # noqa: E731
+        at_k = lambda bh, t, qi, kb: kb[t]                  # noqa: E731
+    else:
+        at_q = lambda bh, qb, kb: qb                        # noqa: E731
+        at_k = lambda bh, qb, kb: kb                        # noqa: E731
+    rows, keys_of, _, stats = _block_specs(
+        block_q, block_k, Lq, at_q, at_k,
+        None if heads is None else H // 2)
+    in_specs = [rows(D, cols[0]), keys_of(Dn, rep, cols[1])] \
+        + [keys_of(D - Dn, H)] * shared + [keys_of(Dv, rep, cols[2])]
+    # The lse is a [block_q, 1] column per head: the statistics' native
+    # layout (see the kernel's Mosaic-discipline note); the trailing
+    # singleton is dropped OUTSIDE the kernel where a relayout is just an
+    # XLA reshape.
+    out_specs = [rows(Dv), stats()]
+    params = {} if heads is None else {
+        "vmem_limit_bytes": FLASH_SPLIT_VMEM + pair_vmem_bytes(
+            q.dtype.itemsize)}
     if truncated:
         qi_tab, kb_tab = _causal_step_tables(n_qblocks, n_kblocks,
                                              block_q, block_k,
                                              window=window)
-        kernel = functools.partial(_flash_kernel, block_k=block_k,
-                                   n_kblocks=n_kblocks, causal=causal,
-                                   scale=scale, block_q=block_q,
-                                   delta=0, packed=True, window=window,
-                                   shared=shared)
-        key_specs = [pl.BlockSpec((None, block_k, Dn),
-                                  lambda bh, t, qi, kb: (bh // rep, kb[t], 0))]
-        if shared:                  # program bh = b*H + h reads row b
-            key_specs.append(pl.BlockSpec(
-                (None, block_k, D - Dn),
-                lambda bh, t, qi, kb: (bh // H, kb[t], 0)))
         # The STEP axis enumerates only the live at-or-below-diagonal
         # (q-block, k-block) pairs — ~(n+1)/2n of the full causal grid.
         # Still sequential ("arbitrary") so the scratch-carried softmax
@@ -799,24 +1068,8 @@ def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
         # block indices come off the scalar-prefetched tables.
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B * H, int(qi_tab.size)),
-            in_specs=[
-                pl.BlockSpec((None, block_q, D),
-                             lambda bh, t, qi, kb: (bh, qi[t], 0)),
-                *key_specs,
-                pl.BlockSpec((None, block_k, Dv),
-                             lambda bh, t, qi, kb: (bh // rep, kb[t], 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, block_q, Dv),
-                             lambda bh, t, qi, kb: (bh, qi[t], 0)),
-                # [block_q, 1] column per program — the statistics'
-                # native layout (see the kernel's Mosaic-discipline
-                # note); the trailing singleton is dropped OUTSIDE the
-                # kernel where a relayout is just an XLA reshape.
-                pl.BlockSpec((None, block_q, 1),
-                             lambda bh, t, qi, kb: (bh, qi[t], 0)),
-            ],
+            grid=(programs, int(qi_tab.size)),
+            in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch,
         )
         out, lse = pl.pallas_call(
@@ -824,56 +1077,36 @@ def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary"), **params),
             interpret=interpret, name=FWD_KERNEL,
         )(jnp.asarray(qi_tab), jnp.asarray(kb_tab), qr, *keys, vr)
     else:
-        kernel = functools.partial(_flash_kernel, block_k=block_k,
-                                   n_kblocks=n_kblocks, causal=causal,
-                                   scale=scale, block_q=block_q,
-                                   delta=delta, packed=False, window=window,
-                                   shared=shared)
-        key_specs = [pl.BlockSpec((None, block_k, Dn),
-                                  lambda bh, qb, kb: (bh // rep, kb, 0))]
-        if shared:
-            key_specs.append(pl.BlockSpec(
-                (None, block_k, D - Dn), lambda bh, qb, kb: (bh // H, kb, 0)))
         out, lse = pl.pallas_call(
             kernel,
             # K blocks ride the grid's INNERMOST axis: sequential
             # ("arbitrary") so the scratch-carried softmax state is
             # legal, while Mosaic double-buffers the [block_k, D] K/V
             # tile DMAs.
-            grid=(B * H, n_qblocks, n_kblocks),
-            in_specs=[
-                pl.BlockSpec((None, block_q, D),
-                             lambda bh, qb, kb: (bh, qb, 0)),
-                *key_specs,
-                pl.BlockSpec((None, block_k, Dv),
-                             lambda bh, qb, kb: (bh // rep, kb, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, block_q, Dv),
-                             lambda bh, qb, kb: (bh, qb, 0)),
-                pl.BlockSpec((None, block_q, 1),
-                             lambda bh, qb, kb: (bh, qb, 0)),
-            ],
+            grid=(programs, n_qblocks, n_kblocks),
+            in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                **params),
             interpret=interpret, name=FWD_KERNEL,
         )(qr, *keys, vr)
-    return (out.reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3),
-            lse.reshape(B, H, Lq))
+    if heads is None:
+        out = out.reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)
+    return out, lse.reshape(B, H, Lq)
 
 
 def _flash_fwd_vjp(q, k, v, k_shared, causal, scale, block_q, block_k,
                    interpret, bwd_impl, q_offset, k_offset, truncate,
-                   window=None):
+                   window=None, heads=None):
     o, lse = _flash_forward(q, k, v, k_shared, causal, scale, block_q,
                             block_k, interpret, q_offset, k_offset, truncate,
-                            window)
+                            window, heads)
     return o, (q, k, v, k_shared, o, lse)
 
 
@@ -952,7 +1185,7 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                           block_k: int, n_qblocks: int, delta: int,
                           packed: bool, window: Optional[int] = None,
                           shared: bool = False, fused: bool = False,
-                          n_kblocks: int = 0):
+                          n_kblocks: int = 0, heads: int = 1):
     """dK/dV: full grid (batch*head, k-block, Q-BLOCK stream) or the
     packed K-MAJOR causal grid — transposing the dQ kernel's roles, so
     the truncated region is the symmetric above-diagonal half over the
@@ -970,7 +1203,17 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
     the order the dQ kernel sums them; they are zeroed at the q-block's
     first k-block and cast into the ``[Lq, Dk]`` output block (whose index
     is constant over the program, so it leaves VMEM once) at its last: the
-    dQ kernel's two conditions."""
+    dQ kernel's two conditions.
+
+    ``heads`` (with ``fused``) is :func:`_flash_kernel`'s: at 2 every tile
+    holds two heads of 64 side by side and the five products run once a
+    head, each with one operand masked to the head's lanes (``q_h k^T``,
+    ``dO_h v^T``, ``p^T dO_h``, ``ds^T q_h``, ``ds k_h``), so what a head
+    adds to a 128-lane sum is zero outside its own columns. The last input
+    is then no column of ``D = rowsum(dO . O)`` but the rows of ``O`` where
+    the forward wrote them, and the kernel sums ``dO . O`` over a head's
+    lanes itself: outside a kernel a sum over 64 of a row's columns is a
+    relayout of the float32 products."""
     from jax.experimental import pallas as pl
 
     if packed:
@@ -1023,38 +1266,53 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
         k_blk = k_ref[...]
         v_blk = v_ref[...]
         do_blk = do_ref[...]
-        s = _block_scores(q, k_blk, ks_ref, scale)
-        if causal:
-            q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(_band_mask(q_pos, k_pos, window), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[...])                    # [bq, bk]
-        dv_scr[...] += jnp.dot(p.T.astype(do_blk.dtype), do_blk,
-                               preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - d_ref[...])
-        if ks_ref is None:
-            dk_scr[...] += jnp.dot(ds.T.astype(q.dtype), q,
-                                   preferred_element_type=jnp.float32) * scale
-        else:
-            own, ds_t = k_blk.shape[-1], ds.T.astype(q.dtype)
-            dk_scr[...] += jnp.dot(
-                ds_t, q[:, :own], preferred_element_type=jnp.float32) * scale
-            dks_scr[...] += jnp.dot(
-                ds_t, q[:, own:], preferred_element_type=jnp.float32) * scale
-        if not fused:
-            return
-        ds = ds.astype(k_blk.dtype)     # the dQ kernel's products
-        if ks_ref is None:
-            dq_scr[rows, :] += jnp.dot(
-                ds, k_blk, preferred_element_type=jnp.float32) * scale
-        else:
-            dq_scr[rows, :own] += jnp.dot(
-                ds, k_blk, preferred_element_type=jnp.float32) * scale
-            dq_scr[rows, own:] += jnp.dot(
-                ds, ks_ref[...], preferred_element_type=jnp.float32) * scale
+        seen = None
+        if heads > 1:       # dO . O, a head's lanes of which sum to its D
+            do_o = do_blk.astype(jnp.float32) * d_ref[...].astype(jnp.float32)
+        for h in range(heads):
+            at = (h,) if heads > 1 else (Ellipsis,)     # head h's statistics
+            q_h, do_h = _own_lanes(q, heads, h), _own_lanes(do_blk, heads, h)
+            s = _block_scores(q_h, k_blk, ks_ref, scale)
+            if causal:
+                if seen is None:            # one mask for the tile's heads
+                    q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 0)
+                    k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 1)
+                    seen = _band_mask(q_pos, k_pos, window)
+                s = jnp.where(seen, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[at])                     # [bq, bk]
+            dv_scr[...] += jnp.dot(p.T.astype(do_blk.dtype), do_h,
+                                   preferred_element_type=jnp.float32)
+            dp = jnp.dot(do_h, v_blk.T, preferred_element_type=jnp.float32)
+            d = d_ref[...] if heads == 1 else jnp.sum(
+                _own_lanes(do_o, heads, h), axis=-1, keepdims=True)
+            ds = p * (dp - d)
+            if ks_ref is None:
+                dk_scr[...] += jnp.dot(
+                    ds.T.astype(q.dtype), q_h,
+                    preferred_element_type=jnp.float32) * scale
+            else:
+                own, ds_t = k_blk.shape[-1], ds.T.astype(q.dtype)
+                dk_scr[...] += jnp.dot(
+                    ds_t, q[:, :own],
+                    preferred_element_type=jnp.float32) * scale
+                dks_scr[...] += jnp.dot(
+                    ds_t, q[:, own:],
+                    preferred_element_type=jnp.float32) * scale
+            if not fused:
+                return
+            ds = ds.astype(k_blk.dtype)     # the dQ kernel's products
+            if ks_ref is None:
+                dq_scr[rows, :] += jnp.dot(
+                    ds, _own_lanes(k_blk, heads, h),
+                    preferred_element_type=jnp.float32) * scale
+            else:
+                dq_scr[rows, :own] += jnp.dot(
+                    ds, k_blk, preferred_element_type=jnp.float32) * scale
+                dq_scr[rows, own:] += jnp.dot(
+                    ds, ks_ref[...],
+                    preferred_element_type=jnp.float32) * scale
 
     if causal and not packed:
         # Q-blocks fully ABOVE the diagonal (every q_pos < every k_pos),
@@ -1157,7 +1415,7 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
 
 def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
                       q_offset, k_offset, truncate, window, res, do,
-                      fused=False):
+                      fused=False, heads=None):
     """Flash backward as one Pallas kernel (``fused``: dQ, dK and dV from
     one walk over the k-major grid, dQ summed in a float32 ``[Lq, Dk]``
     scratch that stays in VMEM a (batch, head) program; see
@@ -1177,14 +1435,27 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     diagonal skip their compute only. With grouped K and V (``G`` KV heads
     under ``H`` query heads) both kernels read the group's K/V block by
     index map; dK/dV come out a query head, in float32, and the group's
-    are added up outside the kernel."""
+    are added up outside the kernel.
+
+    ``heads`` (the one kernel only) is :func:`_flash_forward`'s: q, k, v, o
+    and dO arrive ``[B, L, H x 64]`` (or q the fused ``[B, L, 3 x H x 64]``)
+    and are read as they stand, two heads a program; dQ, dK and dV are
+    written in that layout, and for the fused projection laid side by side
+    into its one gradient."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, k_shared, o, lse = res
-    B, Lq, H, D = q.shape
-    Lk, G = k.shape[1], k.shape[2]
-    Dn, Dv = k.shape[-1], v.shape[-1]
+    whole_projection = k is None
+    if heads is None:
+        B, Lq, H, D = q.shape
+        Lk, G = k.shape[1], k.shape[2]
+        Dn, Dv = k.shape[-1], v.shape[-1]
+    else:
+        assert fused, "two heads a program: the one-kernel backward only"
+        (q, k, v), cols = _paired_operands(q, k, v, heads)
+        B, Lq, Lk, H, G = q.shape[0], q.shape[1], k.shape[1], heads, heads
+        D = Dn = Dv = PAIR_WIDTH
     shared = k_shared is not None
     rep = _kv_group(H, G)
     bq = min(block_q, Lq)
@@ -1194,17 +1465,27 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
     delta = q_offset - k_offset
     truncated = _grid_truncates(causal, Lq, Lk, q_offset, k_offset, truncate)
 
-    qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dn)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dv)
-    keys = (kr, k_shared) if shared else (kr,)
-    dor = do.transpose(0, 2, 1, 3).reshape(B * H, Lq, Dv)
     # lse arrives [B, H, Lq]; D_i rowsum in fp32. Both as [bh, Lq, 1]
     # columns — the statistics' native kernel layout.
-    lser = lse.reshape(B * H, Lq, 1)
-    d_row = jnp.sum(dor.astype(jnp.float32)
-                    * o.transpose(0, 2, 1, 3).reshape(B * H, Lq, Dv)
-                    .astype(jnp.float32), axis=-1, keepdims=True)
+    if heads is None:
+        qr = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
+        kr = k.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dn)
+        vr = v.transpose(0, 2, 1, 3).reshape(B * G, Lk, Dv)
+        dor = do.transpose(0, 2, 1, 3).reshape(B * H, Lq, Dv)
+        lser = lse.reshape(B * H, Lq, 1)
+        d_row = jnp.sum(dor.astype(jnp.float32)
+                        * o.transpose(0, 2, 1, 3).reshape(B * H, Lq, Dv)
+                        .astype(jnp.float32), axis=-1, keepdims=True)
+        programs, per, cols = B * H, 1, (0, 0, 0)
+        gradient = (B * H, Lq, D), (B * H, Lk, Dn), (B * H, Lk, Dv)
+    else:
+        # the kernel sums dO . O a head itself, from o where it lies: a sum
+        # over 64 of a row's 1,024 columns is a relayout outside a kernel
+        qr, kr, vr, dor, d_row = q, k, v, do, o
+        lser = lse.reshape(B * H, Lq, 1)
+        programs, per = B * H // 2, 2
+        gradient = (B, Lq, H * D), (B, Lk, H * Dn), (B, Lk, H * Dv)
+    keys = (kr, k_shared) if shared else (kr,)
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, causal=causal, scale=scale, block_q=bq,
@@ -1214,17 +1495,17 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
         _flash_bwd_dkv_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_qblocks=nqb, delta=0 if truncated else delta,
         packed=truncated, window=window, shared=shared, fused=fused,
-        n_kblocks=nkb)
-    dq_out_shape = jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype)
+        n_kblocks=nkb, heads=per)
+    dq_out_shape = jax.ShapeDtypeStruct(gradient[0], q.dtype)
     dkv_dtype = jnp.float32 if rep > 1 else k.dtype    # a group's are summed
     # dK, the shared key's gradient a query head (float32: summed over the
     # heads below), dV
-    dkv_out_shape = [jax.ShapeDtypeStruct((B * H, Lk, Dn), dkv_dtype)] \
+    dkv_out_shape = [jax.ShapeDtypeStruct(gradient[1], dkv_dtype)] \
         + [jax.ShapeDtypeStruct((B * H, Lk, D - Dn), jnp.float32)] * shared \
-        + [jax.ShapeDtypeStruct((B * H, Lk, Dv), dkv_dtype)]
-    dkv_scratch = [pltpu.VMEM((bk, Dn), jnp.float32)] \
+        + [jax.ShapeDtypeStruct(gradient[2], dkv_dtype)]
+    dkv_scratch = [pltpu.VMEM((bk, per * Dn), jnp.float32)] \
         + [pltpu.VMEM((bk, D - Dn), jnp.float32)] * shared \
-        + [pltpu.VMEM((bk, Dv), jnp.float32)]
+        + [pltpu.VMEM((bk, per * Dv), jnp.float32)]
 
     def call(kernel, name, k_major, out_widths, out_shape, scratch,
              whole_q=()):
@@ -1249,38 +1530,30 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
             at_q = lambda bh, i, j: i                       # noqa: E731
             at_k = lambda bh, i, j: j                       # noqa: E731
 
-        def rows(width):            # a query head's block of q rows
-            return pl.BlockSpec((None, bq, width),
-                                lambda bh, *g: (bh, at_q(bh, *g), 0))
-
-        def keys_of(width, per=None):   # a block of keys: the query head's
-            if per is None:             # own row, or row ``bh // per``
-                return pl.BlockSpec((None, bk, width),
-                                    lambda bh, *g: (bh, at_k(bh, *g), 0))
-            return pl.BlockSpec((None, bk, width),
-                                lambda bh, *g: (bh // per, at_k(bh, *g), 0))
-
-        ins = [rows(D), keys_of(Dn, rep)] \
+        rows, keys_of, whole, stats = _block_specs(
+            bq, bk, Lq, at_q, at_k, None if heads is None else H // 2)
+        ins = [rows(D, cols[0]), keys_of(Dn, rep, cols[1])] \
             + [keys_of(D - Dn, H)] * shared \
-            + [keys_of(Dv, rep), rows(Dv), rows(1), rows(1)]
-        out_specs = [pl.BlockSpec((None, Lq, w), lambda bh, *g: (bh, 0, 0))
-                     for w in whole_q] \
+            + [keys_of(Dv, rep, cols[2]), rows(Dv), stats(),
+               stats() if heads is None else rows(Dv)]
+        out_specs = [whole(w) for w in whole_q] \
             + [(keys_of if k_major else rows)(w) for w in out_widths]
         params = {}
         if whole_q:
             params["vmem_limit_bytes"] = fused_bwd_vmem_bytes(
-                Lq, D, q.dtype.itemsize)
+                Lq, D, q.dtype.itemsize) + (
+                    0 if heads is None else pair_vmem_bytes(q.dtype.itemsize))
         if truncated:
             tables = _causal_step_tables(nqb, nkb, bq, bk, k_major=k_major,
                                          window=window)
             how = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=(B * H, int(tables[0].size)),
+                num_scalar_prefetch=2, grid=(programs, int(tables[0].size)),
                 in_specs=ins, out_specs=out_specs, scratch_shapes=scratch))
             semantics = ("parallel", "arbitrary")
         else:
             tables = ()
-            how = dict(grid=(B * H, nkb, nqb) if k_major
-                       else (B * H, nqb, nkb),
+            how = dict(grid=(programs, nkb, nqb) if k_major
+                       else (programs, nqb, nkb),
                        in_specs=ins, out_specs=out_specs,
                        scratch_shapes=scratch)
             semantics = ("parallel", "arbitrary" if whole_q else "parallel",
@@ -1295,7 +1568,12 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
         dq, dk, *dks, dv = call(
             dkv_kernel, BWD_KERNEL, True, [Dn] + [D - Dn] * shared + [Dv],
             [dq_out_shape] + dkv_out_shape,
-            [pltpu.VMEM((Lq, D), jnp.float32)] + dkv_scratch, whole_q=[D])
+            [pltpu.VMEM((Lq, per * D), jnp.float32)] + dkv_scratch,
+            whole_q=[D])
+        if heads is not None:
+            if whole_projection:    # the fused projection's one gradient
+                return jnp.concatenate([dq, dk, dv], -1), None, None, None
+            return dq, dk, dv, None
     else:
         dq, = call(dq_kernel, DQ_KERNEL, False, [D], [dq_out_shape],
                    [pltpu.VMEM((bq, D), jnp.float32)])
@@ -1318,12 +1596,14 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
 
 
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
-                   q_offset, k_offset, truncate, window, res, do):
+                   q_offset, k_offset, truncate, window, heads, res, do):
     """``bwd_impl`` arrives resolved ("scan" | "pallas" | "fused") from
     flash_attention: part of the trace key, so the selection can never
-    desync from a cached trace."""
+    desync from a cached trace. ``heads`` (two heads a program) comes with
+    ``"fused"`` alone."""
     fn = {"scan": _flash_bwd_scan, "pallas": _flash_bwd_pallas,
-          "fused": functools.partial(_flash_bwd_pallas, fused=True)}[bwd_impl]
+          "fused": functools.partial(_flash_bwd_pallas, fused=True,
+                                     heads=heads)}[bwd_impl]
     return fn(causal, scale, block_q, block_k, interpret,
               q_offset, k_offset, truncate, window, res, do)
 

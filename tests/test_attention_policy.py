@@ -28,30 +28,33 @@ from horovod_tpu.ops.attention import (
 )
 
 BF16, F32 = jnp.bfloat16, jnp.float32
-# (Lq, Lk, heads, KV heads, head width, window, dtype, backend) -> plan
+# (Lq, Lk, heads, KV heads, head width, window, dtype, backend) -> plan; the
+# last field, the heads a kernel program serves, is 1 where none is written
 TABLE = {
     "gpt2_medium_cell": ((1024, 1024, 16, 16, 64, None, BF16, "tpu"),
-                         ("flash", 1024, 1024, "fused")),
+                         ("flash", 1024, 1024, "fused", 2)),
     "trinity_sliding_layer": ((4096, 4096, 32, 4, 128, 2048, BF16, "tpu"),
                               ("flash", 1024, 1024, "fused")),
     "trinity_full_layer": ((4096, 4096, 32, 4, 128, None, BF16, "tpu"),
                            ("flash", 1024, 1024, "fused")),
     "heads_of_64_at_2048": ((2048, 2048, 16, 16, 64, None, BF16, "tpu"),
-                            ("flash", 1024, 1024, "fused")),
+                            ("flash", 1024, 1024, "fused", 2)),
     "float32_inputs": ((4096, 4096, 8, 8, 64, None, F32, "tpu"),
-                       ("flash", 1024, 1024, "fused")),
+                       ("flash", 1024, 1024, "fused", 2)),
     "blocks_of_512_divide": ((1536, 1536, 16, 16, 64, None, BF16, "tpu"),
-                             ("flash", 512, 512, "fused")),
+                             ("flash", 512, 512, "fused", 2)),
+    # the layout is the shapes' alone, as the backward is: a dense answer
+    # names it too
     "only_256_divides": ((1280, 1280, 16, 16, 64, None, BF16, "tpu"),
-                         ("dense", 256, 256, "fused")),
+                         ("dense", 256, 256, "fused", 2)),
     "rectangular": ((512, 768, 4, 4, 64, None, BF16, "tpu"),
                     ("dense", 512, 256, "fused")),
     "below_the_measured_lengths": ((512, 512, 16, 16, 64, None, BF16, "tpu"),
-                                   ("dense", 512, 512, "fused")),
+                                   ("dense", 512, 512, "fused", 2)),
     "no_block_divides": ((100, 100, 4, 4, 64, None, BF16, "tpu"),
-                         ("dense", None, None, "fused")),
+                         ("dense", None, None, "fused", 2)),
     "cpu_backend": ((1024, 1024, 16, 16, 64, None, BF16, "cpu"),
-                    ("dense", 1024, 1024, "fused")),
+                    ("dense", 1024, 1024, "fused", 2)),
     "this_platform": ((4096, 4096, 32, 4, 128, 2048, BF16, None),
                       ("dense", 1024, 1024, "fused")),
     # the one-kernel backward's resident dQ: 65,536 queries of 128 are the
@@ -86,7 +89,7 @@ def test_the_plan_answers_the_backward_from_the_shapes():
     for length, heads, kv_heads, width, window in cells:
         assert attention.attention_plan(
             length, length, heads, kv_heads, width, window,
-            backend="tpu") == ("flash", 1024, 1024, "fused")
+            backend="tpu")[:4] == ("flash", 1024, 1024, "fused")
     mib = 1 << 20
     assert attention.fused_bwd_vmem_bytes(1024, 64, 2) == 17 * mib
     assert attention.fused_bwd_vmem_bytes(4096, 128, 2) == 20 * mib
@@ -122,6 +125,77 @@ def test_the_plan_answers_the_backward_from_the_shapes():
     assert gauges["hvd.attn.fused_bwd_calls"][program] == 0
     with pytest.raises(ValueError, match="auto|scan|pallas|fused"):
         flash_attention(q, q, q, causal=True, bwd_impl="split")
+
+
+PAIRS = {   # (Lq, Lk, heads, KV heads, width), keywords -> heads a program
+    "heads_of_64": ((1024, 1024, 16, 16, 64), {}, 2),
+    "two_heads_of_64": ((1024, 1024, 2, 2, 64), {}, 2),
+    "under_a_window": ((4096, 4096, 8, 8, 64), {"window": 2048}, 2),
+    "keys_and_values_of_64_said_apart": ((1024, 1024, 16, 16, (64, 64)), {},
+                                         2),
+    "heads_of_128": ((4096, 4096, 16, 16, 128), {}, 1),
+    "heads_of_32": ((1024, 1024, 16, 16, 32), {}, 1),
+    "an_odd_number_of_heads": ((1024, 1024, 15, 15, 64), {}, 1),
+    "grouped_kv_heads": ((1024, 1024, 16, 4, 64), {}, 1),
+    "keys_wider_than_values": ((8192, 8192, 16, 16, (192, 128)), {}, 1),
+    "values_of_64_under_wider_keys": ((1024, 1024, 16, 16, (128, 64)), {},
+                                      1),
+    "a_shared_key": ((1024, 1024, 16, 16, 64), {"shared_key": True}, 1),
+    "an_offset": ((1024, 1024, 16, 16, 64), {"q_offset": 1024}, 1),
+    "rectangular": ((1024, 2048, 16, 16, 64), {}, 1),
+    # past the one-kernel backward's budget the split runs, one head a program
+    "the_split_backward": ((131072, 131072, 2, 2, 64), {}, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAIRS))
+def test_the_plan_pairs_heads_of_64_and_nothing_else(case):
+    """Two heads a program exactly where two heads fill a 128-lane tile and
+    the kernels can read it where a projection wrote it: keys and values
+    both 64 wide, an even number of heads, a KV head a query head, no shared
+    key, the causal square call with no offset, the one-kernel backward.
+    The answer is the shapes' alone: the same on every backend."""
+    shape, keywords, want = PAIRS[case]
+    for backend in ("tpu", "cpu"):
+        assert attention_plan(*shape, backend=backend,
+                              **keywords).heads_per_program == want
+
+
+@pytest.mark.parametrize("fused_projection", [False, True])
+def test_attend_counts_the_calls_whose_programs_serve_two_heads(
+        fused_projection):
+    """``hvd.attn.paired_calls`` reads the kernels' calls of the traced
+    program that run two heads a program: heads of 64 do, from separate q, k
+    and v as from a fused projection; a pinned split backward, a dense call
+    and heads of another width do not."""
+    from horovod_tpu.utils import timeline
+
+    def call(width, heads=2, **kw):
+        q = jnp.ones((1, 32, heads, width))
+        if fused_projection:
+            return attend(jnp.ones((1, 32, 3 * heads * width)), heads=heads,
+                          **kw).shape == (1, 32, heads * width)
+        return attend(q, q, q, **kw).shape == q.shape
+
+    blocks = dict(block_q=8, block_k=8)
+    timeline.reset()
+    with timeline.span("hvd.spmd.dispatch", handle="step_fn",
+                       program="step_fn#0", call=0):
+        assert call(64, impl="flash", **blocks)
+        assert call(64, 4, impl="flash", **blocks)
+        assert call(64, impl="flash", bwd_impl="pallas", **blocks)
+        assert call(64, impl="dense")
+        assert call(64)                     # the plan's: dense on the CPU
+        assert call(16, impl="flash", **blocks)
+    gauges = timeline.snapshot()["gauges"]
+    got = {name.rsplit(".", 1)[1]: by_program["step_fn#0"]
+           for name, by_program in gauges.items()
+           if name.startswith("hvd.attn.") and name.endswith("_calls")}
+    assert got == {"flash_calls": 4, "dense_calls": 2, "fused_bwd_calls": 3,
+                   "paired_calls": 2}
+    with pytest.raises(ValueError, match="fused projection"):
+        attend(jnp.ones((1, 32, 3 * 2 * 64)))           # heads not said
+    timeline.reset()
 
 
 def test_the_plan_refuses_heads_no_group_divides():
@@ -213,30 +287,46 @@ def test_attend_runs_what_the_plan_or_the_caller_says(impl):
 def test_on_a_tpu_attend_traces_the_kernels_but_not_under_an_offset(
         monkeypatch):
     """What the chip gets at GPT-2-medium's shape (traced only: nothing is
-    compiled or run): one forward kernel over a (batch x heads, 1) grid; an
-    offset mask is outside what the policy was measured on and stays
-    dense."""
+    compiled or run): one forward kernel over a (batch x heads / 2, 1) grid,
+    two heads of 64 a program, from separate q, k and v as from the block's
+    fused projection, and over (batch x heads, 1) at heads of 128; an offset
+    mask is outside what the policy was measured on and stays dense."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     q = jax.ShapeDtypeStruct((2, 1024, 16, 64), BF16)
-    assert _kernel_grids(attend, q, q, q) == [(32, 1)]
+    assert _kernel_grids(attend, q, q, q) == [(16, 1)]
+    assert _kernel_grids(lambda qkv: attend(qkv, heads=16),
+                         jax.ShapeDtypeStruct((2, 1024, 3 * 1024), BF16)) \
+        == [(16, 1)]
+    wide = jax.ShapeDtypeStruct((2, 1024, 16, 128), BF16)
+    assert _kernel_grids(attend, wide, wide, wide) == [(32, 1)]
     assert not _kernel_grids(lambda *a: attend(*a, q_offset=1024), q, q, q)
     assert FLASH_BWD == "pallas"
 
 
 FUSED_CALLS = "flash_fused_bwd_calls_per_step.tok"
+PAIRED_CALLS = "flash_paired_calls_per_step.tok"
+LANGUAGE_CELLS = ("gpt2m_seq1024_1chip", "gpt2m_seq1024_dp4",
+                  "trinity_mini_seq4096_1chip", "ouro_seq4096_1chip",
+                  "moonlight_seq8192_1chip")
+# metric -> (the gauge it reads, the cells that list it)
+CALL_COUNTERS = {
+    FUSED_CALLS: ("hvd.attn.fused_bwd_calls", LANGUAGE_CELLS),
+    PAIRED_CALLS: ("hvd.attn.paired_calls", LANGUAGE_CELLS[:2]),
+}
 
 
-@pytest.mark.parametrize("cell, listed", [
-    ("gpt2m_seq1024_1chip", True), ("gpt2m_seq1024_dp4", True),
-    ("trinity_mini_seq4096_1chip", True), ("ouro_seq4096_1chip", True),
-    ("moonlight_seq8192_1chip", True), ("resnet50_bs128_1chip", False)])
-def test_the_fused_backward_calls_are_a_metric_of_the_language_cells(
-        cell, listed):
+@pytest.mark.parametrize("cell", LANGUAGE_CELLS + ("resnet50_bs128_1chip",))
+@pytest.mark.parametrize("metric", sorted(CALL_COUNTERS))
+def test_the_kernels_call_counters_are_metrics_of_their_cells(metric, cell):
     """``flash_fused_bwd_calls_per_step.tok`` (``BENCHMARK.json``; the reader
     ``benchmarks/metrics/``) is listed by the five cells that train through
     the kernels and reads the gauge ``hvd.attn.fused_bwd_calls`` of the step
     handle's program: nothing on a program that sets no such gauge (the
-    parent of PR 35) or whose calls all take the split."""
+    parent of PR 35) or whose calls all take the split.
+    ``flash_paired_calls_per_step.tok``, the manifest's last ``per_layer``
+    entry, is listed by the two cells whose heads are 64 wide and reads
+    ``hvd.attn.paired_calls`` the same way: nothing at the parent of PR 37
+    or where every call runs one head a program."""
     import json
     import os
     import sys
@@ -247,18 +337,20 @@ def test_the_fused_backward_calls_are_a_metric_of_the_language_cells(
     from benchmarks import run
     from horovod_tpu.utils import timeline
 
+    gauge, cells = CALL_COUNTERS[metric]
     with open(os.path.join(repo, "BENCHMARK.json")) as f:
         manifest = json.load(f)
+    assert manifest["per_layer"][-1]["name"] == PAIRED_CALLS
     reported = {m["name"]: m for m in run.metrics_of(manifest, cell,
                                                      "per_layer")}
-    assert (FUSED_CALLS in reported) == listed
-    if not listed:
+    assert (metric in reported) == (cell in cells)
+    if cell not in cells:
         return
-    entry = reported[FUSED_CALLS]
+    entry = reported[metric]
     assert (entry["unit"], entry["better"], entry["source"], entry["layer"],
             entry["moves"]) == ("calls", "higher", "program_counter",
                                 "training_kernels", "tok_per_s_per_chip")
-    read = run.load_reader(FUSED_CALLS)
+    read = run.load_reader(metric)
     timeline.reset()
     for call in range(3):
         with timeline.span("hvd.spmd.dispatch", handle="step_fn",
@@ -266,9 +358,9 @@ def test_the_fused_backward_calls_are_a_metric_of_the_language_cells(
             pass
     timeline.gauge("hvd.attn.flash_calls", 24, key="step_fn#0")
     assert read({}) is None             # the parent: no such gauge
-    timeline.gauge("hvd.attn.fused_bwd_calls", 0, key="step_fn#0")
-    assert read({}) is None             # every call took the split
-    timeline.gauge("hvd.attn.fused_bwd_calls", 24, key="step_fn#0")
-    timeline.gauge("hvd.attn.fused_bwd_calls", 3, key="other#1")
+    timeline.gauge(gauge, 0, key="step_fn#0")
+    assert read({}) is None             # no call of the kind
+    timeline.gauge(gauge, 24, key="step_fn#0")
+    timeline.gauge(gauge, 3, key="other#1")
     assert read({}) == 24
     timeline.reset()

@@ -257,10 +257,11 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
 
 def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
     """GPT-2-medium's attention layer as ``attention_plan`` runs it on the
-    chip (q/k/v ``[8, 1024, 16, 64]`` bf16, blocks of 1,024, the one-kernel
-    backward): the two kernels through Mosaic on one chip, and inside a
-    ``shard_map`` over the host's four chips, 8 sequences each, as the
-    data-parallel cell runs them."""
+    chip (the block's fused projection ``[8, 1024, 3 x 16 x 64]`` bf16, two
+    heads a program, blocks of 1,024, the one-kernel backward): the two
+    kernels through Mosaic on one chip, and inside a ``shard_map`` over the
+    host's four chips, 8 sequences each, as the data-parallel cell runs
+    them; in float32 too, whose tiles pass the one-head programs' VMEM."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -270,25 +271,68 @@ def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
     from horovod_tpu.ops.attention import attention_plan, flash_attention
 
     plan = attention_plan(1024, 1024, 16, 16, 64, backend="tpu")
-    assert plan == ("flash", 1024, 1024, "fused")
+    assert plan == ("flash", 1024, 1024, "fused", 2)
 
-    def grads(q, k, v):
-        return jax.grad(lambda *a: flash_attention(
-            *a, causal=True, interpret=False).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
+    def grads(qkv):
+        return jax.grad(lambda x: flash_attention(
+            x, causal=True, heads=16,
+            interpret=False).astype(jnp.float32).sum())(qkv)
 
     mesh = Mesh(np.array(topo.devices), ("hvd",))
     spread = jax.shard_map(grads, mesh=mesh, in_specs=P("hvd"),
                            out_specs=P("hvd"),
                            check_vma=False)         # as hvd.spmd_fn's
-    for fn, batch, sharding in (
-            (grads, 8, SingleDeviceSharding(topo.devices[0])),
-            (spread, 32, NamedSharding(mesh, P("hvd")))):
-        qkv = jax.ShapeDtypeStruct((batch, 1024, 16, 64), jnp.bfloat16,
+    for fn, batch, sharding, dtype in (
+            (grads, 8, SingleDeviceSharding(topo.devices[0]), jnp.bfloat16),
+            (grads, 8, SingleDeviceSharding(topo.devices[0]), jnp.float32),
+            (spread, 32, NamedSharding(mesh, P("hvd")), jnp.bfloat16)):
+        qkv = jax.ShapeDtypeStruct((batch, 1024, 3 * 16 * 64), dtype,
                                    sharding=sharding)
-        text = jax.jit(fn).lower(qkv, qkv, qkv).compile().as_text()
+        text = jax.jit(fn).lower(qkv).compile().as_text()
         for kernel in FLASH_KERNELS:
             assert f"%{kernel}" in text, (kernel, batch)
+
+
+def test_no_copy_lies_between_a_gpt2_projection_and_the_kernel(topo):
+    """A GPT-2-medium block's forward pass compiled for the v5e: the forward
+    kernel's q, k and v are one and the same array, the fusion that holds
+    the ``qkv`` projection's product, and what it writes goes into the
+    output projection's fusion: under the attention's scope the compiled
+    program holds the kernel alone, no copy, transpose or slice."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models.transformer import TransformerBlock
+    from horovod_tpu.ops.attention import attend
+    from horovod_tpu.utils import timeline
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    block = TransformerBlock(16, attn_fn=functools.partial(
+        attend, impl="flash", interpret=False))
+    x = jax.ShapeDtypeStruct((8, 1024, 1024), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: block.init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype))))
+    text = jax.jit(block.apply).lower(params, x).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    call = re.search(r"%hvd_flash_fwd[.\d]* = .*? custom-call\(([^)]*)\)",
+                     entry)
+    operands = [name.strip() for name in call.group(1).split(",")]
+    assert len(operands) == 5 and len(set(operands[2:])) == 1, operands
+    producer = re.search(
+        rf"{re.escape(operands[2])} = .*? (\S+)\(.*op_name=\"([^\"]*)\"",
+        entry)
+    assert producer.group(1) == "fusion" and "Dense_0" in producer.group(2)
+    scoped = [line for line in entry.splitlines()
+              if f"/{timeline.ATTN_FULL}/" in line]
+    assert scoped and all(        # the step tables are its two constants
+        re.search(r" (custom-call|get-tuple-element|constant)\(", line)
+        for line in scoped), scoped
 
 
 TRINITY = dict(

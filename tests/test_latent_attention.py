@@ -262,8 +262,9 @@ def test_the_plan_takes_the_two_widths():
     at 192 beside 128 chose the blocks it had chosen at 64 and 128, so the
     answer is the lengths' alone."""
     assert attention_plan(4096, 4096, 32, 4, 128, 2048, backend="tpu") \
-        == ("flash", 1024, 1024, "fused")
-    assert attention_plan(8192, 8192, 16, 16, (192, 128), backend="tpu") \
-        == ("flash", 1024, 1024, "fused")
+        == ("flash", 1024, 1024, "fused", 1)
+    assert attention_plan(8192, 8192, 16, 16, (192, 128), backend="tpu",
+                          shared_key=True) \
+        == ("flash", 1024, 1024, "fused", 1)    # one head a program
     assert attention_plan(8192, 8192, 16, 16, (192, 128),
                           backend="cpu").impl == "dense"

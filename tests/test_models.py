@@ -277,6 +277,54 @@ def test_transformer_lm_trains_with_flash_attention(rng):
                                    rtol=5e-4, atol=5e-5)
 
 
+@pytest.mark.parametrize("width, remat", [(64, False), (64, True),
+                                          (8, False)])
+def test_transformer_lm_hands_attend_its_fused_projection(rng, width, remat):
+    """``TransformerBlock`` hands ``attend`` (a partial of it here, as
+    ``bench.py`` builds one) the fused ``qkv`` projection whole: at heads of
+    64 the kernels read q, k and v out of it two heads a program and write
+    what the output projection reads, at another width ``attend`` splits it
+    for one-head programs. Loss and gradients equal those of the same model
+    around a plain callable, which gets q, k and v ``[B, L, H, D]`` split in
+    the block as before."""
+    import functools
+
+    from horovod_tpu.ops.attention import attend, dot_product_attention
+    from horovod_tpu.utils import timeline
+
+    kw = dict(vocab_size=32, num_layers=2, num_heads=2, embed_dim=2 * width,
+              max_len=32, dtype=jnp.float32, remat=remat)
+    dense_m = models.TransformerLM(
+        attn_fn=lambda q, k, v: dot_product_attention(q, k, v, causal=True),
+        **kw)
+    flash_m = models.TransformerLM(
+        attn_fn=functools.partial(attend, impl="flash", block_q=8,
+                                  block_k=8), **kw)
+    tokens = jax.random.randint(rng, (2, 16), 0, 32)
+    params = dense_m.init(rng, tokens, train=False)["params"]
+
+    def loss_fn(model, params):
+        logits = model.apply({"params": params}, tokens, train=False)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        return -jnp.mean(jnp.take_along_axis(
+            logp[:, :-1], tokens[:, 1:, None], -1))
+
+    ld, gd = jax.value_and_grad(lambda p: loss_fn(dense_m, p))(params)
+    timeline.reset()
+    with timeline.span("hvd.spmd.dispatch", handle="loss", program="loss#0",
+                       call=0):
+        lf, gf = jax.value_and_grad(lambda p: loss_fn(flash_m, p))(params)
+    gauges = timeline.snapshot()["gauges"]
+    assert gauges["hvd.attn.flash_calls"]["loss#0"] == 2
+    assert gauges["hvd.attn.paired_calls"]["loss#0"] == (2 if width == 64
+                                                         else 0)
+    np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gd)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5)
+
+
 def test_scan_layers_matches_unrolled(rng):
     """scan_layers compiles ONE weight-stacked block (lax.scan) instead
     of num_layers unrolled copies; per-layer math must be identical.

@@ -408,6 +408,78 @@ class TestFlashAttention:
         for a, b in zip(grads("fused"), grads("pallas")):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("truncate", [None, False],
+                             ids=["packed", "full_grid"])
+    @pytest.mark.parametrize("whole_projection", [False, True],
+                             ids=["separate", "fused_projection"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_two_heads_a_program_equal_one(self, dtype, whole_projection,
+                                           truncate, window):
+        """Two heads of 64 a program, read by index map from ``[B, L, H x
+        64]`` arrays (or from one fused ``[B, L, 3 x H x 64]`` projection)
+        and written back the same way: every product takes one operand with
+        the other head's lanes zeroed, so the sums gain exact zeros and o,
+        dQ, dK and dV are the one-head programs' to the last bit, in float32
+        and (the same roundings at the same places) in bfloat16; on the
+        packed grid and on the full one, under a window too, at four blocks
+        a side so that every scratch is revisited. Against the dense
+        reference within this class's tolerances."""
+        B, L, H, D = 2, 64, 4, 64
+        key = jax.random.PRNGKey(29)
+        q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i),
+                                         (B, L, H, D), dtype)
+                       for i in range(4))
+        kw = dict(causal=True, block_q=16, block_k=16, truncate=truncate,
+                  window=window)
+
+        def flat(t):
+            return t.reshape(B, L, H * D)
+
+        def two(q, k, v):
+            if whole_projection:
+                out = flash_attention(
+                    jnp.concatenate([flat(q), flat(k), flat(v)], -1),
+                    heads=H, **kw)
+            else:
+                out = flash_attention(flat(q), flat(k), flat(v), heads=H,
+                                      **kw)
+            assert out.shape == (B, L, H * D)
+            return out.reshape(B, L, H, D)
+
+        one_out, one_vjp = jax.vjp(
+            lambda *a: flash_attention(*a, **kw), q, k, v)
+        two_out, two_vjp = jax.vjp(two, q, k, v)
+        for a, b in zip((one_out, *one_vjp(do)), (two_out, *two_vjp(do))):
+            assert a.dtype == b.dtype == dtype
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        ref_out, ref_vjp = jax.vjp(
+            lambda *a: dot_product_attention(*a, causal=True, window=window),
+            *(t.astype(jnp.float32) for t in (q, k, v)))
+        tol = 2e-5 if dtype == jnp.float32 else 6e-2
+        for a, b in zip((ref_out, *ref_vjp(do.astype(jnp.float32))),
+                        (two_out, *two_vjp(do))):
+            np.testing.assert_allclose(np.asarray(b, np.float32),
+                                       np.asarray(a), atol=tol, rtol=tol)
+
+    def test_two_heads_a_program_refuse_what_they_cannot_read(self):
+        """``heads`` asks for the projections' layout: an even number of
+        heads of 64 in three ``[B, L, H x 64]`` arrays or one fused one, no
+        shared key, the one-kernel backward."""
+        x = jnp.ones((1, 32, 2 * 64))
+        for args, kw in [
+                ((x, x, x), dict(heads=3)),                 # odd
+                ((x, x, x), dict(heads=4)),                 # not 4 x 64 wide
+                ((x, x), dict(heads=2)),                    # no v
+                ((x,), dict(heads=2)),                      # not 3 x 2 x 64
+                ((x.reshape(1, 32, 2, 64),) * 3, dict(heads=2)),
+                ((x, x, x), dict(heads=2, bwd_impl="pallas")),
+                ((x, x, x), dict(heads=2, k_shared=jnp.ones((1, 32, 8))))]:
+            with pytest.raises(ValueError, match="two heads a program"):
+                flash_attention(*args, causal=True, block_q=8, block_k=8,
+                                **kw)
+
     def test_causal_rejects_fully_masked_rows(self):
         """q_offset < k_offset leaves query rows with NO visible key —
         an undefined softmax where the kernel's 0-output would silently

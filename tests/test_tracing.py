@@ -381,8 +381,10 @@ def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
     """A GPT-2-shaped step (heads of 64, block recomputation on): the gauges
     ``hvd.attn.*_calls`` of the step's program count its three attention
     calls by implementation (unset, the policy's: dense on this platform),
-    ``.block_q`` / ``.block_k`` are the kernels' blocks, and the attention
-    runs under the scope ``hvd_attn_full``."""
+    ``.block_q`` / ``.block_k`` are the kernels' blocks, ``.paired_calls``
+    the kernels' calls whose programs serve two heads (all three: two heads
+    of 64 from the block's fused projection), and the attention runs under
+    the scope ``hvd_attn_full``."""
     monkeypatch.syspath_prepend(REPO)
     import bench
 
@@ -405,10 +407,11 @@ def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
            if name.startswith("hvd.attn.") and step in by_program}
     if pinned == "flash":
         assert got == {"flash_calls": 3, "dense_calls": 0, "block_q": 64,
-                       "block_k": 64, "fused_bwd_calls": 3}
+                       "block_k": 64, "fused_bwd_calls": 3,
+                       "paired_calls": 3}
     else:
         assert got == {"flash_calls": 0, "dense_calls": 3,
-                       "fused_bwd_calls": 0}
+                       "fused_bwd_calls": 0, "paired_calls": 0}
     assert lane.stamp["attention"] == (pinned or "dense")
 
 
@@ -452,7 +455,8 @@ def test_the_looped_step_carries_its_scopes_and_gauges(hvd, monkeypatch):
     assert got == {"hvd.loop.applications": 8,
                    "hvd.exit.live_logits_bytes": 4 * 4 * 15 * 64,
                    "hvd.attn.kv_heads": 2, "hvd.attn.dense_calls": 8,
-                   "hvd.attn.flash_calls": 0, "hvd.attn.fused_bwd_calls": 0}
+                   "hvd.attn.flash_calls": 0, "hvd.attn.fused_bwd_calls": 0,
+                   "hvd.attn.paired_calls": 0}
 
 
 def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
@@ -501,7 +505,7 @@ def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
     assert got == {"hvd.attn.latent_expanded_bytes":
                    2 * 16 * (2 * (8 + 8) + 4) * 2,
                    "hvd.attn.dense_calls": 2, "hvd.attn.flash_calls": 0,
-                   "hvd.attn.fused_bwd_calls": 0}
+                   "hvd.attn.fused_bwd_calls": 0, "hvd.attn.paired_calls": 0}
 
 
 def test_windowed_train_step_has_the_same_scopes(hvd):

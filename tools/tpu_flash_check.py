@@ -18,7 +18,10 @@ attributable to a concrete grid, not just a wall time.
 constants come from (``ops.attention.attention_plan``; PERF.md, PR 29 and
 PR 35): dense against the kernels over blocks and the three backwards (one
 kernel, the dQ / dK+dV split, the scan) at :data:`SWEEP_SHAPES`, each
-kernel's own device time beside the wall times.
+kernel's own device time beside the wall times; and, where the plan answers
+two heads a program (heads of 64), a layer through ``attend`` from the fused
+projection and back, the copies around the kernels included, one head a
+program against two (PR 37).
 
 Run on a TPU host:  python tools/tpu_flash_check.py
 """
@@ -29,7 +32,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.attention import (dot_product_attention,
+from horovod_tpu.ops.attention import (FLASH_BLOCK, attention_plan,
+                                       dot_product_attention,
                                        flash_attention, flash_grid_info)
 
 
@@ -94,11 +98,21 @@ def _kernel_ms(fn, *args, calls=4):
     return {k: round(v / calls / 1e6, 4) for k, v in sorted(ns.items())}
 
 
-def _grad_of(attend):
+def _grad_of(attend, argnums=(0, 1, 2)):
     """The jitted gradients of ``sum(attend(q, k, v))`` by q, k and v."""
     return jax.jit(jax.grad(
         lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
-        argnums=(0, 1, 2)))
+        argnums=argnums))
+
+
+def _one_head_a_program(qkv, heads, **kw):
+    """What ``attend`` does with a fused projection where the plan answers
+    one head a program: split it, give every head its ``[L, D]`` slab (the
+    transposes inside ``flash_attention``), and lay the result back."""
+    q, k, v = (t.reshape(*t.shape[:2], heads, -1)
+               for t in jnp.split(qkv, 3, axis=-1))
+    out = flash_attention(q, k, v, causal=True, **kw)
+    return out.reshape(*out.shape[:2], -1)
 
 
 def main():
@@ -146,6 +160,23 @@ def main():
                for a, b in zip(fused, split))
     print(f"fused-vs-split backward max err: {ferr:.2e}", file=sys.stderr)
     assert ferr < 1e-2, ferr
+    # Two heads a program against one ON HARDWARE, from a fused projection
+    # of 4 heads of 64 at four blocks a side: zeros added to the same sums.
+    fused_qkv = jax.random.normal(jax.random.fold_in(key, 9),
+                                  (2, 2048, 3 * 4 * 64), jnp.bfloat16)
+    lanes = dict(block_q=512, block_k=512)
+    perr = 0.0
+    for one, two in (
+            (_one_head_a_program(fused_qkv, 4, **lanes),
+             flash_attention(fused_qkv, causal=True, heads=4, **lanes)),
+            (_grad_of(functools.partial(_one_head_a_program, heads=4,
+                                        **lanes), 0)(fused_qkv),
+             _grad_of(functools.partial(flash_attention, causal=True,
+                                        heads=4, **lanes), 0)(fused_qkv))):
+        perr = max(perr, float(jnp.max(jnp.abs(
+            one.astype(jnp.float32) - two.astype(jnp.float32)))))
+    print(f"two-heads-a-program vs one max err: {perr:.2e}", file=sys.stderr)
+    assert perr < 1e-2, perr
     # Sentinel BEFORE the timing ladder: the kernel validation above is
     # the scarce evidence — a dense-path OOM in the secondary
     # benchmark below must not make it read as a failure.
@@ -196,6 +227,11 @@ def main():
 # the last 64 of them one rope key a token, values of 128).
 SWEEP_SHAPES = (
     ("gpt2m_1024", (8, 1024, 16, 64), 16, None, (256, 512, 1024)),
+    # the same layer with no block swept: dense, and the layer through
+    # ``attend`` one head a program against two (PR 37)
+    ("gpt2m_1024_layer", (8, 1024, 16, 64), 16, None, ()),
+    ("h64_2048_layer", (4, 2048, 16, 64), 16, None, ()),
+    ("h64_4096_layer", (2, 4096, 16, 64), 16, None, ()),
     ("h64_2048", (4, 2048, 16, 64), 16, None, (256, 512, 1024, 2048)),
     ("h64_4096", (2, 4096, 16, 64), 16, None, (256, 512, 1024, 2048)),
     # 256 x 256 at heads of 128 is PR 28's reading (PERF.md): 7.64 / 21.24
@@ -235,9 +271,9 @@ def block_sweep(key, only=None):
         row = dict(shape=shape_name, impl=label, **stamp)
         try:
             row["fwd_ms"] = 1e3 * _time(jax.jit(attend), *qkv)
-            grad = _grad_of(attend)
+            grad = _grad_of(attend, tuple(range(min(len(qkv), 3))))
             row["fwd_bwd_ms"] = 1e3 * _time(grad, *qkv)
-            if label == "flash":
+            if label != "dense":
                 row["kernels_ms"] = _kernel_ms(grad, *qkv)
         except Exception as exc:  # noqa: BLE001: a refusal is a record too
             row["failed"] = f"{type(exc).__name__}: {str(exc)[:160]}"
@@ -271,6 +307,24 @@ def block_sweep(key, only=None):
                     measure(name, "flash", shared_key(
                         flash_attention, block_q=bq, block_k=bk,
                         bwd_impl=bwd), qkv, block_q=bq, block_k=bk, bwd=bwd)
+        if attention_plan(length, length, h, g, (d, value), window,
+                          backend="tpu", shared_key=bool(shared)
+                          ).heads_per_program == 2:
+            # A layer THROUGH ``attend``, from the fused projection to what
+            # the output projection reads and back to the projection's
+            # gradient, the copies between them included: the same kernels,
+            # one head a program (split, transposed) against two (read where
+            # the projection wrote them).
+            fused_qkv = [jnp.concatenate(
+                [t.reshape(b, length, -1) for t in qkv], -1)]
+            for per, fn in ((1, functools.partial(_one_head_a_program,
+                                                  heads=h, window=window)),
+                            (2, functools.partial(flash_attention,
+                                                  causal=True, heads=h,
+                                                  window=window))):
+                measure(name, "attend", fn, fused_qkv, block_q=FLASH_BLOCK,
+                        block_k=FLASH_BLOCK, bwd="fused",
+                        heads_per_program=per)
     with open(os.path.join(out_dir, "flash_sweep.jsonl"), "w") as f:
         f.writelines(json.dumps(r) + "\n" for r in rows)
     done = [r for r in rows if "fwd_bwd_ms" in r]
