@@ -150,7 +150,7 @@ def lm_model_args(args, attention: str) -> dict:
     from horovod_tpu.models import decoder
 
     kinds = {"sliding": decoder.SLIDING, "full": decoder.FULL,
-             "latent": decoder.LATENT}
+             "latent": decoder.LATENT, "mamba": decoder.MAMBA}
     names = (args.lm_layer_types.split(",") if args.lm_layer_types
              else ["full"] * args.lm_layers)
     if len(names) != args.lm_layers or set(names) - set(kinds):
@@ -175,7 +175,13 @@ def lm_model_args(args, attention: str) -> dict:
         route_scale=args.moe_route_scale, attention=attention,
         embed_scale=args.lm_embed_scale, norm_outputs=args.lm_output_norms,
         rope_dim=args.lm_rope_dim, value_dim=args.lm_value_dim,
-        latent_dim=args.lm_latent_dim)
+        latent_dim=args.lm_latent_dim, qk_norm=args.lm_qk_norm,
+        attn_gate=args.lm_attn_gate, attn_scale=args.lm_attn_scale,
+        ssm_heads=args.ssm_heads, ssm_head_dim=args.ssm_head_dim,
+        ssm_state=args.ssm_state, ssm_conv=args.ssm_conv,
+        ssm_chunk=args.ssm_chunk, embed_multiplier=args.lm_embed_multiplier,
+        residual_scale=args.lm_residual_scale, tie_head=args.lm_tie_head,
+        logit_divisor=args.lm_logit_divisor)
 
 
 def build_lane(args, log) -> Lane:
@@ -303,10 +309,45 @@ def build_parser():
                         help="moe_lm: window of a sliding layer")
     parser.add_argument("--lm-layer-types", default=None,
                         help="moe_lm: 'sliding' (window, rotary positions), "
-                             "'full' (no positional encoding) or 'latent' "
+                             "'full' (no positional encoding), 'latent' "
                              "(keys and values expanded from a compressed "
-                             "row, one rotated key all heads share) a "
+                             "row, one rotated key all heads share) or "
+                             "'mamba' (Mamba-2's state-space mixer) a "
                              "layer, comma-separated (default: all full)")
+    parser.add_argument("--lm-qk-norm", default=True,
+                        action=argparse.BooleanOptionalAction,
+                        help="moe_lm: an RMS norm a head on q and k in a "
+                             "sliding or full layer")
+    parser.add_argument("--lm-attn-gate", default=True,
+                        action=argparse.BooleanOptionalAction,
+                        help="moe_lm: a sigmoid gate on a sliding or full "
+                             "layer's output")
+    parser.add_argument("--lm-attn-scale", type=float, default=None,
+                        help="moe_lm: scale of a sliding or full layer's "
+                             "scores (default: 1 / sqrt(head size))")
+    parser.add_argument("--ssm-heads", type=int, default=64,
+                        help="moe_lm, a mamba layer: heads of the scan")
+    parser.add_argument("--ssm-head-dim", type=int, default=64,
+                        help="moe_lm, a mamba layer: size of a head")
+    parser.add_argument("--ssm-state", type=int, default=128,
+                        help="moe_lm, a mamba layer: size of the state "
+                             "(of B and C)")
+    parser.add_argument("--ssm-conv", type=int, default=4,
+                        help="moe_lm, a mamba layer: taps of the causal "
+                             "depthwise conv")
+    parser.add_argument("--ssm-chunk", type=int, default=256,
+                        help="moe_lm, a mamba layer: tokens a chunk of the "
+                             "scan")
+    parser.add_argument("--lm-embed-multiplier", type=float, default=None,
+                        help="moe_lm: the embedding times this number")
+    parser.add_argument("--lm-residual-scale", type=float, default=1.0,
+                        help="moe_lm: each branch's output times this "
+                             "number before it is added to the stream")
+    parser.add_argument("--lm-tie-head", action="store_true",
+                        help="moe_lm: the logits from the embedding's "
+                             "transpose, no head of their own")
+    parser.add_argument("--lm-logit-divisor", type=float, default=1.0,
+                        help="moe_lm: the logits divided by this number")
     parser.add_argument("--lm-latent-dim", type=int, default=512,
                         help="moe_lm, a latent layer: width of the "
                              "compressed row")

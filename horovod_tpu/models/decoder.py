@@ -17,10 +17,16 @@ made of them: a sparse one and a looped one.
   token passes;
 * :class:`DecoderBlock`: an RMS norm before each branch and, where
   ``norm_outputs`` says so, after it;
+* :class:`Mamba2Mixer`: Mamba-2's state-space mixer (in-projection, causal
+  depthwise conv, the chunked scan of :mod:`horovod_tpu.ops.ssd`, the gated
+  norm, out-projection);
 * :class:`SparseDecoderLM`: leading dense layers, then expert layers
   (``afmoe``: a window layer rotates, a full layer has no positional
   encoding at all; every layer has the q/k norms and the gate;
-  ``deepseek_v3``: latent layers, two norms a block);
+  ``deepseek_v3``: latent layers, two norms a block;
+  ``granitemoehybrid``: Mamba layers among full ones with no q/k norm or
+  gate, every layer dense, the branches and the embedding scaled by
+  numbers, the head tied to the embedding);
 * :class:`LoopedDecoderLM`: a stack of blocks declared once and applied
   ``loops`` times with the same weights, an exit after each application
   (``ouro``: full attention with rotary positions, no q/k norm, no gate), and
@@ -46,13 +52,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.ops import ssd as ssd_op
 from horovod_tpu.ops.attention import attend
 from horovod_tpu.ops.xent import T_CHUNK, token_nll
 from horovod_tpu.parallel import moe
 from horovod_tpu.utils import timeline
 
-SLIDING, FULL, LATENT = ("sliding_attention", "full_attention",
-                         "latent_attention")
+SLIDING, FULL, LATENT, MAMBA = ("sliding_attention", "full_attention",
+                                "latent_attention", "mamba")
 
 
 class RMSNorm(nn.Module):
@@ -116,6 +123,7 @@ class GroupedAttention(nn.Module):
     rope_base: float = 10000.0
     attention: Optional[str] = None
     dtype: Any = jnp.bfloat16
+    scale: Optional[float] = None       # scores' scale; None: head_dim^-0.5
 
     @nn.compact
     def __call__(self, x):
@@ -145,7 +153,8 @@ class GroupedAttention(nn.Module):
         scope = (timeline.ATTN_FULL if self.window is None
                  else timeline.ATTN_WINDOW)
         with jax.named_scope(scope):
-            out = attend(q, k, v, window=self.window, impl=self.attention)
+            out = attend(q, k, v, window=self.window, impl=self.attention,
+                         scale=self.scale)
         out = out.reshape(b, length, h * d)
         if self.gate:
             out = out * nn.sigmoid(gate)
@@ -224,6 +233,84 @@ class LatentAttention(nn.Module):
 _expanded: dict = {}
 
 
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's mixer (``modeling_granitemoehybrid``'s, no bias on the
+    projections, one group): for a token's normed state ``x``
+
+        z | xBC | dt = x W_in                 inner | inner + 2N | heads
+        xBC          = silu(conv(xBC) + b)    causal, depthwise, ``conv`` taps
+        x | B | C    = xBC                    heads x head_dim | N | N
+        Delta        = softplus(dt + dt_bias);  A = -exp(A_log)
+        y            = ssd(x, Delta, A, B, C) + D x       (ops/ssd.py)
+        y            = RMS(y * silu(z)) * w   over inner: the gate first
+        out          = y W_out
+
+    The scan reads x, B and C where the conv wrote them (``ssd``'s packed
+    form). ``impl`` is ``ssd``'s. Scopes ``hvd_ssm_mixer`` (the whole mixer)
+    and ``hvd_ssd`` (the scan); for the program being traced the counter
+    ``hvd.ssd.calls`` and the gauges ``hvd.ssd.chunk`` and
+    ``hvd.ssd.state_bytes`` (chunk states the forward kernels write to HBM,
+    summed over the layers and over the forward calls a step executes: a
+    recomputed block's twice) and ``hvd.ssd.fwd_calls``."""
+
+    heads: int
+    head_dim: int
+    state: int
+    conv: int = 4
+    chunk: int = ssd_op.CHUNK
+    eps: float = 1e-5
+    impl: Optional[str] = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, length, d = x.shape
+        inner = self.heads * self.head_dim
+        width = inner + 2 * self.state          # x | B | C
+        with jax.named_scope(timeline.SSM_MIXER):
+            proj = nn.Dense(inner + width + self.heads, use_bias=False,
+                            dtype=self.dtype, name="in_proj")(x)
+            z, xbc, dt = (proj[..., :inner], proj[..., inner:inner + width],
+                          proj[..., inner + width:])
+            kernel = self.param("conv1d_kernel",
+                                nn.initializers.lecun_normal(),
+                                (self.conv, width))
+            bias = self.param("conv1d_bias", nn.initializers.zeros, (width,))
+            padded = jnp.pad(xbc, ((0, 0), (self.conv - 1, 0), (0, 0)))
+            conv = bias + sum(padded[:, i:i + length].astype(jnp.float32)
+                              * kernel[i] for i in range(self.conv))
+            xbc = nn.silu(conv).astype(self.dtype)
+            dt_bias = self.param("dt_bias", nn.initializers.zeros,
+                                 (self.heads,))
+            a_log = self.param("A_log", nn.initializers.zeros, (self.heads,))
+            skip = self.param("D", nn.initializers.ones, (self.heads,))
+            delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            program, tally = timeline.program_tally(_scans, lambda: [0, 0])
+            again = 1 + _recomputing[0]     # forward calls a step executes
+            tally[0] += again
+            tally[1] += again * ssd_op.state_bytes(
+                b, length, self.heads, self.state, self.head_dim, self.chunk)
+            timeline.count("hvd.ssd.calls")
+            timeline.gauge("hvd.ssd.chunk", self.chunk, key=program)
+            timeline.gauge("hvd.ssd.fwd_calls", tally[0], key=program)
+            timeline.gauge("hvd.ssd.state_bytes", tally[1], key=program)
+            with jax.named_scope(timeline.SSD):
+                y = ssd_op.ssd(xbc, delta, -jnp.exp(a_log), D=skip,
+                               state_dim=self.state, chunk=self.chunk,
+                               impl=self.impl)
+            gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+            y = RMSNorm(self.eps, name="norm")(gated).astype(self.dtype)
+            return nn.Dense(d, use_bias=False, dtype=self.dtype,
+                            name="out_proj")(y)
+
+
+# Forward kernel calls and chunk-state bytes of the program being traced:
+# program -> (id of its dispatch span, [calls, bytes]); and whether the block
+# being applied is one the backward pass runs again (``_applications``).
+_scans: dict = {}
+_recomputing = [False]
+
+
 class SparseExperts(nn.Module):
     """``MLP_shared(x) + sum over the chosen experts held here of w_e
     MLP_e(x)``: the chip's share of the layer (``first_expert`` and
@@ -274,8 +361,11 @@ class DecoderBlock(nn.Module):
     ``F`` a :class:`GatedMLP` (``moe`` None) or :class:`SparseExperts`;
     without ``norm_outputs`` the two norms before the branches alone:
     ``h += attention(RMS_1(h))``; ``h += F(RMS_2(h))``. ``attn`` holds the
-    fields of a :class:`GroupedAttention`, or of a :class:`LatentAttention`
-    where it names a ``latent_dim``."""
+    fields of a :class:`GroupedAttention`, of a :class:`LatentAttention`
+    where it names a ``latent_dim``, or of a :class:`Mamba2Mixer` (named
+    ``mamba``; its norm keeps the name ``norm_attn``) where it names a
+    ``state``. ``residual_scale`` multiplies each branch's output before it
+    is added (Granite's ``residual_multiplier``)."""
 
     attn: dict                          # the attention layer's fields
     ffn_width: int
@@ -283,18 +373,25 @@ class DecoderBlock(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     norm_outputs: bool = True           # a norm after each branch too
+    residual_scale: float = 1.0
 
     @nn.compact
     def __call__(self, h):
         def branch_output(y, name):
             if self.norm_outputs:
                 y = RMSNorm(self.eps, name=name)(y)
+            if self.residual_scale != 1.0:
+                y = y * self.residual_scale
             return y.astype(h.dtype)
 
-        layer = LatentAttention if "latent_dim" in self.attn \
-            else GroupedAttention
+        if "state" in self.attn:
+            layer, name = Mamba2Mixer, "mamba"
+        else:
+            layer = LatentAttention if "latent_dim" in self.attn \
+                else GroupedAttention
+            name = "attn"
         a = RMSNorm(self.eps, name="norm_attn")(h)
-        a = layer(eps=self.eps, dtype=self.dtype, name="attn", **self.attn)(a)
+        a = layer(eps=self.eps, dtype=self.dtype, name=name, **self.attn)(a)
         h = h + branch_output(a, "norm_attn_out")
         m = RMSNorm(self.eps, name="norm_ffn")(h)
         if self.moe is None:
@@ -314,7 +411,8 @@ class DecoderBlock(nn.Module):
         recompute themselves (``parallel/moe.py``). An estimate, which
         ``tests/test_chip_smoke.py`` holds to the compiler's count at
         Trinity-Mini's widths (0.99 of it for the dense block, 1.23 for an
-        expert block) and at Moonlight's (1.06 and 1.16)."""
+        expert block), at Moonlight's (1.06 and 1.16) and at Granite's (1.21
+        for a Mamba block)."""
         e = jnp.dtype(self.dtype).itemsize
         a = self.attn
         # the stream before each branch, its norm and the branch's output
@@ -322,7 +420,17 @@ class DecoderBlock(nn.Module):
         # compiler's count the normed copy is then made again in the
         # products that read it)
         a_token = (6 if self.norm_outputs else 2) * width * e
-        if "latent_dim" in a:
+        if "state" in a:
+            # the in-projection's output (z | xBC | dt), the conv's output
+            # and the scan's y (the gated norm XLA makes again inside the
+            # out-projection's fusions); Delta in float32 and the chunk
+            # states the forward kernel writes
+            inner = a["heads"] * a["head_dim"]
+            conv = inner + 2 * a["state"]
+            a_token += (inner + conv + a["heads"] + conv + inner) * e \
+                + 2 * 4 * a["heads"] + inner * a["state"] * 4 // a.get(
+                    "chunk", ssd_op.CHUNK)
+        elif "latent_dim" in a:
             # the compressed row with the rope key before its norm and after,
             # then the kernels' operands: q, the expanded k and v with the
             # one rope key, the output
@@ -336,9 +444,10 @@ class DecoderBlock(nn.Module):
                 a_token += (q + kv) * e         # q and k before their norms
             if a.get("gate"):
                 a_token += 2 * q * e            # its logits, the gated output
-        # the kernels' log-sum-exp a head: float32, each a lane row of 128 in
-        # HBM
-        a_token += a["heads"] * 128 * 4
+        if "state" not in a:
+            # the kernels' log-sum-exp a head: float32, each a lane row of
+            # 128 in HBM
+            a_token += a["heads"] * 128 * 4
         if self.moe is None:
             hidden = self.ffn_width
         else:
@@ -492,7 +601,11 @@ def _applications(remat, total: int, counted=("hvd.remat.applications",)):
         for name in counted:
             timeline.gauge(name, tally[0], key=program)
         timeline.gauge("hvd.remat.recomputed", tally[1], key=program)
-        return (_apply_again if again else _apply)(block, h)
+        _recomputing[0] = again
+        try:
+            return (_apply_again if again else _apply)(block, h)
+        finally:
+            _recomputing[0] = False
 
     return apply
 
@@ -505,15 +618,25 @@ class SparseDecoderLM(nn.Module):
     (:class:`GroupedAttention`) / ``"latent_attention"``
     (:class:`LatentAttention`: ``head_dim`` is a key's own width beside the
     shared ``rope_dim``, ``value_dim`` a value's, ``latent_dim`` the
-    compressed row's) a layer; the first ``dense_layers`` have a
+    compressed row's) / ``"mamba"`` (:class:`Mamba2Mixer` of ``ssm_heads``
+    heads of ``ssm_head_dim``, a state of ``ssm_state``, ``ssm_conv`` taps,
+    chunks of ``ssm_chunk``) a layer; the first ``dense_layers`` have a
     :class:`GatedMLP` of ``dense_width``, the others :class:`SparseExperts`.
-    ``embed_scale`` multiplies the embedding by ``sqrt(embed_dim)``;
-    ``norm_outputs`` is :class:`DecoderBlock`'s. ``remat`` says how many
-    block applications (here: blocks) the backward pass runs again instead
-    of keeping what they computed: a count or a :class:`RecomputePlan` (the
-    first so many; the last ones are kept), ``True`` all, ``False`` none. A
-    lane's ``--remat`` fills it with :func:`plan_recomputation`'s answer:
-    the fewest that fit the device's memory."""
+    A grouped attention layer has the q/k norms and the output gate where
+    ``qk_norm`` and ``attn_gate`` say (``afmoe``: both), scores scaled by
+    ``attn_scale`` (None: ``head_dim ** -0.5``), rotary positions in a
+    sliding layer only. ``embed_scale`` multiplies the embedding by
+    ``sqrt(embed_dim)``, ``embed_multiplier`` by a number;
+    ``residual_scale`` each branch's output (:class:`DecoderBlock`);
+    ``tie_head`` takes the logits from the embedding's transpose, and they
+    are divided by ``logit_divisor`` (:func:`loss_head` gives a fused loss
+    the same two). ``norm_outputs`` is :class:`DecoderBlock`'s. ``remat``
+    says how many block applications (here: blocks) the backward pass runs
+    again instead of keeping what they computed: a count or a
+    :class:`RecomputePlan` (the first so many; the last ones are kept),
+    ``True`` all, ``False`` none. A lane's ``--remat`` fills it with
+    :func:`plan_recomputation`'s answer: the fewest that fit the device's
+    memory."""
 
     vocab_size: int
     embed_dim: int
@@ -541,24 +664,43 @@ class SparseDecoderLM(nn.Module):
     rope_dim: int = 0               # a latent layer's three further widths
     value_dim: int = 0
     latent_dim: int = 0
+    qk_norm: bool = True            # a grouped attention layer's choices
+    attn_gate: bool = True
+    attn_scale: Optional[float] = None
+    ssm_heads: int = 0              # a Mamba layer's widths
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = ssd_op.CHUNK
+    ssm_impl: Optional[str] = None
+    embed_multiplier: Optional[float] = None
+    residual_scale: float = 1.0
+    tie_head: bool = False
+    logit_divisor: float = 1.0
 
     @nn.nowrap
     def block(self, i: int) -> DecoderBlock:
         """Layer ``i``'s block, by the name its parameters have."""
         kind = self.layer_types[i]
-        if kind not in (SLIDING, FULL, LATENT):
+        if kind not in (SLIDING, FULL, LATENT, MAMBA):
             raise ValueError(f"layer {i}: no layer type {kind!r}")
         if kind == LATENT:
             attn = dict(heads=self.heads, nope_dim=self.head_dim,
                         rope_dim=self.rope_dim, value_dim=self.value_dim,
                         latent_dim=self.latent_dim, rope_base=self.rope_base,
                         attention=self.attention)
+        elif kind == MAMBA:
+            attn = dict(heads=self.ssm_heads, head_dim=self.ssm_head_dim,
+                        state=self.ssm_state, conv=self.ssm_conv,
+                        chunk=self.ssm_chunk, impl=self.ssm_impl)
         else:
             attn = dict(heads=self.heads, kv_heads=self.kv_heads,
                         head_dim=self.head_dim, rope_base=self.rope_base,
                         window=self.window if kind == SLIDING else None,
-                        rotary=kind == SLIDING, qk_norm=True, gate=True,
-                        attention=self.attention)
+                        rotary=kind == SLIDING, qk_norm=self.qk_norm,
+                        gate=self.attn_gate, attention=self.attention)
+            if self.attn_scale is not None:
+                attn["scale"] = self.attn_scale
         sparse = None if i < self.dense_layers else dict(
             experts=self.experts, experts_held=self.experts_held,
             first_expert=self.first_expert, top_k=self.top_k,
@@ -566,7 +708,7 @@ class SparseDecoderLM(nn.Module):
             shared=self.shared_experts)
         return DecoderBlock(attn, self.dense_width, sparse, self.eps,
                             self.dtype, self.norm_outputs,
-                            name=f"DecoderBlock_{i}")
+                            self.residual_scale, name=f"DecoderBlock_{i}")
 
     @nn.nowrap
     def applications(self) -> list:
@@ -578,18 +720,38 @@ class SparseDecoderLM(nn.Module):
     def __call__(self, tokens, train: bool = True,
                  return_hidden: bool = False):
         del train                                   # no dropout anywhere
-        h = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype,
-                     name="embed")(tokens)
+        embed = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype,
+                         name="embed")
+        h = embed(tokens)
         if self.embed_scale:
             h = h * jnp.asarray(self.embed_dim ** 0.5, h.dtype)
+        if self.embed_multiplier is not None:
+            h = h * jnp.asarray(self.embed_multiplier, h.dtype)
         apply_block = _applications(self.remat, len(self.layer_types))
         for i in range(len(self.layer_types)):
             h = apply_block(self.block(i), h)
         h = RMSNorm(self.eps, name="final_norm")(h)
         if return_hidden:
             return h
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                        name="lm_head")(h)
+        if self.tie_head:
+            logits = jnp.dot(h, embed.embedding.T.astype(jnp.float32))
+        else:
+            logits = nn.Dense(self.vocab_size, use_bias=False,
+                              dtype=jnp.float32, name="lm_head")(h)
+        if self.logit_divisor != 1.0:
+            logits = logits / self.logit_divisor
+        return logits
+
+
+def loss_head(model, params):
+    """``(kernel [embed_dim, vocab], divisor)`` of a language model's loss
+    head: its ``lm_head``, or the embedding's transpose where the model ties
+    the two (``tie_head``); the logits are ``hidden @ kernel / divisor``."""
+    if getattr(model, "tie_head", False):
+        kernel = params["embed"]["embedding"].T
+    else:
+        kernel = params["lm_head"]["kernel"]
+    return kernel, getattr(model, "logit_divisor", 1.0)
 
 
 class LoopedDecoderLM(nn.Module):

@@ -304,6 +304,7 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, *,
             # logits tensor — the step's largest single HBM sink —
             # never materializes; the vocab projection's gradient comes
             # out of the same scan.
+            from horovod_tpu.models.decoder import loss_head
             from horovod_tpu.ops.xent import fused_cross_entropy
 
             def loss_fn(params):
@@ -312,7 +313,12 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, *,
                 with jax.named_scope(timeline.LOSS):
                     e = hidden.shape[-1]
                     h = hidden[:, :-1].reshape(-1, e).astype(jnp.float32)
-                    wv = params["lm_head"]["kernel"].astype(jnp.float32)
+                    # the head's kernel, or the embedding's transpose where
+                    # the model ties them; the logits' divisor on h
+                    kernel, divisor = loss_head(model, params)
+                    if divisor != 1.0:
+                        h = h / divisor
+                    wv = kernel.astype(jnp.float32)
                     return fused_cross_entropy(
                         h, wv, tokens[:, 1:].reshape(-1)), wrote
         else:

@@ -138,9 +138,12 @@ MOE_SHARED = "hvd_moe_shared"       # the shared expert every token passes
 LOOP_STEP = "hvd_loop_step"     # one application of a looped model's stack
 EXIT_GATE = "hvd_exit_gate"     # the exit gate, and the exit distribution
 EXIT_LOSS = "hvd_exit_loss"     # every exit's per-token loss, in chunks
+SSM_MIXER = "hvd_ssm_mixer"     # a Mamba-2 mixer, projections to projection
+SSD = "hvd_ssd"                 # its chunked scan (ops/ssd.py's kernels)
 LAYER_SCOPES = (ATTN_WINDOW, ATTN_FULL, ATTN_LATENT, LATENT_COMPRESS,
                 LATENT_EXPAND, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
-                MOE_COMBINE, MOE_SHARED, LOOP_STEP, EXIT_GATE, EXIT_LOSS)
+                MOE_COMBINE, MOE_SHARED, LOOP_STEP, EXIT_GATE, EXIT_LOSS,
+                SSM_MIXER, SSD)
 RING = 8192     # records kept: a 10 s window of 46 ms steps is 217 of them
 
 _lock = threading.Lock()

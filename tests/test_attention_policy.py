@@ -307,26 +307,30 @@ FUSED_CALLS = "flash_fused_bwd_calls_per_step.tok"
 PAIRED_CALLS = "flash_paired_calls_per_step.tok"
 LANGUAGE_CELLS = ("gpt2m_seq1024_1chip", "gpt2m_seq1024_dp4",
                   "trinity_mini_seq4096_1chip", "ouro_seq4096_1chip",
-                  "moonlight_seq8192_1chip")
+                  "moonlight_seq8192_1chip", "gpt2m_seq4096_flash_1chip")
 # metric -> (the gauge it reads, the cells that list it)
 CALL_COUNTERS = {
     FUSED_CALLS: ("hvd.attn.fused_bwd_calls", LANGUAGE_CELLS),
-    PAIRED_CALLS: ("hvd.attn.paired_calls", LANGUAGE_CELLS[:2]),
+    PAIRED_CALLS: ("hvd.attn.paired_calls",
+                   LANGUAGE_CELLS[:2] + LANGUAGE_CELLS[-1:]),
 }
 
 
-@pytest.mark.parametrize("cell", LANGUAGE_CELLS + ("resnet50_bs128_1chip",))
+# the state-space cell runs the kernels too, and lists no flash metric
+@pytest.mark.parametrize("cell", LANGUAGE_CELLS + (
+    "resnet50_bs128_1chip", "granite_h_micro_seq16384_1chip"))
 @pytest.mark.parametrize("metric", sorted(CALL_COUNTERS))
 def test_the_kernels_call_counters_are_metrics_of_their_cells(metric, cell):
     """``flash_fused_bwd_calls_per_step.tok`` (``BENCHMARK.json``; the reader
-    ``benchmarks/metrics/``) is listed by the five cells that train through
-    the kernels and reads the gauge ``hvd.attn.fused_bwd_calls`` of the step
+    ``benchmarks/metrics/``) is listed by six of the cells that train
+    through the kernels (the state-space cell, whose one attention layer
+    runs them, lists no flash metric) and reads the gauge ``hvd.attn.fused_bwd_calls`` of the step
     handle's program: nothing on a program that sets no such gauge (the
     parent of PR 35) or whose calls all take the split.
-    ``flash_paired_calls_per_step.tok``, the manifest's last ``per_layer``
-    entry, is listed by the two cells whose heads are 64 wide and reads
-    ``hvd.attn.paired_calls`` the same way: nothing at the parent of PR 37
-    or where every call runs one head a program."""
+    ``flash_paired_calls_per_step.tok`` is listed by the three cells whose
+    heads are 64 wide, one a KV head, and reads ``hvd.attn.paired_calls``
+    the same way: nothing at the parent of PR 37 or where every call runs
+    one head a program."""
     import json
     import os
     import sys
@@ -340,7 +344,6 @@ def test_the_kernels_call_counters_are_metrics_of_their_cells(metric, cell):
     gauge, cells = CALL_COUNTERS[metric]
     with open(os.path.join(repo, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    assert manifest["per_layer"][-1]["name"] == PAIRED_CALLS
     reported = {m["name"]: m for m in run.metrics_of(manifest, cell,
                                                      "per_layer")}
     assert (metric in reported) == (cell in cells)

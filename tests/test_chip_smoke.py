@@ -255,6 +255,35 @@ def test_kernels_compile_for_a_tpu_from_here(topo):
     assert "%ragged-dot-none" in lowered.compile().as_text()
 
 
+def test_the_scan_kernels_compile_for_a_tpu_at_the_cells_widths(topo):
+    """Granite's Mamba layer at its real widths (64 heads of 64, a state of
+    128, one group, chunks of 256) over the cell's 16,384 tokens: the
+    chunked scan's forward and backward kernels go through Mosaic itself,
+    both under the one profile name, reading the conv's output ``[1,
+    16,384, 4,352]`` where it lies."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops import ssd
+
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def loss(xbc, dt, a, d):
+        return ssd.ssd(xbc, dt, a, D=d, state_dim=128, impl="pallas",
+                       interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        spec((1, 16384, 4352), jnp.bfloat16), spec((1, 16384, 64)),
+        spec((64,)), spec((64,))).compile().as_text()
+    named = [line for line in text.splitlines()
+             if "custom-call(" in line and f"%{ssd.KERNEL}." in line]
+    assert len(named) == 2, named           # the forward and the backward
+
+
 def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
     """GPT-2-medium's attention layer as ``attention_plan`` runs it on the
     chip (the block's fused projection ``[8, 1024, 3 x 16 x 64]`` bf16, two
@@ -347,10 +376,19 @@ MOONLIGHT = dict(
     norm_outputs=False, embed_scale=False)
 
 
+GRANITE = dict(
+    layer_types=("mamba", "mamba"), heads=32, kv_heads=8, head_dim=64,
+    window=0, dense_layers=2, dense_width=8192, experts=8, experts_held=8,
+    top_k=2, expert_width=1024, ssm_heads=64, ssm_head_dim=64,
+    ssm_state=128, ssm_chunk=256, ssm_impl="pallas", residual_scale=0.22,
+    norm_outputs=False, embed_scale=False)
+
+
 @pytest.mark.parametrize("sizes, length, reckoned", [
     (TRINITY, 4096, 998_244_352),           # 0.93 GiB; read at 0.99
     (MOONLIGHT, 8192, 1_715_470_336),       # 1.60 GiB; read at 1.06
-], ids=["trinity", "moonlight"])
+    (GRANITE, 8192, 1_637_875_712),         # 1.53 GiB; read at 1.21
+], ids=["trinity", "moonlight", "granite"])
 def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(
         topo, sizes, length, reckoned):
     """``SparseDecoderLM`` at a cell's widths over its 2 sequences, its first
@@ -359,14 +397,15 @@ def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(
     the compiler counts for keeping the first as well is what
     ``DecoderBlock.kept_bytes`` reckons for it, to the stated factors
     (Trinity-Mini's dense block reads 0.99 and an expert block 1.23;
-    Moonlight's latent ones 1.06 and 1.16). The plan stands on that sum, so
+    Moonlight's latent ones 1.06 and 1.16; Granite's Mamba block 1.21).
+    The plan stands on that sum, so
     this pins it to the compiler and not to a guess."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu import models
-    from horovod_tpu.ops import attention
+    from horovod_tpu.ops import attention, ssd
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     tokens = jax.ShapeDtypeStruct((2, length), jnp.int32, sharding=one_chip)
@@ -382,27 +421,32 @@ def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(
                 jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
 
         def loss(params, buffers, tokens):
-            hidden = model.apply({"params": params, "buffers": buffers},
-                                 tokens, return_hidden=True)
+            hidden = model.apply({"params": params, **buffers}, tokens,
+                                 return_hidden=True)
             return jnp.mean(jnp.square(hidden))
 
+        buffers = {k: v for k, v in variables.items() if k == "buffers"}
         program = jax.jit(jax.grad(loss)).lower(
-            variables["params"], variables["buffers"], tokens).compile()
-        calls = sum("custom-call(" in line and "hvd_flash_fwd" in line
+            variables["params"], buffers, tokens).compile()
+        calls = sum("custom-call(" in line and kernel in line
                     for line in program.as_text().splitlines())
         return model, program.memory_analysis().temp_size_in_bytes, calls
 
     # the kernels compiled by Mosaic, as on the chip: the CPU default is the
-    # interpreter
-    real = attention.pallas_interpret
-    attention.pallas_interpret = lambda: False
+    # interpreter; a Mamba block's kernels are the scan's, whose name its
+    # two backward calls share
+    ssm = "ssm_heads" in sizes
+    kernel = ssd.KERNEL if ssm else "hvd_flash_fwd"
+    backward = 2 if ssm else 0
+    real = attention.pallas_interpret, ssd.pallas_interpret
+    attention.pallas_interpret = ssd.pallas_interpret = lambda: False
     try:
         model, one_kept, calls = compiled(1)
-        assert calls == 3                   # two forward, one run again
+        assert calls == 3 + backward        # two forward, one run again
         _, both_kept, calls = compiled(0)
-        assert calls == 2
+        assert calls == 2 + backward
     finally:
-        attention.pallas_interpret = real
+        attention.pallas_interpret, ssd.pallas_interpret = real
     assert model.block(0).kept_bytes(2 * length, 2048) == reckoned
     counted = both_kept - one_kept
     assert 0.9 * counted <= reckoned <= 1.3 * counted, (reckoned, counted)

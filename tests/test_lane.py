@@ -101,7 +101,7 @@ def test_removed_arguments_are_usage_errors(bench, capsys):
             parser.parse_args(argv)
         assert stop.value.code == 2, argv
         assert "unrecognized arguments" in capsys.readouterr().err, argv
-    assert len(parser._actions) - 1 == 35           # less --help
+    assert len(parser._actions) - 1 == 47           # less --help
 
 
 def _spans(name=None):

@@ -58,7 +58,7 @@ def test_manifest_is_well_formed_and_names_the_cell():
         text = f.read()
     manifest = json.loads(text)
     assert check_manifest.check(manifest, REPO, len(text.encode())) == []
-    assert len(manifest["workloads"]) == 6
+    assert len(manifest["workloads"]) >= 6
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
     manifest, cell, config = run.load_cell(CELL)
     assert cell["chips"] == 1 and cell["bench_args"] == [

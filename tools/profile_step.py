@@ -15,11 +15,12 @@ collective time (an asynchronous collective from its start to its done), the
 part of it during which nothing else ran on the chip and what ran beside the
 rest, the idle gaps by the ``hvd.*`` host span open at their middle, and the
 ``hvd.attn.*`` / ``hvd.moe.*`` / ``hvd.loop.*`` / ``hvd.exit.*`` /
-``hvd.remat.*`` / ``hvd.spmd.*`` gauges of the step's program (attention
-calls by implementation, the kernels' blocks, the expert layers' rows, a
-looped model's applications and the logits its exit loss holds, the block
-applications recomputed and what the kept ones were reckoned to hold, the
-compiler options the handle passed).
+``hvd.remat.*`` / ``hvd.spmd.*`` / ``hvd.ssd.*`` gauges of the step's
+program (attention calls by implementation, the kernels' blocks, the expert
+layers' rows, a looped model's applications and the logits its exit loss
+holds, the block applications recomputed and what the kept ones were
+reckoned to hold, the compiler options the handle passed, the scan's forward
+calls and the chunk states they write).
 The platform must be ``tpu`` (``HVD_TPU_FORCE_CPU=1`` runs the same code on
 a virtual CPU mesh, whose profile holds no chip: nothing is printed for it).
 """
@@ -107,7 +108,7 @@ def main():
             for name, by_program in sorted(snap["gauges"].items())
             if step in by_program and name.startswith(
                 ("hvd.attn.", "hvd.moe.", "hvd.loop.", "hvd.exit.",
-                 "hvd.remat.", "hvd.spmd."))}
+                 "hvd.remat.", "hvd.spmd.", "hvd.ssd."))}
     if said:
         print(f"  gauges of {step}: {said}")
     if args.json:
