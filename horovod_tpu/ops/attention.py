@@ -21,7 +21,10 @@ and dK/dV pair) executes a PACKED at-or-below-diagonal grid — the
 strictly-masked half of the (q-block,
 k-block) plane never occupies a grid step, so neither its K/V DMA bytes
 nor its loop overhead is paid (closing the traffic debt PERF.md's
-"Streamed-causal K/V traffic tradeoff" recorded). A ``window`` narrows the
+"Streamed-causal K/V traffic tradeoff" recorded), and with the one-kernel
+backward a step computes what the mask lets through: the block the diagonal
+crosses in row slabs that stop at the diagonal, a block it does not cross
+with no mask (``attention_plan(...).slab_rows``). A ``window`` narrows the
 triangle to a band (query i sees keys j with ``0 <= i - j < window``): the
 packed grid then leaves out the blocks below the band as well. K and V may
 have fewer heads than Q (grouped-query attention): query head h reads KV
@@ -315,10 +318,54 @@ def _own_lanes(x, heads: int, h: int):
     return jnp.where(_head_lanes(x.shape, heads, h), x, jnp.zeros_like(x))
 
 
+def _diagonal_slabs(block: int, slab: int, window: Optional[int]):
+    """The slabs of a diagonal block of ``block`` rows and keys walked
+    ``slab`` rows at a time: for slab ``r`` its rows, the block's first ``(r
+    + 1) x slab`` keys (the last key a row of it sees is its own), and
+    ``band()``, the mask of its scores and the first column it covers:
+    ``n (n + 1) / 2`` of the block's ``n^2`` sub-tiles of ``slab x slab``
+    are computed (``n = block / slab``; 10 of 16 at four slabs a block).
+    The slab's older keys are all seen, so its mask covers its own diagonal
+    sub-tile alone, unless a window narrower than the block can leave some
+    of them behind."""
+    whole = window is not None and window < block
+    for r in range(block // slab):
+        keys = (r + 1) * slab
+        first = 0 if whole else r * slab
+
+        def band(r=r, keys=keys, first=first):
+            q_pos = r * slab + jax.lax.broadcasted_iota(
+                jnp.int32, (slab, 1), 0)
+            k_pos = first + jax.lax.broadcasted_iota(
+                jnp.int32, (1, keys - first), 1)
+            return _band_mask(q_pos, k_pos, window), first
+
+        yield slice(r * slab, (r + 1) * slab), slice(0, keys), band
+
+
+def _masked(s, seen, first: int = 0):
+    """Scores ``s`` with ``NEG_INF`` where ``seen``, the mask of their
+    columns from ``first`` on (a multiple of 128 lanes), is False."""
+    if not first:
+        return jnp.where(seen, s, NEG_INF)
+    return jnp.concatenate(
+        [s[:, :first], jnp.where(seen, s[:, first:], NEG_INF)], axis=1)
+
+
+def _interior(qi, kb, block: int, window: Optional[int]):
+    """On the packed grid with square blocks: whether q-block ``qi`` sees all
+    of k-block ``kb`` (its first row the block's last key and, under a
+    window, its last row the block's first), so that no mask is needed."""
+    seen = qi > kb
+    if window is not None:
+        seen &= (qi - kb) * block + block - 1 < window
+    return seen
+
+
 def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
                   scale: float, block_q: int, delta: int, packed: bool,
                   window: Optional[int] = None, shared: bool = False,
-                  heads: int = 1):
+                  heads: int = 1, slab: Optional[int] = None):
     """One streamed-forward grid step. Two grid layouts share this body:
 
     * full (``packed=False``) — grid (batch*head, q-block, K-BLOCK): the
@@ -346,6 +393,15 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
     over the same tiles and the same mask: the head's scores are ``(q with
     the other head's lanes zeroed) k^T``, the MXU pass a 64-wide contraction
     costs, and of ``p v`` the head keeps its own lanes of the accumulator.
+
+    ``slab`` (packed square grids only; :func:`attention_plan`) makes a
+    step compute what the causal edge lets through: the diagonal block is
+    walked in row slabs (:func:`_diagonal_slabs`), slab ``r`` updating its
+    own rows of ``m``, ``l`` and ``acc`` from its scores against the block's
+    first ``(r + 1) x slab`` keys under its own mask, and a block the edge
+    does not cross (:func:`_interior`) applies no mask. Under a window the
+    blocks its lower edge crosses keep the whole masked body, which is every
+    step's body where ``slab`` is None.
 
     VMEM is O(block) — the pre-streaming design mapped the FULL [Lk, d]
     K/V into each program's VMEM, which hit the 16 MB scoped limit at
@@ -385,7 +441,13 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def _compute():
+    def _update(q_ref, k_ref, ks_ref, v_ref, acc_scr, band, rows=None):
+        """The online-softmax step of the rows ``q_ref`` holds (``acc``
+        holds of them, and ``rows`` of ``m`` and ``l``: all where None)
+        against the keys ``k_ref`` holds: the whole block's refs, or views
+        of a slab's. ``band()`` is the mask of their scores and the first
+        column it covers (:func:`_masked`), ``None`` where they see every
+        key."""
         # All softmax statistics stay f32 whatever the inputs' type.
         q = q_ref[...]                              # [block_q, dk]
         k_blk = k_ref[...]                          # [block_k, dk]
@@ -393,15 +455,13 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
         seen = None
         for h in range(heads):
             at = (h,) if heads > 1 else (Ellipsis,)     # head h's statistics
+            if rows is not None:
+                at = (h, rows) if heads > 1 else (rows,)
             s = _block_scores(_own_lanes(q, heads, h), k_blk, ks_ref, scale)
-            if causal:
+            if band is not None:
                 if seen is None:            # one mask for the tile's heads
-                    q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 0)
-                    k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1)
-                    seen = _band_mask(q_pos, k_pos, window)
-                s = jnp.where(seen, s, NEG_INF)
+                    seen, first = band()
+                s = _masked(s, seen, first)
             m = m_scr[at]
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -417,14 +477,36 @@ def _flash_kernel(*refs, block_k: int, n_kblocks: int, causal: bool,
                                 acc_scr[...])
             acc_scr[...] = acc
 
+    def _band():
+        q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return _band_mask(q_pos, k_pos, window), 0
+
+    def _compute(band=_band if causal else None):
+        _update(q_ref, k_ref, ks_ref, v_ref, acc_scr, band)
+
     if causal and not packed:
         # A k-block strictly past this q-block's last row (or wholly
         # older than its window) is fully masked: skip its compute (its
         # DMA is pipelined regardless).
         pl.when(_block_live(qi, kb, block_q, block_k, delta,
                             window))(_compute)
-    else:
+    elif slab is None:
         _compute()  # packed grids enumerate live steps only
+    else:
+        @pl.when(qi == kb)
+        def _diagonal():
+            for rows, keys, band in _diagonal_slabs(block_q, slab, window):
+                _update(q_ref.at[rows], k_ref.at[keys],
+                        None if ks_ref is None else ks_ref.at[keys],
+                        v_ref.at[keys], acc_scr.at[rows], band, rows)
+
+        interior = _interior(qi, kb, block_q, window)
+        pl.when(interior)(functools.partial(_compute, None))
+        if window is not None:      # the blocks the window's edge crosses
+            pl.when((qi != kb) & ~interior)(_compute)
 
     if packed:
         last_kb = jnp.minimum(n_kblocks - 1,
@@ -491,6 +573,7 @@ class AttentionPlan(NamedTuple):
     block_k: Optional[int]      # block divides a length (then ``dense``)
     bwd: str                    # the kernels' backward: "fused" | "pallas"
     heads_per_program: int = 1  # heads a kernel program serves: 1 | 2
+    slab_rows: Optional[int] = None     # a diagonal block's row slabs
 
 
 # The policy's constants, each from ``tools/tpu_flash_check.py
@@ -525,6 +608,14 @@ class AttentionPlan(NamedTuple):
 # around them, 0.16, is the three gradients laid side by side. That is
 # :func:`_planned_heads`' one measurement; dense there: 1.32 and 3.96.
 FLASH_BLOCK = 1024
+# Rows of the slabs a diagonal block is walked in (:func:`_planned_slab`): a
+# 1,024 block's causal half computed as 4 slabs of 256 rows against 256, 512,
+# 768 and 1,024 keys, 10 of its 16 sub-tiles. On one v5e (chip run of PR 39,
+# a traced step; PERF.md section 6) GPT-2-medium's two kernels took 29.3 ms
+# a step at 1,024 keys against 38.8 with every block whole (``hvd_flash_bwd``
+# 16.63 against 25.05, ``hvd_flash_fwd`` 12.69 against 13.75), and 82.8 against
+# 92.7 at 4,096 keys.
+FLASH_SLAB = 256
 # Smallest block the kernels are chosen at: at 256 x 256 they lose to dense
 # at 1,024 keys (4.38 against 3.95) and win by 2 to 5% at 2,048 and 4,096.
 FLASH_MIN_BLOCK = 512
@@ -592,6 +683,28 @@ def _planned_bwd(seq_q: int, key_width: int, dtype,
     return "fused" if fits else FLASH_BWD
 
 
+def _planned_slab(block_q: int, block_k: int, bwd: str, packed: bool,
+                  pin: Optional[int] = None) -> Optional[int]:
+    """The rows of a diagonal block's slabs (:func:`_diagonal_slabs`): the
+    caller's ``pin`` (0: none), or :data:`FLASH_SLAB` where the kernels walk
+    the packed causal grid (``packed``) with square blocks of two slabs or
+    more and the one-kernel backward. ``None`` elsewhere: every step computes
+    its whole square, masked where the mask is causal (the split's kernels
+    and the scan keep that body, and are the slabs' references in tests)."""
+    rows = FLASH_SLAB if pin is None else pin
+    if not rows:
+        return None
+    if (packed and bwd == "fused" and block_q == block_k
+            and block_q % rows == 0 and block_q >= 2 * rows):
+        return rows
+    if pin is not None:
+        raise ValueError(
+            f"row slabs of {pin} need the packed causal grid, square blocks "
+            f"of two slabs or more and the one-kernel backward (packed="
+            f"{packed}, blocks {block_q} x {block_k}, bwd_impl={bwd!r})")
+    return None
+
+
 def _planned_heads(seq_q: int, seq_k: int, heads: int, kv_heads: int,
                    head_dim, bwd: str, shared_key: bool = False,
                    q_offset: int = 0) -> int:
@@ -630,6 +743,13 @@ def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
     lengths alone; the other arguments are what a later measurement may key
     on without a new call site.
 
+    ``slab_rows`` is how the kernels walk a diagonal block
+    (:func:`_planned_slab`): in slabs of that many rows, each against the
+    keys up to its own last row, with no mask on a block the causal edge
+    does not cross; ``None`` (offsets, rectangular calls or blocks, blocks
+    of fewer than two slabs, the split backward) computes every block's
+    whole square under the mask.
+
     ``heads_per_program`` is the kernels' layout (:func:`_planned_heads`):
     at heads of 64 the ``[B, L, H, 64]`` to ``[B x H, L, 64]`` transposes
     around one-head programs are copies XLA folds nowhere (a head is half a
@@ -653,8 +773,11 @@ def attention_plan(seq_q: int, seq_k: int, heads: int, kv_heads: int,
         backend = jax.default_backend()
     flash = (backend == "tpu" and seq_k >= FLASH_MIN_KEYS
              and min(block_q, block_k) >= FLASH_MIN_BLOCK)
+    slab = _planned_slab(block_q, block_k, bwd,
+                         _grid_truncates(True, seq_q, seq_k, q_offset, 0,
+                                         None))
     return AttentionPlan("flash" if flash else "dense", block_q, block_k,
-                         bwd, paired)
+                         bwd, paired, slab)
 
 
 def _planned_blocks(seq_q: int, seq_k: int, block_q: Optional[int],
@@ -691,13 +814,14 @@ def attend(q, k=None, v=None, *, heads: Optional[int] = None,
     is split here. :func:`attention_plan` picks the implementation and the
     heads a program from the shapes unless ``impl`` (``"dense"`` |
     ``"flash"``) pins the first;
-    ``flash_args`` go to :func:`flash_attention` (an A/B's ``truncate`` and
-    ``bwd_impl``). Sets the gauges ``hvd.attn.flash_calls`` /
+    ``flash_args`` go to :func:`flash_attention` (an A/B's ``truncate``,
+    ``bwd_impl`` and ``slab``). Sets the gauges ``hvd.attn.flash_calls`` /
     ``.dense_calls`` (attention calls traced into the step's program, by
     implementation), ``.fused_bwd_calls`` (those of the kernels' calls whose
     backward is the one kernel), ``.paired_calls`` (those whose programs
-    serve two heads) and ``.block_q`` / ``.block_k`` (the kernels'
-    blocks)."""
+    serve two heads), ``.diagonal_slab_calls`` (those whose kernels walk the
+    diagonal blocks in row slabs), ``.block_q`` / ``.block_k`` (the
+    kernels' blocks) and ``.slab_rows`` (the slabs' rows, 0 for none)."""
     whole_projection = k is None
     if whole_projection:
         if heads is None or v is not None or q.shape[-1] % (3 * heads):
@@ -719,19 +843,28 @@ def attend(q, k=None, v=None, *, heads: Optional[int] = None,
     paired = impl == "flash" and _planned_heads(
         seq, seq, heads, kv_heads, widths, bwd, k_shared is not None,
         q_offset) == 2
-    program, calls = timeline.program_tally(
-        _traced, lambda: {"flash": 0, "dense": 0, "fused_bwd": 0,
-                          "paired": 0})
-    calls[impl] += 1
-    calls["fused_bwd"] += impl == "flash" and bwd == "fused"
-    calls["paired"] += paired
-    for name, n in calls.items():
-        timeline.gauge(f"hvd.attn.{name}_calls", n, key=program)
+    slab = None
     if impl == "flash":
         block_q, block_k = _planned_blocks(
             seq, seq, flash_args.get("block_q"), flash_args.get("block_k"))
+        slab = _planned_slab(
+            min(block_q, seq), min(block_k, seq), bwd,
+            _grid_truncates(True, seq, seq, q_offset, 0,
+                            flash_args.get("truncate")),
+            flash_args.get("slab"))
+    program, calls = timeline.program_tally(
+        _traced, lambda: {"flash": 0, "dense": 0, "fused_bwd": 0,
+                          "paired": 0, "diagonal_slab": 0})
+    calls[impl] += 1
+    calls["fused_bwd"] += impl == "flash" and bwd == "fused"
+    calls["paired"] += paired
+    calls["diagonal_slab"] += slab is not None
+    for name, n in calls.items():
+        timeline.gauge(f"hvd.attn.{name}_calls", n, key=program)
+    if impl == "flash":
         timeline.gauge("hvd.attn.block_q", block_q, key=program)
         timeline.gauge("hvd.attn.block_k", block_k, key=program)
+        timeline.gauge("hvd.attn.slab_rows", slab or 0, key=program)
     if paired:          # the projections' layout, read where it lies
         operands = (q,) if whole_projection else tuple(
             t.reshape(*t.shape[:2], -1) for t in (q, k, v))
@@ -756,7 +889,7 @@ def attend(q, k=None, v=None, *, heads: Optional[int] = None,
                                              "block_k", "interpret",
                                              "bwd_impl", "q_offset",
                                              "k_offset", "truncate",
-                                             "window", "heads"))
+                                             "window", "heads", "slab"))
 def flash_attention(q, k=None, v=None, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -766,7 +899,8 @@ def flash_attention(q, k=None, v=None, causal: bool = False,
                     q_offset: int = 0, k_offset: int = 0,
                     truncate: Optional[bool] = None,
                     window: Optional[int] = None, k_shared=None,
-                    heads: Optional[int] = None):
+                    heads: Optional[int] = None,
+                    slab: Optional[int] = None):
     """Pallas flash attention. Shapes q [B, L, H, Dk], k [B, L, G, Dk],
     v [B, L, G, Dv] -> [B, L, H, Dv]; ``G`` divides ``H`` and query head h
     reads KV head ``h // (H / G)`` (grouped-query attention; K and V are
@@ -797,14 +931,17 @@ def flash_attention(q, k=None, v=None, causal: bool = False,
 
     ``window`` (static; plain causal square attention only) lets query i
     see keys j with ``0 <= i - j < window``: the mask is applied inside
-    every live block (those the band's edges do not cross included), and
-    blocks wholly outside the band never occupy a step of the packed grid
-    (or skip their compute on the full one).
+    every live block the band's edges cross (and in every live block where
+    the plan answers no slab), and blocks wholly outside the band never
+    occupy a step of the packed grid (or skip their compute on the full
+    one).
 
     Sequence lengths must be multiples of the block sizes (pad upstream).
-    Block sizes and the backward (``bwd_impl`` None or ``"auto"``) default
-    to :func:`attention_plan`'s, measured on the v5e; pass explicit values
-    to override (``"fused"`` | ``"pallas"``, the split | ``"scan"``).
+    Block sizes, the backward (``bwd_impl`` None or ``"auto"``) and the rows
+    of a diagonal block's slabs (``slab`` None) default to
+    :func:`attention_plan`'s, measured on the v5e; pass explicit values to
+    override (``"fused"`` | ``"pallas"``, the split | ``"scan"``; ``slab``
+    0 for none: every block's whole square under the mask).
     ``interpret`` defaults to the platform's: compiled on a TPU,
     interpreted on the CPU test platform.
 
@@ -893,18 +1030,23 @@ def flash_attention(q, k=None, v=None, causal: bool = False,
                 f"could be left with no key at all")
         if window >= seq_k:
             window = None              # the band is the whole triangle
+    slab = _planned_slab(
+        min(block_q, q.shape[1]), min(block_k, seq_k), bwd_impl,
+        _grid_truncates(causal, q.shape[1], seq_k, q_offset, k_offset,
+                        truncate), slab)
     return _flash(q, k, v, k_shared, causal, float(scale), block_q, block_k,
                   interpret, bwd_impl, int(q_offset), int(k_offset),
-                  truncate, window, heads)
+                  truncate, window, heads, slab)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15))
 def _flash(q, k, v, k_shared, causal, scale, block_q, block_k, interpret,
-           bwd_impl, q_offset, k_offset, truncate, window=None, heads=None):
+           bwd_impl, q_offset, k_offset, truncate, window=None, heads=None,
+           slab=None):
     out, _ = _flash_forward(q, k, v, k_shared, causal, scale, block_q,
                             block_k, interpret, q_offset, k_offset, truncate,
-                            window, heads)
+                            window, heads, slab)
     return out
 
 
@@ -982,7 +1124,7 @@ def _block_specs(block_q: int, block_k: int, seq_q: int, at_q, at_k,
 
 def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
                    interpret, q_offset=0, k_offset=0, truncate=None,
-                   window=None, heads=None):
+                   window=None, heads=None, slab=None):
     """Returns (out, lse [B, H, Lq]). One head a program (``heads`` None): q
     ``[B, Lq, H, D]``, out ``[B, Lq, H, Dv]``; the operands are transposed
     to ``[B x H, L, D]`` around the call. Two heads a program (``heads`` the
@@ -1036,7 +1178,8 @@ def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, n_kblocks=n_kblocks, causal=causal,
         scale=scale, block_q=block_q, delta=0 if truncated else delta,
-        packed=truncated, window=window, shared=shared, heads=per)
+        packed=truncated, window=window, shared=shared, heads=per,
+        slab=slab)
     if truncated:
         at_q = lambda bh, t, qi, kb: qi[t]                  # noqa: E731
         at_k = lambda bh, t, qi, kb: kb[t]                  # noqa: E731
@@ -1103,10 +1246,10 @@ def _flash_forward(q, k, v, k_shared, causal, scale, block_q, block_k,
 
 def _flash_fwd_vjp(q, k, v, k_shared, causal, scale, block_q, block_k,
                    interpret, bwd_impl, q_offset, k_offset, truncate,
-                   window=None, heads=None):
+                   window=None, heads=None, slab=None):
     o, lse = _flash_forward(q, k, v, k_shared, causal, scale, block_q,
                             block_k, interpret, q_offset, k_offset, truncate,
-                            window, heads)
+                            window, heads, slab)
     return o, (q, k, v, k_shared, o, lse)
 
 
@@ -1185,7 +1328,8 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                           block_k: int, n_qblocks: int, delta: int,
                           packed: bool, window: Optional[int] = None,
                           shared: bool = False, fused: bool = False,
-                          n_kblocks: int = 0, heads: int = 1):
+                          n_kblocks: int = 0, heads: int = 1,
+                          slab: Optional[int] = None):
     """dK/dV: full grid (batch*head, k-block, Q-BLOCK stream) or the
     packed K-MAJOR causal grid — transposing the dQ kernel's roles, so
     the truncated region is the symmetric above-diagonal half over the
@@ -1213,7 +1357,13 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
     is then no column of ``D = rowsum(dO . O)`` but the rows of ``O`` where
     the forward wrote them, and the kernel sums ``dO . O`` over a head's
     lanes itself: outside a kernel a sum over 64 of a row's columns is a
-    relayout of the float32 products."""
+    relayout of the float32 products.
+
+    ``slab`` (with ``fused``) is :func:`_flash_kernel`'s: in the diagonal
+    pair slab ``r`` of the q-block adds ``p_r^T dO_r`` and ``ds_r^T q_r``
+    into the k-block's first ``(r + 1) x slab`` rows of ``dV`` and ``dK``
+    and ``ds_r k`` into its own rows of ``dQ``; a pair the causal edge does
+    not cross applies no mask."""
     from jax.experimental import pallas as pl
 
     if packed:
@@ -1260,32 +1410,41 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
             dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[-1]),
                                         jnp.float32)
 
-    def _compute():
+    def _pair(q_ref, k_ref, ks_ref, v_ref, do_ref, dk_scr, dks_scr, dv_scr,
+              rows, band, part=None):
+        """The products of the query rows ``q_ref`` holds (``rows`` of dQ;
+        ``part`` of the q-block's statistics, all where None) against the
+        keys ``k_ref`` holds (the rows of ``dk_scr``, ``dks_scr`` and
+        ``dv_scr`` given): the whole pair's refs, or views of a slab's.
+        ``band()`` is the mask of their scores and the first column it
+        covers (:func:`_masked`), ``None`` where they see every key."""
         # Input-dtype matmuls, f32 accumulation (see _block_scores).
         q = q_ref[...]
         k_blk = k_ref[...]
         v_blk = v_ref[...]
         do_blk = do_ref[...]
+
+        def d_rows():
+            return d_ref[...] if part is None else d_ref[part, :]
+
         seen = None
         if heads > 1:       # dO . O, a head's lanes of which sum to its D
-            do_o = do_blk.astype(jnp.float32) * d_ref[...].astype(jnp.float32)
+            do_o = do_blk.astype(jnp.float32) * d_rows().astype(jnp.float32)
         for h in range(heads):
             at = (h,) if heads > 1 else (Ellipsis,)     # head h's statistics
+            if part is not None:
+                at = (h, part) if heads > 1 else (part,)
             q_h, do_h = _own_lanes(q, heads, h), _own_lanes(do_blk, heads, h)
             s = _block_scores(q_h, k_blk, ks_ref, scale)
-            if causal:
+            if band is not None:
                 if seen is None:            # one mask for the tile's heads
-                    q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 0)
-                    k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1)
-                    seen = _band_mask(q_pos, k_pos, window)
-                s = jnp.where(seen, s, NEG_INF)
+                    seen, first = band()
+                s = _masked(s, seen, first)
             p = jnp.exp(s - lse_ref[at])                     # [bq, bk]
             dv_scr[...] += jnp.dot(p.T.astype(do_blk.dtype), do_h,
                                    preferred_element_type=jnp.float32)
             dp = jnp.dot(do_h, v_blk.T, preferred_element_type=jnp.float32)
-            d = d_ref[...] if heads == 1 else jnp.sum(
+            d = d_rows() if heads == 1 else jnp.sum(
                 _own_lanes(do_o, heads, h), axis=-1, keepdims=True)
             ds = p * (dp - d)
             if ks_ref is None:
@@ -1314,13 +1473,43 @@ def _flash_bwd_dkv_kernel(*refs, causal: bool, scale: float, block_q: int,
                     ds, ks_ref[...],
                     preferred_element_type=jnp.float32) * scale
 
+    def _band():
+        q_pos = delta + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return _band_mask(q_pos, k_pos, window), 0
+
+    def _compute(band=_band if causal else None):
+        _pair(q_ref, k_ref, ks_ref, v_ref, do_ref, dk_scr,
+              dks_scr if shared else None, dv_scr, rows if fused else None,
+              band)
+
     if causal and not packed:
         # Q-blocks fully ABOVE the diagonal (every q_pos < every k_pos),
         # or wholly past the window, contribute nothing to this k-block.
         pl.when(_block_live(qi, kb, block_q, block_k, delta,
                             window))(_compute)
-    else:
+    elif slab is None:
         _compute()  # packed grids enumerate live steps only
+    else:
+        assert fused, "row slabs: the one-kernel backward only"
+
+        @pl.when(qi == kb)
+        def _diagonal():
+            for part, keys, band in _diagonal_slabs(block_q, slab, window):
+                q_rows = pl.ds(pl.multiple_of(
+                    qi * block_q + part.start, slab), slab)
+                _pair(q_ref.at[part], k_ref.at[keys],
+                      None if ks_ref is None else ks_ref.at[keys],
+                      v_ref.at[keys], do_ref.at[part], dk_scr.at[keys],
+                      dks_scr.at[keys] if shared else None, dv_scr.at[keys],
+                      q_rows, band, part)
+
+        interior = _interior(qi, kb, block_q, window)
+        pl.when(interior)(functools.partial(_compute, None))
+        if window is not None:      # the blocks the window's edge crosses
+            pl.when((qi != kb) & ~interior)(_compute)
 
     @pl.when(qi == last_qi)
     def _finalize():
@@ -1415,7 +1604,7 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret,
 
 def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
                       q_offset, k_offset, truncate, window, res, do,
-                      fused=False, heads=None):
+                      fused=False, heads=None, slab=None):
     """Flash backward as one Pallas kernel (``fused``: dQ, dK and dV from
     one walk over the k-major grid, dQ summed in a float32 ``[Lq, Dk]``
     scratch that stays in VMEM a (batch, head) program; see
@@ -1495,7 +1684,7 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
         _flash_bwd_dkv_kernel, causal=causal, scale=scale, block_q=bq,
         block_k=bk, n_qblocks=nqb, delta=0 if truncated else delta,
         packed=truncated, window=window, shared=shared, fused=fused,
-        n_kblocks=nkb, heads=per)
+        n_kblocks=nkb, heads=per, slab=slab)
     dq_out_shape = jax.ShapeDtypeStruct(gradient[0], q.dtype)
     dkv_dtype = jnp.float32 if rep > 1 else k.dtype    # a group's are summed
     # dK, the shared key's gradient a query head (float32: summed over the
@@ -1596,14 +1785,15 @@ def _flash_bwd_pallas(causal, scale, block_q, block_k, interpret,
 
 
 def _flash_bwd_vjp(causal, scale, block_q, block_k, interpret, bwd_impl,
-                   q_offset, k_offset, truncate, window, heads, res, do):
+                   q_offset, k_offset, truncate, window, heads, slab, res,
+                   do):
     """``bwd_impl`` arrives resolved ("scan" | "pallas" | "fused") from
     flash_attention: part of the trace key, so the selection can never
-    desync from a cached trace. ``heads`` (two heads a program) comes with
-    ``"fused"`` alone."""
+    desync from a cached trace. ``heads`` (two heads a program) and
+    ``slab`` (row slabs on the diagonal) come with ``"fused"`` alone."""
     fn = {"scan": _flash_bwd_scan, "pallas": _flash_bwd_pallas,
           "fused": functools.partial(_flash_bwd_pallas, fused=True,
-                                     heads=heads)}[bwd_impl]
+                                     heads=heads, slab=slab)}[bwd_impl]
     return fn(causal, scale, block_q, block_k, interpret,
               q_offset, k_offset, truncate, window, res, do)
 

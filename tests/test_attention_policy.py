@@ -19,6 +19,7 @@ import pytest
 from horovod_tpu.ops import attention
 from horovod_tpu.ops.attention import (
     FLASH_BWD,
+    FLASH_SLAB,
     AttentionPlan,
     attend,
     attention_plan,
@@ -28,42 +29,55 @@ from horovod_tpu.ops.attention import (
 )
 
 BF16, F32 = jnp.bfloat16, jnp.float32
-# (Lq, Lk, heads, KV heads, head width, window, dtype, backend) -> plan; the
-# last field, the heads a kernel program serves, is 1 where none is written
+
+
+def _slabs(block):
+    """The plan's slab rows for square blocks of ``block`` on the packed
+    path with the one-kernel backward: two slabs or more, or none."""
+    return FLASH_SLAB if block >= 2 * FLASH_SLAB else None
+
+
+# (Lq, Lk, heads, KV heads, head width, window, dtype, backend) -> plan: the
+# implementation, blocks, backward, heads a kernel program serves and the
+# rows of a diagonal block's slabs
 TABLE = {
     "gpt2_medium_cell": ((1024, 1024, 16, 16, 64, None, BF16, "tpu"),
-                         ("flash", 1024, 1024, "fused", 2)),
+                         ("flash", 1024, 1024, "fused", 2, _slabs(1024))),
     "trinity_sliding_layer": ((4096, 4096, 32, 4, 128, 2048, BF16, "tpu"),
-                              ("flash", 1024, 1024, "fused")),
+                              ("flash", 1024, 1024, "fused", 1,
+                               _slabs(1024))),
     "trinity_full_layer": ((4096, 4096, 32, 4, 128, None, BF16, "tpu"),
-                           ("flash", 1024, 1024, "fused")),
+                           ("flash", 1024, 1024, "fused", 1, _slabs(1024))),
     "heads_of_64_at_2048": ((2048, 2048, 16, 16, 64, None, BF16, "tpu"),
-                            ("flash", 1024, 1024, "fused", 2)),
+                            ("flash", 1024, 1024, "fused", 2, _slabs(1024))),
     "float32_inputs": ((4096, 4096, 8, 8, 64, None, F32, "tpu"),
-                       ("flash", 1024, 1024, "fused", 2)),
+                       ("flash", 1024, 1024, "fused", 2, _slabs(1024))),
     "blocks_of_512_divide": ((1536, 1536, 16, 16, 64, None, BF16, "tpu"),
-                             ("flash", 512, 512, "fused", 2)),
+                             ("flash", 512, 512, "fused", 2, _slabs(512))),
     # the layout is the shapes' alone, as the backward is: a dense answer
-    # names it too
+    # names it too, and the slabs
     "only_256_divides": ((1280, 1280, 16, 16, 64, None, BF16, "tpu"),
-                         ("dense", 256, 256, "fused", 2)),
+                         ("dense", 256, 256, "fused", 2, _slabs(256))),
     "rectangular": ((512, 768, 4, 4, 64, None, BF16, "tpu"),
-                    ("dense", 512, 256, "fused")),
+                    ("dense", 512, 256, "fused", 1, None)),
     "below_the_measured_lengths": ((512, 512, 16, 16, 64, None, BF16, "tpu"),
-                                   ("dense", 512, 512, "fused", 2)),
+                                   ("dense", 512, 512, "fused", 2,
+                                    _slabs(512))),
     "no_block_divides": ((100, 100, 4, 4, 64, None, BF16, "tpu"),
-                         ("dense", None, None, "fused", 2)),
+                         ("dense", None, None, "fused", 2, None)),
     "cpu_backend": ((1024, 1024, 16, 16, 64, None, BF16, "cpu"),
-                    ("dense", 1024, 1024, "fused", 2)),
+                    ("dense", 1024, 1024, "fused", 2, _slabs(1024))),
     "this_platform": ((4096, 4096, 32, 4, 128, 2048, BF16, None),
-                      ("dense", 1024, 1024, "fused")),
+                      ("dense", 1024, 1024, "fused", 1, _slabs(1024))),
     # the one-kernel backward's resident dQ: 65,536 queries of 128 are the
-    # budget, a Ulysses shard of 131,072 is past it
+    # budget, a Ulysses shard of 131,072 is past it (and its split keeps
+    # every block's whole square)
     "longest_side_measured": ((65536, 65536, 2, 2, 128, None, BF16, "tpu"),
-                              ("flash", 1024, 1024, "fused")),
+                              ("flash", 1024, 1024, "fused", 1,
+                               _slabs(1024))),
     "query_side_past_the_budget": ((131072, 131072, 2, 2, 128, None, BF16,
                                     "tpu"),
-                                   ("flash", 1024, 1024, "pallas")),
+                                   ("flash", 1024, 1024, "pallas", 1, None)),
 }
 
 
@@ -192,7 +206,7 @@ def test_attend_counts_the_calls_whose_programs_serve_two_heads(
            for name, by_program in gauges.items()
            if name.startswith("hvd.attn.") and name.endswith("_calls")}
     assert got == {"flash_calls": 4, "dense_calls": 2, "fused_bwd_calls": 3,
-                   "paired_calls": 2}
+                   "paired_calls": 2, "diagonal_slab_calls": 0}
     with pytest.raises(ValueError, match="fused projection"):
         attend(jnp.ones((1, 32, 3 * 2 * 64)))           # heads not said
     timeline.reset()
@@ -305,6 +319,7 @@ def test_on_a_tpu_attend_traces_the_kernels_but_not_under_an_offset(
 
 FUSED_CALLS = "flash_fused_bwd_calls_per_step.tok"
 PAIRED_CALLS = "flash_paired_calls_per_step.tok"
+SLAB_CALLS = "flash_diagonal_slab_calls_per_step.tok"
 LANGUAGE_CELLS = ("gpt2m_seq1024_1chip", "gpt2m_seq1024_dp4",
                   "trinity_mini_seq4096_1chip", "ouro_seq4096_1chip",
                   "moonlight_seq8192_1chip", "gpt2m_seq4096_flash_1chip")
@@ -313,10 +328,12 @@ CALL_COUNTERS = {
     FUSED_CALLS: ("hvd.attn.fused_bwd_calls", LANGUAGE_CELLS),
     PAIRED_CALLS: ("hvd.attn.paired_calls",
                    LANGUAGE_CELLS[:2] + LANGUAGE_CELLS[-1:]),
+    SLAB_CALLS: ("hvd.attn.diagonal_slab_calls",
+                 LANGUAGE_CELLS + ("granite_h_micro_seq16384_1chip",)),
 }
 
 
-# the state-space cell runs the kernels too, and lists no flash metric
+# the state-space cell runs the kernels too, and lists the slab counter alone
 @pytest.mark.parametrize("cell", LANGUAGE_CELLS + (
     "resnet50_bs128_1chip", "granite_h_micro_seq16384_1chip"))
 @pytest.mark.parametrize("metric", sorted(CALL_COUNTERS))
@@ -330,7 +347,10 @@ def test_the_kernels_call_counters_are_metrics_of_their_cells(metric, cell):
     ``flash_paired_calls_per_step.tok`` is listed by the three cells whose
     heads are 64 wide, one a KV head, and reads ``hvd.attn.paired_calls``
     the same way: nothing at the parent of PR 37 or where every call runs
-    one head a program."""
+    one head a program. ``flash_diagonal_slab_calls_per_step.tok`` is
+    listed by all seven cells that train through the kernels and reads
+    ``hvd.attn.diagonal_slab_calls``: nothing at the parent of PR 39 or
+    where no call walks its diagonal in slabs."""
     import json
     import os
     import sys

@@ -297,10 +297,11 @@ def test_gpt2_cell_attention_compiles_alone_and_under_four_chips(topo):
     from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.ops.attention import attention_plan, flash_attention
+    from horovod_tpu.ops.attention import (FLASH_SLAB, attention_plan,
+                                           flash_attention)
 
     plan = attention_plan(1024, 1024, 16, 16, 64, backend="tpu")
-    assert plan == ("flash", 1024, 1024, "fused", 2)
+    assert plan == ("flash", 1024, 1024, "fused", 2, FLASH_SLAB)
 
     def grads(qkv):
         return jax.grad(lambda x: flash_attention(

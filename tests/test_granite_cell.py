@@ -131,8 +131,10 @@ def test_manifest_is_well_formed_and_names_both_cells():
     assert {"step_mfu_pct.tok", "device_step_ms.tok", "peak_hbm_gib.tok",
             "device_idle_pct.tok", "setup_lane_build_s",
             "recomputed_applications_per_step.tok"} <= reported
-    # it joins none of the flash or expert lists
-    assert not {m for m in reported if m.startswith(("flash", "moe", "mla"))}
+    # of the flash or expert lists it joins the slab counter's alone (its
+    # attention layer walks its diagonal blocks in slabs)
+    assert {m for m in reported if m.startswith(("flash", "moe", "mla"))} \
+        == {"flash_diagonal_slab_calls_per_step.tok"}
     assert {m["name"] for m in run.metrics_of(manifest, CELL_NAME,
                                               "end_to_end")} \
         == {"tok_per_s_per_chip", "setup_s"}

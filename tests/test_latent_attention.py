@@ -39,6 +39,7 @@ from benchmarks import compare, run  # noqa: E402
 from benchmarks.reference import common, moonlight  # noqa: E402
 from horovod_tpu.models import decoder  # noqa: E402
 from horovod_tpu.ops.attention import (  # noqa: E402
+    FLASH_SLAB,
     attend,
     attention_plan,
     dot_product_attention,
@@ -262,9 +263,10 @@ def test_the_plan_takes_the_two_widths():
     at 192 beside 128 chose the blocks it had chosen at 64 and 128, so the
     answer is the lengths' alone."""
     assert attention_plan(4096, 4096, 32, 4, 128, 2048, backend="tpu") \
-        == ("flash", 1024, 1024, "fused", 1)
+        == ("flash", 1024, 1024, "fused", 1, FLASH_SLAB)
     assert attention_plan(8192, 8192, 16, 16, (192, 128), backend="tpu",
                           shared_key=True) \
-        == ("flash", 1024, 1024, "fused", 1)    # one head a program
+        == ("flash", 1024, 1024, "fused", 1,    # one head a program
+            FLASH_SLAB)
     assert attention_plan(8192, 8192, 16, 16, (192, 128),
                           backend="cpu").impl == "dense"

@@ -408,10 +408,12 @@ def test_the_lm_step_says_which_attention_it_traced(hvd, monkeypatch,
     if pinned == "flash":
         assert got == {"flash_calls": 3, "dense_calls": 0, "block_q": 64,
                        "block_k": 64, "fused_bwd_calls": 3,
-                       "paired_calls": 3}
+                       "paired_calls": 3, "diagonal_slab_calls": 0,
+                       "slab_rows": 0}
     else:
         assert got == {"flash_calls": 0, "dense_calls": 3,
-                       "fused_bwd_calls": 0, "paired_calls": 0}
+                       "fused_bwd_calls": 0, "paired_calls": 0,
+                       "diagonal_slab_calls": 0}
     assert lane.stamp["attention"] == (pinned or "dense")
 
 
@@ -456,7 +458,8 @@ def test_the_looped_step_carries_its_scopes_and_gauges(hvd, monkeypatch):
                    "hvd.exit.live_logits_bytes": 4 * 4 * 15 * 64,
                    "hvd.attn.kv_heads": 2, "hvd.attn.dense_calls": 8,
                    "hvd.attn.flash_calls": 0, "hvd.attn.fused_bwd_calls": 0,
-                   "hvd.attn.paired_calls": 0}
+                   "hvd.attn.paired_calls": 0,
+                   "hvd.attn.diagonal_slab_calls": 0}
 
 
 def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
@@ -505,7 +508,8 @@ def test_the_latent_step_carries_its_scopes_and_its_gauge(hvd, monkeypatch):
     assert got == {"hvd.attn.latent_expanded_bytes":
                    2 * 16 * (2 * (8 + 8) + 4) * 2,
                    "hvd.attn.dense_calls": 2, "hvd.attn.flash_calls": 0,
-                   "hvd.attn.fused_bwd_calls": 0, "hvd.attn.paired_calls": 0}
+                   "hvd.attn.fused_bwd_calls": 0, "hvd.attn.paired_calls": 0,
+                   "hvd.attn.diagonal_slab_calls": 0}
 
 
 def test_windowed_train_step_has_the_same_scopes(hvd):

@@ -18,10 +18,13 @@ attributable to a concrete grid, not just a wall time.
 constants come from (``ops.attention.attention_plan``; PERF.md, PR 29 and
 PR 35): dense against the kernels over blocks and the three backwards (one
 kernel, the dQ / dK+dV split, the scan) at :data:`SWEEP_SHAPES`, each
-kernel's own device time beside the wall times; and, where the plan answers
+kernel's own device time beside the wall times; where the plan answers
 two heads a program (heads of 64), a layer through ``attend`` from the fused
 projection and back, the copies around the kernels included, one head a
-program against two (PR 37).
+program against two (PR 37); and at the plan's blocks and backward the rows
+of a diagonal block's slabs (:data:`SWEEP_SLABS`, ``FLASH_SLAB``), each
+row with its largest difference from the kernels without slabs (PR 39).
+``--slabs-only`` keeps the slab rows alone.
 
 Run on a TPU host:  python tools/tpu_flash_check.py
 """
@@ -35,6 +38,8 @@ import jax.numpy as jnp
 from horovod_tpu.ops.attention import (FLASH_BLOCK, attention_plan,
                                        dot_product_attention,
                                        flash_attention, flash_grid_info)
+
+F32 = jnp.float32
 
 
 def _grid_stamp(seq, heads, head_dim, batch=2, block_q=None, block_k=None,
@@ -105,6 +110,12 @@ def _grad_of(attend, argnums=(0, 1, 2)):
         argnums=argnums))
 
 
+def _max_err(got, want):
+    """The largest absolute difference between two lists of arrays."""
+    return max(float(jnp.max(jnp.abs(a.astype(F32) - b.astype(F32))))
+               for a, b in zip(got, want))
+
+
 def _one_head_a_program(qkv, heads, **kw):
     """What ``attend`` does with a fused projection where the plan answers
     one head a program: split it, give every head its ``[L, D]`` slab (the
@@ -138,33 +149,48 @@ def main():
     print(f"backward max err: {gerr:.2e}", file=sys.stderr)
     assert gerr < 5e-2, gerr
     # Truncated-vs-full parity ON HARDWARE: the causal square default
-    # runs the packed at-or-below-diagonal grid; pin it bit-exact
-    # against the full grid's compute-skip path (interpret-mode CI pins
-    # the same equality, but only the chip runs real Mosaic).
+    # runs the packed at-or-below-diagonal grid; pin it, without row slabs,
+    # bit-exact against the full grid's compute-skip path (interpret-mode
+    # CI pins the same equality, but only the chip runs real Mosaic).
     out_full = flash_attention(q, k, v, causal=True, truncate=False)
-    terr = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
-                                 out_full.astype(jnp.float32))))
+    out_whole = flash_attention(q, k, v, causal=True, slab=0)
+    terr = _max_err([out_whole], [out_full])
     print(f"truncated-vs-full grid max err: {terr:.2e} "
           f"[{_grid_stamp(L, H, D)}]", file=sys.stderr)
     assert terr == 0.0, terr
+    # The diagonal block in row slabs (the plan's, two of 256 in a block of
+    # 512) against its whole masked square, outputs and gradients.
+    # Other sums, so bf16 roundings apart: largest error over largest value.
+    serr = max(_max_err([a], [b]) / float(jnp.max(jnp.abs(b.astype(F32))))
+               for a, b in zip(
+                   (out,) + _grad_of(functools.partial(
+                       flash_attention, causal=True))(q, k, v),
+                   (out_whole,) + _grad_of(functools.partial(
+                       flash_attention, causal=True, slab=0))(q, k, v)))
+    print(f"slabs-vs-whole-diagonal max relative err: {serr:.2e}",
+          file=sys.stderr)
+    assert serr < 2e-2, serr
     # The one-kernel backward against the split ON HARDWARE: the same
-    # products summed in the same order, at four blocks a side so that the
-    # resident dQ rows are revisited.
+    # products summed in the same order (the diagonal blocks whole, as the
+    # split computes them), at four blocks a side so that the resident dQ
+    # rows are revisited.
     qkv4 = [jax.random.normal(jax.random.fold_in(key, 5 + i),
                               (1, 2048, 2, D), jnp.bfloat16) for i in range(3)]
     fused, split = (_grad_of(functools.partial(
         flash_attention, causal=True, block_q=512, block_k=512,
-        bwd_impl=bwd))(*qkv4) for bwd in ("fused", "pallas"))
+        bwd_impl=bwd, **pin))(*qkv4)
+        for bwd, pin in (("fused", dict(slab=0)), ("pallas", {})))
     ferr = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) -
                                      b.astype(jnp.float32))))
                for a, b in zip(fused, split))
     print(f"fused-vs-split backward max err: {ferr:.2e}", file=sys.stderr)
     assert ferr < 1e-2, ferr
     # Two heads a program against one ON HARDWARE, from a fused projection
-    # of 4 heads of 64 at four blocks a side: zeros added to the same sums.
+    # of 4 heads of 64 at four blocks a side: zeros added to the same sums
+    # (the diagonal blocks whole; the sweep holds the slabs to them).
     fused_qkv = jax.random.normal(jax.random.fold_in(key, 9),
                                   (2, 2048, 3 * 4 * 64), jnp.bfloat16)
-    lanes = dict(block_q=512, block_k=512)
+    lanes = dict(block_q=512, block_k=512, slab=0)
     perr = 0.0
     for one, two in (
             (_one_head_a_program(fused_qkv, 4, **lanes),
@@ -187,7 +213,9 @@ def main():
         # flash-vs-dense ladder (the separate flash_check lane owns it
         # — re-paying its 6 timed compiles here would eat the sweep
         # lane's budget). Names after the flag keep those shapes only.
-        block_sweep(key, sys.argv[sys.argv.index("--block-sweep") + 1:])
+        names = [a for a in sys.argv[sys.argv.index("--block-sweep") + 1:]
+                 if not a.startswith("--")]
+        block_sweep(key, names, slabs_only="--slabs-only" in sys.argv)
         return
 
     # Micro A/B: fwd+bwd wall time of one layer, GPT-2-small-ish head
@@ -250,15 +278,22 @@ SWEEP_SHAPES = (
 )
 # f32 scores of one block the kernels are tried at: 1,024 x 1,024
 SWEEP_MAX_SCORES = 1024 * 1024
+# Rows of a diagonal block's slabs, at the plan's blocks: 0 is none (every
+# block's whole square under the mask)
+SWEEP_SLABS = (0, 128, 256, 512)
 
 
-def block_sweep(key, only=None):
+def block_sweep(key, only=None, slabs_only=False):
     """Forward and forward + backward ms of ONE attention layer, dense
     against the flash kernels over blocks and the three backwards, at
-    :data:`SWEEP_SHAPES` (``only``: names to keep); for the kernels also
-    each one's device ms a call (``kernels_ms``). One JSON line a
-    measurement on standard output and in ``chiprun_out/flash_sweep.jsonl``;
-    the last line names the best of every shape."""
+    :data:`SWEEP_SHAPES` (``only``: names to keep), and at the plan's blocks
+    and backward over the rows of a diagonal block's slabs
+    (:data:`SWEEP_SLABS`; ``slabs_only`` keeps those rows alone); for the
+    kernels also each one's device ms a call (``kernels_ms``), for a slab
+    row the largest difference of its output and gradients from the row
+    without slabs (``max_err_vs_no_slab``). One JSON line a measurement on
+    standard output and in ``chiprun_out/flash_sweep.jsonl``; the last line
+    names the best of every shape."""
     import json
     import os
 
@@ -267,18 +302,27 @@ def block_sweep(key, only=None):
     os.makedirs(out_dir, exist_ok=True)
     rows = []
 
-    def measure(shape_name, label, attend, qkv, **stamp):
+    def measure(shape_name, label, attend, qkv, no_slab=None, **stamp):
+        """One row; a slab row returns its output and gradients, and
+        compares them with ``no_slab``'s where given."""
         row = dict(shape=shape_name, impl=label, **stamp)
+        got = None
         try:
-            row["fwd_ms"] = 1e3 * _time(jax.jit(attend), *qkv)
+            fwd = jax.jit(attend)
+            row["fwd_ms"] = 1e3 * _time(fwd, *qkv)
             grad = _grad_of(attend, tuple(range(min(len(qkv), 3))))
             row["fwd_bwd_ms"] = 1e3 * _time(grad, *qkv)
             if label != "dense":
                 row["kernels_ms"] = _kernel_ms(grad, *qkv)
+            if "slab" in stamp:
+                got = (fwd(*qkv),) + tuple(grad(*qkv))
+                if no_slab is not None:
+                    row["max_err_vs_no_slab"] = _max_err(got, no_slab)
         except Exception as exc:  # noqa: BLE001: a refusal is a record too
             row["failed"] = f"{type(exc).__name__}: {str(exc)[:160]}"
         rows.append(row)
         print(json.dumps(row), flush=True)
+        return got
 
     for name, (b, length, h, d), g, window, blocks, *widths in SWEEP_SHAPES:
         if only and name not in only:
@@ -295,6 +339,32 @@ def block_sweep(key, only=None):
                 q, k, v, causal=True, window=window,
                 **(dict(k_shared=rest[0]) if rest else {}), **kw)
 
+        plan = attention_plan(length, length, h, g, (d, value), window,
+                              backend="tpu", shared_key=bool(shared))
+        if plan.heads_per_program == 2:     # the block's fused projection
+            fused_qkv = [jnp.concatenate(
+                [t.reshape(b, length, -1) for t in qkv], -1)]
+        no_slab = None
+        for slab in SWEEP_SLABS:
+            # The plan's kernels (two heads a program from the fused
+            # projection where it pairs them) with the diagonal blocks in
+            # slabs of ``slab`` rows; 0 first, the reference of the others.
+            if slab and plan.block_q < 2 * slab:
+                continue
+            if plan.heads_per_program == 2:
+                attend, args = functools.partial(
+                    flash_attention, causal=True, heads=h, window=window,
+                    slab=slab), fused_qkv
+            else:
+                attend, args = shared_key(flash_attention, slab=slab), qkv
+            got = measure(name, "slabs", attend, args, no_slab,
+                          block_q=plan.block_q, block_k=plan.block_k,
+                          bwd=plan.bwd, slab=slab,
+                          heads_per_program=plan.heads_per_program)
+            if not slab:
+                no_slab = got
+        if slabs_only:
+            continue
         if length <= 4096:      # the scores of 8,192 keys do not fit
             measure(name, "dense", shared_key(dot_product_attention), qkv)
         for bq in blocks:
@@ -307,16 +377,12 @@ def block_sweep(key, only=None):
                     measure(name, "flash", shared_key(
                         flash_attention, block_q=bq, block_k=bk,
                         bwd_impl=bwd), qkv, block_q=bq, block_k=bk, bwd=bwd)
-        if attention_plan(length, length, h, g, (d, value), window,
-                          backend="tpu", shared_key=bool(shared)
-                          ).heads_per_program == 2:
+        if plan.heads_per_program == 2:
             # A layer THROUGH ``attend``, from the fused projection to what
             # the output projection reads and back to the projection's
             # gradient, the copies between them included: the same kernels,
             # one head a program (split, transposed) against two (read where
             # the projection wrote them).
-            fused_qkv = [jnp.concatenate(
-                [t.reshape(b, length, -1) for t in qkv], -1)]
             for per, fn in ((1, functools.partial(_one_head_a_program,
                                                   heads=h, window=window)),
                             (2, functools.partial(flash_attention,
@@ -344,6 +410,15 @@ def block_sweep(key, only=None):
                 f"{name}: best {best['block_q']}x{best['block_k']} "
                 f"{best['bwd']} {best['fwd_bwd_ms']:.3f} ms"
                 + (f" (dense {dense['fwd_bwd_ms']:.3f})" if dense else ""))
+        slabs = [r for r in per if r["impl"] == "slabs"]
+        if slabs:
+            fastest = min(slabs, key=lambda r: r["fwd_bwd_ms"])
+            summary.append(
+                f"{name}: slabs " + ", ".join(
+                    f"{r['slab']} {r['fwd_bwd_ms']:.3f} ms"
+                    + (f" (err {r['max_err_vs_no_slab']:.1e})"
+                       if "max_err_vs_no_slab" in r else "")
+                    for r in slabs) + f"; best {fastest['slab']}")
     line = "block sweep: " + "; ".join(summary)
     # The summary is the last line of both streams.
     print(line, file=sys.stderr, flush=True)
