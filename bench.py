@@ -151,12 +151,25 @@ def lm_model_args(args, attention: str) -> dict:
 
     kinds = {"sliding": decoder.SLIDING, "full": decoder.FULL,
              "latent": decoder.LATENT, "mamba": decoder.MAMBA}
-    names = (args.lm_layer_types.split(",") if args.lm_layer_types
-             else ["full"] * args.lm_layers)
-    if len(names) != args.lm_layers or set(names) - set(kinds):
-        raise ValueError(
-            f"--lm-layer-types needs {args.lm_layers} of "
-            f"{sorted(kinds)}, comma-separated; got {args.lm_layer_types!r}")
+    letters = {decoder.MIXER_MAMBA, decoder.MIXER_MOE,
+               decoder.MIXER_ATTENTION}
+    if args.lm_pattern:
+        if args.lm_layer_types or len(args.lm_pattern) != args.lm_layers \
+                or set(args.lm_pattern) - letters:
+            raise ValueError(
+                f"--lm-pattern needs {args.lm_layers} of the letters "
+                f"{''.join(sorted(letters))} and no --lm-layer-types; got "
+                f"{args.lm_pattern!r}")
+        names = []
+    else:
+        names = (args.lm_layer_types.split(",") if args.lm_layer_types
+                 else ["full"] * args.lm_layers)
+        if len(names) != args.lm_layers or set(names) - set(kinds):
+            raise ValueError(
+                f"--lm-layer-types needs {args.lm_layers} of "
+                f"{sorted(kinds)}, comma-separated; got "
+                f"{args.lm_layer_types!r}")
+    ungated = {} if args.moe_act == "swiglu" else dict(expert_gated=False)
     held = args.moe_experts_held or args.moe_experts
     if not 0 <= args.moe_first_expert <= args.moe_experts - held:
         raise ValueError(
@@ -181,7 +194,8 @@ def lm_model_args(args, attention: str) -> dict:
         ssm_state=args.ssm_state, ssm_conv=args.ssm_conv,
         ssm_chunk=args.ssm_chunk, embed_multiplier=args.lm_embed_multiplier,
         residual_scale=args.lm_residual_scale, tie_head=args.lm_tie_head,
-        logit_divisor=args.lm_logit_divisor)
+        logit_divisor=args.lm_logit_divisor, pattern=args.lm_pattern or "",
+        ssm_groups=args.ssm_groups, **ungated)
 
 
 def build_lane(args, log) -> Lane:
@@ -314,6 +328,13 @@ def build_parser():
                              "row, one rotated key all heads share) or "
                              "'mamba' (Mamba-2's state-space mixer) a "
                              "layer, comma-separated (default: all full)")
+    parser.add_argument("--lm-pattern", default=None,
+                        help="moe_lm: one branch a layer, h + F(RMS(h)), "
+                             "F by the layer's letter of nemotron_h's "
+                             "hybrid_override_pattern: M Mamba-2's mixer, "
+                             "E the expert layer, * full attention (no "
+                             "positional encoding); --lm-layers letters, "
+                             "in place of --lm-layer-types")
     parser.add_argument("--lm-qk-norm", default=True,
                         action=argparse.BooleanOptionalAction,
                         help="moe_lm: an RMS norm a head on q and k in a "
@@ -332,6 +353,10 @@ def build_parser():
     parser.add_argument("--ssm-state", type=int, default=128,
                         help="moe_lm, a mamba layer: size of the state "
                              "(of B and C)")
+    parser.add_argument("--ssm-groups", type=int, default=1,
+                        help="moe_lm, a mamba layer: groups of B and C (head "
+                             "h reads group h // (heads / groups)) and of "
+                             "the gated norm")
     parser.add_argument("--ssm-conv", type=int, default=4,
                         help="moe_lm, a mamba layer: taps of the causal "
                              "depthwise conv")
@@ -384,6 +409,12 @@ def build_parser():
     parser.add_argument("--moe-shared", type=int, default=1,
                         help="moe_lm: shared experts every token passes")
     parser.add_argument("--moe-route-scale", type=float, default=1.0)
+    parser.add_argument("--moe-act", default="swiglu",
+                        choices=("swiglu", "relu2"),
+                        help="moe_lm: an expert's MLP, swiglu ((silu(x "
+                             "W_gate) * x W_up) W_down) or relu2 (relu(x "
+                             "W_up)^2 W_down, no gate), the shared one's "
+                             "too")
     parser.add_argument("--moe-bias-coeff", type=float, default=0.001,
                         help="moe_lm: step of the selection bias's "
                              "balancing rule after every optimizer step")

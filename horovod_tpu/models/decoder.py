@@ -1,8 +1,9 @@
 """The pieces of today's decoder blocks, written once, and two decoder LMs
 made of them: a sparse one and a looped one.
 
-* :class:`RMSNorm`; :func:`rotary` (the half-split pairing of
-  ``rotate_half``); :class:`GatedMLP` (SwiGLU);
+* :class:`RMSNorm` (over the last axis, or over each of its groups);
+  :func:`rotary` (the half-split pairing of ``rotate_half``);
+  :class:`GatedMLP` (SwiGLU); :class:`SquaredReluMLP` (no gate);
 * :class:`GroupedAttention`: ``H`` query heads over ``G`` KV heads, causal,
   under a ``window`` or over the whole sequence, and three choices that are
   the layer's own: rotary positions, an RMS norm a head on queries and keys,
@@ -14,9 +15,11 @@ made of them: a sparse one and a looped one.
 * :class:`SparseExperts`: a router over all ``E`` experts, this chip's
   ``held`` of them (:mod:`horovod_tpu.parallel.moe`: top ``k`` of sigmoid
   scores plus a selection bias, nothing dropped) and a shared expert every
-  token passes;
+  token passes; the experts gated (SwiGLU) or not (squared ReLU);
 * :class:`DecoderBlock`: an RMS norm before each branch and, where
   ``norm_outputs`` says so, after it;
+* :class:`MixerBlock`: one branch a layer, ``h + F(RMS(h))`` with ``F`` a
+  Mamba mixer, grouped attention or the expert layer;
 * :class:`Mamba2Mixer`: Mamba-2's state-space mixer (in-projection, causal
   depthwise conv, the chunked scan of :mod:`horovod_tpu.ops.ssd`, the gated
   norm, out-projection);
@@ -26,7 +29,9 @@ made of them: a sparse one and a looped one.
   ``deepseek_v3``: latent layers, two norms a block;
   ``granitemoehybrid``: Mamba layers among full ones with no q/k norm or
   gate, every layer dense, the branches and the embedding scaled by
-  numbers, the head tied to the embedding);
+  numbers, the head tied to the embedding; ``nemotron_h``: a ``pattern`` of
+  single-branch layers, Mamba with B and C in groups, squared-ReLU
+  experts);
 * :class:`LoopedDecoderLM`: a stack of blocks declared once and applied
   ``loops`` times with the same weights, an exit after each application
   (``ouro``: full attention with rotary positions, no q/k norm, no gate), and
@@ -60,20 +65,31 @@ from horovod_tpu.utils import timeline
 
 SLIDING, FULL, LATENT, MAMBA = ("sliding_attention", "full_attention",
                                 "latent_attention", "mamba")
+# a single-branch layer's kind by its letter of ``nemotron_h``'s
+# ``hybrid_override_pattern``
+MIXER_MAMBA, MIXER_MOE, MIXER_ATTENTION = "M", "E", "*"
 
 
 class RMSNorm(nn.Module):
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, in
-    float32."""
+    float32; with ``groups`` the mean is each of that many equal groups of
+    the last axis's own (``MambaRMSNormGated``'s ``group_size``), and the
+    scale is one over the whole axis."""
 
     eps: float = 1e-5
+    groups: int = 1
 
     @nn.compact
     def __call__(self, x):
         x = x.astype(jnp.float32)
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return x * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+        if self.groups == 1:
+            return x * jax.lax.rsqrt(
+                jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+        parts = x.reshape(*x.shape[:-1], self.groups, -1)
+        parts = parts * jax.lax.rsqrt(
+            jnp.mean(jnp.square(parts), -1, keepdims=True) + self.eps)
+        return parts.reshape(x.shape) * scale
 
 
 def rotary(x, base: float = 10000.0):
@@ -104,6 +120,22 @@ class GatedMLP(nn.Module):
         hidden = nn.silu(dense(self.width, "gate")(x)) \
             * dense(self.width, "up")(x)
         return dense(x.shape[-1], "down")(hidden)
+
+
+class SquaredReluMLP(nn.Module):
+    """``relu(x W_up)^2 W_down``, no gate, no bias (``nemotron_h``'s
+    ``relu2`` MLP)."""
+
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        up = nn.Dense(self.width, use_bias=False, dtype=self.dtype,
+                      name="up")(x)
+        hidden = jnp.square(nn.relu(up.astype(jnp.float32))).astype(up.dtype)
+        return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                        name="down")(hidden)
 
 
 class GroupedAttention(nn.Module):
@@ -234,24 +266,27 @@ _expanded: dict = {}
 
 
 class Mamba2Mixer(nn.Module):
-    """Mamba-2's mixer (``modeling_granitemoehybrid``'s, no bias on the
-    projections, one group): for a token's normed state ``x``
+    """Mamba-2's mixer (``modeling_granitemoehybrid``'s and
+    ``modeling_nemotron_h``'s, no bias on the projections): for a token's
+    normed state ``x``, with B and C in ``groups`` groups of N
 
-        z | xBC | dt = x W_in                 inner | inner + 2N | heads
+        z | xBC | dt = x W_in                 inner | inner + 2GN | heads
         xBC          = silu(conv(xBC) + b)    causal, depthwise, ``conv`` taps
-        x | B | C    = xBC                    heads x head_dim | N | N
+        x | B | C    = xBC                    heads x head_dim | G x N | G x N
         Delta        = softplus(dt + dt_bias);  A = -exp(A_log)
         y            = ssd(x, Delta, A, B, C) + D x       (ops/ssd.py)
-        y            = RMS(y * silu(z)) * w   over inner: the gate first
+        y            = RMS_G(y * silu(z)) * w   the gate first; each of the G
+                                                groups of inner / G alone
         out          = y W_out
 
-    The scan reads x, B and C where the conv wrote them (``ssd``'s packed
-    form). ``impl`` is ``ssd``'s. Scopes ``hvd_ssm_mixer`` (the whole mixer)
-    and ``hvd_ssd`` (the scan); for the program being traced the counter
-    ``hvd.ssd.calls`` and the gauges ``hvd.ssd.chunk`` and
-    ``hvd.ssd.state_bytes`` (chunk states the forward kernels write to HBM,
-    summed over the layers and over the forward calls a step executes: a
-    recomputed block's twice) and ``hvd.ssd.fwd_calls``."""
+    Head h reads group ``h // (heads / G)``. The scan reads x, B and C where
+    the conv wrote them (``ssd``'s packed form). ``impl`` is ``ssd``'s.
+    Scopes ``hvd_ssm_mixer`` (the whole mixer) and ``hvd_ssd`` (the scan);
+    for the program being traced the counter ``hvd.ssd.calls`` and the
+    gauges ``hvd.ssd.chunk``, ``hvd.ssd.groups`` and ``hvd.ssd.state_bytes``
+    (chunk states the forward kernels write to HBM, summed over the layers
+    and over the forward calls a step executes: a recomputed block's twice)
+    and ``hvd.ssd.fwd_calls``."""
 
     heads: int
     head_dim: int
@@ -261,12 +296,13 @@ class Mamba2Mixer(nn.Module):
     eps: float = 1e-5
     impl: Optional[str] = None
     dtype: Any = jnp.bfloat16
+    groups: int = 1
 
     @nn.compact
     def __call__(self, x):
         b, length, d = x.shape
         inner = self.heads * self.head_dim
-        width = inner + 2 * self.state          # x | B | C
+        width = inner + 2 * self.groups * self.state        # x | B | C
         with jax.named_scope(timeline.SSM_MIXER):
             proj = nn.Dense(inner + width + self.heads, use_bias=False,
                             dtype=self.dtype, name="in_proj")(x)
@@ -292,14 +328,16 @@ class Mamba2Mixer(nn.Module):
                 b, length, self.heads, self.state, self.head_dim, self.chunk)
             timeline.count("hvd.ssd.calls")
             timeline.gauge("hvd.ssd.chunk", self.chunk, key=program)
+            timeline.gauge("hvd.ssd.groups", self.groups, key=program)
             timeline.gauge("hvd.ssd.fwd_calls", tally[0], key=program)
             timeline.gauge("hvd.ssd.state_bytes", tally[1], key=program)
             with jax.named_scope(timeline.SSD):
                 y = ssd_op.ssd(xbc, delta, -jnp.exp(a_log), D=skip,
-                               state_dim=self.state, chunk=self.chunk,
-                               impl=self.impl)
+                               state_dim=self.state, groups=self.groups,
+                               chunk=self.chunk, impl=self.impl)
             gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
-            y = RMSNorm(self.eps, name="norm")(gated).astype(self.dtype)
+            y = RMSNorm(self.eps, self.groups, name="norm")(gated).astype(
+                self.dtype)
             return nn.Dense(d, use_bias=False, dtype=self.dtype,
                             name="out_proj")(y)
 
@@ -314,7 +352,10 @@ _recomputing = [False]
 class SparseExperts(nn.Module):
     """``MLP_shared(x) + sum over the chosen experts held here of w_e
     MLP_e(x)``: the chip's share of the layer (``first_expert`` and
-    ``experts_held`` say which experts are its own), nothing dropped."""
+    ``experts_held`` say which experts are its own), nothing dropped. An
+    MLP is a :class:`GatedMLP` where ``gated`` and a
+    :class:`SquaredReluMLP` where not, the shared one ``shared x width``
+    wide."""
 
     experts: int
     experts_held: int
@@ -324,6 +365,7 @@ class SparseExperts(nn.Module):
     route_scale: float = 1.0
     shared: int = 1                      # shared experts, of ``width`` each
     dtype: Any = jnp.bfloat16
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -333,9 +375,10 @@ class SparseExperts(nn.Module):
                             (d, self.experts))
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
-        stacked = {"gate": self.param("experts_gate", init, (held, d, f)),
-                   "up": self.param("experts_up", init, (held, d, f)),
-                   "down": self.param("experts_down", init, (held, f, d))}
+        stacked = {"gate": self.param("experts_gate", init, (held, d, f))} \
+            if self.gated else {}
+        stacked.update(up=self.param("experts_up", init, (held, d, f)),
+                       down=self.param("experts_down", init, (held, f, d)))
         bias = self.variable("buffers", "selection_bias", jnp.zeros,
                              (self.experts,), jnp.float32)
         seen = self.variable("buffers", "expert_counts", jnp.zeros,
@@ -344,15 +387,16 @@ class SparseExperts(nn.Module):
         y, counts = moe.routed_experts(
             flat, router, stacked, bias.value, first=self.first_expert,
             top_k=self.top_k, route_scale=self.route_scale,
-            dtype=self.dtype, name="/".join(self.path))
+            dtype=self.dtype, name="/".join(self.path),
+            recomputed=_recomputing[0])
         if not self.is_initializing() \
                 and self.is_mutable_collection("buffers"):
             seen.value = counts
         y = y.reshape(b, length, d)
         if self.shared:
             with jax.named_scope(timeline.MOE_SHARED):
-                y = y + GatedMLP(self.shared * f, self.dtype,
-                                 name="shared")(x)
+                mlp = GatedMLP if self.gated else SquaredReluMLP
+                y = y + mlp(self.shared * f, self.dtype, name="shared")(x)
         return y
 
 
@@ -414,48 +458,97 @@ class DecoderBlock(nn.Module):
         expert block), at Moonlight's (1.06 and 1.16) and at Granite's (1.21
         for a Mamba block)."""
         e = jnp.dtype(self.dtype).itemsize
-        a = self.attn
         # the stream before each branch, its norm and the branch's output
         # where a norm reads it; where none does, the stream alone (by the
         # compiler's count the normed copy is then made again in the
         # products that read it)
-        a_token = (6 if self.norm_outputs else 2) * width * e
-        if "state" in a:
-            # the in-projection's output (z | xBC | dt), the conv's output
-            # and the scan's y (the gated norm XLA makes again inside the
-            # out-projection's fusions); Delta in float32 and the chunk
-            # states the forward kernel writes
-            inner = a["heads"] * a["head_dim"]
-            conv = inner + 2 * a["state"]
-            a_token += (inner + conv + a["heads"] + conv + inner) * e \
-                + 2 * 4 * a["heads"] + inner * a["state"] * 4 // a.get(
-                    "chunk", ssd_op.CHUNK)
-        elif "latent_dim" in a:
-            # the compressed row with the rope key before its norm and after,
-            # then the kernels' operands: q, the expanded k and v with the
-            # one rope key, the output
-            row = a["latent_dim"] + a["rope_dim"]
-            a_token += (2 * row + a["heads"] * (
-                2 * a["nope_dim"] + a["rope_dim"] + 2 * a["value_dim"])) * e
-        else:
-            q, kv = a["heads"] * a["head_dim"], a["kv_heads"] * a["head_dim"]
-            a_token += (2 * q + 2 * kv) * e     # the kernels' q, k, v, output
-            if a.get("qk_norm"):
-                a_token += (q + kv) * e         # q and k before their norms
-            if a.get("gate"):
-                a_token += 2 * q * e            # its logits, the gated output
-        if "state" not in a:
-            # the kernels' log-sum-exp a head: float32, each a lane row of
-            # 128 in HBM
-            a_token += a["heads"] * 128 * 4
+        a_token = (6 if self.norm_outputs else 2) * width * e \
+            + _mixer_kept(self.attn, e)
         if self.moe is None:
-            hidden = self.ffn_width
-        else:
-            # the router's scores in float32 and a token's choices; what the
-            # routed experts take is the norm's output and the indices
-            hidden = self.moe["shared"] * self.moe["width"]
-            a_token += 3 * 4 * self.moe["experts"] + 4 * 4 * self.moe["top_k"]
-        return tokens * (a_token + 3 * hidden * e)  # gate, up, their product
+            return tokens * (a_token + 3 * self.ffn_width * e)
+        return tokens * (a_token + _experts_kept(self.moe, e))
+
+
+def _mixer_kept(a: dict, e: int) -> int:
+    """Bytes a token that a Mamba mixer or an attention layer of fields
+    ``a`` keeps for the backward pass (:meth:`DecoderBlock.kept_bytes`)."""
+    if "state" in a:
+        # the in-projection's output (z | xBC | dt), the conv's output and
+        # the scan's y (the gated norm XLA makes again inside the
+        # out-projection's fusions); Delta in float32 and the chunk states
+        # the forward kernel writes
+        inner = a["heads"] * a["head_dim"]
+        conv = inner + 2 * a.get("groups", 1) * a["state"]
+        return (inner + conv + a["heads"] + conv + inner) * e \
+            + 2 * 4 * a["heads"] + inner * a["state"] * 4 // a.get(
+                "chunk", ssd_op.CHUNK)
+    if "latent_dim" in a:
+        # the compressed row with the rope key before its norm and after,
+        # then the kernels' operands: q, the expanded k and v with the one
+        # rope key, the output
+        row = a["latent_dim"] + a["rope_dim"]
+        kept = (2 * row + a["heads"] * (
+            2 * a["nope_dim"] + a["rope_dim"] + 2 * a["value_dim"])) * e
+    else:
+        q, kv = a["heads"] * a["head_dim"], a["kv_heads"] * a["head_dim"]
+        kept = (2 * q + 2 * kv) * e             # the kernels' q, k, v, output
+        if a.get("qk_norm"):
+            kept += (q + kv) * e                # q and k before their norms
+        if a.get("gate"):
+            kept += 2 * q * e                   # its logits, the gated output
+    # the kernels' log-sum-exp a head: float32, each a lane row of 128 in HBM
+    return kept + a["heads"] * 128 * 4
+
+
+def _experts_kept(moe: dict, e: int) -> int:
+    """Bytes a token that an expert layer of fields ``moe`` keeps: the
+    router's scores in float32 and a token's choices (what the routed
+    experts take is the norm's output and the indices), and the shared
+    expert's operands: gate, up and their product, or without a gate the
+    up-projection alone (its square XLA makes again inside the
+    down-projection's fusion)."""
+    kept = 3 * 4 * moe["experts"] + 4 * 4 * moe["top_k"]
+    shared = moe["shared"] * moe["width"] * e
+    return kept + (3 * shared if moe.get("gated", True) else shared)
+
+
+class MixerBlock(nn.Module):
+    """One branch a layer (``nemotron_h``'s block): ``h = h + F(RMS(h))``,
+    no norm after the branch, ``F`` by the layer's letter ``kind`` a
+    :class:`Mamba2Mixer` (``M``, named ``mamba``), a
+    :class:`GroupedAttention` (``*``, named ``attn``) or
+    :class:`SparseExperts` (``E``, named ``moe``) of the fields ``fields``;
+    the norm is ``norm``."""
+
+    kind: str
+    fields: dict
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        layer, name = {MIXER_MAMBA: (Mamba2Mixer, "mamba"),
+                       MIXER_ATTENTION: (GroupedAttention, "attn"),
+                       MIXER_MOE: (SparseExperts, "moe")}[self.kind]
+        fields = dict(self.fields)
+        if self.kind != MIXER_MOE:
+            fields["eps"] = self.eps
+        a = RMSNorm(self.eps, name="norm")(h)
+        y = layer(dtype=self.dtype, name=name, **fields)(a)
+        return h + y.astype(h.dtype)
+
+    @nn.nowrap
+    def kept_bytes(self, tokens: int, width: int) -> int:
+        """:meth:`DecoderBlock.kept_bytes` of the one branch: the stream and
+        its norm, and what the mixer or the expert layer keeps.
+        ``tests/test_chip_smoke.py`` holds each kind to the compiler's count
+        at Nemotron-3-Nano's widths: 0.91 of it for a Mamba layer, 0.94 for
+        an expert layer, 1.45 for the attention layer (whose log-sum-exp the
+        compiler does not hold at 128 lanes a head in this call)."""
+        e = jnp.dtype(self.dtype).itemsize
+        own = _experts_kept(self.fields, e) if self.kind == MIXER_MOE \
+            else _mixer_kept(self.fields, e)
+        return tokens * (2 * width * e + own)
 
 
 class RecomputePlan(NamedTuple):
@@ -630,7 +723,14 @@ class SparseDecoderLM(nn.Module):
     ``residual_scale`` each branch's output (:class:`DecoderBlock`);
     ``tie_head`` takes the logits from the embedding's transpose, and they
     are divided by ``logit_divisor`` (:func:`loss_head` gives a fused loss
-    the same two). ``norm_outputs`` is :class:`DecoderBlock`'s. ``remat``
+    the same two). ``norm_outputs`` is :class:`DecoderBlock`'s.
+
+    ``pattern`` (``nemotron_h``'s ``hybrid_override_pattern`` of the layers
+    held) takes the place of ``layer_types`` and ``dense_layers``: a
+    :class:`MixerBlock` a letter, ``M`` a Mamba mixer (``ssm_groups``
+    groups of B and C), ``E`` the expert layer, ``*`` grouped attention with
+    no positional encoding. ``expert_gated`` false makes every expert and
+    the shared one a :class:`SquaredReluMLP`. ``remat``
     says how many block applications (here: blocks) the backward pass runs
     again instead of keeping what they computed: a count or a
     :class:`RecomputePlan` (the first so many; the last ones are kept),
@@ -677,10 +777,55 @@ class SparseDecoderLM(nn.Module):
     residual_scale: float = 1.0
     tie_head: bool = False
     logit_divisor: float = 1.0
+    pattern: str = ""
+    ssm_groups: int = 1
+    expert_gated: bool = True
 
     @nn.nowrap
-    def block(self, i: int) -> DecoderBlock:
+    def depth(self) -> int:
+        """Layers: the pattern's letters, or ``layer_types``."""
+        return len(self.pattern) if self.pattern else len(self.layer_types)
+
+    @nn.nowrap
+    def _experts(self) -> dict:
+        """An expert layer's :class:`SparseExperts` fields."""
+        fields = dict(
+            experts=self.experts, experts_held=self.experts_held,
+            first_expert=self.first_expert, top_k=self.top_k,
+            width=self.expert_width, route_scale=self.route_scale,
+            shared=self.shared_experts)
+        if not self.expert_gated:
+            fields["gated"] = False
+        return fields
+
+    @nn.nowrap
+    def _mixer_block(self, i: int) -> MixerBlock:
+        kind = self.pattern[i]
+        if kind == MIXER_MAMBA:
+            fields = dict(heads=self.ssm_heads, head_dim=self.ssm_head_dim,
+                          state=self.ssm_state, conv=self.ssm_conv,
+                          chunk=self.ssm_chunk, impl=self.ssm_impl,
+                          groups=self.ssm_groups)
+        elif kind == MIXER_ATTENTION:
+            fields = dict(heads=self.heads, kv_heads=self.kv_heads,
+                          head_dim=self.head_dim, rotary=False,
+                          qk_norm=self.qk_norm, gate=self.attn_gate,
+                          attention=self.attention)
+            if self.attn_scale is not None:
+                fields["scale"] = self.attn_scale
+        elif kind == MIXER_MOE:
+            fields = self._experts()
+        else:
+            raise ValueError(f"layer {i}: no layer letter {kind!r} "
+                             f"(M, E or *)")
+        return MixerBlock(kind, fields, self.eps, self.dtype,
+                          name=f"DecoderBlock_{i}")
+
+    @nn.nowrap
+    def block(self, i: int) -> Union[DecoderBlock, MixerBlock]:
         """Layer ``i``'s block, by the name its parameters have."""
+        if self.pattern:
+            return self._mixer_block(i)
         kind = self.layer_types[i]
         if kind not in (SLIDING, FULL, LATENT, MAMBA):
             raise ValueError(f"layer {i}: no layer type {kind!r}")
@@ -701,11 +846,7 @@ class SparseDecoderLM(nn.Module):
                         gate=self.attn_gate, attention=self.attention)
             if self.attn_scale is not None:
                 attn["scale"] = self.attn_scale
-        sparse = None if i < self.dense_layers else dict(
-            experts=self.experts, experts_held=self.experts_held,
-            first_expert=self.first_expert, top_k=self.top_k,
-            width=self.expert_width, route_scale=self.route_scale,
-            shared=self.shared_experts)
+        sparse = None if i < self.dense_layers else self._experts()
         return DecoderBlock(attn, self.dense_width, sparse, self.eps,
                             self.dtype, self.norm_outputs,
                             self.residual_scale, name=f"DecoderBlock_{i}")
@@ -714,7 +855,7 @@ class SparseDecoderLM(nn.Module):
     def applications(self) -> list:
         """The layer whose block each application of the forward pass
         applies, in order: every layer once."""
-        return list(range(len(self.layer_types)))
+        return list(range(self.depth()))
 
     @nn.compact
     def __call__(self, tokens, train: bool = True,
@@ -727,8 +868,8 @@ class SparseDecoderLM(nn.Module):
             h = h * jnp.asarray(self.embed_dim ** 0.5, h.dtype)
         if self.embed_multiplier is not None:
             h = h * jnp.asarray(self.embed_multiplier, h.dtype)
-        apply_block = _applications(self.remat, len(self.layer_types))
-        for i in range(len(self.layer_types)):
+        apply_block = _applications(self.remat, self.depth())
+        for i in range(self.depth()):
             h = apply_block(self.block(i), h)
         h = RMSNorm(self.eps, name="final_norm")(h)
         if return_hidden:

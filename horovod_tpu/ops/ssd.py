@@ -12,7 +12,7 @@ The chunked form (Dao & Gu 2024, section 6): within a chunk of ``T`` tokens
 with ``cs`` the running sum of ``Delta A`` from the chunk's start (inclusive)
 and ``H`` the state entering it (stored ``N x P``),
 
-    G      = C B^T                              T x T, shared by all heads
+    G      = C B^T                              T x T, shared by a group's heads
     L[t,u] = exp(cs_t - cs_u) where u <= t, else 0 (masked before the exp)
     y      = (G . L) (Delta x) + exp(cs) . (C H)
     H     <- exp(cs_T) H + B^T (exp(cs_T - cs) . Delta x)
@@ -29,17 +29,20 @@ by ``impl`` or by the platform:
   128-lane column block (as ``ops.attention`` pairs flash heads), each of
   its products taking one operand with the other head's lanes zeroed. The
   chunk axis is sequential and every pair's state stays in a float32 VMEM
-  scratch from chunk to chunk; ``G`` is made once a chunk, by the chunk's
-  first pair. x, B and C are read by index map from the conv's output
-  ``[Bt, L, H x P + 2N]`` where it lies (no split copies), y is written as
+  scratch from chunk to chunk. B and C come in ``G`` groups (head h reads
+  group ``h // (H / G)``, so a group's pairs are consecutive on the grid);
+  ``G`` is made once a chunk a group, by the group's first pair. x, B and C
+  are read by index map from the conv's output ``[Bt, L, H x P + 2 G N]``
+  where it lies (no split copies), a pair's B and C its group's column
+  blocks; y is written as
   the ``[Bt, L, H x P]`` that the gated norm reads. The forward kernel also
   writes the state entering each chunk to HBM (``[Bt, L / T, H / 2, N,
   128]`` float32, 128 MiB a layer at 16,384 tokens and Granite's widths),
   the residual the backward kernel reads instead of running the recurrence
   again; the backward kernel walks the chunks in reverse carrying ``dH`` in
-  VMEM, sums the gradient of ``G`` over the pairs in VMEM and turns it into
-  B's and C's once a chunk, at its last pair, and sums B's and C's gradients
-  over the pairs in its output blocks. Products take the inputs' type with
+  VMEM, sums the gradient of ``G`` over a group's pairs in VMEM and turns it
+  into the group's B's and C's once a chunk, at the group's last pair, and
+  sums B's and C's gradients over the group's pairs in its output blocks. Products take the inputs' type with
   float32 accumulation; cumulative sums and exponentials are float32.
 * ``"chunked"``: the same decomposition in ``jax.numpy`` (a scan over
   chunks), the CPU's path and the kernels' oracle in the tests.
@@ -69,20 +72,22 @@ NEG_INF = -1e30         # finite: exp() of it is exactly 0
 def _pallas_shapes_ok(heads: int, head_dim: int, groups: int,
                       state_dim: int) -> bool:
     """What the kernels are written for: two heads of 64 fill a 128-lane
-    block, one group of B and C, a state of 128."""
-    return (head_dim * PAIR == LANES and heads % PAIR == 0 and groups == 1
-            and state_dim == LANES)
+    block, each group of B and C is read by whole pairs of heads, a state of
+    128."""
+    return (head_dim * PAIR == LANES and groups >= 1 and heads % groups == 0
+            and (heads // groups) % PAIR == 0 and state_dim == LANES)
 
 
 def ssd(x, dt, A, B=None, C=None, D=None, *, chunk: int = CHUNK,
-        state_dim: Optional[int] = None, impl: Optional[str] = None,
-        interpret: Optional[bool] = None):
+        state_dim: Optional[int] = None, groups: int = 1,
+        impl: Optional[str] = None, interpret: Optional[bool] = None):
     """The scan of module docstring's equations.
 
     ``x [Bt, L, H, P]`` with ``B``, ``C`` ``[Bt, L, G, N]`` (``G`` divides
     ``H``; head h reads group ``h // (H / G)``); or ``x`` the conv's output
-    ``[Bt, L, H x P + 2N]`` (x | B | C along its columns, one group) with
-    ``B`` and ``C`` None and ``state_dim`` N, read where it lies. ``dt [Bt,
+    ``[Bt, L, H x P + 2 G N]`` (x | B | C along its columns, B and C each
+    ``groups`` groups of N) with ``B`` and ``C`` None and ``state_dim`` N,
+    read where it lies. ``dt [Bt,
     L, H]`` is ``Delta`` after its softplus, ``A`` and ``D`` ``[H]`` (``A``
     negative; ``D`` None: no skip). Returns y in ``x``'s type: ``[Bt, L, H,
     P]``, or ``[Bt, L, H x P]`` for the packed input. ``impl``: ``"pallas"``
@@ -93,11 +98,11 @@ def ssd(x, dt, A, B=None, C=None, D=None, *, chunk: int = CHUNK,
     if packed:
         if C is not None or state_dim is None:
             raise ValueError("the conv's output needs state_dim and no B, C")
-        head_dim = (x.shape[-1] - 2 * state_dim) // heads
-        groups, n = 1, state_dim
-        if heads * head_dim + 2 * n != x.shape[-1]:
+        n = state_dim
+        head_dim = (x.shape[-1] - 2 * groups * n) // heads
+        if heads * head_dim + 2 * groups * n != x.shape[-1]:
             raise ValueError(f"{x.shape[-1]} columns are not {heads} heads "
-                             f"and two states of {n}")
+                             f"and two states of {groups} x {n}")
     else:
         head_dim, groups, n = x.shape[-1], B.shape[2], B.shape[3]
     if impl is None:
@@ -108,15 +113,16 @@ def ssd(x, dt, A, B=None, C=None, D=None, *, chunk: int = CHUNK,
     if impl == "pallas":
         if not _pallas_shapes_ok(heads, head_dim, groups, n):
             raise ValueError(
-                f"the kernels take an even number of heads of {LANES // PAIR}"
-                f", one group and a state of {LANES}: got {heads} heads of "
-                f"{head_dim}, {groups} groups, state {n}")
+                f"the kernels take heads of {LANES // PAIR}, an even number "
+                f"of them a group, and a state of {LANES}: got {heads} heads "
+                f"of {head_dim}, {groups} groups, state {n}")
         xbc = x if packed else jnp.concatenate(
-            [x.reshape(bt, length, -1), B[:, :, 0], C[:, :, 0]], -1)
+            [x.reshape(bt, length, -1), B.reshape(bt, length, -1),
+             C.reshape(bt, length, -1)], -1)
         xbc, dtp = _pad(xbc, pad), _pad(dt, pad)
         y = _scan_pallas(xbc, dtp.astype(jnp.float32), A, heads, chunk,
                          pallas_interpret() if interpret is None
-                         else interpret)[:, :length]
+                         else interpret, groups)[:, :length]
         xs = xbc[:, :length, :heads * head_dim]
         if not packed:
             y, xs = y.reshape(x.shape), x
@@ -124,8 +130,9 @@ def ssd(x, dt, A, B=None, C=None, D=None, *, chunk: int = CHUNK,
         if packed:
             inner = heads * head_dim
             xs = x[..., :inner].reshape(bt, length, heads, head_dim)
-            Bs, Cs = (x[..., inner + i * n:inner + (i + 1) * n][:, :, None]
-                      for i in (0, 1))
+            gn = groups * n
+            Bs, Cs = (x[..., inner + i * gn:inner + (i + 1) * gn].reshape(
+                bt, length, groups, n) for i in (0, 1))
         else:
             xs, Bs, Cs = x, B, C
         y = _scan_chunked(*(_pad(t, pad) for t in (xs, dt, Bs, Cs)), A,
@@ -242,7 +249,7 @@ def _dot(a, b, dtype, contract=((1,), (0,))):
 
 
 def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, y_ref, h_ref,
-                state_scr, g_scr, *, chunk: int):
+                state_scr, g_scr, *, chunk: int, per_group: int):
     from jax.experimental import pallas as pl
 
     c, p = pl.program_id(1), pl.program_id(2)
@@ -253,8 +260,8 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, y_ref, h_ref,
     def _():
         state_scr[p] = jnp.zeros(state_scr.shape[1:], jnp.float32)
 
-    @pl.when(p == 0)
-    def _():        # G = C B^T, shared by every head of the chunk
+    @pl.when(p % per_group == 0)
+    def _():        # G = C B^T, shared by every head of the group
         g_scr[...] = _dot(c_ref[...], b_ref[...], mm, ((1,), (1,)))
 
     cs, dt = cs_ref[...], dt_ref[...]
@@ -281,7 +288,7 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, y_ref, h_ref,
 
 def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, h_ref, dy_ref,
                 dx_ref, db_ref, dc_ref, ddt_ref, dcs_ref,
-                dh_scr, g_scr, dg_scr, *, chunk: int, pairs: int):
+                dh_scr, g_scr, dg_scr, *, chunk: int, per_group: int):
     from jax.experimental import pallas as pl
 
     r, p = pl.program_id(1), pl.program_id(2)
@@ -293,7 +300,7 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, h_ref, dy_ref,
     def _():
         dh_scr[p] = jnp.zeros(dh_scr.shape[1:], jnp.float32)
 
-    @pl.when(p == 0)
+    @pl.when(p % per_group == 0)
     def _():
         g_scr[...] = _dot(c_ref[...], b_ref[...], mm, ((1,), (1,)))
         dg_scr[...] = jnp.zeros(dg_scr.shape, jnp.float32)
@@ -356,18 +363,21 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, h_ref, dy_ref,
         + _dot(c_ref[...].astype(jnp.float32).T, dye, mm)
     dx_ref[...] = (dxt * dtl).astype(dx_ref.dtype)
 
-    @pl.when(p == pairs - 1)
-    def _():        # G's gradient, summed over the pairs, into C's and B's
+    @pl.when(p % per_group == per_group - 1)
+    def _():        # G's gradient, summed over the group's pairs, into C's
+        # and B's
         dg_all = dg_scr[...]
         dc_ref[...] += _dot(dg_all, b_ref[...], mm)
         db_ref[...] += _dot(dg_all.T, c_ref[...], mm)
 
 
-def _specs(heads: int, chunk: int, n_chunks: int, reverse: bool):
-    """BlockSpecs of x, B and C (column blocks of the conv's output), of a
-    pair's rows of ``Delta`` / ``cs`` and of a pair's ``[T, 128]`` tile of
-    ``[Bt, L, H x P]``, on the grid (batch, chunk or reversed chunk,
-    pair)."""
+def _specs(heads: int, chunk: int, n_chunks: int, reverse: bool,
+           groups: int = 1):
+    """BlockSpecs of x, B and C (column blocks of the conv's output: a
+    pair's group's B and C), of a pair's rows of ``Delta`` / ``cs``, of a
+    pair's ``[T, 128]`` tile of ``[Bt, L, H x P]`` and of its group's
+    ``[T, 128]`` tile of ``[Bt, L, G x N]`` (B's and C's gradients), on the
+    grid (batch, chunk or reversed chunk, pair)."""
     from jax.experimental import pallas as pl
 
     def at(c):
@@ -376,29 +386,33 @@ def _specs(heads: int, chunk: int, n_chunks: int, reverse: bool):
     b_col = heads * LANES // PAIR // LANES
     tile = pl.BlockSpec((None, chunk, LANES), lambda b, c, p: (b, at(c), p))
     x = tile
+    per_group = heads // PAIR // groups
     bmat = pl.BlockSpec((None, chunk, LANES),
-                        lambda b, c, p: (b, at(c), b_col))
-    cmat = pl.BlockSpec((None, chunk, LANES),
-                        lambda b, c, p: (b, at(c), b_col + 1))
+                        lambda b, c, p: (b, at(c), b_col + p // per_group))
+    cmat = pl.BlockSpec(
+        (None, chunk, LANES),
+        lambda b, c, p: (b, at(c), b_col + groups + p // per_group))
+    group_tile = pl.BlockSpec((None, chunk, LANES),
+                              lambda b, c, p: (b, at(c), p // per_group))
     rows = pl.BlockSpec((None, None, PAIR, chunk),
                         lambda b, c, p: (b, p, 0, at(c)))
     state = pl.BlockSpec((None, None, None, LANES, LANES),
                          lambda b, c, p: (b, at(c), p, 0, 0))
-    whole_chunk = pl.BlockSpec((None, chunk, LANES),
-                               lambda b, c, p: (b, at(c), 0))
-    return x, bmat, cmat, rows, state, tile, whole_chunk
+    return x, bmat, cmat, rows, state, tile, group_tile
 
 
 def _forward(xbc, dt_rows, cs_rows, heads: int, chunk: int,
-             interpret: bool):
+             interpret: bool, groups: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bt, length, _ = xbc.shape
     nc, pairs = length // chunk, heads // PAIR
-    x, bmat, cmat, rows, state, tile, _ = _specs(heads, chunk, nc, False)
+    x, bmat, cmat, rows, state, tile, _ = _specs(heads, chunk, nc, False,
+                                                 groups)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk),
+        functools.partial(_fwd_kernel, chunk=chunk,
+                          per_group=pairs // groups),
         grid=(bt, nc, pairs),
         in_specs=[x, bmat, cmat, rows, rows],
         out_specs=[tile, state],
@@ -415,24 +429,25 @@ def _forward(xbc, dt_rows, cs_rows, heads: int, chunk: int,
 
 
 def _backward(xbc, dt_rows, cs_rows, states, dy, heads: int, chunk: int,
-              interpret: bool):
+              interpret: bool, groups: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bt, length, _ = xbc.shape
     nc, pairs = length // chunk, heads // PAIR
-    x, bmat, cmat, rows, state, tile, whole_chunk = _specs(heads, chunk, nc,
-                                                           True)
+    x, bmat, cmat, rows, state, tile, group_tile = _specs(heads, chunk, nc,
+                                                          True, groups)
     f32 = jnp.float32
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, pairs=pairs),
+        functools.partial(_bwd_kernel, chunk=chunk,
+                          per_group=pairs // groups),
         grid=(bt, nc, pairs),
         in_specs=[x, bmat, cmat, rows, rows, state, tile],
-        out_specs=[tile, whole_chunk, whole_chunk, rows, rows],
+        out_specs=[tile, group_tile, group_tile, rows, rows],
         out_shape=[
             jax.ShapeDtypeStruct(dy.shape, xbc.dtype),
-            jax.ShapeDtypeStruct((bt, length, LANES), f32),
-            jax.ShapeDtypeStruct((bt, length, LANES), f32),
+            jax.ShapeDtypeStruct((bt, length, groups * LANES), f32),
+            jax.ShapeDtypeStruct((bt, length, groups * LANES), f32),
             jax.ShapeDtypeStruct(dt_rows.shape, f32),
             jax.ShapeDtypeStruct(cs_rows.shape, f32)],
         scratch_shapes=[pltpu.VMEM((pairs, LANES, LANES), f32),
@@ -444,21 +459,23 @@ def _backward(xbc, dt_rows, cs_rows, states, dy, heads: int, chunk: int,
     )(xbc, xbc, xbc, dt_rows, cs_rows, states, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _kernels(xbc, dt_rows, cs_rows, heads, chunk, interpret):
-    return _forward(xbc, dt_rows, cs_rows, heads, chunk, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _kernels(xbc, dt_rows, cs_rows, heads, chunk, interpret, groups):
+    return _forward(xbc, dt_rows, cs_rows, heads, chunk, interpret,
+                    groups)[0]
 
 
-def _kernels_fwd(xbc, dt_rows, cs_rows, heads, chunk, interpret):
-    y, states = _forward(xbc, dt_rows, cs_rows, heads, chunk, interpret)
+def _kernels_fwd(xbc, dt_rows, cs_rows, heads, chunk, interpret, groups):
+    y, states = _forward(xbc, dt_rows, cs_rows, heads, chunk, interpret,
+                         groups)
     return y, (xbc, dt_rows, cs_rows, states)
 
 
-def _kernels_bwd(heads, chunk, interpret, res, dy):
+def _kernels_bwd(heads, chunk, interpret, groups, res, dy):
     xbc, dt_rows, cs_rows, states = res
     dx, db, dc, ddt, dcs = _backward(xbc, dt_rows, cs_rows, states,
                                      dy.astype(xbc.dtype), heads, chunk,
-                                     interpret)
+                                     interpret, groups)
     dxbc = jnp.concatenate([dx, db.astype(dx.dtype), dc.astype(dx.dtype)], -1)
     return dxbc, ddt, dcs
 
@@ -466,8 +483,9 @@ def _kernels_bwd(heads, chunk, interpret, res, dy):
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _scan_pallas(xbc, dt, A, heads: int, chunk: int, interpret: bool):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _scan_pallas(xbc, dt, A, heads: int, chunk: int, interpret: bool,
+                 groups: int = 1):
     """y ``[Bt, L, H x P]`` through the kernels; ``Delta`` and its running
     sums go in as a pair's rows, made here where XLA differentiates them.
     Under ``jax.jit``, so that a differentiated program names the kernels
@@ -475,7 +493,7 @@ def _scan_pallas(xbc, dt, A, heads: int, chunk: int, interpret: bool):
     profile's family is that name)."""
     cs = chunk_cumsum(dt, A, chunk)
     return _kernels(xbc, _rows(dt, heads), _rows(cs, heads), heads, chunk,
-                    interpret)
+                    interpret, groups)
 
 
 def state_bytes(batch: int, length: int, heads: int, state_dim: int,

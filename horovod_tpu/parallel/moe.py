@@ -15,7 +15,8 @@ pairs are sorted by expert, pairs of absent experts last, into a buffer whose
 provable bound is ``T x k`` rows (every token choosing ``k`` held experts
 fills it), and three grouped matrix products (``jax.lax.ragged_dot``, which
 XLA:TPU lowers to its own grouped-matmul kernel; the group sizes are data)
-run over it. The gathers and elementwise passes around those products cost
+run over it: gate, up and down; two where an expert has no gate (``relu(x
+W_up)^2 W_down``). The gathers and elementwise passes around those products cost
 by the buffer's rows and not by the occupied ones, so the step looks at how
 many rows are occupied and runs on the buffer's first ``2 x`` what even
 routing fills where that holds them all, and on the whole bound where it
@@ -26,7 +27,10 @@ scatter-add runs on the device.
 
 Scopes (docs/timeline.md): ``hvd_moe_route``, ``hvd_moe_dispatch`` (sort
 and gather), ``hvd_moe_experts`` (the grouped products), ``hvd_moe_combine``;
-gauges ``hvd.moe.*`` of the program being traced.
+gauges ``hvd.moe.*`` of the program being traced, ``hvd.moe.expert_products``
+among them: the forward grouped products the step's expert layers issue, a
+recomputed layer's twice (the backward pass issues two for each of a
+layer's).
 """
 
 from __future__ import annotations
@@ -141,10 +145,12 @@ def gated_mlp_grouped(rows, sizes, experts: Dict):
     """``(silu(rows W_gate) * (rows W_up)) W_down`` with every row under its
     own expert's matrices: ``experts`` holds ``gate`` and ``up`` ``[held, d,
     f]`` and ``down`` ``[held, f, d]``, ``rows`` lie sorted by expert and
-    ``sizes [held]`` says how many each has. Rows past their sum belong to
-    no expert: they come back as zeros and take no gradient, and every
-    product's operands hold zeros there, so whatever a grouped-product kernel
-    leaves or reads past the last group can reach no result."""
+    ``sizes [held]`` says how many each has. Without ``gate`` the MLP is
+    ``relu(rows W_up)^2 W_down``: two products, not three. Rows past their
+    sum belong to no expert: they come back as zeros and take no gradient,
+    and every product's operands hold zeros there, so whatever a
+    grouped-product kernel leaves or reads past the last group can reach no
+    result."""
     occupied = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
 
     def dot(lhs, rhs):
@@ -153,6 +159,10 @@ def gated_mlp_grouped(rows, sizes, experts: Dict):
                              preferred_element_type=lhs.dtype)
         return jnp.where(occupied, out, 0)
 
+    if "gate" not in experts:
+        up = dot(rows, experts["up"]).astype(jnp.float32)
+        hidden = jnp.square(jax.nn.relu(up))
+        return dot(hidden.astype(rows.dtype), experts["down"])
     gate, up = dot(rows, experts["gate"]), dot(rows, experts["up"])
     hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
     return dot(hidden.astype(rows.dtype), experts["down"])
@@ -198,24 +208,30 @@ def _held_part(n: int, top_k: int, x, experts, order, inverse, sizes,
 
 def routed_experts(x, router_w, experts: Dict, bias=None, *, first=0,
                    top_k: int, route_scale: float = 1.0, dtype=None,
-                   name: str = "") -> Tuple[jax.Array, jax.Array]:
+                   name: str = "", recomputed: bool = False
+                   ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer's result, and the step's counts.
 
     ``x [T, d]``; ``router_w [d, E]`` scores all ``E`` experts; ``experts``
     are the stacked matrices of the ``held`` experts ``first .. first +
-    held - 1`` (:func:`gated_mlp_grouped`); ``bias [E]`` or ``None``. The
+    held - 1`` (:func:`gated_mlp_grouped`: with or without ``gate``);
+    ``bias [E]`` or ``None``. The
     router reads ``x`` as it is given (float32 from a norm); the experts'
     products run in ``dtype`` (default: ``x``'s), which ``y`` comes back in.
+    ``recomputed`` says that the backward pass runs the layer's forward
+    again (the gauge ``hvd.moe.expert_products`` counts its products twice).
     Returns ``(y [T, d], counts [E])``: ``y = sum over chosen experts held
     here of w_e MLP_e(x)``, exact for any routing (module docstring), and
     how many tokens chose each of the ``E``."""
     tokens, _ = x.shape
     dtype = dtype or x.dtype
-    held, scored = experts["gate"].shape[0], router_w.shape[-1]
+    held, scored = experts["up"].shape[0], router_w.shape[-1]
     bound = tokens * top_k
     expected = bound * held / scored
     cuts = buffer_sizes(bound, expected)
-    _record(name, experts=scored, experts_held=held, top_k=top_k,
+    products = (3 if "gate" in experts else 2) * (1 + recomputed)
+    _record(name, products, experts=scored,
+            experts_held=held, top_k=top_k,
             tokens=tokens, row_bound=bound, expected_rows=expected,
             cut_rows=cuts[0])
     with jax.named_scope(timeline.MOE_ROUTE):
@@ -242,22 +258,27 @@ def routed_experts(x, router_w, experts: Dict, bias=None, *, first=0,
 
 # The layers of the program being traced, as gauges keyed by that program
 # (the ``program`` of the ``hvd.spmd.dispatch`` span whose call traces it,
-# as the gradient exchange's are): program -> (id of that span, layer
-# names). Block recomputation traces a layer more than once, a re-trace
-# starts anew, so layers are told apart by name.
+# as the gradient exchange's are): program -> (id of that span, {layer name:
+# its grouped products}). Block recomputation traces a layer more than once,
+# a re-trace starts anew, so layers are told apart by name.
 _traced: dict = {}
 
 
-def _record(name: str, **sizes) -> None:
-    """``hvd.moe.layers`` (expert layers in the step's program) and, of one
-    layer: ``.experts`` (scored), ``.experts_held``, ``.top_k``, ``.tokens``
-    (a step, on this chip), ``.row_bound`` (rows of the sorted buffer: what
-    never dropping is sized for), ``.expected_rows`` (rows a step under even
-    routing: ``tokens x top_k x held / experts``) and ``.cut_rows`` (rows
-    the layer's passes run over while the occupied ones fit its first
-    cut)."""
-    program, names = timeline.program_tally(_traced, set)
-    names.add(name)
-    timeline.gauge("hvd.moe.layers", len(names), key=program)
+def _record(name: str, products: int, **sizes) -> None:
+    """``hvd.moe.layers`` (expert layers in the step's program),
+    ``hvd.moe.expert_products`` (the forward grouped products a step
+    issues, each layer once however often it is traced: 3 a gated layer, 2
+    one without a gate, twice that where the backward pass recomputes the
+    layer) and, of one layer: ``.experts`` (scored),
+    ``.experts_held``, ``.top_k``, ``.tokens`` (a step, on this chip),
+    ``.row_bound`` (rows of the sorted buffer: what never dropping is sized
+    for), ``.expected_rows`` (rows a step under even routing: ``tokens x
+    top_k x held / experts``) and ``.cut_rows`` (rows the layer's passes run
+    over while the occupied ones fit its first cut)."""
+    program, layers = timeline.program_tally(_traced, dict)
+    layers[name] = products
+    timeline.gauge("hvd.moe.layers", len(layers), key=program)
+    timeline.gauge("hvd.moe.expert_products", sum(layers.values()),
+                   key=program)
     for what, value in sizes.items():
         timeline.gauge("hvd.moe." + what, value, key=program)

@@ -453,6 +453,80 @@ def test_a_kept_block_compiles_and_holds_what_the_plan_reckons(
     assert 0.9 * counted <= reckoned <= 1.3 * counted, (reckoned, counted)
 
 
+NEMOTRON = dict(
+    heads=32, kv_heads=2, head_dim=128, window=0, dense_layers=0,
+    dense_width=1856, experts=128, experts_held=8, top_k=6,
+    expert_width=1856, shared_experts=2, route_scale=2.5,
+    expert_gated=False, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+    ssm_groups=8, ssm_chunk=128, ssm_impl="pallas", qk_norm=False,
+    attn_gate=False, embed_scale=False, layer_types=())
+
+
+@pytest.mark.parametrize("pattern, reckoned, low, high", [
+    ("MM", 1_126_170_624, 0.85, 1.0),       # 1.05 GiB; read at 0.91
+    ("**", 729_808_896, 1.35, 1.55),        # 0.68 GiB; read at 1.45
+    ("EE", 324_534_272, 0.85, 1.05),        # 0.30 GiB; read at 0.94
+], ids=["mamba", "attention", "experts"])
+def test_a_kept_single_branch_layer_holds_what_the_plan_reckons(
+        topo, pattern, reckoned, low, high):
+    """Two single-branch layers of one kind (``MixerBlock``) at
+    Nemotron-3-Nano's widths over the cell's 2 sequences of 8,192: what the
+    compiler counts for keeping the first as well as the last is what
+    ``MixerBlock.kept_bytes`` reckons for it, to the factors read (a Mamba
+    layer 0.91, an expert layer 0.94; the attention layer 1.45, its
+    log-sum-exp reckoned at 128 lanes a head as the two-branch blocks'
+    is, which the compiler does not hold so in this call: the plan
+    recomputes more than it must there, never less); a Mamba layer's scan
+    runs its forward kernel three times and not four where the first is
+    recomputed."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu import models
+    from horovod_tpu.ops import attention, ssd
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def compiled(remat):
+        model = models.build(
+            "moe_lm", vocab_size=256, embed_dim=2688, attention="flash",
+            remat=remat, pattern=pattern, **NEMOTRON)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+
+        def loss(params, buffers, tokens):
+            hidden = model.apply({"params": params, **buffers}, tokens,
+                                 return_hidden=True)
+            return jnp.mean(jnp.square(hidden))
+
+        buffers = {k: v for k, v in variables.items() if k == "buffers"}
+        program = jax.jit(jax.grad(loss)).lower(
+            variables["params"], buffers, tokens).compile()
+        calls = sum("custom-call(" in line and f"%{ssd.KERNEL}." in line
+                    for line in program.as_text().splitlines())
+        return model, program.memory_analysis().temp_size_in_bytes, calls
+
+    real = attention.pallas_interpret, ssd.pallas_interpret
+    attention.pallas_interpret = ssd.pallas_interpret = lambda: False
+    try:
+        model, one_kept, calls = compiled(1)
+        if pattern == "MM":
+            assert calls == 3 + 2           # two forward, one run again
+        _, both_kept, calls = compiled(0)
+        if pattern == "MM":
+            assert calls == 2 + 2
+    finally:
+        attention.pallas_interpret, ssd.pallas_interpret = real
+    assert model.block(0).kept_bytes(2 * 8192, 2688) == reckoned
+    counted = both_kept - one_kept
+    assert low * counted <= reckoned <= high * counted, (reckoned, counted)
+
+
 def test_rehearsal_passes():
     proc = _run("chip_smoke.py", "--rehearsal", timeout=1500)
     assert proc.returncode == 0, proc.stderr[-3000:]

@@ -159,6 +159,10 @@ def _toy(argv):
         else "resnet50"
     toy = TOY[family]
     small = dict(zip(toy[::2], toy[1::2]))
+    if "--lm-pattern" in argv:
+        # a pattern names the layers: the toy keeps the cell's, and its depth
+        small.pop("--lm-layer-types")
+        small["--lm-layers"] = str(len(argv[argv.index("--lm-pattern") + 1]))
     out, i = [], 0
     while i < len(argv):
         flag = argv[i]

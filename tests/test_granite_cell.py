@@ -120,7 +120,7 @@ def test_manifest_is_well_formed_and_names_both_cells():
         text = f.read()
     manifest = json.loads(text)
     assert check_manifest.check(manifest, REPO, len(text.encode())) == []
-    assert len(manifest["workloads"]) == 8
+    assert len(manifest["workloads"]) >= 8
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
     manifest, cell, config = run.load_cell(CELL_NAME)
     assert cell["chips"] == 1 and cell["bench_args"] == [

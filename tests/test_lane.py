@@ -101,7 +101,9 @@ def test_removed_arguments_are_usage_errors(bench, capsys):
             parser.parse_args(argv)
         assert stop.value.code == 2, argv
         assert "unrecognized arguments" in capsys.readouterr().err, argv
-    assert len(parser._actions) - 1 == 47           # less --help
+    # less --help; --lm-pattern, --ssm-groups and --moe-act since
+    # Nemotron-3-Nano's cell
+    assert len(parser._actions) - 1 == 50
 
 
 def _spans(name=None):

@@ -1,6 +1,7 @@
 """Mamba-2's chunked scan (``horovod_tpu/ops/ssd.py``) at a small size on
 the CPU: both implementations (the Pallas kernels interpreted) against the
-recurrence token by token, the definition, in float32.
+recurrence token by token, the definition, in float32, with B and C in one
+group or several.
 
 Tolerances and why: the chunked form adds the same terms in another order
 (a chunk's products, then the carried state) and its decays are
@@ -76,6 +77,41 @@ def test_the_scan_is_the_recurrence_forward_and_backward(impl, length):
         _close(g, r, 2e-5, name)
 
 
+@pytest.mark.parametrize("groups", [1, 8])
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_the_kernels_take_groups_at_the_cells_chunks(groups, chunk):
+    """Heads of 64 reading B and C in one group (4 heads) or in eight (32
+    heads: two programs of two heads a group, so a group's gradient of ``G``
+    is summed over its pairs, as over Nemotron-3-Nano's four), two chunks of
+    128 (Nemotron-3-Nano's) or of 256 (Granite's), the conv's packed output:
+    the kernels, interpreted, against the chunked oracle and the
+    recurrence, y and every gradient."""
+    args, w = operands(2 * chunk, heads=4 if groups == 1 else 32,
+                       groups=groups, seed=groups)
+    x, dt, A, B, C, D = args
+    length = 2 * chunk
+
+    def packed(x, dt, A, B, C, D, impl):
+        xbc = jnp.concatenate([x.reshape(1, length, -1),
+                               B.reshape(1, length, -1),
+                               C.reshape(1, length, -1)], -1)
+        y = ssd_op.ssd(xbc, dt, A, D=D, state_dim=128, groups=groups,
+                       chunk=chunk, impl=impl)
+        return y.reshape(x.shape)
+
+    want = recurrence(*args)
+    for impl in ("pallas", "chunked"):
+        _close(packed(*args, impl), want, 2e-5, impl)
+    got, oracle, truth = (
+        jax.grad(lambda *a: jnp.sum(f(*a) * w), range(6))(*args)
+        for f in (lambda *a: packed(*a, "pallas"),
+                  lambda *a: packed(*a, "chunked"), recurrence))
+    for name, g, o, r in zip(("x", "dt", "A", "B", "C", "D"), got, oracle,
+                             truth):
+        _close(g, o, 2e-5, name)
+        _close(g, r, 2e-5, name)
+
+
 def test_the_chunked_path_takes_several_groups():
     args, _ = operands(32, heads=4, p=8, n=16, groups=2)
     _close(ssd_op.ssd(*args, chunk=8, impl="chunked"), recurrence(*args),
@@ -128,6 +164,10 @@ def test_the_kernels_in_bfloat16_follow_the_float32_path():
 def test_the_kernels_refuse_what_they_are_not_written_for():
     args, _ = operands(32, p=32)
     with pytest.raises(ValueError, match="heads of 64"):
+        ssd_op.ssd(*args, chunk=16, impl="pallas")
+    # a group's heads have to make whole pairs
+    args, _ = operands(32, heads=4, groups=4)
+    with pytest.raises(ValueError, match="an even number of them a group"):
         ssd_op.ssd(*args, chunk=16, impl="pallas")
     with pytest.raises(ValueError, match="impl"):
         ssd_op.ssd(*args, chunk=16, impl="dense")
